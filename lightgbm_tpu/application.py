@@ -375,6 +375,8 @@ def _iter_predict_chunks(data_path: str, has_header: bool, label_idx: int,
 
 def main(argv: Optional[List[str]] = None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    from .jaxutil import enable_compile_cache
+    enable_compile_cache()
     try:
         Application(argv).run()
     except LightGBMError as e:

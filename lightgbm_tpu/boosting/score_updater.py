@@ -167,27 +167,32 @@ def _add_raw(score, raw):
     return score + raw
 
 
-@jax.jit
-def _add_from_leaf(score_row, leaf_idx, leaf_values):
+@functools.partial(jax.jit, static_argnames=("tree_id", "spmd"))
+def _add_leaf_to_row_jit(score, leaf_id, leaf_values, *, tree_id: int,
+                         spmd: bool):
     # one-hot matmul, not table gather: XLA's [N] gather from a leaf-sized
     # table runs at <1 GB/s on TPU (see ops/lookup.py) and cost ~65 ms per
     # iteration at N=4M; the matmul is exact for f32 leaf values
-    val = table_lookup(leaf_values[None], leaf_idx,
-                       num_slots=leaf_values.shape[0])[0]
-    return score_row + val
+    lv = leaf_values.astype(jnp.float32)
+    val = table_lookup(lv[None], leaf_id, num_slots=lv.shape[0],
+                       spmd=spmd)[0]
+    return score.at[tree_id].set(score[tree_id] + val)
 
 
-@functools.partial(jax.jit, static_argnames=("tree_id",))
 def _add_leaf_to_row(score, leaf_id, leaf_values, *, tree_id: int):
     """score[tree_id] += leaf_values[leaf_id], all inside ONE program.
     Eager `score[tree_id]` / `score.at[tree_id].set(...)` lower to
     dynamic_slice/scatter whose start index is uploaded host→device on
     every call — one implicit transfer per boosting iteration under the
     sanitizer's guard; a STATIC tree_id is a trace constant (the jit
-    cache holds K entries, K = trees per iteration)."""
-    val = _add_from_leaf(score[tree_id], leaf_id,
-                         leaf_values.astype(jnp.float32))
-    return score.at[tree_id].set(val)
+    cache holds K entries, K = trees per iteration).
+
+    A data-parallel learner hands back a `leaf_id` sharded over its
+    mesh; this program runs under plain jit, so the lookup must then be
+    one XLA can partition (table_lookup spmd=True)."""
+    spmd = len(leaf_id.sharding.device_set) > 1
+    return _add_leaf_to_row_jit(score, leaf_id, leaf_values,
+                                tree_id=tree_id, spmd=spmd)
 
 
 @functools.partial(jax.jit, static_argnames=("tree_id",))

@@ -80,7 +80,7 @@ device.  These rules lean on the same traced-region call graph:
 Traced-region discovery: jit roots are ``@jax.jit`` /
 ``functools.partial(jax.jit, static_argnames=...)`` decorators,
 ``jax.jit(f)`` / ``jax.jit(functools.partial(f, ...))`` /
-``jax.jit(compat_shard_map(f, ...))`` call sites, and bodies handed to
+``jax.jit(jax.shard_map(f, ...))`` call sites, and bodies handed to
 ``lax.{fori_loop,while_loop,scan,cond,switch}`` / ``jax.vmap`` /
 ``shard_map`` (lax control flow traces its body even outside jit).
 Reachability then propagates through same-package calls (local names,
@@ -159,7 +159,6 @@ _TRACE_WRAPPER_FN_ARGS = {
     "checkpoint": (0,),
     "remat": (0,),
     "shard_map": (0,),
-    "compat_shard_map": (0,),
 }
 
 
@@ -660,7 +659,7 @@ class Package:
                              tracer_params=False)
 
         # shard_map reachability (shardlint): the bodies handed to
-        # shard_map / compat_shard_map, then everything they call
+        # shard_map, then everything they call
         # (including lax control-flow bodies and partial aliases) — the
         # region where mesh axes are bound and collectives are legal
         smap_work: List[FuncInfo] = []
@@ -675,8 +674,7 @@ class Package:
                 if not isinstance(node, ast.Call):
                     continue
                 chain = _attr_chain(node.func)
-                if chain and chain[-1] in ("shard_map", "compat_shard_map") \
-                        and node.args:
+                if chain and chain[-1] == "shard_map" and node.args:
                     for fn, _extra in self._fn_refs(mi, node.args[0]):
                         mark_smap(fn)
         seen_s: Set[Tuple[str, str]] = set()
@@ -728,7 +726,7 @@ class Package:
                 name = aname
             yield self.resolve(mi.name, name), bound
             return
-        if isinstance(expr, ast.Call):     # jit(compat_shard_map(fn, ...))
+        if isinstance(expr, ast.Call):     # jit(shard_map(fn, ...))
             chain = _attr_chain(expr.func)
             if chain and chain[-1] in _TRACE_WRAPPER_FN_ARGS and expr.args:
                 yield from self._fn_refs(mi, expr.args[0])

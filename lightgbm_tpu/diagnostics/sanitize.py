@@ -108,8 +108,6 @@ def transfer_guard_effective() -> bool:
     op whose scalar operand must be uploaded)."""
     import jax
     import jax.numpy as jnp
-    if not hasattr(jax, "transfer_guard"):
-        return False
     x = jnp.zeros(2)            # committed before the guard
     try:
         with jax.transfer_guard("disallow"):
@@ -365,21 +363,16 @@ class HotPathSanitizer:
         attribute compile events to retraces."""
         import jax
         before = self._handler.count
-        guarded = (self.steps >= self.warmup
-                   and hasattr(jax, "transfer_guard"))
+        guarded = self.steps >= self.warmup
         try:
             with contextlib.ExitStack() as stack:
                 if guarded:
-                    if hasattr(jax, "transfer_guard_host_to_device"):
-                        stack.enter_context(
-                            jax.transfer_guard_host_to_device(self.guard))
-                        stack.enter_context(
-                            jax.transfer_guard_device_to_host(self.guard))
-                        stack.enter_context(
-                            jax.transfer_guard_device_to_device(
-                                self.d2d_guard))
-                    else:       # older jax: one knob for all directions
-                        stack.enter_context(jax.transfer_guard(self.guard))
+                    stack.enter_context(
+                        jax.transfer_guard_host_to_device(self.guard))
+                    stack.enter_context(
+                        jax.transfer_guard_device_to_host(self.guard))
+                    stack.enter_context(
+                        jax.transfer_guard_device_to_device(self.d2d_guard))
                 yield
         except Exception as e:   # noqa: BLE001 — classify, then re-raise
             if guarded and _is_transfer_guard_error(e):

@@ -14,9 +14,40 @@ learner/common.py exists for the split-search setup).
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.  Where JAX_COMPILATION_CACHE_DIR is set, JAX
+    itself reads it as the default of `jax_compilation_cache_dir`, so
+    nothing is set here: the cache is placed from outside.  Otherwise
+    it goes to `<checkout>/.jax_cache` — a fixed path, because the path
+    is part of what a later process must repeat to find the entries."""
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not d:
+        d = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", d)
+    return d
+
+
+def require_accelerator() -> dict:
+    """The device a measurement runs on, as JAX reports it — or
+    SystemExit when JAX found only the CPU.  Every script that writes a
+    time under a device metric's name calls this before it times
+    anything: a CPU number under such a name is worse than no number."""
+    devs = jax.devices()
+    d = devs[0]
+    if d.platform == "cpu":
+        raise SystemExit(
+            "no accelerator: JAX reports platform 'cpu'; this script "
+            "measures the chip and does not fall back")
+    return {"platform": d.platform, "kind": d.device_kind,
+            "count": len(devs)}
 
 
 @functools.partial(jax.jit, static_argnames=("pad",))

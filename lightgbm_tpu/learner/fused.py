@@ -674,8 +674,7 @@ class FusedTreeLearner:
             in_specs = (bins_spec, P(da), P(da), P(da), P(fa), P(fa), P(fa))
             out_specs = (jax.tree_util.tree_map(lambda _: P(), TreeArrays(
                 *[0] * len(TreeArrays._fields))), P(da))
-            from ..sharded.mesh import compat_shard_map
-            self._build = jax.jit(compat_shard_map(
+            self._build = jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False))
             if self.mh is not None:

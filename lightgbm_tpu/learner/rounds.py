@@ -48,7 +48,8 @@ from ..dataset import Dataset
 from ..sharded.mesh import (check_scatter_divisible, check_tree_divergence,
                             mesh_axes, pad_cols_to_ndev,
                             resolve_hist_exchange)
-from .common import (gather_capacity_tiers, gather_scratch_capacity,
+from .common import (CPU_TIER_BYTES_LIMIT, device_bytes_limit,
+                     gather_capacity_tiers, gather_scratch_capacity,
                      make_split_kw, padded_bin_count, resolve_hist_rows,
                      sentinel_bins_t, use_parent_hist_cache)
 from .fused import TreeArrays, tree_arrays_to_host
@@ -85,9 +86,10 @@ import os as _os
 
 def _clamp_k(v: int) -> int:
     """Clamp to [1, 336]: 3K is the matmul M dim and the masked kernel's
-    VMEM vals block is [3K, chunk] — 336 (M=1024) is a safe ceiling well
-    past any profitable K (the chunk cap in ops/histogram.py shrinks the
-    row chunk to keep the block inside VMEM)."""
+    VMEM vals block is [3K, chunk].  Past K=84 the row chunk shrinks in
+    proportion (ops/histogram._masked_chunk); the TPU compiler accepts
+    every K up to 336 with int32 bins, and none past 84 with int8-stored
+    bins, whose [32, 3K, 128] output block outgrows the VMEM scope."""
     c = max(1, min(v, 336))
     if c != v:
         from .. import log
@@ -337,8 +339,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 input_dtype=input_dtype)
         return hist_multileaf_masked(
             binsf, lid_, gh8, sl_, num_bins_padded=B, backend=backend,
-            input_dtype=input_dtype, max_num_bin=max_num_bin,
-            num_leaves=L)
+            input_dtype=input_dtype, max_num_bin=max_num_bin)
 
     def find_best_batch(hists, sums):
         """hists [K2, C, 3, B] reduced STORE histograms (C = F, or this
@@ -606,8 +607,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         # M dimension for mostly-empty slots — tiered kernels cut the
         # early rounds' MXU work: a chunk with <= 8 active slots runs
         # the K=8 kernel (rounds 1-4 of a balanced tree), <= 32 the
-        # K=32 kernel (rounds 5-6; the matmul is ~62% of the pass once
-        # the compares are narrow, so Mp 256->96 matters), else full K.
+        # K=32 kernel (rounds 5-6), else full K.
         # Results are zero-padded to Kc — inactive slots are dropped
         # downstream, so the padding rows are never read.
         K_SMALL = min(8, K)
@@ -623,7 +623,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 return hist_multileaf_masked(
                     binsf, leaf_id2, gh8, slv_k, num_bins_padded=B,
                     backend=backend, input_dtype=input_dtype,
-                    max_num_bin=max_num_bin, num_leaves=L)
+                    max_num_bin=max_num_bin)
 
             def at(Kt):
                 h = full_call(slv[:Kt])
@@ -975,7 +975,7 @@ class RoundsTreeLearner:
                 self.bins_dev = jnp.asarray(bins_np)
         else:
             from jax.sharding import PartitionSpec as P, NamedSharding
-            from ..sharded.mesh import compat_shard_map, row_shard_axes
+            from ..sharded.mesh import row_shard_axes
             fn = functools.partial(
                 build_tree_rounds, **kw,
                 data_axis="data" if self.dd > 1 else None,
@@ -990,7 +990,7 @@ class RoundsTreeLearner:
             in_specs = (bins_spec, P(da), P(da), P(da), P(), P(), P())
             out_specs = (jax.tree_util.tree_map(lambda _: P(), TreeArrays(
                 *[0] * len(TreeArrays._fields))), P(da), P())
-            self._build = jax.jit(compat_shard_map(
+            self._build = jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False))
             if self.mh is not None:
@@ -1054,11 +1054,7 @@ class RoundsTreeLearner:
         # bins shard along the data axis: the pressure that matters is
         # the PER-DEVICE share of the int32 STORE layout
         int32_bytes = 4.0 * self.Cstore * self.Np / max(self.dd * self.df, 1)
-        try:
-            stats = jax.local_devices()[0].memory_stats()
-            limit = float(stats.get("bytes_limit", 0)) or 16e9
-        except Exception:
-            limit = 16e9
+        limit = device_bytes_limit() or CPU_TIER_BYTES_LIMIT
         return int32_bytes > 0.25 * limit
 
     @property

@@ -83,80 +83,19 @@ def hist_xla(gb: jax.Array, vals: jax.Array, *, num_bins_padded: int,
 
 FEATURE_GROUP = 8  # features per kernel block (TPU second-minor tiling)
 
-
-def _feature_group_from_env() -> int:
-    """LGBT_FEATURE_GROUP overrides the int32-bin feature-block height
-    for on-chip tuning (wide-feature shapes recompute the [Mp, Ck] vals
-    block once per feature block — a taller block amortizes that over
-    more features at the cost of more VMEM per grid cell).  Clamped to
-    a multiple of 8 in [8, 64]."""
-    try:
-        v = int(_os.environ.get("LGBT_FEATURE_GROUP", "") or FEATURE_GROUP)
-    except ValueError:
-        return FEATURE_GROUP
-    return max(8, min(64, (v // 8) * 8))
-
-# Row-chunk length per pallas grid cell.  Larger chunks amortize grid
-# overhead; VMEM per cell stays small (one-hot [CK, B] + vals [M, CK]).
-# Env-tunable for on-chip experiments; parsed defensively and rounded to
-# the 128-lane multiple the TPU block tiling requires.
-import os as _os
-
-
-def _hist_chunk_from_env(default: int) -> int:
-    try:
-        v = int(_os.environ.get("LGBT_HIST_CHUNK", "") or default)
-    except ValueError:
-        v = default
-    return max(512, (v // 128) * 128)
-
-
-# The gather-fed kernels keep the conservative chunk (their f32 one-hot
-# transient is 4x the masked kernel's int8 ones); the masked hot-path
-# kernel defaults larger — chip-measured ~6% faster per pass at 8192 —
-# and self-caps by a VMEM model (see hist_multileaf_masked).
-HIST_CHUNK = _hist_chunk_from_env(2048)
-MASKED_HIST_CHUNK = _hist_chunk_from_env(8192)
-
-
-def effective_gather_chunk(num_bins_padded: int,
-                           input_dtype: str = "float32") -> int:
-    """The row-chunk the gather-fed kernels ACTUALLY run (env global +
-    VMEM self-cap) — for artifacts that must record the real
-    configuration, not the env-derived request."""
-    if input_dtype == "int8":
-        input_dtype = "float32"   # gather kernels coerce (_coerce_dtype)
-    isz = jnp.dtype(input_dtype).itemsize
-    return min(HIST_CHUNK, _gather_chunk_cap(num_bins_padded, isz))
+# Row-chunk length per grid cell of the gather-fed kernels (hist_pallas,
+# hist_pallas_multileaf): their f32 one-hot transient is 4x the masked
+# kernel's int8 one, so they keep a short chunk and self-cap by B.
+HIST_CHUNK = 2048
 
 
 def _gather_chunk_cap(B: int, itemsize: int = 4) -> int:
     """VMEM self-cap for the gather-fed kernels' one-hot transient
-    ([Ck, B] in the compute dtype): LGBT_HIST_CHUNK drives both chunk
-    globals, so a masked-kernel sweep value (e.g. 16384) must not hand
-    these kernels a ~16 MB f32 transient.  Budget 4 MB, 128-aligned.
+    ([Ck, B] in the compute dtype).  Budget 4 MB, 128-aligned.
     The floor is one 128-lane tile — a 512-row floor would let padded
-    B >= 2048 blow the stated budget (512*2048*4 = 4.2 MB+); this cap
-    model also sizes the gathered-segment kernel's scratch chunks."""
+    B >= 2048 blow the stated budget (512*2048*4 = 4.2 MB+)."""
     cap = int(4e6) // (itemsize * max(B, 1))
     return max(128, (cap // 128) * 128)
-
-# Narrow-dtype one-hot compare in the masked kernels (int8/bf16 instead
-# of int32 — see _packed_onehot).  Kill-switch for on-chip A/B.
-NARROW_ONEHOT = _os.environ.get("LGBT_NARROW_ONEHOT", "1") != "0"
-
-
-def disable_narrow_onehot():
-    """Runtime fallback if a TPU generation's Mosaic rejects an int8
-    vector op the narrow paths assume: flip the flag AND drop this
-    module's compiled traces (the flag is read at trace time, so a
-    stale cache would keep returning the narrow program).  Callers
-    must rebuild their own jitted closures (e.g. recreate the Booster)."""
-    global NARROW_ONEHOT
-    NARROW_ONEHOT = False
-    hist_multileaf_masked.clear_cache()
-    hist_pallas.clear_cache()
-    hist_pallas_multileaf.clear_cache()
 
 
 def _coerce_dtype(input_dtype: str) -> str:
@@ -364,18 +303,16 @@ def hist_multileaf(gb_t: jax.Array, vals: jax.Array, *, num_bins_padded: int,
 
 
 def _simple_onehot(gb, B, input_dtype):
-    """Unpacked one-hot for the gather-fed kernels: the compare runs in
-    bf16 when the output is bf16 (2x the int32 VPU lane volume; bins
-    <= 255 are bf16-exact — gated on B <= 256), else in int32."""
+    """Unpacked one-hot for the gather-fed kernels.  The compare runs in
+    int32 whatever the matmul operand dtype: the v5e VPU has no int8 or
+    bf16 vector compare (Mosaic: "Target does not support this
+    comparison"), so only the RESULT narrows."""
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1)
-    if input_dtype == jnp.bfloat16 and NARROW_ONEHOT and B <= 256:
-        return (gb.astype(jnp.bfloat16)[:, None]
-                == iota.astype(jnp.bfloat16)).astype(jnp.bfloat16)
     return (gb[:, None] == iota).astype(input_dtype)
 
 
 def _packed_onehot(gb_ref, g_, B, pack, bins_sub, out_dtype,
-                   bin_offset=0, bwin=0, narrow=False):
+                   bin_offset=0, bwin=0):
     """One-hot block for `pack` features sharing the 128 lanes: feature
     s of the pack occupies lanes [s·bins_sub, (s+1)·bins_sub), so ONE
     [M, Ck] @ [Ck, B] matmul histograms all `pack` features — the fix
@@ -389,47 +326,16 @@ def _packed_onehot(gb_ref, g_, B, pack, bins_sub, out_dtype,
 
     bwin: first bin of this grid cell's output window (the bin axis may
     be split across a grid dimension so the per-cell output block stays
-    one 128-lane tile — the full [G, Mp, 256] block double-buffers to
-    16 MB and overflows VMEM on multi-feature-block grids).  B here is
-    the WINDOW width (the out block's lane count), not the full bin
-    count.
+    one 128-lane tile).  B here is the WINDOW width (the out block's
+    lane count), not the full bin count.
 
-    narrow: run the [Ck, B] equality in the NARROWEST dtype holding the
-    bin domain instead of int32.  This compare (plus its cast to the
-    matmul operand dtype) is the dominant per-pass cost at north-star
-    shape — the pass is VPU-bound, not MXU-bound: K=1 costs 207 ms vs
-    214 ms at K=128 (profile_hotpath_measured.json).  int8 tiles are
-    (32, 128) = 4x the int32 lane volume per op, and select replaces
-    the bool→int32→int8 double cast.  Exactness: every shifted operand
-    (bin + s·bins_sub, lane + bwin, both shifted by -128) lies in ONE
-    256-wide window, so mod-256 int8 equality IS value equality — the
-    caller sets narrow only when the full bin count <= 256."""
+    The [Ck, B] equality runs in int32 and only its result narrows to
+    the matmul operand dtype.  A compare in the operand dtype (int8 /
+    bf16 tiles hold 4x / 2x the lanes) does not exist on the v5e VPU:
+    Mosaic refuses `arith.cmpi` on i8 vectors and `arith.cmpf` on bf16
+    ("Target does not support this comparison"), and an i1 mask has no
+    relayout to the (32, 128) int8 tile, hence the i32 hop below."""
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1) + bwin
-    if narrow and out_dtype == jnp.int8:
-        # int8 compare domain: x - 128 for every operand
-        iota8 = (iota - 128).astype(jnp.int8)
-        acc = None
-        for s in range(pack):
-            gb = gb_ref[0, g_ * pack + s, :]
-            if gb.dtype == jnp.int8:
-                # stored value-128 already; the pack shift cannot
-                # overflow: value-128 < bins_sub-128 <= -64, shift <= 96
-                if s:
-                    gb = gb + jnp.int8(s * bins_sub)
-            else:
-                gb = (gb + (s * bins_sub - 128)).astype(jnp.int8)
-            cmp = gb[:, None] == iota8
-            acc = cmp if acc is None else acc | cmp
-        return jnp.where(acc, jnp.int8(1), jnp.int8(0))
-    if narrow and out_dtype == jnp.bfloat16:
-        # bf16 tiles are (16, 128) = 2x int32; bins <= 255 are exact
-        iotab = iota.astype(jnp.bfloat16)
-        acc = None
-        for s in range(pack):
-            gb = gb_ref[0, g_ * pack + s, :].astype(jnp.int32) + bin_offset
-            cmp = (gb + (s * bins_sub)).astype(jnp.bfloat16)[:, None] == iotab
-            acc = cmp if acc is None else acc | cmp
-        return acc.astype(jnp.bfloat16)
     acc = None
     for s in range(pack):
         gb = gb_ref[0, g_ * pack + s, :].astype(jnp.int32) + bin_offset
@@ -443,7 +349,7 @@ def _packed_onehot(gb_ref, g_, B, pack, bins_sub, out_dtype,
 def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
                         B: int, K: int, input_dtype, pack: int = 1,
                         bins_sub: int = 0, bin_offset: int = 0,
-                        windowed: bool = False, narrow: bool = False):
+                        windowed: bool = False):
     """Multi-leaf histogram with the leaf masks built in VMEM.
 
     sl_ref : [Kp, 128] int32 — small-leaf id per slot, replicated across
@@ -495,7 +401,7 @@ def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
     G = gb_ref.shape[1]
     for g_ in range(G // pack):
         oh = _packed_onehot(gb_ref, g_, Bs, pack, bins_sub, input_dtype,
-                            bin_offset, bwin, narrow)
+                            bin_offset, bwin)
         out_ref[0, g_, :, :] += jnp.dot(
             vals, oh, preferred_element_type=jnp.float32, precision=prec)
 
@@ -503,8 +409,7 @@ def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
 def _hist_kernel_masked_q(sl_ref, gb_ref, lid_ref, ghq_ref, out_ref, *,
                           B: int, K: int, pack: int = 1,
                           bins_sub: int = 0, bin_offset: int = 0,
-                          windowed: bool = False, narrow: bool = False,
-                          narrow_lid: bool = False):
+                          windowed: bool = False):
     """int8-quantized variant of _hist_kernel_masked: vals and one-hot
     are int8 and the contraction accumulates exactly in int32 (v5e runs
     int8 MXU matmuls at 2x bf16 throughput).  ghq rows are pre-quantized
@@ -529,43 +434,24 @@ def _hist_kernel_masked_q(sl_ref, gb_ref, lid_ref, ghq_ref, out_ref, *,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     Mp = out_ref.shape[2]
-    if narrow_lid:
-        # leaf-id compare and mask-select natively in int8 ((32, 128)
-        # VPU tiles = 4x the int32 lane volume; a where replaces the
-        # int32 multiply + narrowing cast).  Exact while leaf ids fit
-        # one 256-window after the -128 shift: the caller gates on
-        # num_leaves <= 255, so live ids map to [-128, 126] and the
-        # empty-slot sentinel -1 wraps to 127, which no live id takes.
-        # Padded rows (lid sentinel -2 wraps to 126 = id 254's code)
-        # carry all-zero ghq rows, so an aliased mask hit contributes 0.
-        lid8 = (lid_ref[0, :] - 128).astype(jnp.int8)
-        sl8 = (sl_ref[:K, 0:1] - 128).astype(jnp.int8)
-        cmp = lid8[None, :] == sl8                       # [K, Ck]
-        z = jnp.int8(0)
-        parts = [jnp.where(cmp, ghq_ref[r:r + 1, :].astype(jnp.int8), z)
-                 for r in range(3)]
-        if Mp > 3 * K:
-            parts.append(jnp.zeros((Mp - 3 * K, cmp.shape[1]), jnp.int8))
-        vals = jnp.concatenate(parts, axis=0)            # [Mp, Ck] int8
-    else:
-        lid = lid_ref[0, :]
-        sl = sl_ref[:K, 0:1]
-        # elementwise mask work stays in i32 (Mosaic has neither int8
-        # 'arith.muli' nor an i1->(32,128)-tile relayout on this target);
-        # only the matmul OPERANDS are int8 — that is where the 2x
-        # throughput lives, and i32->i8 truncation is a supported cast
-        m = (lid[None, :] == sl).astype(jnp.int32)       # [K, Ck]
-        vals32 = jnp.concatenate([m * ghq_ref[0:1, :], m * ghq_ref[1:2, :],
-                                  m * ghq_ref[2:3, :]], axis=0)  # [3K, Ck]
-        if Mp > 3 * K:
-            vals32 = jnp.concatenate(
-                [vals32, jnp.zeros((Mp - 3 * K, vals32.shape[1]),
-                                   jnp.int32)], axis=0)
-        vals = vals32.astype(jnp.int8)
+    lid = lid_ref[0, :]
+    sl = sl_ref[:K, 0:1]
+    # elementwise mask work stays in i32 (Mosaic has neither int8
+    # 'arith.muli' nor an i1->(32,128)-tile relayout on this target);
+    # only the matmul OPERANDS are int8 — that is where the 2x
+    # throughput lives, and i32->i8 truncation is a supported cast
+    m = (lid[None, :] == sl).astype(jnp.int32)       # [K, Ck]
+    vals32 = jnp.concatenate([m * ghq_ref[0:1, :], m * ghq_ref[1:2, :],
+                              m * ghq_ref[2:3, :]], axis=0)  # [3K, Ck]
+    if Mp > 3 * K:
+        vals32 = jnp.concatenate(
+            [vals32, jnp.zeros((Mp - 3 * K, vals32.shape[1]),
+                               jnp.int32)], axis=0)
+    vals = vals32.astype(jnp.int8)
     G = gb_ref.shape[1]
     for g_ in range(G // pack):
         oh = _packed_onehot(gb_ref, g_, Bs, pack, bins_sub, jnp.int8,
-                            bin_offset, bwin, narrow)
+                            bin_offset, bwin)
         out_ref[0, g_, :, :] += jnp.dot(
             vals, oh, preferred_element_type=jnp.int32)
 
@@ -580,6 +466,36 @@ def _quantize_gh(gh8):
         jnp.round(gh8[0:1] / sg), jnp.round(gh8[1:2] / sh), gh8[2:3],
         jnp.zeros_like(gh8[3:])], axis=0).astype(jnp.int32)
     return ghq, sg, sh
+
+
+# Row-chunk length per grid cell of the masked kernels at the reference
+# block (Mp <= 256 value rows, <= 256 output lanes), keyed by (bin
+# storage itemsize, float32 operands?).  These are not a model of the
+# transients: Mosaic's scheduler decides how many of the G unrolled
+# [Ck, Bs] one-hots and how much of the [Mp, Ck] vals block are live at
+# once, and what it asks of the 16 MB VMEM scope is neither linear nor
+# monotone in K (int8 bins, int8 operands: 46.6 MB at K=8 but < 16 MB at
+# K=84, both at Ck=8192; float32 at K=84 needs 4.4 KB per chunk row,
+# three bf16 passes per operand).  Each entry is the largest power of
+# two that the TPU compiler accepts for a v5e at EVERY tier the rounds
+# learner runs (K = 1, 3, 8, 32, 84; B = 256) — tests/test_tpu_compile.py
+# holds the main-path cases, so a change here is checked without a chip.
+_MASKED_CHUNK = {
+    (4, False): 8192,   # int32 bins, bf16 / int8 operands
+    (4, True): 2048,    # int32 bins, float32 operands
+    (1, False): 2048,   # int8 bins (G = 32), bf16 / int8 operands
+    (1, True): 1024,    # int8 bins (G = 32), float32 operands
+}
+
+
+def _masked_chunk(Mp: int, bins_itemsize: int, input_dtype: str) -> int:
+    """Rows per grid cell of the masked kernels: the compile-validated
+    base, shrunk in proportion when the value-row block is taller than
+    the reference 256 (LGBT_LEAVES_PER_BATCH > 84)."""
+    ck = _MASKED_CHUNK[(bins_itemsize, input_dtype == "float32")]
+    if Mp > 256:
+        ck = ck * 256 // Mp
+    return max(128, ck // 128 * 128)
 
 
 def packed_bins_layout(max_num_bin: int, num_bins_padded: int):
@@ -598,14 +514,13 @@ def packed_bins_layout(max_num_bin: int, num_bins_padded: int):
 
 @functools.partial(jax.jit, static_argnames=("num_bins_padded", "backend",
                                              "input_dtype", "interpret",
-                                             "max_num_bin", "num_leaves"))
+                                             "max_num_bin"))
 def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
                           sl: jax.Array, *, num_bins_padded: int,
                           backend: str = "xla",
                           input_dtype: str = "float32",
                           interpret: bool = False,
-                          max_num_bin: int = 0,
-                          num_leaves: int = 0) -> jax.Array:
+                          max_num_bin: int = 0) -> jax.Array:
     """Histogram K leaves in one pass, masks built on the fly.
 
     gb_t: [F, C] int bins; lid: [C] int32 leaf ids; gh8: [8, C] f32
@@ -614,12 +529,6 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
 
     max_num_bin (static; 0 = unknown) enables feature packing on the
     pallas path when all bins fit a 16/32/64-lane sub-block.
-
-    num_leaves (static; 0 = unknown): the leaf COUNT — an EXCLUSIVE
-    bound on leaf ids (ids < num_leaves; an id equal to num_leaves=255
-    would wrap onto the empty-slot sentinel).  When <= 255 the
-    quantized kernel runs the leaf-id mask compare in int8 (see
-    _hist_kernel_masked_q narrow_lid).
 
     input_dtype "int8" (the validated bench default) selects per-pass symmetric
     gradient quantization with exact int32 accumulation: counts are
@@ -670,33 +579,22 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
                          axis=2).transpose(1, 0, 2, 3)
 
     # int8 bins keep their narrow dtype into the kernel; the int8 VMEM
-    # tile is (32, 128), so the feature-group sublane dim grows to 32.
-    # The int32 path reads LGBT_FEATURE_GROUP (process-start value: the
-    # flag is trace-time, like the narrow-kernel switches)
-    G = 32 if bin_offset else _feature_group_from_env()
-    Ck = min(C, MASKED_HIST_CHUNK)
-    if bin_offset:
-        # the G=32 layout quadruples the per-cell output block
-        # (G·Mp·B·4 at B=256 double-buffers past the 16 MB VMEM scope
-        # with long row chunks); keep the chip-validated chunk
-        Ck = min(Ck, 2048)
-    else:
-        # cap the big per-chunk transients — the [Mp, Ck] vals
-        # intermediate plus the [Ck, B] one-hot — at ~15 MB, the
-        # measured VMEM ceiling: Mp=256/Ck=16384 int32 vals (16.8 MB
-        # alone) OOMs on chip, Mp=384/Ck=8192 (12.6 + 2 MB) fits.  The
-        # narrow-lid quant path never materializes int32 vals (the
-        # where-select emits int8 directly), so its rows are ~4x
-        # cheaper and admit a larger LGBT_HIST_CHUNK.
-        Mp_ = 8 * ((3 * K + 7) // 8)
-        isz = jnp.dtype(input_dtype).itemsize
-        if quant:
-            vals_b = Mp_ * (1 if (NARROW_ONEHOT and 0 < num_leaves <= 255)
-                            else 4)
-            per_row = vals_b + B
-        else:
-            per_row = Mp_ * isz + B * isz
-        Ck = min(Ck, max(512, (int(15e6) // per_row) // 128 * 128))
+    # tile is (32, 128), so the feature-group sublane dim grows to 32
+    G = 32 if bin_offset else FEATURE_GROUP
+    Mp = 8 * ((3 * K + 7) // 8)
+    Kp = 8 * ((K + 7) // 8)
+    bins_sub, pack = packed_bins_layout(max_num_bin, B)
+    Gp = G // pack
+    # bin windows: the output block of one grid cell is at most 256 lanes
+    # wide (128 at G=32), the bin axis beyond that goes over the grid.
+    # The full [1, Gp, Mp, B] f32 block double-buffers to 4 MB at G=8,
+    # Mp=256, B=256 — a quarter of the VMEM scope, and what
+    # _MASKED_CHUNK was validated against; G=32 or B=512 would double
+    # it.  The one-hot compare is redone per window (cheap), the matmul
+    # work is unchanged.
+    Bs = min(B, 128 if (bin_offset or B % 256) else 256)
+    nB = B // Bs
+    Ck = min(C, _masked_chunk(Mp, gb_t.dtype.itemsize, input_dtype))
     if C % Ck:
         pad = Ck - C % Ck
         gb_t = jnp.pad(gb_t, ((0, 0), (0, pad)))
@@ -709,19 +607,8 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
     gb_g = gb_t.reshape(Fg // G, G, C)
     if not bin_offset:
         gb_g = gb_g.astype(jnp.int32)
-    Mp = 8 * ((3 * K + 7) // 8)
-    Kp = 8 * ((K + 7) // 8)
     sl2 = jnp.broadcast_to(jnp.pad(sl, (0, Kp - K),
                                    constant_values=-1)[:, None], (Kp, 128))
-    bins_sub, pack = packed_bins_layout(max_num_bin, B)
-    Gp = G // pack
-    # bin windows: one 128-lane output block per grid cell.  The full
-    # [1, Gp, Mp, 256] block is 8 MB at G=32 and double-buffers to 16 MB
-    # across feature blocks — over the VMEM scope.  Splitting the bin
-    # axis over the grid keeps the block one lane-tile wide; the one-hot
-    # compare is redone per window (cheap), the matmul work is unchanged.
-    nB = B // 128 if (bin_offset and B > 128) else 1
-    Bs = B // nB
     if nB > 1:
         grid = (Fg // G, nB, C // Ck)
         in_specs = [
@@ -756,20 +643,12 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
         h = h.transpose(0, 1, 3, 2, 4).reshape(Fg, Mp, bins_sub)
         return jnp.pad(h, ((0, 0), (0, 0), (0, B - bins_sub)))[:F]
 
-    # narrow compare is exact only while every operand fits one 256-wide
-    # window (see _packed_onehot); B > 256 would alias mod 256.  The
-    # leaf-id compare narrows under the same window argument when the
-    # caller states num_leaves <= 255 (0 = unknown, stay wide).
-    narrow = NARROW_ONEHOT and B <= 256
-    narrow_lid = NARROW_ONEHOT and 0 < num_leaves <= 255
-
     if quant:
         ghq, sg, sh = _quantize_gh(gh8)
         out = pl.pallas_call(
             functools.partial(_hist_kernel_masked_q, B=B, K=K, pack=pack,
                               bins_sub=bins_sub, bin_offset=bin_offset,
-                              windowed=nB > 1, narrow=narrow,
-                              narrow_lid=narrow_lid),
+                              windowed=nB > 1),
             out_shape=jax.ShapeDtypeStruct((Fg // G, Gp, Mp, B), jnp.int32),
             grid=grid,
             in_specs=in_specs,
@@ -785,8 +664,7 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
     out = pl.pallas_call(
         functools.partial(_hist_kernel_masked, B=B, K=K, input_dtype=dt,
                           pack=pack, bins_sub=bins_sub,
-                          bin_offset=bin_offset, windowed=nB > 1,
-                          narrow=narrow),
+                          bin_offset=bin_offset, windowed=nB > 1),
         out_shape=jax.ShapeDtypeStruct((Fg // G, Gp, Mp, B), jnp.float32),
         grid=grid,
         in_specs=in_specs,
@@ -906,14 +784,11 @@ def hist_multileaf_gathered(bins_fn: jax.Array, gh8: jax.Array,
     live = (slot >= 0)
     ghg = jnp.take(gh8, idx, axis=1) * live[None, :].astype(jnp.float32)
     sl = jax.lax.iota(jnp.int32, K)
-    # the in-kernel "leaf" ids are the slot ids, so the narrow-compare
-    # gate is the slot count (exclusive bound on every live lid)
     return hist_multileaf_masked(gbg, slot, ghg, sl,
                                  num_bins_padded=num_bins_padded,
                                  backend=backend, input_dtype=input_dtype,
                                  interpret=interpret,
-                                 max_num_bin=max_num_bin,
-                                 num_leaves=K if K <= 255 else 0)
+                                 max_num_bin=max_num_bin)
 
 
 # ----------------------------------------------------------------------------
@@ -1135,17 +1010,16 @@ def _hist_kernel_sparse(sl_ref, fb_ref, lid_ref, gh_ref, out_ref, *,
     """One (window, entry-chunk) grid cell of the sparse histogram.
 
     sl_ref : [Kp, 128] int32 slot leaf ids (replicated across lanes)
-    fb_ref : [1, Eblk] int32 flat local bin ids (sentinel WB matches
+    fb_ref : [1, 1, Eblk] int32 flat local bin ids (sentinel WB matches
              no lane)
-    lid_ref: [1, Eblk] int32 leaf id of each entry's row
+    lid_ref: [1, 1, Eblk] int32 leaf id of each entry's row
     gh_ref : [1, 8, Eblk] f32 (g·valid, h·valid, valid, pads)
     out_ref: [1, Mp, WB] f32 accumulated across the chunk grid axis
 
     Identical inner shape to _hist_kernel_masked (leaf masks in VMEM,
     one [Mp, Eblk] @ [Eblk, WB] MXU contraction) — only the one-hot
     axis is the window's flat (local column, bin) product.  The compare
-    runs in int32: flat ids reach W*B = 1024, past the int8/bf16 exact
-    windows the narrow dense compares rely on.
+    runs in int32, like every compare on this chip.
     """
     from jax.experimental import pallas as pl
 
@@ -1155,7 +1029,7 @@ def _hist_kernel_sparse(sl_ref, fb_ref, lid_ref, gh_ref, out_ref, *,
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    lid = lid_ref[0, :]                                  # [Eblk]
+    lid = lid_ref[0, 0, :]                               # [Eblk]
     sl = sl_ref[:K, 0:1]                                 # [K, 1]
     m = (lid[None, :] == sl).astype(input_dtype)         # [K, Eblk]
     g = gh_ref[0, 0:1, :].astype(input_dtype)
@@ -1169,7 +1043,7 @@ def _hist_kernel_sparse(sl_ref, fb_ref, lid_ref, gh_ref, out_ref, *,
             axis=0)
     prec = (jax.lax.Precision.HIGHEST if input_dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
-    fb = fb_ref[0, :]
+    fb = fb_ref[0, 0, :]
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, WB), 1)
     oh = (fb[:, None] == iota).astype(input_dtype)       # [Eblk, WB]
     out_ref[0, :, :] += jnp.dot(vals, oh,
@@ -1198,7 +1072,7 @@ def _hist_kernel_sparse_q(sl_ref, fb_ref, lid_ref, gh_ref, out_ref, *,
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    lid = lid_ref[0, :]                                  # [Eblk]
+    lid = lid_ref[0, 0, :]                               # [Eblk]
     sl = sl_ref[:K, 0:1]                                 # [K, 1]
     m = (lid[None, :] == sl).astype(jnp.int32)           # [K, Eblk]
     vals32 = jnp.concatenate([m * gh_ref[0, 0:1, :], m * gh_ref[0, 1:2, :],
@@ -1209,7 +1083,7 @@ def _hist_kernel_sparse_q(sl_ref, fb_ref, lid_ref, gh_ref, out_ref, *,
             [vals32, jnp.zeros((Mp - 3 * K, vals32.shape[1]), jnp.int32)],
             axis=0)
     vals = vals32.astype(jnp.int8)
-    fb = fb_ref[0, :]
+    fb = fb_ref[0, 0, :]
     iota = jax.lax.broadcasted_iota(jnp.int32, (1, WB), 1)
     # flat ids reach W*B = 1024, so the compare runs in int32; only the
     # RESULT narrows to int8 (0/1 — exact)
@@ -1279,13 +1153,15 @@ def hist_sparse_pallas(e_row: jax.Array, e_flat: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((Kp, 128), lambda w, k: (0, 0)),
-            pl.BlockSpec((1, Eblk), lambda w, k: (w, k)),
-            pl.BlockSpec((1, Eblk), lambda w, k: (w, k)),
+            # a singleton second-minor axis: Mosaic wants the last two
+            # block dims (8, 128)-divisible or equal to the array's own
+            pl.BlockSpec((1, 1, Eblk), lambda w, k: (w, 0, k)),
+            pl.BlockSpec((1, 1, Eblk), lambda w, k: (w, 0, k)),
             pl.BlockSpec((1, 8, Eblk), lambda w, k: (w, 0, k)),
         ],
         out_specs=pl.BlockSpec((1, Mp, WB), lambda w, k: (w, 0, 0)),
         interpret=interpret,
-    )(sl2, e_flat, lid_e, ghm)
+    )(sl2, e_flat[:, None, :], lid_e[:, None, :], ghm)
     # [nwin, Mp, W, B] → [nslots, Mp, B] → columns → [K, Cp, 3, B]
     h_slots = (out.reshape(nwin, Mp, W, B).transpose(0, 2, 1, 3)
                .reshape(nwin * W, Mp, B))

@@ -58,20 +58,25 @@ def _lookup_pallas(tables: jax.Array, ids: jax.Array,
     return out[:T, :N]
 
 
-@functools.partial(jax.jit, static_argnames=("num_slots",))
+@functools.partial(jax.jit, static_argnames=("num_slots", "spmd"))
 def table_lookup(tables: jax.Array, ids: jax.Array, *,
-                 num_slots: int) -> jax.Array:
+                 num_slots: int, spmd: bool = False) -> jax.Array:
     """tables [T, S] f32, ids [N] int32 in [0, num_slots) → [T, N] f32.
 
     S must be >= num_slots; slots >= num_slots are never selected (ids
     outside [0, S) select nothing and yield 0.0).  Exact for any f32
     table values (see module docstring).  On TPU the fused pallas path
-    keeps the one-hot in VMEM; the XLA scan is the fallback for huge
-    tables and other backends.
+    keeps the one-hot in VMEM; the XLA scan serves huge tables and
+    other backends.
+
+    spmd=True says that `ids` may be sharded over several devices under
+    plain jit (outside any shard_map): XLA partitions the scan itself,
+    a Mosaic kernel it cannot ("Mosaic kernels cannot be automatically
+    partitioned"), so that caller gets the XLA formulation on TPU too.
     """
     T, S = tables.shape
     N = ids.shape[0]
-    if (jax.default_backend() == "tpu" and S <= 2048
+    if (jax.default_backend() == "tpu" and not spmd and S <= 2048
             and T <= 8 and N >= _PALLAS_CHUNK):
         return _lookup_pallas(tables, ids)
     C = min(_CHUNK, N)

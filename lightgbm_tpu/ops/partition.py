@@ -7,15 +7,14 @@ reference does this as random-access loads per row
 (data_partition.hpp:80-130, dense_bin.hpp:67-120); XLA:TPU expresses it
 as two one-hot matmuls plus elementwise selects (ops/lookup.py), which
 materialize [N, ·] one-hots in HBM — measured 41 ms/round at the
-north-star shape (profile_hotpath_measured.json), a quarter of the
-iteration once the histogram kernels are narrow.
+north-star shape (profile_hotpath_measured.json, older than this
+kernel).
 
 The pallas kernel fuses the whole step in VMEM per row-chunk:
 
 - ONE int8 [8, S] @ [S, Ck] matmul performs ALL table lookups: the
-  slot one-hot is built with the narrow int8 compare (ids - 128, exact
-  while S <= 256 — same window argument as ops/histogram._packed_onehot)
-  and the table rows carry threshold-128, is-cat|default-left flags,
+  slot one-hot (an int32 compare whose 0/1 result narrows to int8;
+  S <= 256) and the table rows carry threshold-128, is-cat|default-left flags,
   new-leaf-128, the in-range window bounds lo-128 / hi-128, and the
   split column as two base-128 digits (c_hi, c_lo), every entry in
   int8 range, each product exact, int32 accumulation of a single
@@ -42,21 +41,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-import os as _os
-
-from .histogram import MASKED_HIST_CHUNK
 from .lookup import table_lookup, select_bin_by_feature
 
-# kill-switch for on-chip A/B: 0 routes every call to the XLA composition
-FUSED_PARTITION = _os.environ.get("LGBT_FUSED_PARTITION", "1") != "0"
-
-
-def disable_fused_partition():
-    """Runtime fallback (see histogram.disable_narrow_onehot): flip the
-    flag and drop compiled traces; callers rebuild their jits."""
-    global FUSED_PARTITION
-    FUSED_PARTITION = False
-    _partition_pallas.clear_cache()
+# row-chunk ceiling per grid cell; the VMEM model in _partition_pallas
+# shrinks it for wide stores
+_PARTITION_CHUNK = 8192
 
 
 def _augment_tbl(tbl: jax.Array) -> jax.Array:
@@ -79,10 +68,11 @@ def _partition_kernel(tbl_ref, gb_ref, lid_ref, out_ref, *, S: int,
     hi1-128, dl); gb_ref [1, F, Ck] int bins (int8 holds value-128 when
     bin_offset); lid_ref/out_ref [1, Ck] int32."""
     lidv = lid_ref[0, :]                                     # [Ck] i32
-    lid8 = (lidv - 128).astype(jnp.int8)
-    iota8 = (jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0)
-             - 128).astype(jnp.int8)
-    oh = jnp.where(iota8 == lid8[None, :], jnp.int8(1), jnp.int8(0))
+    # the slot compare runs in int32 and only its result narrows (via
+    # i32: an i1 mask has no relayout to the int8 tile) — the v5e VPU
+    # has no int8 vector compare
+    iota = jax.lax.broadcasted_iota(jnp.int32, (S, 1), 0)
+    oh = (iota == lidv[None, :]).astype(jnp.int32).astype(jnp.int8)
     r = jnp.dot(tbl_ref[:, :], oh,
                 preferred_element_type=jnp.int32)            # [8, Ck]
     fi = r[0] * 128 + r[1]
@@ -100,8 +90,11 @@ def _partition_kernel(tbl_ref, gb_ref, lid_ref, out_ref, *, S: int,
     # selected bin; padded feature rows are never selected (fi < F)
     vi = jnp.sum(jnp.where(fi[None, :] == iof, gb.astype(jnp.int32), 0),
                  axis=0) + bin_offset                        # [Ck]
-    gl = jnp.where(ci, vi == ti, vi <= ti)
-    gl = jnp.where((vi >= lo) & (vi <= hi1), gl, dl)
+    # mask logic, not selects: a select between two i1 vectors lowers
+    # through i8 and Mosaic has no i8 -> i1 truncation on this target
+    gl = (ci & (vi == ti)) | (~ci & (vi <= ti))
+    inwin = (vi >= lo) & (vi <= hi1)
+    gl = (inwin & gl) | (~inwin & dl)
     out_ref[0, :] = jnp.where((nli > 0) & ~gl, nli, lidv)
 
 
@@ -121,7 +114,7 @@ def _partition_pallas(tbl8, gb_t, lid, *, num_slots: int,
         F = gb_t.shape[0]
     # VMEM model: bins block F*Ck*isz, its int32 widen F*Ck*4, the
     # [S, Ck] one-hot — keep under ~10 MB
-    Ck = min(C, MASKED_HIST_CHUNK)
+    Ck = min(C, _PARTITION_CHUNK)
     per_row = F * (isz + 4) + num_slots
     Ck = min(Ck, max(512, (int(10e6) // per_row) // 128 * 128))
     if C % Ck:
@@ -173,7 +166,7 @@ def partition_rows(bins_fn: jax.Array, leaf_id: jax.Array,
     # ~3.8k int8 / ~2.4k int32 features; larger goes to the XLA path
     isz = jnp.dtype(bins_fn.dtype).itemsize
     f_fits = 512 * (F * (isz + 4) + 256) <= int(10e6)
-    fits = (FUSED_PARTITION and backend == "pallas" and num_slots <= 256
+    fits = (backend == "pallas" and num_slots <= 256
             and 0 < num_bins_padded <= 256 and f_fits)
     if not fits:
         r = table_lookup(tbl, leaf_id, num_slots=num_slots)

@@ -119,6 +119,19 @@ class TreeStack(NamedTuple):
     num_leaves: jax.Array      # [T] int32
 
 
+def threshold_f32(th) -> np.ndarray:
+    """Raw f64 thresholds as the largest f32 NOT ABOVE them.  The device
+    walk compares f32 features with f32 thresholds; with this rounding
+    `x <= t` decides exactly as the f64 host walk (tree.py) does for
+    every f32-representable x.  Round-to-nearest can land above t, and
+    a row with x == f32(t) > t then goes left on the device and right
+    on the host — about one row in a few million at Higgs width."""
+    th = np.asarray(th, np.float64)
+    t32 = th.astype(np.float32)
+    above = t32.astype(np.float64) > th
+    return np.where(above, np.nextafter(t32, np.float32(-np.inf)), t32)
+
+
 def stack_trees(trees, binned: bool) -> TreeStack:
     """Stack host Tree objects into one padded TreeStack (device)."""
     m = max(max(t.max_leaves for t in trees), 2)
@@ -140,7 +153,7 @@ def stack_trees(trees, binned: bool) -> TreeStack:
         sf[i, :k] = (t.split_feature_inner[:k] if binned
                      else t.split_feature[:k])
         th[i, :k] = (t.threshold_in_bin[:k].astype(np.float32) if binned
-                     else t.threshold[:k].astype(np.float32))
+                     else threshold_f32(t.threshold[:k]))
         dc[i, :k] = t.decision_type[:k]
         lc[i, :k] = t.left_child[:k]
         rc[i, :k] = t.right_child[:k]
@@ -364,7 +377,7 @@ def _build_perfect(flat, meta: EnsembleMeta, binned: bool = False
         # binned stacks speak (inner feature, in-bin threshold) — both
         # < 2^24, exact in the f32 lanes
         sf = t.split_feature_inner if binned else t.split_feature
-        th = t.threshold_in_bin if binned else t.threshold
+        th = t.threshold_in_bin if binned else threshold_f32(t.threshold)
         if t.num_leaves < 2:                 # stump: one giant filler
             last[i, :, 2] = last[i, :, 3] = np.float32(t.leaf_value[0])
             continue
@@ -447,7 +460,7 @@ def _fill_stack(flat, m: int, binned: bool):
             nodes[i, :knodes, 2] = dec[:knodes]
         else:
             nodes[i, :knodes, 0] = t.split_feature[:knodes]
-            nodes[i, :knodes, 1] = t.threshold[:knodes].astype(np.float32)
+            nodes[i, :knodes, 1] = threshold_f32(t.threshold[:knodes])
             nodes[i, :knodes, 2] = t.decision_type[:knodes]
         nodes[i, :knodes, 3] = t.left_child[:knodes]
         nodes[i, :knodes, 4] = t.right_child[:knodes]

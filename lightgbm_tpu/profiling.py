@@ -66,9 +66,15 @@ _deferred: Dict[str, object] = {}
 # the replicated tree state) and sanitize/divergences (bitwise
 # mismatches — the hard-fail condition); bench.py and the MULTICHIP
 # dryrun record both beside the retrace/transfer counters.
+#  - HIST_ROWS_DOWNGRADES: learners whose gathered row feed (asked
+#    for, or what `auto` picks on TPU) was refused by the scratch
+#    memory gate and replaced by the masked full stream — a slower
+#    path than the one the run was configured for, so it is counted
+#    (chip_smoke.py asserts 0).
 HIST_ROWS_TOUCHED = "tree/hist_rows_touched"
 HIST_EXCHANGE_BYTES = "tree/hist_exchange_bytes"
 SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
+HIST_ROWS_DOWNGRADES = "tree/hist_rows_downgrades"
 
 # Canonical sparse-store counters (docs/Sparse.md), the nnz-scaling
 # evidence behind the sparse-vs-dense CTR A/B:
@@ -185,7 +191,7 @@ ROUTER_REHASHES = "router/rehashes"
 # sites use the constants instead of re-typing the strings.
 CANONICAL_COUNTERS = (
     HIST_ROWS_TOUCHED, HIST_EXCHANGE_BYTES, SPLIT_RECORDS_BYTES,
-    SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
+    HIST_ROWS_DOWNGRADES, SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,
     SERVE_QUANTIZE_BYTES_IN, SERVE_BINNED_REQUESTS,

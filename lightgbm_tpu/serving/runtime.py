@@ -268,9 +268,6 @@ class PredictorRuntime:
         flavor."""
         import jax
 
-        # X is donated only where donation is real; on CPU it would just
-        # print an "unusable donated buffer" warning per call
-        self._donate = jax.default_backend() in ("tpu", "gpu")
         # the fleet: one model copy + executable cache per local device
         self.replicas: List[_Replica] = [
             _Replica(i, dev, jax.device_put(host_stacks, dev))
@@ -447,12 +444,13 @@ class PredictorRuntime:
         from jax.sharding import SingleDeviceSharding
 
         fn = self._program(kind)
-        donate = (1,) if self._donate else ()
+        # no donation of X: the [K, bucket] output cannot alias the
+        # [bucket, F] request buffer, on the chip as on the CPU
         x_spec = jax.ShapeDtypeStruct(
             (bucket, self._buf_cols), jnp.dtype(self._buf_dtype),
             sharding=SingleDeviceSharding(replica.device))
         t0 = time.perf_counter()
-        compiled = (jax.jit(fn, donate_argnums=donate)
+        compiled = (jax.jit(fn)
                     .lower(replica.stacks, x_spec)
                     .compile())
         dt = time.perf_counter() - t0

@@ -106,19 +106,6 @@ class MultiHostRows:
                               axis=-1)[..., : self.n_local]
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map with a fallback to the pre-graduation API
-    (jax<=0.5 ships it as jax.experimental.shard_map.shard_map, with
-    the replication-check flag named check_rep instead of check_vma)."""
-    import jax
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check_vma)
-
-
 def pad_cols_to_ndev(n_cols: int, ndev: int, align: int = 1) -> int:
     """Smallest column count >= `n_cols` that tiles the mesh axis the
     psum_scatter histogram exchange scatters over: a multiple of

@@ -70,7 +70,6 @@ def _peak_rss_mb() -> float:
 
 def worker(mode: str, rows: int, chunk: int) -> None:
     """One configuration in a fresh process; prints its own JSON."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import hashlib
     import numpy as np
     from lightgbm_tpu.config import config_from_params
@@ -104,8 +103,7 @@ def run_config(mode: str, rows: int, chunk: int) -> dict:
     r = subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--worker", mode,
          str(rows), str(chunk)],
-        capture_output=True, text=True, timeout=3600,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        capture_output=True, text=True, timeout=3600)
     if r.returncode != 0:
         raise RuntimeError(f"worker {mode}/{rows}/{chunk} failed:\n"
                            f"{r.stderr[-2000:]}")
@@ -140,7 +138,6 @@ def main() -> None:
     if SANITIZE:
         # streamed store must feed the training kernels at steady state
         # with 0 retraces / 0 implicit transfers, like any other store
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         import lightgbm_tpu as lgb
         from lightgbm_tpu.config import config_from_params
         from lightgbm_tpu.dataset import Dataset

@@ -65,8 +65,6 @@ import tempfile
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402

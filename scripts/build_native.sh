@@ -12,7 +12,9 @@
 set -e
 cd "$(dirname "$0")/.."
 mkdir -p lightgbm_tpu/lib
-g++ -O3 -march=native -std=c++17 -shared -fPIC \
+# no -march=native: lib/ is gitignored but copied with the checkout as it
+# stands on disk, so a library built here must run on another CPU
+g++ -O3 -std=c++17 -shared -fPIC \
     -o lightgbm_tpu/lib/liblgbt_native.so \
     src/native/loader.cpp src/native/c_api.cpp
 echo "built lightgbm_tpu/lib/liblgbt_native.so"

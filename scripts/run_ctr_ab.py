@@ -27,8 +27,8 @@ Four measured runs on the same synthetic wide-sparse lambdarank data
    tree/sparse_fallbacks at EXACTLY 0 (sparse binned score replay).
 
 Writes bench_ctr_measured.json (BENCH_CTR_OUT overrides).  Shape via
-BENCH_ROWS / BENCH_CTR_* envs; when the TPU backend is unreachable the
-run degrades to a reduced CPU shape and says so in the artifact.
+BENCH_ROWS / BENCH_CTR_* envs.  The run fails when JAX finds no
+accelerator: its times are device metrics.
 Acceptance gates are asserted AFTER the JSON prints/writes, so a
 failed gate still leaves the measurements on disk.
 """
@@ -42,7 +42,7 @@ import numpy as np
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
-from bench import default_backend_alive, force_cpu_backend, synth_ctr  # noqa: E402
+from bench import synth_ctr  # noqa: E402
 
 OUT = os.environ.get("BENCH_CTR_OUT",
                      os.path.join(ROOT, "bench_ctr_measured.json"))
@@ -100,30 +100,17 @@ def _train(X, y, group, params, iters, warmup, fobj=None):
 
 
 def main():
-    global ROWS, FEATURES, ITERS
-    note = None
-    if not default_backend_alive():
-        force_cpu_backend()
+    global ROWS, FEATURES
     import jax
-    if jax.default_backend() != "tpu":
-        # the dense-store baseline is infeasible at the acceptance
-        # shape on the CPU tier (its chunked one-hot transient is
-        # [F_eff, chunk, B] — tens of GB at 4k+ columns); degrade the
-        # A/B and say so (the csr_full_shape block below still proves
-        # the sparse path at >= 50k features)
-        ROWS = min(ROWS, 8_192)
-        FEATURES = min(FEATURES, 2_048)
-        ITERS = min(ITERS, 6)
-        note = (f"non-TPU backend ({jax.default_backend()}); reduced "
-                "CPU shape - NOT the tracked metric")
-    else:
-        # the DENSE leg bounds the A/B shape on chip too: an int32/int8
-        # [F, N] store plus [K, F, 3, B] histograms at 50k columns
-        # would blow past one chip's HBM — the csr_full_shape probe
-        # below carries the >= 50k-feature evidence instead
-        FEATURES = min(FEATURES, 8_192)
-        ROWS = min(ROWS, 1_000_000)
-    import lightgbm_tpu as lgb  # noqa: F401  (backend pinned first)
+    from lightgbm_tpu.jaxutil import require_accelerator
+    device = require_accelerator()
+    # the DENSE leg bounds the A/B shape: an int32/int8 [F, N] store
+    # plus [K, F, 3, B] histograms at 50k columns would blow past one
+    # chip's HBM — the csr_full_shape probe below carries the
+    # >= 50k-feature evidence instead
+    FEATURES = min(FEATURES, 8_192)
+    ROWS = min(ROWS, 1_000_000)
+    import lightgbm_tpu as lgb  # noqa: F401
     from lightgbm_tpu import profiling
 
     X, y, group = synth_ctr(ROWS, FEATURES, DENSITY, query=QUERY)
@@ -141,9 +128,7 @@ def main():
     out = {"metric": f"synthetic-ctr {len(y)}x{FEATURES} lambdarank "
                      f"{LEAVES} leaves: sparse-store + adaptive-bin A/B",
            "rows": len(y), "features": FEATURES, "density": DENSITY,
-           "iters": ITERS}
-    if note:
-        out["note"] = note
+           "iters": ITERS, "device": device}
 
     # ---- 1+2: dense vs csr store ------------------------------------
     runs = {}

@@ -22,12 +22,10 @@ own rules' entries.
 
 `--json` emits machine-readable findings on stdout
 (file/line/rule/qualname/message plus the stale entries) with a
-one-line summary on stderr, for the chip-queue preflight and CI
-annotation.  `--rules a,b,...` restricts the run to the named rules
+one-line summary on stderr, for CI annotation.  `--rules a,b,...` restricts the run to the named rules
 (the stale audit is skipped then: with rules filtered out, absence of
 a finding proves nothing).  Run from tier-1
-(tests/test_lint_clean.py), the chip-queue preflight
-(scripts/run_chip_queue.sh), and standalone:
+(tests/test_lint_clean.py) and standalone:
 
     python scripts/run_lint.py [--json] [--rules r1,r2] [paths...]
 

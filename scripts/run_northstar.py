@@ -36,7 +36,9 @@ BINS = int(os.environ.get("NS_BINS", 255))
 def main():
     import jax
     import lightgbm_tpu as lgb
+    from lightgbm_tpu.jaxutil import require_accelerator
 
+    require_accelerator()
     backend = jax.default_backend()
     t0 = time.perf_counter()
     X, y = synth_higgs(ROWS, seed=42)

@@ -1,7 +1,6 @@
 """Print a one-screen summary of every measured artifact in the repo
-root (the *_measured.json files each chip-queue stage writes, plus the
-per-round BENCH files).  Used after draining scripts/run_chip_queue.sh
-to fold numbers into BASELINE.md; safe to run any time."""
+root (the *_measured.json files the measuring scripts write); safe to
+run any time."""
 import glob
 import json
 import os
@@ -16,17 +15,6 @@ def show(path):
         print(f"{os.path.basename(path)}: UNREADABLE ({e})")
         return
     name = os.path.basename(path)
-    if "tail" in d and "metric" in str(d.get("tail", "")):
-        # driver BENCH_r0N wrapper: the bench JSON line is in "tail"
-        try:
-            inner = json.loads(d["tail"].strip().splitlines()[-1])
-            print(f"{name}: {inner.get('value')} {inner.get('unit', '')} "
-                  f" vs_baseline={inner.get('vs_baseline')}"
-                  + (f"  NOTE: {inner['note']}" if inner.get("note")
-                     else ""))
-        except Exception:
-            print(f"{name}: (unparsed tail)")
-        return
     if "results" in d and isinstance(d["results"], list):
         print(f"{name} (backend={d.get('backend', '?')}):")
         for r in d["results"]:
@@ -39,11 +27,6 @@ def show(path):
             if "final_test_ndcg" in r:
                 extra += f" ndcg={r['final_test_ndcg']}"
             print(f"  {key}{extra}: {spi} s/iter")
-        return
-    if "results" in d and isinstance(d["results"], dict):   # eps_tune
-        print(f"{name}:")
-        for k, v in d["results"].items():
-            print(f"  {k}: {v.get('s_per_iter', v)}")
         return
     spi = d.get("seconds_per_iter") or d.get("value")
     bits = [f"{name}: {spi} s/iter" if spi else name]
@@ -60,9 +43,8 @@ def show(path):
 
 
 def main():
-    for pat in ("*_measured.json", "BENCH_r0*.json"):
-        for p in sorted(glob.glob(os.path.join(ROOT, pat))):
-            show(p)
+    for p in sorted(glob.glob(os.path.join(ROOT, "*_measured.json"))):
+        show(p)
 
 
 if __name__ == "__main__":

@@ -160,13 +160,29 @@ def test_python_api_doc_in_sync(tmp_path):
         "docs/Python-API.md is stale; run scripts/gen_python_api_doc.py"
 
 
-def test_feature_group_env_clamping(monkeypatch):
-    """LGBT_FEATURE_GROUP parses defensively: multiples of 8 in [8, 64],
-    junk falls back to the default."""
-    from lightgbm_tpu.ops.histogram import _feature_group_from_env
-    monkeypatch.delenv("LGBT_FEATURE_GROUP", raising=False)
-    assert _feature_group_from_env() == 8
-    for raw, want in (("16", 16), ("64", 64), ("100", 64), ("12", 8),
-                      ("junk", 8), ("0", 8)):
-        monkeypatch.setenv("LGBT_FEATURE_GROUP", raw)
-        assert _feature_group_from_env() == want, raw
+def test_compile_cache_is_placed_from_outside(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: it is used and nothing is set in
+    code; unset: <checkout>/.jax_cache, a fixed path."""
+    import jax
+    from lightgbm_tpu.jaxutil import enable_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: calls.append((k, v)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert enable_compile_cache() == str(tmp_path)
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert enable_compile_cache() == os.path.join(root, ".jax_cache")
+    assert calls == [("jax_compilation_cache_dir",
+                      os.path.join(root, ".jax_cache"))]
+    assert jax.config.jax_compilation_cache_dir == was
+
+
+def test_measuring_scripts_fail_without_an_accelerator():
+    """jaxutil.require_accelerator: on the CPU tier it exits instead of
+    letting a CPU timing be written under a device metric's name."""
+    from lightgbm_tpu.jaxutil import require_accelerator
+    with pytest.raises(SystemExit, match="no accelerator"):
+        require_accelerator()

@@ -323,3 +323,36 @@ def test_gather_chunk_cap_respects_vmem_budget():
         assert ck % 128 == 0 and ck >= 128
         if ck > 128:          # above the floor the budget must hold
             assert ck * B * 4 <= int(4e6)
+
+
+def test_gathered_downgrade_is_counted():
+    """The scratch memory gate still replaces gathered by masked, but no
+    longer in silence: tree/hist_rows_downgrades moves (chip_smoke.py
+    asserts it stays 0)."""
+    from lightgbm_tpu import profiling
+    from lightgbm_tpu.config import config_from_params
+    from lightgbm_tpu.learner.common import resolve_hist_rows
+    cfg = config_from_params({"hist_rows": "gathered", "verbose": -1})
+    c0 = profiling.counter_value(profiling.HIST_ROWS_DOWNGRADES)
+    # 50M rows x 2000 int32 columns: a 200 GB scratch fits no device
+    assert resolve_hist_rows(cfg, backend="pallas", num_columns=2000,
+                             np_rows=50_000_000) == "masked"
+    assert profiling.counter_value(profiling.HIST_ROWS_DOWNGRADES) == c0 + 1
+    assert resolve_hist_rows(cfg, backend="pallas", num_columns=28,
+                             np_rows=10_500_000) == "gathered"
+    assert profiling.counter_value(profiling.HIST_ROWS_DOWNGRADES) == c0 + 1
+
+
+@pytest.mark.parametrize("bins_itemsize,dtype,want", [
+    (4, "int8", 8192), (4, "bfloat16", 8192), (4, "float32", 2048),
+    (1, "int8", 2048), (1, "float32", 1024)])
+def test_masked_chunk_is_the_compile_validated_table(bins_itemsize, dtype,
+                                                     want):
+    """The masked kernels' row chunk comes from the table that
+    tests/test_tpu_compile.py validates against the TPU compiler, and
+    shrinks in proportion for value-row blocks taller than K=84's."""
+    from lightgbm_tpu.ops.histogram import _masked_chunk
+    for Mp in (8, 24, 96, 256):
+        assert _masked_chunk(Mp, bins_itemsize, dtype) == want
+    tall = _masked_chunk(512, bins_itemsize, dtype)
+    assert tall == want // 2 and tall % 128 == 0

@@ -80,12 +80,33 @@ def test_default_budget_reads_device_memory(monkeypatch):
         def memory_stats(self):
             return self._s
 
-    monkeypatch.setattr(jax, "devices",
+    monkeypatch.setattr(jax, "local_devices",
                         lambda: [FakeDev({"bytes_limit": 16e9})])
     assert common._default_pool_budget() == 4e9
     assert common.use_parent_hist_cache(
         Config(num_leaves=255), 2000, 256)      # Epsilon cache fits
-    monkeypatch.setattr(jax, "devices", lambda: [FakeDev(None)])
+    monkeypatch.setattr(jax, "local_devices", lambda: [FakeDev(None)])
     assert common._default_pool_budget() == 1.5e9
     assert not common.use_parent_hist_cache(
         Config(num_leaves=255), 2000, 256)      # floor bounds it
+
+
+@pytest.mark.quick
+def test_tpu_without_memory_stats_is_an_error(monkeypatch):
+    """No assumed 4 GB / 16 GB: on TPU a device that reports no
+    bytes_limit raises; the CPU tier keeps its constant."""
+    import jax
+    from lightgbm_tpu.learner import common
+
+    class FakeDev:
+        def memory_stats(self):
+            return None
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [FakeDev()])
+    assert common.device_bytes_limit() is None            # CPU tier
+    assert common.gathered_scratch_fits(28, 10_500_000)   # 16e9 constant
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        common.device_bytes_limit()
+    with pytest.raises(RuntimeError, match="bytes_limit"):
+        common._default_pool_budget()

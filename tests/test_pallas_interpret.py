@@ -218,11 +218,10 @@ def test_hist_masked_int8_stored_bins(input_dtype):
                                rtol=0, atol=1e-4)
 
 
-def test_hist_masked_bf16_narrow_onehot():
-    """The bf16 masked kernel with the narrow (bf16-domain) one-hot
-    compare: bin values <= 255 are exact in bf16, so the pallas result
-    must match the XLA bf16 formulation bit-for-bit in the one-hot and
-    to bf16 summation tolerance in the totals."""
+def test_hist_masked_bf16_onehot():
+    """The bf16 masked kernel (int32 one-hot compare, bf16 operands):
+    the pallas result must match the XLA bf16 formulation bit-for-bit
+    in the one-hot and to bf16 summation tolerance in the totals."""
     rng, gb = _rand(2051, 5, 255, seed=9)
     B = 256
     lid = rng.randint(0, 10, size=2051).astype(np.int32)
@@ -249,11 +248,10 @@ def test_hist_masked_bf16_narrow_onehot():
 
 @pytest.mark.parametrize("input_dtype", ["bfloat16", "int8"])
 def test_hist_masked_int8_stored_packed_bins(input_dtype):
-    """int8-STORED bins combined with feature packing: the narrow
-    compare applies the pack shift IN int8 (`gb + s*bins_sub` on the
-    value-128 layout), whose no-overflow bound (stored <= bins_sub-129,
-    shift <= 128-bins_sub... <= 96) is the most delicate branch of
-    _packed_onehot — pin it against int32 storage through XLA."""
+    """int8-STORED bins combined with feature packing: the widen,
+    un-offset and pack shift (`gb + 128 + s*bins_sub`) all run on the
+    value-128 layout inside _packed_onehot — pin it against int32
+    storage through XLA."""
     rng, gb = _rand(2500, 33, 60, seed=21)      # 60 bins -> bins_sub=64
     B = 128
     lid = rng.randint(0, 6, size=2500).astype(np.int32)
@@ -279,12 +277,11 @@ def test_hist_masked_int8_stored_packed_bins(input_dtype):
     assert np.asarray(h_pl)[2].max() == 0.0
 
 
-def test_hist_masked_narrow_lid_aliasing():
-    """The int8 leaf-id compare (quant kernel, num_leaves<=255): padded
-    rows carry lid sentinel -2, which wraps to the same int8 code as
-    leaf 254 — the kernel stays exact because padded ghq rows are zero.
-    Stress exactly that: C > chunk (real padding), a slot holding leaf
-    254, empty -1 slots, and num_leaves at the 255 gate boundary."""
+def test_hist_masked_int8_padded_rows_and_top_leaf():
+    """The quantized kernel over a padded row stream: padded rows carry
+    lid sentinel -2 and all-zero ghq rows, empty slots carry -1, and
+    neither may leak into a live slot.  C > chunk (real padding), a
+    slot holding the top leaf id 254, empty -1 slots."""
     rng, gb = _rand(9000, 4, 200, seed=31)      # 9000 > 8192 chunk -> pad
     B = 256
     lid = rng.randint(0, 255, size=9000).astype(np.int32)
@@ -297,8 +294,7 @@ def test_hist_masked_narrow_lid_aliasing():
     args = (jnp.asarray(gb), jnp.asarray(lid), jnp.asarray(gh8),
             jnp.asarray(sl))
     h_n = hist_multileaf_masked(*args, num_bins_padded=B, backend="pallas",
-                                input_dtype="int8", interpret=True,
-                                num_leaves=255)
+                                input_dtype="int8", interpret=True)
     h_x = hist_multileaf_masked(*args, num_bins_padded=B, backend="xla",
                                 input_dtype="int8")
     np.testing.assert_allclose(np.asarray(h_n), np.asarray(h_x),
@@ -362,9 +358,9 @@ def test_hist_multileaf_gathered_pallas(input_dtype, int8_store):
                                    rtol=0, atol=1e-4)
 
 
-def test_hist_pallas_bf16_narrow_onehot():
-    """Gather-fed kernels with the bf16 narrow compare (_simple_onehot):
-    must match the XLA bf16 formulation."""
+def test_hist_pallas_bf16_onehot():
+    """Gather-fed kernels with bf16 operands (_simple_onehot): must
+    match the XLA bf16 formulation."""
     rng, gb = _rand(3001, 9, 255, seed=33)
     vals8 = np.zeros((8, 3001), np.float32)
     vals8[0] = rng.randn(3001)

@@ -90,6 +90,27 @@ def test_dyadic_bitwise_parity_both_layouts():
     assert np.array_equal(ref, got_s)
 
 
+@pytest.mark.parametrize("kernel", ["walk", "perfect", "soa"])
+def test_f32_threshold_never_rounds_above_the_f64_one(kernel):
+    """A raw threshold whose nearest f32 lies ABOVE it: a row holding
+    exactly that f32 value goes RIGHT in the f64 host walk, so it must
+    go right in every device stack too (ops/predict.threshold_f32)."""
+    x = np.float32(0.3)
+    thr = float(x) - 1e-9               # nearest f32 is x itself, > thr
+    assert np.float32(thr) == x and float(np.float32(thr)) > thr
+    t = Tree(2)
+    t.split(0, 0, NUMERICAL_DECISION, 0, 0, thr, -1.0, 1.0, 10, 10, 1.0)
+    X = np.array([[x], [np.nextafter(x, np.float32(0))]], np.float32)
+    host = t.predict_raw(X.astype(np.float64))
+    assert host.tolist() == [1.0, -1.0]
+    if kernel == "walk":
+        got = _walk_raw([[t]], X)
+    else:
+        got, _ = _tens_raw([[t]], X,
+                           layout="auto" if kernel == "perfect" else "soa")
+    assert np.asarray(got).reshape(-1).tolist() == host.tolist()
+
+
 @pytest.mark.parametrize("leaves,maxdepth", [(2, 1), (3, 2), (15, 4),
                                              (63, 8), (40, 30)])
 def test_parity_across_depths(leaves, maxdepth):

@@ -195,9 +195,9 @@ def test_int8_stored_bins_grow_identical_trees():
 
 
 def test_rounds_num_leaves_past_int8_gates():
-    """num_leaves > 255 exceeds both narrow int8 encodings (leaf-id mask
-    compare, fused partition slot table) — the gates must route to the
-    wide paths and grow a correct tree rather than alias mod-256."""
+    """num_leaves > 255 exceeds the fused partition kernel's int8 slot
+    table — the gate must route to the XLA composition and grow a
+    correct tree rather than alias mod-256."""
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(3)
     X = rng.randn(4000, 6)

@@ -6,6 +6,8 @@ boosting loop (the BENCH_SANITIZE=1 assertion in miniature).
 Transfer-guard tests carry the `sanitize` marker (pytest.ini): the guard
 is backend-enforced and a no-op for some directions on some platforms —
 they self-skip when the probe says so."""
+import functools
+
 import numpy as np
 import pytest
 
@@ -153,10 +155,10 @@ def test_warmup_steps_run_unguarded():
 
 def _mesh_and_smap():
     from jax.sharding import Mesh, PartitionSpec as P
-    from lightgbm_tpu.learner.common import compat_shard_map
     n = len(jax.devices())
     mesh = Mesh(np.asarray(jax.devices()).reshape(n), ("data",))
-    return mesh, P, compat_shard_map
+    # replication is what these tests break on purpose
+    return mesh, P, functools.partial(jax.shard_map, check_vma=False)
 
 
 @needs_mesh

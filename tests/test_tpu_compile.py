@@ -1,0 +1,185 @@
+"""The main-path kernels, compiled for a TPU v5e that is described and not
+attached (on-chip-measurement guide, section 2): what Mosaic or XLA:TPU
+refuses — a layout it cannot infer, an op the v5e VPU lacks, more scoped
+VMEM than 16 MB — fails here, on the CPU tier, before any chip run.
+
+Every shape is the north-star's: F=28 (or a 2000-wide store), B=256 bins,
+the rounds learner's K tiers (1 = root, 8, 32, 84), float32 / bfloat16 /
+int8 operands, int32 and int8-stored bins.  Nothing runs; a compile that
+passes is not a chip run.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process may load the TPU library, and under pytest-xdist
+every worker imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+N = 1 << 20        # rows; the kernels' grids scale with it, nothing else
+B = 256
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip: keep it out
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def shape(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    return s
+
+
+def compile_for_chip(fn, *args, kernel=True):
+    compiled = jax.jit(fn).lower(*args).compile()
+    if kernel:
+        assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def masked(dtype):
+    """hist_multileaf_masked as learner/rounds.py calls it."""
+    from lightgbm_tpu.ops.histogram import hist_multileaf_masked
+
+    def f(gb, lid, gh, sl):
+        return hist_multileaf_masked(gb, lid, gh, sl, num_bins_padded=B,
+                                     backend="pallas", input_dtype=dtype,
+                                     max_num_bin=255)
+    return f
+
+
+def masked_args(shape, F, bins_dtype, K, n=N):
+    return (shape((F, n), bins_dtype), shape((n,), jnp.int32),
+            shape((8, n), jnp.float32), shape((K,), jnp.int32))
+
+
+@pytest.mark.parametrize("K", [1, 8, 32, 84])
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_masked_histogram_every_tier(shape, dtype, K):
+    """int32 bins.  float32 is the Config default; K=84 float32 once asked 32.8 MB of
+    VMEM, and the tiers are not monotone (K=8 can need more than K=84)."""
+    compile_for_chip(masked(dtype), *masked_args(shape, 28, jnp.int32, K))
+
+
+@pytest.mark.parametrize("dtype,F,bins_dtype", [
+    ("bfloat16", 28, jnp.int32),
+    ("float32", 32, jnp.int8), ("int8", 32, jnp.int8),
+    ("int8", 2000, jnp.int32)])
+def test_masked_histogram_k84_other_layouts(shape, dtype, F, bins_dtype):
+    """bf16 operands, int8-stored bins (G=32 feature blocks, 128-lane bin
+    windows) and a 2000-wide store, all at the full K=84 pass."""
+    n = N if F < 100 else N // 8
+    compile_for_chip(masked(dtype), *masked_args(shape, F, bins_dtype, 84, n))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_gathered_histogram(shape, dtype):
+    """hist_multileaf_gathered — the default row feed on the chip — at the
+    ceil(N/2) smaller-child capacity."""
+    from lightgbm_tpu.ops.histogram import hist_multileaf_gathered
+
+    def f(bins, gh, perm, off, cnt):
+        return hist_multileaf_gathered(
+            bins, gh, perm, off, cnt, capacity=N // 2, num_bins_padded=B,
+            backend="pallas", input_dtype=dtype, max_num_bin=255)
+    compile_for_chip(f, shape((28, N), jnp.int32), shape((8, N), jnp.float32),
+                     shape((N,), jnp.int32), shape((84,), jnp.int32),
+                     shape((84,), jnp.int32))
+
+
+@pytest.mark.parametrize("F,bins_dtype", [(28, jnp.int32), (32, jnp.int8),
+                                          (2000, jnp.int32)])
+def test_fused_partition(shape, F, bins_dtype):
+    """partition_rows through the fused VMEM kernel (256 slots)."""
+    from lightgbm_tpu.ops.partition import partition_rows
+
+    def f(bins, lid, tbl):
+        return partition_rows(bins, lid, tbl, num_slots=256,
+                              backend="pallas", num_bins_padded=B)
+    n = N if F < 100 else N // 8
+    compile_for_chip(f, shape((F, n), bins_dtype), shape((n,), jnp.int32),
+                     shape((7, 256), jnp.float32))
+
+
+def test_table_lookup_kernel(shape):
+    from lightgbm_tpu.ops.lookup import _lookup_pallas
+    compile_for_chip(_lookup_pallas, shape((7, 256), jnp.float32),
+                     shape((N,), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_sparse_histogram(shape, dtype):
+    """hist_sparse_pallas over slot-segmented entry streams (CTR width:
+    8-column windows x 256 bins = 2048 one-hot lanes)."""
+    from lightgbm_tpu.ops.histogram import hist_sparse_pallas
+    nwin, ew, cols, rows = 256, 4096, 2048, 1 << 19
+
+    def f(e_row, e_flat, e_valid, slot_col, zero_bin, lid, gh, sl):
+        return hist_sparse_pallas(e_row, e_flat, e_valid, slot_col,
+                                  zero_bin, lid, gh, sl,
+                                  num_columns_padded=cols,
+                                  num_bins_padded=B, input_dtype=dtype)
+    compile_for_chip(
+        f, shape((nwin, ew), jnp.int32), shape((nwin, ew), jnp.int32),
+        shape((nwin, ew), jnp.float32), shape((nwin * 8,), jnp.int32),
+        shape((cols,), jnp.int32), shape((rows,), jnp.int32),
+        shape((8, rows), jnp.float32), shape((84,), jnp.int32))
+
+
+def test_binned_traversal_int16_record(shape):
+    """The serving request path on the chip: XLA traversal of a 500-tree,
+    255-leaf ensemble whose node record is narrowed to int16 (TPU only,
+    ops/predict._maybe_narrow) over a uint8 quantized request buffer."""
+    from lightgbm_tpu.ops.predict import (EnsembleMeta, EnsembleStack, _LANES,
+                                          _predict_ensemble_quantized_soa)
+    stack = EnsembleStack(nodes=shape((500, 254, _LANES), jnp.int16),
+                          leaf_value=shape((500, 255), jnp.float32),
+                          root=shape((500,), jnp.int32),
+                          class_id=shape((500,), jnp.int32))
+    meta = EnsembleMeta(depth=16, num_class=1, any_cat=False)
+
+    def f(stack, xb):
+        return _predict_ensemble_quantized_soa(stack, xb, meta=meta)
+    compile_for_chip(f, stack, shape((4096, 28), jnp.uint8), kernel=False)
+
+
+def test_score_update_with_row_sharded_leaf_ids(topo, monkeypatch):
+    """The data-parallel learner returns leaf ids sharded over its mesh and
+    the score update runs under plain jit: with the Mosaic lookup XLA
+    refuses ("cannot be automatically partitioned" — what stopped the first
+    four-chip run), with table_lookup(spmd=True) it compiles.  The backend
+    question is steered here; the program has no option for it."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.boosting.score_updater import _add_leaf_to_row_jit
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
+    rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    n = 10_500_000
+    args = (jax.ShapeDtypeStruct((1, n), jnp.float32, sharding=rep),
+            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rows),
+            jax.ShapeDtypeStruct((255,), jnp.float32, sharding=rep))
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _add_leaf_to_row_jit.lower(*args, tree_id=0, spmd=False)
+    _add_leaf_to_row_jit.lower(*args, tree_id=0, spmd=True).compile()
