@@ -376,15 +376,17 @@ class Booster:
     def update(self, train_set: Optional[Dataset] = None,
                fobj: Optional[Callable] = None) -> bool:
         """One boosting iteration; returns True if no further splits."""
-        if train_set is not None and train_set is not self.train_set:
-            train_set.construct(self.params)
-            self._gbdt.reset_training_data(train_set._inner)
-            self.train_set = train_set
-        if fobj is None:
-            return self._gbdt.train_one_iter(None, None, False)
-        preds = self.__inner_raw_score()
-        grad, hess = fobj(preds, self.train_set)
-        return self.__boost(grad, hess)
+        from . import profiling
+        with profiling.phase("update", iteration=self._gbdt.iter_):
+            if train_set is not None and train_set is not self.train_set:
+                train_set.construct(self.params)
+                self._gbdt.reset_training_data(train_set._inner)
+                self.train_set = train_set
+            if fobj is None:
+                return self._gbdt.train_one_iter(None, None, False)
+            preds = self.__inner_raw_score()
+            grad, hess = fobj(preds, self.train_set)
+            return self.__boost(grad, hess)
 
     def __inner_raw_score(self) -> np.ndarray:
         sc = self._gbdt.train_score.get()

@@ -316,18 +316,23 @@ class GBDT:
             return
         packed, slot, shrink = self._pending
         self._pending = None
+        from .. import profiling
         from ..learner.fused import unpack_tree_arrays, tree_arrays_to_host
-        # explicit fetch (jax.device_get, not np.asarray): the packed
-        # vector was copy_to_host_async'd an iteration ago, and the
-        # explicit API keeps the transfer-guarded hot path clean
-        arrs = unpack_tree_arrays(jax.device_get(packed),
-                                  self.config.num_leaves)
-        tree = tree_arrays_to_host(arrs, self.train_set,
-                                   self.config.num_leaves)
-        tree.apply_shrinkage(shrink)
-        self.models[slot] = tree
-        if tree.num_leaves <= 1:
-            self._pending_stop = True
+        with profiling.phase("collect_tree"):
+            # explicit fetch (jax.device_get, not np.asarray): the packed
+            # vector was copy_to_host_async'd an iteration ago, and the
+            # explicit API keeps the transfer-guarded hot path clean.
+            # The device builds one tree at a time, so this is where the
+            # host waits for the previous iteration to finish
+            with profiling.phase("wait_device"):
+                vec = jax.device_get(packed)
+            arrs = unpack_tree_arrays(vec, self.config.num_leaves)
+            tree = tree_arrays_to_host(arrs, self.train_set,
+                                       self.config.num_leaves)
+            tree.apply_shrinkage(shrink)
+            self.models[slot] = tree
+            if tree.num_leaves <= 1:
+                self._pending_stop = True
 
     def _can_pipeline(self) -> bool:
         import jax

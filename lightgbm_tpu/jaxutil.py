@@ -77,4 +77,6 @@ def unstack_scalars(n: int):
     """Jitted [n] vector → n lazy 0-d device scalars in ONE program
     (eager v[i] uploads a dynamic_slice start index per element).
     Returns the compiled callable; cached per n."""
-    return jax.jit(lambda v: tuple(v[i] for i in range(n)))
+    def unstack_scalars(v):
+        return tuple(v[i] for i in range(n))
+    return jax.jit(unstack_scalars)

@@ -53,11 +53,11 @@ from .common import (CPU_TIER_BYTES_LIMIT, device_bytes_limit,
                      make_split_kw, padded_bin_count, resolve_hist_rows,
                      sentinel_bins_t, use_parent_hist_cache)
 from .fused import TreeArrays, tree_arrays_to_host
-from ..jaxutil import bag_mask_dev, pad_rows_dev, slice_rows_dev, \
-    unstack_scalars
+from .. import profiling
+from ..jaxutil import bag_mask_dev, pad_rows_dev, slice_rows_dev
 from ..ops.histogram import (hist_multileaf_gathered, hist_multileaf_masked,
                              hist_sparse_gathered, hist_sparse_multileaf,
-                             sparse_window_streams)
+                             masked_hist_mxu_ops, sparse_window_streams)
 from ..ops.partition import partition_rows, partition_rows_sparse
 from ..ops.split import (best_split, bundle_predicate_params,
                          combine_sharded_records, identity_feat_table,
@@ -65,6 +65,22 @@ from ..ops.split import (best_split, bundle_predicate_params,
 from ..tree import Tree
 
 NEG_INF = -jnp.inf
+
+# The counters that build_tree_rounds' stats vector feeds, in the
+# vector's order (profiling.py says what each counts).
+STATS_COUNTERS = (
+    profiling.HIST_ROWS_TOUCHED, profiling.HIST_EXCHANGE_BYTES,
+    profiling.SPLIT_RECORDS_BYTES, profiling.SPARSE_NNZ_TOUCHED,
+    profiling.TREE_ROUNDS, profiling.HIST_PASSES, profiling.HIST_SLOTS,
+    profiling.HIST_LIVE_SLOTS, profiling.HIST_MXU_OPS)
+(S_ROWS, S_EXCHANGE, S_RECORDS, S_NNZ, S_ROUNDS, S_PASSES, S_SLOTS, S_LIVE,
+ S_OPS) = range(len(STATS_COUNTERS))
+
+# Every phase of build_tree_rounds runs under a jax.named_scope
+# "lgbt.<phase>", so that an operation in a profiler trace says which
+# phase it belongs to (its op_name carries ".../lgbt.<phase>/..."; where
+# scopes nest, as under lgbt.root, the innermost is the phase).  Scopes
+# are metadata: they add no operation.
 
 # Leaves histogrammed per multi-leaf pass.  3·K is the M dimension of the
 # hist matmul, and a LARGER K means FEWER full-row passes per round.  The
@@ -143,12 +159,18 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                       leaves_per_batch: int = 0,
                       sparse: bool = False):
     """Grow one tree in batched rounds.  Shapes as learner/fused.build_tree.
-    Returns (TreeArrays, leaf_id, stats) — stats is a [4] f32 vector:
-    (rows processed by histogram kernels — global across shards — the
-    live-traffic metric behind the gathered-vs-masked A/B; per-device
-    histogram-exchange payload bytes; per-device best-split-record
-    allgather bytes; stored sparse entries processed — global, 0 on
-    the dense path).
+    Returns (TreeArrays, leaf_id, stats) — stats is a [9] f32 vector in
+    the order of STATS_COUNTERS: rows processed by histogram kernels
+    (global across shards — the live-traffic metric behind the
+    gathered-vs-masked A/B); per-device histogram-exchange payload
+    bytes; per-device best-split-record allgather bytes; stored sparse
+    entries processed (global, 0 on the dense path); rounds of the
+    loop; histogram kernel launches, the root's included; the slots
+    those launches were made for (each launch's K) and the slots among
+    them that held a leaf; and the operations their contractions
+    perform (ops/histogram.masked_hist_mxu_ops per dense launch, global
+    across shards; the sparse kernels add 0).  Every one is a scalar
+    add where the launch is made, on values the build already has.
 
     hist_rows="gathered" maintains a device-resident row partition
     inside the while_loop: a [N] row permutation grouped by leaf plus
@@ -309,6 +331,8 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             # tier reachable so a skewed shard never overflows the
             # scratch and silently drops rows.
             tiers_small = tuple(sorted(set(tiers_small + tiers_all)))
+    else:
+        tiers_all = tiers_small = None
     if ftbl is None:
         ftbl = identity_feat_table(num_bins)
     # Termination is governed by the while_loop predicate (no positive gain
@@ -326,7 +350,24 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     elif bins.dtype == jnp.int8:
         binsf = bins
     else:
-        binsf = bins.astype(jnp.int32)
+        with jax.named_scope("lgbt.feed"):
+            binsf = bins.astype(jnp.int32)
+
+    def mxu_ops(rows: int, k: int) -> float:
+        """S_OPS of one dense launch over `rows` rows for k slots."""
+        if sparse:
+            return 0.0
+        return masked_hist_mxu_ops(
+            F, rows, k, bins_itemsize=binsf.dtype.itemsize,
+            num_bins_padded=B, backend=backend, input_dtype=input_dtype,
+            max_num_bin=max_num_bin)
+
+    def launch_stats(rows, nnz, slots, live, ops):
+        """The stats vector of one histogram kernel launch."""
+        v = [0.0] * len(STATS_COUNTERS)
+        v[S_ROWS], v[S_NNZ], v[S_PASSES] = rows, nnz, 1.0
+        v[S_SLOTS], v[S_LIVE], v[S_OPS] = slots, live, ops
+        return jnp.stack([jnp.float32(x) for x in v])
 
     def hist_masked(lid_, sl_):
         """One masked multi-leaf pass over the full store — dense
@@ -385,80 +426,81 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         return recs
 
     # ---- root ---------------------------------------------------------------
-    gh8 = jnp.zeros((8, Nloc), jnp.float32)
-    gh8 = gh8.at[0].set(grad * row_mask).at[1].set(hess * row_mask)
-    gh8 = gh8.at[2].set(row_mask)
-    lid0 = jnp.zeros(Nloc, jnp.int32)
-    h0 = hist_masked(lid0, jnp.zeros(1, jnp.int32))
-    if hx:
-        # leaf totals from the LOCAL pass (any single store column's bin
-        # sums give them; store column 0 is always real) + one tiny
-        # psum — the scattered histogram no longer holds column 0 on
-        # every shard
-        ls = jnp.stack([jnp.sum(h0[0, 0, 0, :]), jnp.sum(h0[0, 0, 1, :]),
-                        jnp.sum(h0[0, 0, 2, :])])
-        root_sums = jax.lax.psum(ls, row_axes)
-        cnt = root_sums[2]
-        hist0 = exchange(h0[0])                         # [Fs, 3, B]
-    else:
-        hist0 = _psum(h0[0], row_axes)                  # [F, 3, B]
-        sum_g = jnp.sum(hist0[0, 0, :])
-        sum_h = jnp.sum(hist0[0, 1, :])
-        cnt = jnp.sum(hist0[0, 2, :])
-        root_sums = jnp.stack([sum_g, sum_h, cnt])
+    with jax.named_scope("lgbt.feed"):
+        gh8 = jnp.zeros((8, Nloc), jnp.float32)
+        gh8 = gh8.at[0].set(grad * row_mask).at[1].set(hess * row_mask)
+        gh8 = gh8.at[2].set(row_mask)
+    with jax.named_scope("lgbt.root"):
+        lid0 = jnp.zeros(Nloc, jnp.int32)
+        h0 = hist_masked(lid0, jnp.zeros(1, jnp.int32))
+        if hx:
+            # leaf totals from the LOCAL pass (any single store column's bin
+            # sums give them; store column 0 is always real) + one tiny
+            # psum — the scattered histogram no longer holds column 0 on
+            # every shard
+            ls = jnp.stack([jnp.sum(h0[0, 0, 0, :]), jnp.sum(h0[0, 0, 1, :]),
+                            jnp.sum(h0[0, 0, 2, :])])
+            root_sums = jax.lax.psum(ls, row_axes)
+            cnt = root_sums[2]
+            hist0 = exchange(h0[0])                         # [Fs, 3, B]
+        else:
+            hist0 = _psum(h0[0], row_axes)                  # [F, 3, B]
+            sum_g = jnp.sum(hist0[0, 0, :])
+            sum_h = jnp.sum(hist0[0, 1, :])
+            cnt = jnp.sum(hist0[0, 2, :])
+            root_sums = jnp.stack([sum_g, sum_h, cnt])
 
-    leaf_id = jnp.zeros(Nloc, jnp.int32)
-    if gathered:
-        # initial permutation: live (mask > 0) rows first in row order —
-        # root's segment — with sampled-out rows parked past n_active,
-        # outside every leaf segment forever (they still carry leaf ids
-        # and are moved by partition_rows, but no histogram reads them)
-        posn0 = jax.lax.iota(jnp.int32, Nloc)
-        live0 = (row_mask > 0).astype(jnp.int32)
-        ecs0 = jnp.cumsum(live0) - live0           # lives before each row
-        n_active = jnp.sum(live0)
-        dest0 = jnp.where(live0 > 0, ecs0, n_active + (posn0 - ecs0))
-        perm = jnp.zeros(Nloc, jnp.int32).at[dest0].set(posn0)
-        leaf_off = jnp.zeros(L, jnp.int32)
-        leaf_cnt = jnp.zeros(L, jnp.int32).at[0].set(n_active)
-    else:
-        perm = jnp.zeros(0, jnp.int32)
-        leaf_off = jnp.zeros(0, jnp.int32)
-        leaf_cnt = jnp.zeros(0, jnp.int32)
-    # (rows touched by hist kernels, exchange bytes, record bytes,
-    # sparse entries touched) — the root contributes one masked
-    # full-stream pass + one exchange
-    stats = jnp.asarray([float(Nloc), _exchange_bytes(1),
-                         _records_bytes(1), 0.0], jnp.float32)
-    if sparse:
-        stats = stats.at[3].add(nnz_pass)
-    leaf_best = jnp.full((L, 11), NEG_INF, jnp.float32).at[0].set(
-        find_best_batch(hist0[None], root_sums[None])[0])
-    leaf_depth = jnp.zeros(L, jnp.int32)
-    leaf_parent = jnp.full(L, -1, jnp.int32)
-    leaf_side = jnp.zeros(L, jnp.int32)
-    # under psum_scatter the cache holds this shard's column SLICES
-    leaf_hist = (jnp.zeros((L,) + hist0.shape, jnp.float32).at[0].set(hist0)
-                 if cache_parent_hist
-                 else jnp.zeros((1, 1, 1, 1), jnp.float32))
+        leaf_id = jnp.zeros(Nloc, jnp.int32)
+        if gathered:
+            # initial permutation: live (mask > 0) rows first in row order —
+            # root's segment — with sampled-out rows parked past n_active,
+            # outside every leaf segment forever (they still carry leaf ids
+            # and are moved by partition_rows, but no histogram reads them)
+            posn0 = jax.lax.iota(jnp.int32, Nloc)
+            live0 = (row_mask > 0).astype(jnp.int32)
+            ecs0 = jnp.cumsum(live0) - live0           # lives before each row
+            n_active = jnp.sum(live0)
+            dest0 = jnp.where(live0 > 0, ecs0, n_active + (posn0 - ecs0))
+            perm = jnp.zeros(Nloc, jnp.int32).at[dest0].set(posn0)
+            leaf_off = jnp.zeros(L, jnp.int32)
+            leaf_cnt = jnp.zeros(L, jnp.int32).at[0].set(n_active)
+        else:
+            perm = jnp.zeros(0, jnp.int32)
+            leaf_off = jnp.zeros(0, jnp.int32)
+            leaf_cnt = jnp.zeros(0, jnp.int32)
+        # the root contributes one masked full-stream launch for one
+        # slot + one exchange
+        stats = (launch_stats(Nloc, nnz_pass if sparse else 0, 1, 1,
+                              mxu_ops(Nloc, 1))
+                 .at[S_EXCHANGE].set(_exchange_bytes(1))
+                 .at[S_RECORDS].set(_records_bytes(1)))
+        leaf_best = jnp.full((L, 11), NEG_INF, jnp.float32).at[0].set(
+            find_best_batch(hist0[None], root_sums[None])[0])
+        leaf_depth = jnp.zeros(L, jnp.int32)
+        leaf_parent = jnp.full(L, -1, jnp.int32)
+        leaf_side = jnp.zeros(L, jnp.int32)
+        # under psum_scatter the cache holds this shard's column SLICES
+        leaf_hist = (jnp.zeros((L,) + hist0.shape, jnp.float32).at[0].set(hist0)
+                     if cache_parent_hist
+                     else jnp.zeros((1, 1, 1, 1), jnp.float32))
 
-    arrs = TreeArrays(
-        split_feature=jnp.zeros(L - 1, jnp.int32),
-        threshold_bin=jnp.zeros(L - 1, jnp.int32),
-        is_cat=jnp.zeros(L - 1, bool),
-        left_child=jnp.zeros(L - 1, jnp.int32),
-        right_child=jnp.zeros(L - 1, jnp.int32),
-        split_gain=jnp.zeros(L - 1, jnp.float32),
-        internal_value=jnp.zeros(L - 1, jnp.float32),
-        internal_count=jnp.zeros(L - 1, jnp.float32),
-        # leaf 0 stays 0.0 until a split assigns it: a tree that never
-        # splits must contribute zero score (the sync path discards such
-        # trees; the pipelined path applies leaf values before it can know)
-        leaf_value=jnp.zeros(L, jnp.float32),
-        leaf_count=jnp.zeros(L, jnp.float32).at[0].set(cnt),
-        leaf_depth=jnp.zeros(L, jnp.int32),
-        num_leaves=jnp.int32(1),
-    )
+        arrs = TreeArrays(
+            split_feature=jnp.zeros(L - 1, jnp.int32),
+            threshold_bin=jnp.zeros(L - 1, jnp.int32),
+            is_cat=jnp.zeros(L - 1, bool),
+            left_child=jnp.zeros(L - 1, jnp.int32),
+            right_child=jnp.zeros(L - 1, jnp.int32),
+            split_gain=jnp.zeros(L - 1, jnp.float32),
+            internal_value=jnp.zeros(L - 1, jnp.float32),
+            internal_count=jnp.zeros(L - 1, jnp.float32),
+            # leaf 0 stays 0.0 until a split assigns it: a tree that never
+            # splits must contribute zero score (the sync path discards such
+            # trees; the pipelined path applies leaf values before it can know)
+            leaf_value=jnp.zeros(L, jnp.float32),
+            leaf_count=jnp.zeros(L, jnp.float32).at[0].set(cnt),
+            leaf_depth=jnp.zeros(L, jnp.int32),
+            num_leaves=jnp.int32(1),
+        )
 
     def round_body(st):
         (rnd, leaf_id, leaf_best, leaf_depth, leaf_parent, leaf_side,
@@ -466,141 +508,145 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         n_leaves = arrs.num_leaves
 
         # ---- select this round's splits (top-gain within the cap) ---------
-        gated = jnp.where((max_depth <= 0) | (leaf_depth < max_depth),
-                          leaf_best[:, 0], NEG_INF)
-        order = jnp.argsort(-gated).astype(jnp.int32)       # [L]
-        sgain = gated[order]
-        remaining = L - n_leaves
-        slot = jax.lax.broadcasted_iota(jnp.int32, (L,), 0)
-        do = (sgain > 0) & (slot < remaining)               # [L] sorted slots
-        prefix = jnp.cumsum(do.astype(jnp.int32)) - do.astype(jnp.int32)
-        m = jnp.sum(do.astype(jnp.int32))
+        with jax.named_scope("lgbt.select"):
+            gated = jnp.where((max_depth <= 0) | (leaf_depth < max_depth),
+                              leaf_best[:, 0], NEG_INF)
+            order = jnp.argsort(-gated).astype(jnp.int32)       # [L]
+            sgain = gated[order]
+            remaining = L - n_leaves
+            slot = jax.lax.broadcasted_iota(jnp.int32, (L,), 0)
+            do = (sgain > 0) & (slot < remaining)               # [L] sorted slots
+            prefix = jnp.cumsum(do.astype(jnp.int32)) - do.astype(jnp.int32)
+            m = jnp.sum(do.astype(jnp.int32))
 
-        pl_ = order                                          # parent leaf/slot
-        rec = leaf_best[pl_]                                 # [L, 11]
-        feat = rec[:, 1].astype(jnp.int32)
-        thr = rec[:, 2].astype(jnp.int32)
-        catf = is_cat[feat]
-        new_leaf = n_leaves + prefix                         # [L]
-        node = (n_leaves - 1) + prefix                       # [L]
-        l_sums = rec[:, 3:6]
-        r_sums = rec[:, 6:9]
+            pl_ = order                                          # parent leaf/slot
+            rec = leaf_best[pl_]                                 # [L, 11]
+            feat = rec[:, 1].astype(jnp.int32)
+            thr = rec[:, 2].astype(jnp.int32)
+            catf = is_cat[feat]
+            new_leaf = n_leaves + prefix                         # [L]
+            node = (n_leaves - 1) + prefix                       # [L]
+            l_sums = rec[:, 3:6]
+            r_sums = rec[:, 6:9]
 
         # ---- partition all rows in one pass -------------------------------
-        # per-LEAF lookup of (split column, threshold, is-cat, new leaf)
-        # then the per-row bin read and move — fused in one pallas pass
-        # (ops/partition.py; XLA fallback composes the one-hot matmuls of
-        # ops/lookup.py there).  XLA's [Nloc] table gather runs at
-        # <1 GB/s on TPU and cost more than the histogram kernel
-        # (65 ms/table at N=4M); new_leaf > 0 ⟺ leaf splits, leaf 0
-        # is never a NEW leaf, so 0 table rows mean "stay".  The split
-        # (feat, thr) is ORIGINAL space; the table carries the translated
-        # STORE-space predicate (ops/split.bundle_predicate_params), so
-        # bundled columns partition without ever materializing original
-        # bins
-        colv, Tv, lov, hi1v, dlv = bundle_predicate_params(
-            ftbl, feat, thr, catf)
-        tbl_idx = jnp.where(do, pl_, L)                      # drop-slot L
-        zeros = jnp.zeros(L + 1, jnp.float32)
+        with jax.named_scope("lgbt.partition"):
+            # per-LEAF lookup of (split column, threshold, is-cat, new leaf)
+            # then the per-row bin read and move — fused in one pallas pass
+            # (ops/partition.py; XLA fallback composes the one-hot matmuls of
+            # ops/lookup.py there).  XLA's [Nloc] table gather runs at
+            # <1 GB/s on TPU and cost more than the histogram kernel
+            # (65 ms/table at N=4M); new_leaf > 0 ⟺ leaf splits, leaf 0
+            # is never a NEW leaf, so 0 table rows mean "stay".  The split
+            # (feat, thr) is ORIGINAL space; the table carries the translated
+            # STORE-space predicate (ops/split.bundle_predicate_params), so
+            # bundled columns partition without ever materializing original
+            # bins
+            colv, Tv, lov, hi1v, dlv = bundle_predicate_params(
+                ftbl, feat, thr, catf)
+            tbl_idx = jnp.where(do, pl_, L)                      # drop-slot L
+            zeros = jnp.zeros(L + 1, jnp.float32)
 
-        def srow(v):
-            return zeros.at[tbl_idx].set(v.astype(jnp.float32), mode="drop")
+            def srow(v):
+                return zeros.at[tbl_idx].set(v.astype(jnp.float32), mode="drop")
 
-        tbl = jnp.stack([srow(colv), srow(Tv), srow(catf), srow(new_leaf),
-                         srow(lov), srow(hi1v), srow(dlv)])
-        if sparse:
-            leaf_id2 = partition_rows_sparse(sp_cols, sp_bins, sp_zb,
-                                             leaf_id, tbl,
-                                             num_slots=L + 1)
-        else:
-            leaf_id2 = partition_rows(binsf, leaf_id, tbl,
-                                      num_slots=L + 1, backend=backend,
-                                      num_bins_padded=B)
+            tbl = jnp.stack([srow(colv), srow(Tv), srow(catf), srow(new_leaf),
+                             srow(lov), srow(hi1v), srow(dlv)])
+            if sparse:
+                leaf_id2 = partition_rows_sparse(sp_cols, sp_bins, sp_zb,
+                                                 leaf_id, tbl,
+                                                 num_slots=L + 1)
+            else:
+                leaf_id2 = partition_rows(binsf, leaf_id, tbl,
+                                          num_slots=L + 1, backend=backend,
+                                          num_bins_padded=B)
 
-        # ---- stable row compaction (DataPartition::Split, vectorized) -----
-        # Each splitting leaf's contiguous segment of `perm` divides into
-        # a stay-prefix (rows keeping the parent id, original order) and
-        # a moved-suffix (rows taking the new id) — O(N) with one cumsum
-        # and a scatter, no sort.  Parked (sampled-out) rows sit past
-        # n_active and keep their positions.
-        if gathered:
-            posn = jax.lax.iota(jnp.int32, Nloc)
-            n_act = jnp.sum(leaf_cnt)
-            ol = jnp.take(leaf_id, perm)                 # old leaf per slot
-            nl = jnp.take(leaf_id2, perm)                # new leaf per slot
-            stay = nl == ol
-            csp = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                   jnp.cumsum(stay.astype(jnp.int32))])
-            soff = jnp.take(leaf_off, ol)                # segment starts
-            seg_stays = jnp.take(csp, soff)
-            rstay = csp[:Nloc] - seg_stays               # stays before pos
-            ns_row = jnp.take(csp, soff + jnp.take(leaf_cnt, ol)) - seg_stays
-            dest = soff + jnp.where(stay, rstay,
-                                    ns_row + (posn - soff) - rstay)
-            dest = jnp.where(posn >= n_act, posn, dest)
-            perm2 = jnp.zeros_like(perm).at[dest].set(perm)
-            # split each parent's (offset, count): parent keeps the
-            # stay-prefix, the new leaf takes the moved suffix
-            ns_leaf = (jnp.take(csp, leaf_off + leaf_cnt)
-                       - jnp.take(csp, leaf_off))        # [L] stay counts
-            ns_p = jnp.take(ns_leaf, pl_)
-            nii = jnp.where(do, new_leaf, L)
-            pii = jnp.where(do, pl_, L)
-            leaf_off2 = leaf_off.at[nii].set(
-                jnp.take(leaf_off, pl_) + ns_p, mode="drop")
-            leaf_cnt2 = (leaf_cnt.at[nii].set(
-                jnp.take(leaf_cnt, pl_) - ns_p, mode="drop")
-                .at[pii].set(ns_p, mode="drop"))
-        else:
-            perm2, leaf_off2, leaf_cnt2 = perm, leaf_off, leaf_cnt
+            # ---- stable row compaction (DataPartition::Split, vectorized) -----
+            # Each splitting leaf's contiguous segment of `perm` divides into
+            # a stay-prefix (rows keeping the parent id, original order) and
+            # a moved-suffix (rows taking the new id) — O(N) with one cumsum
+            # and a scatter, no sort.  Parked (sampled-out) rows sit past
+            # n_active and keep their positions.
+            if gathered:
+                posn = jax.lax.iota(jnp.int32, Nloc)
+                n_act = jnp.sum(leaf_cnt)
+                ol = jnp.take(leaf_id, perm)                 # old leaf per slot
+                nl = jnp.take(leaf_id2, perm)                # new leaf per slot
+                stay = nl == ol
+                csp = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                       jnp.cumsum(stay.astype(jnp.int32))])
+                soff = jnp.take(leaf_off, ol)                # segment starts
+                seg_stays = jnp.take(csp, soff)
+                rstay = csp[:Nloc] - seg_stays               # stays before pos
+                ns_row = jnp.take(csp, soff + jnp.take(leaf_cnt, ol)) - seg_stays
+                dest = soff + jnp.where(stay, rstay,
+                                        ns_row + (posn - soff) - rstay)
+                dest = jnp.where(posn >= n_act, posn, dest)
+                perm2 = jnp.zeros_like(perm).at[dest].set(perm)
+                # split each parent's (offset, count): parent keeps the
+                # stay-prefix, the new leaf takes the moved suffix
+                ns_leaf = (jnp.take(csp, leaf_off + leaf_cnt)
+                           - jnp.take(csp, leaf_off))        # [L] stay counts
+                ns_p = jnp.take(ns_leaf, pl_)
+                nii = jnp.where(do, new_leaf, L)
+                pii = jnp.where(do, pl_, L)
+                leaf_off2 = leaf_off.at[nii].set(
+                    jnp.take(leaf_off, pl_) + ns_p, mode="drop")
+                leaf_cnt2 = (leaf_cnt.at[nii].set(
+                    jnp.take(leaf_cnt, pl_) - ns_p, mode="drop")
+                    .at[pii].set(ns_p, mode="drop"))
+            else:
+                perm2, leaf_off2, leaf_cnt2 = perm, leaf_off, leaf_cnt
 
         # ---- tree arrays (batched Tree::Split) ----------------------------
-        nodei = jnp.where(do, node, L - 1)                   # drop idx
-        lvali = jnp.where(do, pl_, L)
-        nvali = jnp.where(do, new_leaf, L)
-        pn = leaf_parent[pl_]
-        side = leaf_side[pl_]
-        lpar = jnp.where(do & (pn >= 0) & (side == 0), pn, L - 1)
-        rpar = jnp.where(do & (pn >= 0) & (side == 1), pn, L - 1)
-        child_depth = leaf_depth[pl_] + 1
-        arrs2 = arrs._replace(
-            split_feature=arrs.split_feature.at[nodei].set(
-                feat, mode="drop"),
-            threshold_bin=arrs.threshold_bin.at[nodei].set(thr, mode="drop"),
-            is_cat=arrs.is_cat.at[nodei].set(catf, mode="drop"),
-            split_gain=arrs.split_gain.at[nodei].set(rec[:, 0], mode="drop"),
-            internal_value=arrs.internal_value.at[nodei].set(
-                arrs.leaf_value[pl_], mode="drop"),
-            internal_count=arrs.internal_count.at[nodei].set(
-                l_sums[:, 2] + r_sums[:, 2], mode="drop"),
-            left_child=arrs.left_child.at[lpar].set(
-                node, mode="drop").at[nodei].set(~pl_, mode="drop"),
-            right_child=arrs.right_child.at[rpar].set(
-                node, mode="drop").at[nodei].set(~new_leaf, mode="drop"),
-            leaf_value=arrs.leaf_value.at[lvali].set(
-                rec[:, 9], mode="drop").at[nvali].set(rec[:, 10],
-                                                      mode="drop"),
-            leaf_count=arrs.leaf_count.at[lvali].set(
-                l_sums[:, 2], mode="drop").at[nvali].set(r_sums[:, 2],
-                                                         mode="drop"),
-            leaf_depth=arrs.leaf_depth.at[lvali].set(
-                child_depth, mode="drop").at[nvali].set(child_depth,
-                                                        mode="drop"),
-            num_leaves=n_leaves + m,
-        )
-        leaf_depth2 = leaf_depth.at[lvali].set(
-            child_depth, mode="drop").at[nvali].set(child_depth, mode="drop")
-        leaf_parent2 = leaf_parent.at[lvali].set(
-            node, mode="drop").at[nvali].set(node, mode="drop")
-        leaf_side2 = leaf_side.at[lvali].set(0, mode="drop").at[nvali].set(
-            1, mode="drop")
+        with jax.named_scope("lgbt.tree_arrays"):
+            nodei = jnp.where(do, node, L - 1)                   # drop idx
+            lvali = jnp.where(do, pl_, L)
+            nvali = jnp.where(do, new_leaf, L)
+            pn = leaf_parent[pl_]
+            side = leaf_side[pl_]
+            lpar = jnp.where(do & (pn >= 0) & (side == 0), pn, L - 1)
+            rpar = jnp.where(do & (pn >= 0) & (side == 1), pn, L - 1)
+            child_depth = leaf_depth[pl_] + 1
+            arrs2 = arrs._replace(
+                split_feature=arrs.split_feature.at[nodei].set(
+                    feat, mode="drop"),
+                threshold_bin=arrs.threshold_bin.at[nodei].set(thr, mode="drop"),
+                is_cat=arrs.is_cat.at[nodei].set(catf, mode="drop"),
+                split_gain=arrs.split_gain.at[nodei].set(rec[:, 0], mode="drop"),
+                internal_value=arrs.internal_value.at[nodei].set(
+                    arrs.leaf_value[pl_], mode="drop"),
+                internal_count=arrs.internal_count.at[nodei].set(
+                    l_sums[:, 2] + r_sums[:, 2], mode="drop"),
+                left_child=arrs.left_child.at[lpar].set(
+                    node, mode="drop").at[nodei].set(~pl_, mode="drop"),
+                right_child=arrs.right_child.at[rpar].set(
+                    node, mode="drop").at[nodei].set(~new_leaf, mode="drop"),
+                leaf_value=arrs.leaf_value.at[lvali].set(
+                    rec[:, 9], mode="drop").at[nvali].set(rec[:, 10],
+                                                          mode="drop"),
+                leaf_count=arrs.leaf_count.at[lvali].set(
+                    l_sums[:, 2], mode="drop").at[nvali].set(r_sums[:, 2],
+                                                             mode="drop"),
+                leaf_depth=arrs.leaf_depth.at[lvali].set(
+                    child_depth, mode="drop").at[nvali].set(child_depth,
+                                                            mode="drop"),
+                num_leaves=n_leaves + m,
+            )
+            leaf_depth2 = leaf_depth.at[lvali].set(
+                child_depth, mode="drop").at[nvali].set(child_depth, mode="drop")
+            leaf_parent2 = leaf_parent.at[lvali].set(
+                node, mode="drop").at[nvali].set(node, mode="drop")
+            leaf_side2 = leaf_side.at[lvali].set(0, mode="drop").at[nvali].set(
+                1, mode="drop")
 
         # ---- batched smaller-child histograms -----------------------------
-        small_is_left = l_sums[:, 2] <= r_sums[:, 2]
-        small_leaf = jnp.where(small_is_left, pl_, new_leaf)
-        large_leaf = jnp.where(small_is_left, new_leaf, pl_)
-        small_sums = jnp.where(small_is_left[:, None], l_sums, r_sums)
-        large_sums = jnp.where(small_is_left[:, None], r_sums, l_sums)
+        with jax.named_scope("lgbt.select"):
+            small_is_left = l_sums[:, 2] <= r_sums[:, 2]
+            small_leaf = jnp.where(small_is_left, pl_, new_leaf)
+            large_leaf = jnp.where(small_is_left, new_leaf, pl_)
+            small_sums = jnp.where(small_is_left[:, None], l_sums, r_sums)
+            large_sums = jnp.where(small_is_left[:, None], r_sums, l_sums)
 
         # early rounds have few splittable leaves (1, 2, 4, ... for a
         # balanced tree) but a fixed-K pass pays the full Mp=3K matmul
@@ -614,6 +660,11 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         K_MID = min(32, K)
 
         def hist_tiered(slv, dk, Kc):
+            """Masked histogram of the slots' leaves over all rows, at
+            the narrowest slot tier that holds the active slots.
+            Returns ([Kc, F, 3, B] hists, the launch's stats vector)."""
+            live = jnp.sum(dk.astype(jnp.float32))
+
             def full_call(slv_k):
                 if sparse:
                     return hist_sparse_multileaf(
@@ -627,14 +678,15 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
 
             def at(Kt):
                 h = full_call(slv[:Kt])
-                if Kt >= Kc:
-                    return h
-                return jnp.concatenate(
-                    [h, jnp.zeros((Kc - Kt,) + h.shape[1:], h.dtype)],
-                    axis=0)
+                if Kt < Kc:
+                    h = jnp.concatenate(
+                        [h, jnp.zeros((Kc - Kt,) + h.shape[1:], h.dtype)],
+                        axis=0)
+                return h, launch_stats(Nloc, nnz_pass if sparse else 0, Kt,
+                                       live, mxu_ops(Nloc, Kt))
 
             if Kc <= K_SMALL:
-                return full_call(slv)
+                return at(Kc)
 
             def full_or_mid(_):
                 if Kc <= K_MID:
@@ -654,26 +706,32 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             smallest static capacity tier holding this pass's live rows
             (lax.cond picks the tier at run time; every tier is one
             fixed-shape kernel, so nothing retraces round to round).
-            Returns ([Kc, F, 3, B] hists, f32 rows processed, f32
-            stored entries processed — 0 on the dense path)."""
+            Returns ([Kc, F, 3, B] hists, the launch's stats vector:
+            the tier's capacity as rows processed)."""
+            Kc = slv.shape[0]
             sc = jnp.clip(slv, 0, L - 1)
             act = slv >= 0
-            so = jnp.where(act, jnp.take(leaf_off2, sc), 0)
-            sn = jnp.where(act, jnp.take(leaf_cnt2, sc), 0)
-            total = jnp.sum(sn)
+            with jax.named_scope("lgbt.feed"):
+                so = jnp.where(act, jnp.take(leaf_off2, sc), 0)
+                sn = jnp.where(act, jnp.take(leaf_cnt2, sc), 0)
+                total = jnp.sum(sn)
+            live = jnp.sum(act.astype(jnp.float32))
 
             def call(cap):
                 def f(_):
                     if sparse:
-                        return hist_sparse_gathered(
+                        h, nz = hist_sparse_gathered(
                             (sp_cols, sp_bins, sp_zb), gh8, perm2, so,
                             sn, capacity=cap, num_columns_padded=F,
                             num_bins_padded=B)
-                    return (hist_multileaf_gathered(
-                        binsf, gh8, perm2, so, sn, capacity=cap,
-                        num_bins_padded=B, backend=backend,
-                        input_dtype=input_dtype,
-                        max_num_bin=max_num_bin), jnp.float32(0))
+                    else:
+                        h, nz = hist_multileaf_gathered(
+                            binsf, gh8, perm2, so, sn, capacity=cap,
+                            num_bins_padded=B, backend=backend,
+                            input_dtype=input_dtype,
+                            max_num_bin=max_num_bin), 0
+                    return h, launch_stats(cap, nz, Kc, live,
+                                           mxu_ops(cap, Kc))
                 return f
 
             def pick(i):
@@ -682,15 +740,22 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 return lambda _: jax.lax.cond(
                     total <= tiers[i], call(tiers[i]), pick(i + 1), None)
 
-            rt_pass = jnp.float32(tiers[-1])
-            for cap in tiers[-2::-1]:
-                rt_pass = jnp.where(total <= cap, jnp.float32(cap), rt_pass)
-            h, nz = pick(0)(None)
-            return h, rt_pass, nz
+            return pick(0)(None)
+
+        def hist_pass(slv, dk, tiers):
+            """One histogram launch for the slots `slv` (-1 = empty, dk
+            the active ones) through the resolved row feed; `tiers` are
+            the gathered feed's capacities."""
+            with jax.named_scope("lgbt.hist"):
+                if gathered:
+                    return hist_gathered_tiered(slv, tiers)
+                return hist_tiered(slv, dk, slv.shape[0])
 
         leaf_best2 = leaf_best
         leaf_hist2 = leaf_hist
-        stats2 = stats
+        with jax.named_scope("lgbt.select"):
+            rnd2 = rnd + 1
+            stats2 = stats.at[S_ROUNDS].add(1.0)
         for c in range(n_chunks):
             s = c * K
             Kc = min(K, L - s)                               # last chunk short
@@ -699,48 +764,42 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
 
             def do_chunk(args, s=s, Kc=Kc, dk=dk, sl=sl):
                 leaf_best2, leaf_hist2, stv = args
-                slv = jnp.where(dk, sl, -1)                  # -1 = empty slot
-                if gathered:
-                    h_small, rtp, nz = hist_gathered_tiered(slv,
-                                                            tiers_small)
-                    stv = stv.at[0].add(rtp).at[3].add(nz)
-                else:
-                    h_small = hist_tiered(slv, dk, Kc)
-                    stv = stv.at[0].add(jnp.float32(Nloc))
-                    if sparse:
-                        stv = stv.at[3].add(nnz_pass)
-                h_small = exchange(h_small)        # [Kc, F|Fs, 3, B]
-                stv = stv.at[1].add(_exchange_bytes(Kc))
-                if cache_parent_hist:
-                    h_large = leaf_hist2[pl_[s:s + Kc]] - h_small
-                else:
+                with jax.named_scope("lgbt.select"):
+                    slv = jnp.where(dk, sl, -1)              # -1 = empty slot
                     llv = jnp.where(dk, large_leaf[s:s + Kc], -1)
-                    if gathered:
-                        h_large, rtp, nz = hist_gathered_tiered(llv,
-                                                                tiers_all)
-                        stv = stv.at[0].add(rtp).at[3].add(nz)
-                    else:
-                        h_large = hist_tiered(llv, dk, Kc)
-                        stv = stv.at[0].add(jnp.float32(Nloc))
-                        if sparse:
-                            stv = stv.at[3].add(nnz_pass)
-                    h_large = exchange(h_large)
-                    stv = stv.at[1].add(_exchange_bytes(Kc))
-                rec_s = find_best_batch(h_small, small_sums[s:s + Kc])
-                rec_l = find_best_batch(h_large, large_sums[s:s + Kc])
-                stv = stv.at[2].add(2 * _records_bytes(Kc))
-                sil = small_is_left[s:s + Kc, None]
-                recL = jnp.where(sil, rec_s, rec_l)
-                recR = jnp.where(sil, rec_l, rec_s)
-                li = jnp.where(dk, pl_[s:s + Kc], L)
-                ni = jnp.where(dk, new_leaf[s:s + Kc], L)
-                lb = leaf_best2.at[li].set(recL, mode="drop").at[ni].set(
-                    recR, mode="drop")
+                    sil = small_is_left[s:s + Kc, None]
+                    li = jnp.where(dk, pl_[s:s + Kc], L)
+                    ni = jnp.where(dk, new_leaf[s:s + Kc], L)
+                h_small, launch = hist_pass(slv, dk, tiers_small)
+                with jax.named_scope("lgbt.exchange"):
+                    h_small = exchange(h_small)    # [Kc, F|Fs, 3, B]
+                    stv = (stv + launch).at[S_EXCHANGE].add(
+                        _exchange_bytes(Kc))
                 if cache_parent_hist:
-                    hL = jnp.where(sil[:, :, None, None], h_small, h_large)
-                    hR = jnp.where(sil[:, :, None, None], h_large, h_small)
-                    lh = leaf_hist2.at[li].set(hL, mode="drop").at[ni].set(
-                        hR, mode="drop")
+                    with jax.named_scope("lgbt.subtract"):
+                        h_large = leaf_hist2[pl_[s:s + Kc]] - h_small
+                else:
+                    h_large, launch = hist_pass(llv, dk, tiers_all)
+                    with jax.named_scope("lgbt.exchange"):
+                        h_large = exchange(h_large)
+                        stv = (stv + launch).at[S_EXCHANGE].add(
+                            _exchange_bytes(Kc))
+                with jax.named_scope("lgbt.split"):
+                    rec_s = find_best_batch(h_small, small_sums[s:s + Kc])
+                    rec_l = find_best_batch(h_large, large_sums[s:s + Kc])
+                    stv = stv.at[S_RECORDS].add(2 * _records_bytes(Kc))
+                    recL = jnp.where(sil, rec_s, rec_l)
+                    recR = jnp.where(sil, rec_l, rec_s)
+                    lb = leaf_best2.at[li].set(recL, mode="drop").at[ni].set(
+                        recR, mode="drop")
+                if cache_parent_hist:
+                    with jax.named_scope("lgbt.subtract"):
+                        hL = jnp.where(sil[:, :, None, None], h_small,
+                                       h_large)
+                        hR = jnp.where(sil[:, :, None, None], h_large,
+                                       h_small)
+                        lh = leaf_hist2.at[li].set(hL, mode="drop").at[
+                            ni].set(hR, mode="drop")
                 else:
                     lh = leaf_hist2
                 return lb, lh, stv
@@ -748,32 +807,49 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             def skip_chunk(args):
                 return args
 
+            with jax.named_scope("lgbt.select"):
+                any_dk = jnp.any(dk)
             # graftlint: allow(divergent-collective) — dk slices `do`, derived from the replicated leaf_best records (psum/combine_sharded_records outputs carried through the while_loop), so every shard computes the identical predicate and takes the same branch; the DivergenceSanitizer checks the products at run time
             leaf_best2, leaf_hist2, stats2 = jax.lax.cond(
-                jnp.any(dk), do_chunk, skip_chunk,
+                any_dk, do_chunk, skip_chunk,
                 (leaf_best2, leaf_hist2, stats2))
 
-        return (rnd + 1, leaf_id2, leaf_best2, leaf_depth2, leaf_parent2,
+        return (rnd2, leaf_id2, leaf_best2, leaf_depth2, leaf_parent2,
                 leaf_side2, leaf_hist2, perm2, leaf_off2, leaf_cnt2,
                 stats2, arrs2)
 
     def round_cond(st):
         rnd, leaf_best, leaf_depth, arrs = st[0], st[2], st[3], st[-1]
-        gated = jnp.where((max_depth <= 0) | (leaf_depth < max_depth),
-                          leaf_best[:, 0], NEG_INF)
-        return ((rnd < R) & (arrs.num_leaves < L)
-                & jnp.any(gated > 0))
+        with jax.named_scope("lgbt.select"):
+            gated = jnp.where((max_depth <= 0) | (leaf_depth < max_depth),
+                              leaf_best[:, 0], NEG_INF)
+            return ((rnd < R) & (arrs.num_leaves < L)
+                    & jnp.any(gated > 0))
 
     st = (jnp.int32(0), leaf_id, leaf_best, leaf_depth, leaf_parent,
           leaf_side, leaf_hist, perm, leaf_off, leaf_cnt, stats,
           arrs)
     st = jax.lax.while_loop(round_cond, round_body, st)
-    # rows and sparse entries are summed across shards (global
-    # traffic); the byte counters stay per-device (passes are uniform,
-    # so every shard agrees)
-    stv = st[-2]
-    stv = stv.at[0].set(_psum(stv[0], row_axes))
-    return st[-1], st[1], stv.at[3].set(_psum(stv[3], row_axes))
+    # rows, sparse entries and contraction operations are summed across
+    # shards (global traffic); the byte counters and the round, launch
+    # and slot counts stay per-device (passes are uniform, so every
+    # shard agrees)
+    with jax.named_scope("lgbt.pack"):
+        stv = st[-2]
+        glob = jnp.asarray([S_ROWS, S_NNZ, S_OPS])
+        stv = stv.at[glob].set(_psum(stv[glob], row_axes))
+    return st[-1], st[1], stv
+
+
+def _jit_build(fn):
+    """jit the build step `fn` as `build_tree_rounds`.  jax.jit names a
+    program after the function it is given, and a functools.partial or a
+    shard_map closure has no name: the step would run as `jit__unknown`,
+    which is no handle for a trace."""
+    def step(*args):
+        return fn(*args)
+    step.__name__ = step.__qualname__ = build_tree_rounds.__name__
+    return jax.jit(step)
 
 
 class RoundsTreeLearner:
@@ -966,7 +1042,8 @@ class RoundsTreeLearner:
                   ftbl=ftbl, unb=unb, sparse=self.sparse,
                   input_dtype=getattr(cfg, "histogram_dtype", "float32"))
         if mesh is None:
-            self._build = jax.jit(functools.partial(build_tree_rounds, **kw))
+            self._build = _jit_build(
+                functools.partial(build_tree_rounds, **kw))
             if self.sparse:
                 self.bins_dev = ((jnp.asarray(cols_np),
                                   jnp.asarray(ell_np), jnp.asarray(zb_np))
@@ -990,7 +1067,7 @@ class RoundsTreeLearner:
             in_specs = (bins_spec, P(da), P(da), P(da), P(), P(), P())
             out_specs = (jax.tree_util.tree_map(lambda _: P(), TreeArrays(
                 *[0] * len(TreeArrays._fields))), P(da), P())
-            self._build = jax.jit(jax.shard_map(
+            self._build = _jit_build(jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False))
             if self.mh is not None:
@@ -1127,36 +1204,30 @@ class RoundsTreeLearner:
         with NO device→host sync — callers pipeline the tree fetch and can
         score valid sets straight from the device TreeArrays."""
         from .fused import pack_tree_arrays
-        from .. import profiling
         mask, fmask = self._masks(bag_idx)
         arrs, leaf_id, stats = self._build(
             self.bins_dev, self._pad_rows(grad), self._pad_rows(hess), mask,
             self.num_bins_dev, self.is_cat_dev, fmask)
         # device scalars, folded into the counters at the next metrics
         # read — no sync on the pipelined path
-        self._record_stats(profiling, stats)
+        self._record_stats(stats)
         packed = pack_tree_arrays(arrs)
         check_tree_divergence("rounds/tree", arrs, packed)
         return packed, slice_rows_dev(leaf_id, n=self.N), arrs
 
-    def _record_stats(self, profiling, stats) -> None:
-        # one jitted unstack: eager stats[i] indexing lowers to
-        # dynamic_slice and uploads its start index per iteration
-        s0, s1, s2, s3 = unstack_scalars(4)(stats)
-        profiling.count_deferred(profiling.HIST_ROWS_TOUCHED, s0)
-        profiling.count_deferred(profiling.HIST_EXCHANGE_BYTES, s1)
-        profiling.count_deferred(profiling.SPLIT_RECORDS_BYTES, s2)
-        profiling.count_deferred(profiling.SPARSE_NNZ_TOUCHED, s3)
+    def _record_stats(self, stats) -> None:
+        # the whole vector against its counters: one device add per
+        # iteration and one fetch at the drain, no sync here
+        profiling.count_deferred(STATS_COUNTERS, stats)
 
     def train(self, grad: jax.Array, hess: jax.Array,
               bag_idx: Optional[jax.Array] = None,
               bag_count: Optional[int] = None) -> Tuple[Tree, jax.Array]:
-        from .. import profiling
         mask, fmask = self._masks(bag_idx)
         arrs, leaf_id, stats = self._build(
             self.bins_dev, self._pad_rows(grad), self._pad_rows(hess), mask,
             self.num_bins_dev, self.is_cat_dev, fmask)
-        self._record_stats(profiling, stats)
+        self._record_stats(stats)
         check_tree_divergence("rounds/tree", arrs)
         tree = tree_arrays_to_host(arrs, self.dataset, self.config.num_leaves)
         if self.mh is not None:

@@ -63,6 +63,13 @@ class Objective:
     def to_string(self) -> str:
         return self.name
 
+    def _jit_gradients(self, f):
+        """jit the gradient function under the objective's name: its XLA
+        module reads `jit_gradients_<objective>` in a trace, not
+        `jit_f`."""
+        f.__name__ = f.__qualname__ = f"gradients_{self.name}"
+        return jax.jit(f)
+
     def _apply_weights(self, g, h):
         if self.weights is None:
             return g, h
@@ -81,7 +88,6 @@ class RegressionL2(Objective):
     def init(self, metadata, num_data):
         super().init(metadata, num_data)
 
-        @jax.jit
         def f(score, label, weights):
             g = score - label[None, :]
             h = jnp.ones_like(g)
@@ -89,7 +95,7 @@ class RegressionL2(Objective):
                 g = g * weights[None, :]
                 h = h * weights[None, :]
             return g, h
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         return self._f(score, self.label, self.weights)
@@ -110,7 +116,6 @@ class RegressionL1(Objective):
         super().init(metadata, num_data)
         eta = self.config.gaussian_eta
 
-        @jax.jit
         def f(score, label, weights):
             lab = label[None, :]
             diff = score - lab
@@ -118,7 +123,7 @@ class RegressionL1(Objective):
             g = jnp.where(diff >= 0.0, 1.0, -1.0) * w
             h = w * _gaussian_hessian(score, lab, g, eta, w)
             return g, h
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         return self._f(score, self.label, self.weights)
@@ -146,7 +151,6 @@ class RegressionHuber(Objective):
         delta = self.config.huber_delta
         eta = self.config.gaussian_eta
 
-        @jax.jit
         def f(score, label, weights):
             lab = label[None, :]
             diff = score - lab
@@ -158,7 +162,7 @@ class RegressionHuber(Objective):
                                           eta, w)
             h = jnp.where(small, h_small, h_big)
             return g, h
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         return self._f(score, self.label, self.weights)
@@ -175,14 +179,13 @@ class RegressionFair(Objective):
         super().init(metadata, num_data)
         c = self.config.fair_c
 
-        @jax.jit
         def f(score, label, weights):
             x = score - label[None, :]
             w = jnp.ones_like(score) if weights is None else weights[None, :]
             g = c * x / (jnp.abs(x) + c) * w
             h = c * c / ((jnp.abs(x) + c) ** 2) * w
             return g, h
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         return self._f(score, self.label, self.weights)
@@ -199,7 +202,6 @@ class RegressionPoisson(Objective):
         super().init(metadata, num_data)
         mds = self.config.poisson_max_delta_step
 
-        @jax.jit
         def f(score, label, weights):
             g = score - label[None, :]
             h = score + mds
@@ -207,7 +209,7 @@ class RegressionPoisson(Objective):
                 g = g * weights[None, :]
                 h = h * weights[None, :]
             return g, h
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         return self._f(score, self.label, self.weights)
@@ -238,7 +240,6 @@ class BinaryLogloss(Objective):
         w_pos *= self.config.scale_pos_weight
         sigmoid = self.sigmoid
 
-        @jax.jit
         def f(score, label, weights):
             is_p = label[None, :] > 0
             lbl = jnp.where(is_p, 1.0, -1.0)
@@ -251,7 +252,7 @@ class BinaryLogloss(Objective):
                 g = g * weights[None, :]
                 h = h * weights[None, :]
             return g, h
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         if not self.need_train:
@@ -282,7 +283,6 @@ class MulticlassSoftmax(Objective):
                 f"Label must be in [0, {self.num_class}) for multiclass")
         self._label_int = jnp.asarray(lab)
 
-        @jax.jit
         def f(score, label_int, weights):
             p = softmax(score, axis=0)                       # [K, N]
             onehot = (jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
@@ -293,7 +293,7 @@ class MulticlassSoftmax(Objective):
                 g = g * weights[None, :]
                 h = h * weights[None, :]
             return g, h
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         return self._f(score, self._label_int, self.weights)
@@ -321,7 +321,6 @@ class MulticlassOVA(Objective):
         self._label_int = jnp.asarray(lab)
         sigmoid = self.sigmoid
 
-        @jax.jit
         def f(score, label_int, weights):
             is_p = (jax.lax.broadcasted_iota(jnp.int32, score.shape, 0)
                     == label_int[None, :])
@@ -334,7 +333,7 @@ class MulticlassOVA(Objective):
                 g = g * weights[None, :]
                 h = h * weights[None, :]
             return g, h
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         return self._f(score, self._label_int, self.weights)
@@ -402,7 +401,6 @@ class LambdarankNDCG(Objective):
         self._lab_pad = jnp.asarray(np.concatenate([lab, [0]]).astype(jnp.int32))
         self._q_chunk = qc
 
-        @jax.jit
         def f(score, lab_pad, doc_idx, mask, inv_max_dcg):
             s1 = score[0]
             s_pad = jnp.concatenate([s1, jnp.zeros(1, s1.dtype)])
@@ -462,7 +460,7 @@ class LambdarankNDCG(Objective):
                 h = h * self.weights
             return g[None, :], h[None, :]
 
-        self._f = f
+        self._f = self._jit_gradients(f)
 
     def get_gradients(self, score):
         return self._f(score, self._lab_pad, self._doc_idx, self._mask,

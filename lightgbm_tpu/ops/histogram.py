@@ -22,6 +22,7 @@ Two implementations:
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -512,6 +513,64 @@ def packed_bins_layout(max_num_bin: int, num_bins_padded: int):
     return 0, 1
 
 
+class _MaskedLayout(NamedTuple):
+    """Static shapes of one masked Pallas launch: the feature group and
+    its packed size, value rows and slots as padded to the sublane tile,
+    the bin window, the row chunk, rows as padded to it and columns as
+    padded to the group."""
+    G: int
+    Gp: int
+    pack: int
+    bins_sub: int
+    Mp: int
+    Kp: int
+    Bs: int
+    Ck: int
+    Cp: int
+    Fg: int
+
+
+def _masked_layout(F: int, C: int, K: int, bins_itemsize: int, B: int,
+                   input_dtype: str, max_num_bin: int) -> _MaskedLayout:
+    """The layout of one masked launch over [F, C] bins for K slots."""
+    # int8 bins keep their narrow dtype into the kernel; the int8 VMEM
+    # tile is (32, 128), so the feature-group sublane dim grows to 32
+    narrow = bins_itemsize == 1
+    G = 32 if narrow else FEATURE_GROUP
+    Mp = 8 * ((3 * K + 7) // 8)
+    Kp = 8 * ((K + 7) // 8)
+    bins_sub, pack = packed_bins_layout(max_num_bin, B)
+    # bin windows: the output block of one grid cell is at most 256 lanes
+    # wide (128 at G=32), the bin axis beyond that goes over the grid.
+    # The full [1, Gp, Mp, B] f32 block double-buffers to 4 MB at G=8,
+    # Mp=256, B=256 — a quarter of the VMEM scope, and what
+    # _MASKED_CHUNK was validated against; G=32 or B=512 would double
+    # it.  The one-hot compare is redone per window (cheap), the matmul
+    # work is unchanged.
+    Bs = min(B, 128 if (narrow or B % 256) else 256)
+    Ck = min(C, _masked_chunk(Mp, bins_itemsize, input_dtype))
+    Cp = C + (-C) % Ck
+    Fg = G * ((F + G - 1) // G)
+    return _MaskedLayout(G, G // pack, pack, bins_sub, Mp, Kp, Bs, Ck, Cp, Fg)
+
+
+def masked_hist_mxu_ops(F: int, C: int, K: int, *, bins_itemsize: int,
+                        num_bins_padded: int, backend: str,
+                        input_dtype: str, max_num_bin: int = 0) -> float:
+    """Operations (2 per multiply-add) that the contraction of one
+    `hist_multileaf_masked` launch over [F, C] bins and K slots
+    performs, padding included — what the MXU is asked to do, not what
+    the histogram needs.  Pallas: every row of the padded chunk grid
+    against Mp value rows, for each (packed) column of the padded
+    feature groups, over the padded bins.  XLA fallback: the plain
+    [3K, C] x [F, C, B] einsum."""
+    B = num_bins_padded
+    if backend != "pallas":
+        return 2.0 * C * 3 * K * F * B
+    lay = _masked_layout(F, C, K, bins_itemsize, B, input_dtype, max_num_bin)
+    return 2.0 * lay.Cp * lay.Mp * (lay.Fg // lay.pack) * B
+
+
 @functools.partial(jax.jit, static_argnames=("num_bins_padded", "backend",
                                              "input_dtype", "interpret",
                                              "max_num_bin"))
@@ -560,55 +619,49 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
         quant = False
         input_dtype = "bfloat16"
 
+    # `lgbt.feed` names what prepares the operands of a pass in a trace.
+    # The contraction and its unpacking take the caller's scope
+    # (`lgbt.hist`, `lgbt.root`): a scope opened here around the
+    # pallas_call would give the custom call its name in place of this
+    # function's, which is how a trace finds the kernel
     if backend != "pallas":
-        if bin_offset:
-            gb_t = gb_t.astype(jnp.int32) + bin_offset
-        if quant:
-            ghq, sg, sh = _quantize_gh(gh8)
-            gh8 = jnp.concatenate([
-                ghq[0:1].astype(jnp.float32) * sg,
-                ghq[1:2].astype(jnp.float32) * sh,
-                gh8[2:3], gh8[3:]], axis=0)
-            input_dtype = "float32"
-        m = (lid[None, :] == sl[:, None]).astype(jnp.float32)
-        vals = jnp.concatenate(
-            [m * gh8[0:1], m * gh8[1:2], m * gh8[2:3]], axis=0)  # [3K, C]
+        with jax.named_scope("lgbt.feed"):
+            if bin_offset:
+                gb_t = gb_t.astype(jnp.int32) + bin_offset
+            if quant:
+                ghq, sg, sh = _quantize_gh(gh8)
+                gh8 = jnp.concatenate([
+                    ghq[0:1].astype(jnp.float32) * sg,
+                    ghq[1:2].astype(jnp.float32) * sh,
+                    gh8[2:3], gh8[3:]], axis=0)
+                input_dtype = "float32"
+            m = (lid[None, :] == sl[:, None]).astype(jnp.float32)
+            vals = jnp.concatenate(
+                [m * gh8[0:1], m * gh8[1:2], m * gh8[2:3]], axis=0)  # [3K, C]
         h = hist_multileaf_xla(gb_t, vals, num_bins_padded=B,
                                input_dtype=input_dtype)          # [F, 3K, B]
         return jnp.stack([h[:, :K], h[:, K:2 * K], h[:, 2 * K:3 * K]],
                          axis=2).transpose(1, 0, 2, 3)
 
-    # int8 bins keep their narrow dtype into the kernel; the int8 VMEM
-    # tile is (32, 128), so the feature-group sublane dim grows to 32
-    G = 32 if bin_offset else FEATURE_GROUP
-    Mp = 8 * ((3 * K + 7) // 8)
-    Kp = 8 * ((K + 7) // 8)
-    bins_sub, pack = packed_bins_layout(max_num_bin, B)
-    Gp = G // pack
-    # bin windows: the output block of one grid cell is at most 256 lanes
-    # wide (128 at G=32), the bin axis beyond that goes over the grid.
-    # The full [1, Gp, Mp, B] f32 block double-buffers to 4 MB at G=8,
-    # Mp=256, B=256 — a quarter of the VMEM scope, and what
-    # _MASKED_CHUNK was validated against; G=32 or B=512 would double
-    # it.  The one-hot compare is redone per window (cheap), the matmul
-    # work is unchanged.
-    Bs = min(B, 128 if (bin_offset or B % 256) else 256)
+    G, Gp, pack, bins_sub, Mp, Kp, Bs, Ck, Cp, Fg = _masked_layout(
+        F, C, K, gb_t.dtype.itemsize, B, input_dtype, max_num_bin)
     nB = B // Bs
-    Ck = min(C, _masked_chunk(Mp, gb_t.dtype.itemsize, input_dtype))
-    if C % Ck:
-        pad = Ck - C % Ck
-        gb_t = jnp.pad(gb_t, ((0, 0), (0, pad)))
-        lid = jnp.pad(lid, (0, pad), constant_values=-2)
-        gh8 = jnp.pad(gh8, ((0, 0), (0, pad)))
-        C += pad
-    Fg = G * ((F + G - 1) // G)
-    if Fg > F:
-        gb_t = jnp.pad(gb_t, ((0, Fg - F), (0, 0)))
-    gb_g = gb_t.reshape(Fg // G, G, C)
-    if not bin_offset:
-        gb_g = gb_g.astype(jnp.int32)
-    sl2 = jnp.broadcast_to(jnp.pad(sl, (0, Kp - K),
-                                   constant_values=-1)[:, None], (Kp, 128))
+    with jax.named_scope("lgbt.feed"):
+        if Cp > C:
+            pad = Cp - C
+            gb_t = jnp.pad(gb_t, ((0, 0), (0, pad)))
+            lid = jnp.pad(lid, (0, pad), constant_values=-2)
+            gh8 = jnp.pad(gh8, ((0, 0), (0, pad)))
+            C = Cp
+        if Fg > F:
+            gb_t = jnp.pad(gb_t, ((0, Fg - F), (0, 0)))
+        gb_g = gb_t.reshape(Fg // G, G, C)
+        if not bin_offset:
+            gb_g = gb_g.astype(jnp.int32)
+        sl2 = jnp.broadcast_to(
+            jnp.pad(sl, (0, Kp - K), constant_values=-1)[:, None], (Kp, 128))
+        if quant:
+            ghq, sg, sh = _quantize_gh(gh8)
     if nB > 1:
         grid = (Fg // G, nB, C // Ck)
         in_specs = [
@@ -644,7 +697,6 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
         return jnp.pad(h, ((0, 0), (0, 0), (0, B - bins_sub)))[:F]
 
     if quant:
-        ghq, sg, sh = _quantize_gh(gh8)
         out = pl.pallas_call(
             functools.partial(_hist_kernel_masked_q, B=B, K=K, pack=pack,
                               bins_sub=bins_sub, bin_offset=bin_offset,
@@ -778,12 +830,14 @@ def hist_multileaf_gathered(bins_fn: jax.Array, gh8: jax.Array,
     scales derive from the gathered rows only (a tighter bound than the
     masked kernel's all-rows max — strictly less rounding error)."""
     K = seg_off.shape[0]
-    idx, slot, _ = gather_segments(perm, seg_off, seg_cnt,
-                                   capacity=capacity)
-    gbg = jnp.take(bins_fn, idx, axis=1)             # [F, capacity]
-    live = (slot >= 0)
-    ghg = jnp.take(gh8, idx, axis=1) * live[None, :].astype(jnp.float32)
-    sl = jax.lax.iota(jnp.int32, K)
+    with jax.named_scope("lgbt.feed"):
+        idx, slot, _ = gather_segments(perm, seg_off, seg_cnt,
+                                       capacity=capacity)
+        gbg = jnp.take(bins_fn, idx, axis=1)         # [F, capacity]
+        live = (slot >= 0)
+        ghg = (jnp.take(gh8, idx, axis=1)
+               * live[None, :].astype(jnp.float32))
+        sl = jax.lax.iota(jnp.int32, K)
     return hist_multileaf_masked(gbg, slot, ghg, sl,
                                  num_bins_padded=num_bins_padded,
                                  backend=backend, input_dtype=input_dtype,

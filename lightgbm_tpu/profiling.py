@@ -1,52 +1,55 @@
-"""Phase-bucketed wall-clock tracing (reference TIMETAG subsystem:
-std::chrono accumulators over boosting/bagging/tree/score/metric phases,
-gbdt.cpp:20-29,50-60, serial_tree_learner.cpp:10-17, logged at teardown)
-plus a hook into jax.profiler for device traces.
+"""Host phase spans, counters and sample reservoirs.
 
-Enable with LIGHTGBM_TPU_TIMETAG=1 (compile-time macro in the reference →
-environment switch here); totals print at interpreter exit or via
-`report()`.
+`phase(name)` is the one way the training path marks a host phase
+(boosting/bagging/tree/score/metric — the reference's std::chrono
+accumulators, gbdt.cpp:20-29,50-60): it always opens a
+`jax.profiler.TraceAnnotation("lgbt.<name>")`, so a profiler session
+shows the program's spans on the same clock as the device events, and
+it feeds the wall-clock accumulators only when `telemetry.configure`
+has switched them on (the `train.iteration` event's `phases` field).
+With `force=True` (the serving `/stats` phases) it is the accumulator
+alone, always on, and opens no span.  The
+accumulators time the host: around asynchronous dispatch they measure
+the enqueue, not the device work.
 """
 from __future__ import annotations
 
-import atexit
 import math
-import os
 import threading
 import time
 from collections import defaultdict, deque
 from contextlib import contextmanager
 from typing import Deque, Dict, Iterator, Optional, Tuple
 
-ENABLED = os.environ.get("LIGHTGBM_TPU_TIMETAG", "0") not in ("0", "", "false")
-
 # telemetry.configure() flips this so the phase accumulators run (and
-# feed per-iteration records + /metrics) whenever span tracing is on,
-# without requiring the LIGHTGBM_TPU_TIMETAG env switch too
+# feed per-iteration records + /metrics) whenever span tracing is on
 _PHASES_FORCED = False
+
+# prefix of every span the program writes into a profiler trace
+SPAN_PREFIX = "lgbt."
 
 
 def force_phases(on: bool = True) -> None:
-    """Force the phase accumulators on regardless of the TIMETAG env
-    switch (telemetry.configure does; telemetry.reset undoes)."""
+    """Switch the phase accumulators on (telemetry.configure does;
+    telemetry.reset undoes)."""
     global _PHASES_FORCED
     _PHASES_FORCED = bool(on)
 
 _totals: Dict[str, float] = defaultdict(float)
-_counts: Dict[str, int] = defaultdict(int)
 
 # Always-on counters and bounded sample reservoirs (the serving layer's
-# request/cache/latency metrics flow through these regardless of the
-# TIMETAG switch — a production /stats endpoint cannot depend on a debug
-# env var).  Guarded by one lock: serving increments from many threads.
+# request/cache/latency metrics flow through these whether or not
+# telemetry is configured — a production /stats endpoint cannot depend
+# on a debug switch).  Guarded by one lock: serving increments from many
+# threads.
 _lock = threading.Lock()
 _counters: Dict[str, float] = defaultdict(float)
 _samples: Dict[str, Deque[float]] = {}
 _SAMPLE_CAP = 4096
-# one pending device scalar per name (count_deferred accumulates
-# DEVICE-side, so an arbitrarily long training run holds exactly one
-# live buffer per counter), folded into _counters on read
-_deferred: Dict[str, object] = {}
+# one pending device vector per tuple of names (count_deferred
+# accumulates DEVICE-side, so an arbitrarily long training run holds
+# exactly one live buffer per tuple), folded into _counters on read
+_deferred: Dict[Tuple[str, ...], object] = {}
 
 # Canonical counter names of the data-parallel tree learners' comms
 # layer, fed through count_deferred (device-side accumulation, no sync
@@ -75,6 +78,26 @@ HIST_ROWS_TOUCHED = "tree/hist_rows_touched"
 HIST_EXCHANGE_BYTES = "tree/hist_exchange_bytes"
 SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
 HIST_ROWS_DOWNGRADES = "tree/hist_rows_downgrades"
+
+# Work counters of the rounds build (learner/rounds.build_tree_rounds
+# adds them on the device, where each launch is made; they ride its
+# stats vector like the ones above):
+#  - TREE_ROUNDS: rounds of the build's while_loop (every round
+#    partitions all rows once and splits every splittable leaf).
+#  - HIST_PASSES: histogram kernel launches, the root's included.
+#  - HIST_SLOTS: leaf slots those launches were made for — the K of
+#    each launch (its matmul has 3K value rows), 1 at the root.
+#  - HIST_LIVE_SLOTS: the slots among them that held a leaf;
+#    live / slots is how full the launches ran.
+#  - HIST_MXU_OPS: multiply-adds x 2 that the dense launches'
+#    contractions perform, padding included (ops/histogram.
+#    masked_hist_mxu_ops), summed across shards.  The sparse kernels
+#    add 0: their contraction runs over entry blocks, not rows.
+TREE_ROUNDS = "tree/rounds"
+HIST_PASSES = "tree/hist_passes"
+HIST_SLOTS = "tree/hist_slots"
+HIST_LIVE_SLOTS = "tree/hist_live_slots"
+HIST_MXU_OPS = "tree/hist_mxu_ops"
 
 # Canonical sparse-store counters (docs/Sparse.md), the nnz-scaling
 # evidence behind the sparse-vs-dense CTR A/B:
@@ -191,7 +214,8 @@ ROUTER_REHASHES = "router/rehashes"
 # sites use the constants instead of re-typing the strings.
 CANONICAL_COUNTERS = (
     HIST_ROWS_TOUCHED, HIST_EXCHANGE_BYTES, SPLIT_RECORDS_BYTES,
-    HIST_ROWS_DOWNGRADES, SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
+    HIST_ROWS_DOWNGRADES, TREE_ROUNDS, HIST_PASSES, HIST_SLOTS,
+    HIST_LIVE_SLOTS, HIST_MXU_OPS, SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,
     SERVE_QUANTIZE_BYTES_IN, SERVE_BINNED_REQUESTS,
@@ -225,26 +249,31 @@ def labeled(name: str, **labels) -> str:
 
 
 @contextmanager
-def phase(name: str, force: bool = False) -> Iterator[None]:
-    """Accumulate wall-clock under `name`.  No-op unless enabled, except
-    `force=True` (serving phases) which always accumulates."""
-    if not (ENABLED or force or _PHASES_FORCED):
-        yield
-        return
-    t0 = time.perf_counter()
+def phase(name: str, force: bool = False, **attrs) -> Iterator[None]:
+    """Mark a host phase of training: a `TraceAnnotation` named
+    "lgbt.<name>" (with `attrs` as its stats) that a running profiler
+    session records and that costs a disabled-TraceMe check otherwise;
+    wall-clock is accumulated under `name` only while telemetry is
+    configured.  With `force=True` (the serving `/stats` phases) it is
+    the accumulator alone: always timed, and no span."""
+    timed = force or _PHASES_FORCED
+    t0 = time.perf_counter() if timed else 0.0
     try:
-        yield
+        if force:
+            yield
+        else:
+            import jax
+            with jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **attrs):
+                yield
     finally:
-        with _lock:
-            _totals[name] += time.perf_counter() - t0
-            _counts[name] += 1
+        if timed:
+            add(name, time.perf_counter() - t0, force=True)
 
 
 def add(name: str, seconds: float, force: bool = False) -> None:
-    if ENABLED or force or _PHASES_FORCED:
+    if force or _PHASES_FORCED:
         with _lock:
             _totals[name] += seconds
-            _counts[name] += 1
 
 
 def count(name: str, inc: float = 1.0) -> None:
@@ -253,32 +282,34 @@ def count(name: str, inc: float = 1.0) -> None:
         _counters[name] += inc
 
 
-def count_deferred(name: str, value) -> None:
-    """Accumulate a DEVICE scalar against a counter without forcing a
-    host sync (the pipelined trainer must not stall on a metrics fetch
-    — the device→host transfer that motivates
-    _train_one_iter_pipelined).  Accumulation happens device-side (`+`
-    dispatches asynchronously), so only one buffer per name stays live;
-    the total is converted and folded into the counter on the next
+def count_deferred(names: Tuple[str, ...], values) -> None:
+    """Accumulate a DEVICE vector against a tuple of counters, one
+    element per name, without forcing a host sync (the pipelined
+    trainer must not stall on a metrics fetch — the device→host
+    transfer that motivates _train_one_iter_pipelined).  Accumulation
+    happens device-side (`+` dispatches asynchronously): one device add
+    per call and one live buffer per tuple of names; the totals are
+    converted and folded into the counters on the next
     counter_value()/counters() read, where the caller has chosen to pay
     the sync."""
     with _lock:
-        prev = _deferred.get(name)
-        _deferred[name] = value if prev is None else prev + value
+        prev = _deferred.get(names)
+        _deferred[names] = values if prev is None else prev + values
 
 
 def _drain_deferred_locked() -> None:
     """Fold pending device totals into _counters; caller holds _lock.
-    ONE batched explicit fetch for every pending counter (jax.device_get
+    ONE batched explicit fetch for every pending vector (jax.device_get
     blocks until the values are ready; per-name float() was one sync per
     counter, and implicit under the sanitizer's transfer guard)."""
     if not _deferred:
         return
     import jax
-    names = list(_deferred)
-    vals = jax.device_get([_deferred[n] for n in names])
-    for name, val in zip(names, vals):
-        _counters[name] += float(val)
+    keys = list(_deferred)
+    vals = jax.device_get([_deferred[k] for k in keys])
+    for names, vec in zip(keys, vals):
+        for name, v in zip(names, vec):
+            _counters[name] += float(v)
     _deferred.clear()
 
 
@@ -351,37 +382,17 @@ def snapshot() -> Tuple[Dict[str, float], Dict[str, Dict[str, float]]]:
 
 
 def timings() -> Dict[str, float]:
-    """Phase totals without printing (the /stats view of the TIMETAG
-    accumulators)."""
+    """Phase totals (the /stats view of the phase accumulators)."""
     with _lock:
         return dict(_totals)
-
-
-def report() -> Dict[str, float]:
-    """Totals per phase; also printed when TIMETAG is on (reference logs
-    at destructor time)."""
-    with _lock:
-        totals = dict(_totals)
-        counts = dict(_counts)
-    if ENABLED and totals:
-        print("[LightGBM-TPU] [Info] ===== timer totals =====", flush=True)
-        for name in sorted(totals, key=totals.get, reverse=True):
-            print(f"[LightGBM-TPU] [Info] {name}: {totals[name]:.4f}s "
-                  f"({counts[name]} calls)", flush=True)
-    return totals
 
 
 def reset() -> None:
     with _lock:
         _totals.clear()
-        _counts.clear()
         _counters.clear()
         _samples.clear()
         _deferred.clear()
-
-
-if ENABLED:
-    atexit.register(report)
 
 
 @contextmanager

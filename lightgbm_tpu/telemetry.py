@@ -1,6 +1,6 @@
 """Unified telemetry: structured span tracing + Prometheus exposition.
 
-The reference proved where time went with the TIMETAG accumulators
+The reference proved where time went with its phase timers
 (gbdt.cpp:20-29) and the GPU paper with per-kernel timing logs
 (arXiv:1706.08359 §5); this package has outgrown both — five long-lived
 process roles (trainer, online daemon, serving fleet, chip-queue
@@ -40,9 +40,10 @@ written, no file is created.  Enabled, every record is host-side
 formatting plus one locked file append: no device op, no host↔device
 sync, so the BENCH_SANITIZE zero-retrace / zero-implicit-transfer
 steady-state contract holds with telemetry on (tests/test_telemetry.py
-pins it).  Enabling telemetry also forces the TIMETAG phase
-accumulators on (`profiling.force_phases`) so per-iteration phase
-wall-clock is available without the LIGHTGBM_TPU_TIMETAG env switch.
+pins it).  Enabling telemetry also switches the phase accumulators of
+`profiling.phase` on (`profiling.force_phases`), which is the only way
+they run on the training path: the `train.iteration` event carries
+their per-iteration deltas.
 
 Configuration: ``telemetry_path`` Config key (aliases ``telemetry``,
 ``trace_path``, ``span_path``) or the ``LIGHTGBM_TPU_TELEMETRY`` env
@@ -119,8 +120,8 @@ def enabled() -> bool:
 
 def configure(path: str, process: Optional[str] = None) -> None:
     """Point the span sink at ``path`` (JSONL, append) and enable
-    tracing.  Also forces the TIMETAG phase accumulators on so
-    per-iteration phase wall-clock flows without the env switch."""
+    tracing.  Also switches the phase accumulators on, so the
+    `train.iteration` event carries per-iteration phase wall-clock."""
     global _enabled, _path, _sink
     if process is not None:
         set_process(process)
@@ -612,8 +613,7 @@ def process_info() -> Dict[str, object]:
     return info
 
 
-# env bootstrap: LIGHTGBM_TPU_TELEMETRY=<path> enables at import, the
-# same pattern as profiling's LIGHTGBM_TPU_TIMETAG switch.  An
+# env bootstrap: LIGHTGBM_TPU_TELEMETRY=<path> enables at import.  An
 # unwritable path degrades to disabled with a warning — an env var must
 # never make the package unimportable (the explicit `telemetry_path`
 # config key, by contrast, raises: the user asked for a sink that

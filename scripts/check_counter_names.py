@@ -66,8 +66,8 @@ def canonical_constants() -> Dict[str, Tuple[str, str]]:
 
 def scan_source(src: str, path: str) -> List[Tuple[str, int, str]]:
     """(path, lineno, literal) for every registry call whose first
-    argument is a string literal — ``profiling.count("x")`` and bare
-    ``count("x")`` both match."""
+    argument is a string literal, or a tuple that holds some —
+    ``profiling.count("x")`` and bare ``count("x")`` both match."""
     sites: List[Tuple[str, int, str]] = []
     try:
         tree = ast.parse(src)
@@ -81,9 +81,11 @@ def scan_source(src: str, path: str) -> List[Tuple[str, int, str]]:
                 else fn.id if isinstance(fn, ast.Name) else None)
         if name not in CALLS or not node.args:
             continue
+        # one name, or count_deferred's tuple of names
         arg = node.args[0]
-        if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
-            sites.append((path, node.lineno, arg.value))
+        for a in (arg.elts if isinstance(arg, ast.Tuple) else [arg]):
+            if isinstance(a, ast.Constant) and isinstance(a.value, str):
+                sites.append((path, node.lineno, a.value))
     return sites
 
 

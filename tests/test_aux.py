@@ -31,17 +31,30 @@ def test_pmml_export(model, tmp_path):
     assert "SimplePredicate" in s
 
 
-def test_profiling_timers(binary_example, monkeypatch):
-    from lightgbm_tpu import profiling
-    monkeypatch.setattr(profiling, "ENABLED", True)
-    profiling.reset()
-    X, y, _, _ = binary_example
-    lgb.train({"objective": "binary", "verbose": -1,
-               "min_data_in_leaf": 10}, lgb.Dataset(X, y),
-              num_boost_round=2, verbose_eval=False)
-    totals = profiling.report()
+def test_profiling_timers(tmp_path):
+    """The phase accumulators run while telemetry is configured, and only
+    then: there is no other switch."""
+    from lightgbm_tpu import profiling, telemetry
+    rng = np.random.RandomState(3)
+    X = rng.randn(400, 6)
+    y = (X[:, 0] + X[:, 1] > 0).astype(float)
+
+    def train():
+        profiling.reset()
+        lgb.train({"objective": "binary", "verbose": -1,
+                   "min_data_in_leaf": 10}, lgb.Dataset(X, y),
+                  num_boost_round=2, verbose_eval=False)
+        return profiling.timings()
+
+    assert train() == {}
+    telemetry.configure(str(tmp_path / "spans.jsonl"))
+    try:
+        totals = train()
+    finally:
+        telemetry.reset()
     assert totals.get("tree", 0) > 0
     assert totals.get("boosting", 0) > 0
+    assert totals.get("update", 0) >= totals["tree"]
     profiling.reset()
 
 

@@ -87,9 +87,21 @@ def test_observe_and_count_deferred_sites_are_scanned():
     mod = _load_checker()
     sites = mod.scan_source(
         'profiling.observe("serve.latency_ms", 1.0)\n'
-        'profiling.count_deferred("tree/x", v)\n'
+        'profiling.count_deferred(("tree/x",), v)\n'
         'other.call("not.a.counter")\n', "x.py")
     assert [(s[2]) for s in sites] == ["serve.latency_ms", "tree/x"]
+
+
+def test_a_tuple_of_names_is_scanned_name_by_name():
+    """count_deferred names its counters in a tuple."""
+    mod = _load_checker()
+    consts = mod.canonical_constants()
+    sites = mod.scan_source(
+        'profiling.count_deferred(("tree/rounds", "tree/y", K), vec)\n',
+        "x.py")
+    assert [s[2] for s in sites] == ["tree/rounds", "tree/y"]
+    findings = mod.lint(sites, consts)
+    assert len(findings) == 1 and "TREE_ROUNDS" in findings[0]
 
 
 def test_canonical_constants_are_harvested():

@@ -492,8 +492,8 @@ def test_train_iteration_and_eval_records(telem):
     assert iters[-1]["attrs"]["trees"] >= iters[0]["attrs"]["trees"]
     assert iters[0]["attrs"]["rows"] == 300
     assert iters[0]["attrs"]["seconds"] > 0
-    # telemetry forces the TIMETAG phase accumulators on, so the
-    # per-iteration record carries phase wall-clock without the env var
+    # telemetry switches the phase accumulators on, so the
+    # per-iteration record carries phase wall-clock
     assert any("tree" in r["attrs"]["phases"] for r in iters)
     assert "counters" in iters[0]["attrs"]
     evs = names["train.eval"]
