@@ -314,12 +314,22 @@ def _simple_onehot(gb, B, input_dtype):
 
 def _packed_onehot(gb_ref, g_, B, pack, bins_sub, out_dtype,
                    bin_offset=0, bwin=0):
-    """One-hot block for `pack` features sharing the 128 lanes: feature
-    s of the pack occupies lanes [s·bins_sub, (s+1)·bins_sub), so ONE
-    [M, Ck] @ [Ck, B] matmul histograms all `pack` features — the fix
-    for the 2x bin-axis padding tax at max_bin<=63 (the reference GPU
-    sweet spot, docs/GPU-Performance.md:153-156): without packing a
-    64-bin histogram still pays full 128-lane MXU work.
+    """One-hot block [B, Ck] — bins on the sublanes, rows on the lanes —
+    for `pack` features sharing the 128 bins of one output lane block:
+    feature s of the pack occupies bins [s·bins_sub, (s+1)·bins_sub), so
+    ONE [M, Ck] x [B, Ck] contraction over the rows histograms all `pack`
+    features — the fix for the 2x bin-axis padding tax at max_bin<=63
+    (the reference GPU sweet spot, docs/GPU-Performance.md:153-156):
+    without packing a 64-bin histogram still pays full 128-lane MXU work.
+
+    Rows stay on the lanes, where the bins block holds them: a column's
+    bins are read as a row vector and compared against a [B, 1] iota, so
+    each vreg of eight bins x 128 rows costs one sublane replicate of
+    the bins and one compare.  A [Ck, B] one-hot (`gb[:, None] == iota`)
+    has to move every bin value from a lane to a sublane and spread it
+    over 128 lanes first: one `vbcast_sublane_chunk` per eight rows of
+    every column, which sets the kernel's pace at every K and bin count
+    (PERF.md section 6, PR 30: 1.9x / 5.9x the kernel time at Epsilon).
 
     bin_offset: bins may arrive stored as int8 `bin - 128` (the HBM
     layout that fits Expo-scale 11M x 700 on one chip); the widen +
@@ -330,21 +340,26 @@ def _packed_onehot(gb_ref, g_, B, pack, bins_sub, out_dtype,
     one 128-lane tile).  B here is the WINDOW width (the out block's
     lane count), not the full bin count.
 
-    The [Ck, B] equality runs in int32 and only its result narrows to
+    The [B, Ck] equality runs in int32 and only its result narrows to
     the matmul operand dtype.  A compare in the operand dtype (int8 /
     bf16 tiles hold 4x / 2x the lanes) does not exist on the v5e VPU:
     Mosaic refuses `arith.cmpi` on i8 vectors and `arith.cmpf` on bf16
     ("Target does not support this comparison"), and an i1 mask has no
     relayout to the (32, 128) int8 tile, hence the i32 hop below."""
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, B), 1) + bwin
+    iota = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0) + bwin
     acc = None
     for s in range(pack):
         gb = gb_ref[0, g_ * pack + s, :].astype(jnp.int32) + bin_offset
-        cmp = (gb[:, None] + (s * bins_sub)) == iota
+        cmp = (gb[None, :] + (s * bins_sub)) == iota
         acc = cmp if acc is None else acc | cmp
     if out_dtype == jnp.int8:
         return acc.astype(jnp.int32).astype(jnp.int8)
     return acc.astype(out_dtype)
+
+
+# Both operands of the masked kernels' contraction hold the rows on
+# their last (lane) axis: vals [Mp, Ck] x one-hot [Bs, Ck] -> [Mp, Bs].
+_CONTRACT_ROWS = (((1,), (1,)), ((), ()))
 
 
 def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
@@ -366,6 +381,12 @@ def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
     Fusing the mask construction here avoids materializing the [3K, N]
     values matrix in HBM per chunk (the XLA-level formulation round-trips
     ~0.5 GB per histogram pass at N=1M).
+
+    Every per-row operand keeps the rows on the lanes, as the blocks
+    deliver them: the leaf mask `lid[None, :] == sl` is [K, Ck], vals is
+    [Mp, Ck], the one-hot of a (packed) column is [B, Ck]
+    (_packed_onehot), and the contraction runs over the last axis of
+    both — no per-row value is ever moved from a lane to a sublane.
 
     Grid is (feature-blocks, row-chunks), or (feature-blocks,
     bin-windows, row-chunks) when `windowed` — the out block then
@@ -403,8 +424,9 @@ def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
     for g_ in range(G // pack):
         oh = _packed_onehot(gb_ref, g_, Bs, pack, bins_sub, input_dtype,
                             bin_offset, bwin)
-        out_ref[0, g_, :, :] += jnp.dot(
-            vals, oh, preferred_element_type=jnp.float32, precision=prec)
+        out_ref[0, g_, :, :] += jax.lax.dot_general(
+            vals, oh, _CONTRACT_ROWS, preferred_element_type=jnp.float32,
+            precision=prec)
 
 
 def _hist_kernel_masked_q(sl_ref, gb_ref, lid_ref, ghq_ref, out_ref, *,
@@ -453,8 +475,8 @@ def _hist_kernel_masked_q(sl_ref, gb_ref, lid_ref, ghq_ref, out_ref, *,
     for g_ in range(G // pack):
         oh = _packed_onehot(gb_ref, g_, Bs, pack, bins_sub, jnp.int8,
                             bin_offset, bwin)
-        out_ref[0, g_, :, :] += jnp.dot(
-            vals, oh, preferred_element_type=jnp.int32)
+        out_ref[0, g_, :, :] += jax.lax.dot_general(
+            vals, oh, _CONTRACT_ROWS, preferred_element_type=jnp.int32)
 
 
 def _quantize_gh(gh8):
@@ -473,7 +495,7 @@ def _quantize_gh(gh8):
 # block (Mp <= 256 value rows, <= 256 output lanes), keyed by (bin
 # storage itemsize, float32 operands?).  These are not a model of the
 # transients: Mosaic's scheduler decides how many of the G unrolled
-# [Ck, Bs] one-hots and how much of the [Mp, Ck] vals block are live at
+# [Bs, Ck] one-hots and how much of the [Mp, Ck] vals block are live at
 # once, and what it asks of the 16 MB VMEM scope is neither linear nor
 # monotone in K (int8 bins, int8 operands: 46.6 MB at K=8 but < 16 MB at
 # K=84, both at Ck=8192; float32 at K=84 needs 4.4 KB per chunk row,

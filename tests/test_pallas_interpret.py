@@ -182,6 +182,44 @@ def test_hist_masked_int8_feature_packing():
                                rtol=0, atol=1e-4)
 
 
+@pytest.mark.parametrize("K", [1, 84])
+@pytest.mark.parametrize("B,max_nb", [(256, 255), (128, 63)])
+def test_hist_masked_int8_equals_integer_histogram(K, B, max_nb):
+    """The int8 kernel sums exact products in int32, so the order of the
+    sum cannot show: its histogram is EQUAL, not close, to a numpy
+    integer histogram of the quantized operands — at the root's K=1 and
+    the full K=84 launch, at 256 bins and at the packed 63-bin layout
+    (two columns a lane block), over two row chunks of which the second
+    is mostly padding, with an odd feature count and empty slots."""
+    from lightgbm_tpu.ops.histogram import _quantize_gh
+    C, F = 8192 + 301, 11
+    rng, gb = _rand(C, F, max_nb, seed=30)
+    lid = rng.randint(0, 2 * K + 1, size=C).astype(np.int32)
+    gh8 = np.zeros((8, C), np.float32)
+    gh8[2] = (rng.rand(C) < 0.9)
+    gh8[0] = rng.randn(C) * gh8[2]
+    gh8[1] = rng.rand(C) * gh8[2]
+    sl = rng.permutation(2 * K + 1)[:K].astype(np.int32)
+    sl[K // 3::7] = -1                      # empty slots (none at K=1)
+    h = hist_multileaf_masked(
+        jnp.asarray(gb), jnp.asarray(lid), jnp.asarray(gh8),
+        jnp.asarray(sl), num_bins_padded=B, backend="pallas",
+        input_dtype="int8", interpret=True, max_num_bin=max_nb)
+    ghq, sg, sh = _quantize_gh(jnp.asarray(gh8))
+    ghq = np.asarray(ghq)
+    assert np.abs(ghq[:2]).max() == 127
+    want = np.zeros((K, F, 3, B), np.int64)
+    for k in range(K):
+        rows = np.flatnonzero(lid == sl[k])
+        for f in range(F):
+            for c in range(3):
+                np.add.at(want[k, f, c], gb[f, rows], ghq[c, rows])
+    scale = np.asarray([sg, sh, 1.0], np.float32)[None, None, :, None]
+    assert h.shape == want.shape
+    np.testing.assert_array_equal(
+        np.asarray(h), want.astype(np.float32) * scale)
+
+
 @pytest.mark.parametrize("input_dtype", ["float32", "bfloat16", "int8"])
 def test_hist_masked_int8_stored_bins(input_dtype):
     """int8-STORED bins (value-128 HBM layout, the Expo-scale memory fix)

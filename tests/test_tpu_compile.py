@@ -3,10 +3,11 @@ attached (on-chip-measurement guide, section 2): what Mosaic or XLA:TPU
 refuses — a layout it cannot infer, an op the v5e VPU lacks, more scoped
 VMEM than 16 MB — fails here, on the CPU tier, before any chip run.
 
-Every shape is the north-star's: F=28 (or a 2000-wide store), B=256 bins,
-the rounds learner's K tiers (1 = root, 8, 32, 84), float32 / bfloat16 /
-int8 operands, int32 and int8-stored bins.  Nothing runs; a compile that
-passes is not a chip run.
+Every shape is the north-star's or a benchmark cell's: F=28 or Epsilon's
+2000-wide store, B=256 bins or the packed 63-bin layout, the rounds
+learner's K tiers (1 = root, 8, 32, 84), float32 / bfloat16 / int8
+operands, int32 and int8-stored bins.  Nothing runs; a compile that passes
+is not a chip run.
 
 The topology is described inside a module-scoped fixture and nowhere
 else: only one process may load the TPU library, and under pytest-xdist
@@ -58,14 +59,14 @@ def compile_for_chip(fn, *args, kernel=True):
     return compiled
 
 
-def masked(dtype):
+def masked(dtype, bins=B, max_num_bin=255):
     """hist_multileaf_masked as learner/rounds.py calls it."""
     from lightgbm_tpu.ops.histogram import hist_multileaf_masked
 
     def f(gb, lid, gh, sl):
-        return hist_multileaf_masked(gb, lid, gh, sl, num_bins_padded=B,
+        return hist_multileaf_masked(gb, lid, gh, sl, num_bins_padded=bins,
                                      backend="pallas", input_dtype=dtype,
-                                     max_num_bin=255)
+                                     max_num_bin=max_num_bin)
     return f
 
 
@@ -82,15 +83,22 @@ def test_masked_histogram_every_tier(shape, dtype, K):
     compile_for_chip(masked(dtype), *masked_args(shape, 28, jnp.int32, K))
 
 
-@pytest.mark.parametrize("dtype,F,bins_dtype", [
-    ("bfloat16", 28, jnp.int32),
-    ("float32", 32, jnp.int8), ("int8", 32, jnp.int8),
-    ("int8", 2000, jnp.int32)])
-def test_masked_histogram_k84_other_layouts(shape, dtype, F, bins_dtype):
-    """bf16 operands, int8-stored bins (G=32 feature blocks, 128-lane bin
-    windows) and a 2000-wide store, all at the full K=84 pass."""
+@pytest.mark.parametrize("dtype,F,bins_dtype,K,bins,max_num_bin", [
+    ("bfloat16", 28, jnp.int32, 84, B, 255),
+    ("float32", 32, jnp.int8, 84, B, 255), ("int8", 32, jnp.int8, 84, B, 255),
+    ("int8", 2000, jnp.int32, 84, B, 255), ("int8", 2000, jnp.int32, 1, B, 255),
+    ("int8", 2000, jnp.int32, 84, 128, 63),
+    ("int8", 2000, jnp.int32, 1, 128, 63)])
+def test_masked_histogram_other_layouts(shape, dtype, F, bins_dtype, K, bins,
+                                        max_num_bin):
+    """bf16 operands and int8-stored bins (G=32 feature blocks, 128-lane
+    bin windows) at the full K=84 pass; and every launch of the two
+    Epsilon cells: the root (K=1) and the K=84 pass over a 2000-wide
+    store, at 256 bins and at max_bin=63 (two 64-bin columns packed into
+    one 128-lane block)."""
     n = N if F < 100 else N // 8
-    compile_for_chip(masked(dtype), *masked_args(shape, F, bins_dtype, 84, n))
+    compile_for_chip(masked(dtype, bins, max_num_bin),
+                     *masked_args(shape, F, bins_dtype, K, n))
 
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
