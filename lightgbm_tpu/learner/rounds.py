@@ -72,9 +72,10 @@ STATS_COUNTERS = (
     profiling.HIST_ROWS_TOUCHED, profiling.HIST_EXCHANGE_BYTES,
     profiling.SPLIT_RECORDS_BYTES, profiling.SPARSE_NNZ_TOUCHED,
     profiling.TREE_ROUNDS, profiling.HIST_PASSES, profiling.HIST_SLOTS,
-    profiling.HIST_LIVE_SLOTS, profiling.HIST_MXU_OPS)
+    profiling.HIST_LIVE_SLOTS, profiling.HIST_MXU_OPS, profiling.FEED_ROWS,
+    profiling.FEED_LIVE_ROWS, profiling.PARTITION_ROWS)
 (S_ROWS, S_EXCHANGE, S_RECORDS, S_NNZ, S_ROUNDS, S_PASSES, S_SLOTS, S_LIVE,
- S_OPS) = range(len(STATS_COUNTERS))
+ S_OPS, S_FEED, S_FEED_LIVE, S_PARTITION) = range(len(STATS_COUNTERS))
 
 # Every phase of build_tree_rounds runs under a jax.named_scope
 # "lgbt.<phase>", so that an operation in a profiler trace says which
@@ -159,7 +160,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                       leaves_per_batch: int = 0,
                       sparse: bool = False):
     """Grow one tree in batched rounds.  Shapes as learner/fused.build_tree.
-    Returns (TreeArrays, leaf_id, stats) — stats is a [9] f32 vector in
+    Returns (TreeArrays, leaf_id, stats) — stats is a [12] f32 vector in
     the order of STATS_COUNTERS: rows processed by histogram kernels
     (global across shards — the live-traffic metric behind the
     gathered-vs-masked A/B); per-device histogram-exchange payload
@@ -169,8 +170,13 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     those launches were made for (each launch's K) and the slots among
     them that held a leaf; and the operations their contractions
     perform (ops/histogram.masked_hist_mxu_ops per dense launch, global
-    across shards; the sparse kernels add 0).  Every one is a scalar
-    add where the launch is made, on values the build already has.
+    across shards; the sparse kernels add 0); the scratch rows the
+    gathered launches copy (each launch's capacity tier) and the rows
+    among them that belong to a leaf (both 0 under the masked feed);
+    and the rows whose leaf id and place in the permutation the rounds
+    rewrite (all Nloc in every round) — the last three global across
+    shards.  Every one is a scalar add where the launch or the round
+    is made, on values the build already has.
 
     hist_rows="gathered" maintains a device-resident row partition
     inside the while_loop: a [N] row permutation grouped by leaf plus
@@ -362,11 +368,15 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             num_bins_padded=B, backend=backend, input_dtype=input_dtype,
             max_num_bin=max_num_bin)
 
-    def launch_stats(rows, nnz, slots, live, ops):
-        """The stats vector of one histogram kernel launch."""
+    def launch_stats(rows, nnz, slots, live, ops, feed_live=None):
+        """The stats vector of one histogram kernel launch; a gathered
+        launch says how many of its `rows` scratch rows hold a row of a
+        leaf (`feed_live`)."""
         v = [0.0] * len(STATS_COUNTERS)
         v[S_ROWS], v[S_NNZ], v[S_PASSES] = rows, nnz, 1.0
         v[S_SLOTS], v[S_LIVE], v[S_OPS] = slots, live, ops
+        if feed_live is not None:
+            v[S_FEED], v[S_FEED_LIVE] = rows, feed_live
         return jnp.stack([jnp.float32(x) for x in v])
 
     def hist_masked(lid_, sl_):
@@ -731,7 +741,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                             input_dtype=input_dtype,
                             max_num_bin=max_num_bin), 0
                     return h, launch_stats(cap, nz, Kc, live,
-                                           mxu_ops(cap, Kc))
+                                           mxu_ops(cap, Kc), feed_live=total)
                 return f
 
             def pick(i):
@@ -755,7 +765,8 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         leaf_hist2 = leaf_hist
         with jax.named_scope("lgbt.select"):
             rnd2 = rnd + 1
-            stats2 = stats.at[S_ROUNDS].add(1.0)
+            stats2 = stats.at[S_ROUNDS].add(1.0).at[S_PARTITION].add(
+                float(Nloc))
         for c in range(n_chunks):
             s = c * K
             Kc = min(K, L - s)                               # last chunk short
@@ -830,13 +841,14 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
           leaf_side, leaf_hist, perm, leaf_off, leaf_cnt, stats,
           arrs)
     st = jax.lax.while_loop(round_cond, round_body, st)
-    # rows, sparse entries and contraction operations are summed across
-    # shards (global traffic); the byte counters and the round, launch
-    # and slot counts stay per-device (passes are uniform, so every
-    # shard agrees)
+    # rows (histogrammed, fed, partitioned), sparse entries and
+    # contraction operations are summed across shards (global traffic);
+    # the byte counters and the round, launch and slot counts stay
+    # per-device (passes are uniform, so every shard agrees)
     with jax.named_scope("lgbt.pack"):
         stv = st[-2]
-        glob = jnp.asarray([S_ROWS, S_NNZ, S_OPS])
+        glob = jnp.asarray([S_ROWS, S_NNZ, S_OPS, S_FEED, S_FEED_LIVE,
+                            S_PARTITION])
         stv = stv.at[glob].set(_psum(stv[glob], row_axes))
     return st[-1], st[1], stv
 

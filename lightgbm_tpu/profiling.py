@@ -93,11 +93,24 @@ HIST_ROWS_DOWNGRADES = "tree/hist_rows_downgrades"
 #    contractions perform, padding included (ops/histogram.
 #    masked_hist_mxu_ops), summed across shards.  The sparse kernels
 #    add 0: their contraction runs over entry blocks, not rows.
+#  - FEED_ROWS: scratch rows the gathered launches copy out of the
+#    store — each launch's capacity tier, whatever part of it holds a
+#    row; FEED_LIVE_ROWS: the rows among them that belong to a leaf
+#    (gather_segments' total).  live / rows is how full the scratch
+#    ran.  Both stay 0 under the masked feed, which copies nothing.
+#  - PARTITION_ROWS: rows whose place in the leaf-id vector and the
+#    row permutation a round rewrites, as executed: every row of the
+#    shard in every round today (a partition that moved only the
+#    split leaves' segments would count fewer).
+#    All three are summed across shards.
 TREE_ROUNDS = "tree/rounds"
 HIST_PASSES = "tree/hist_passes"
 HIST_SLOTS = "tree/hist_slots"
 HIST_LIVE_SLOTS = "tree/hist_live_slots"
 HIST_MXU_OPS = "tree/hist_mxu_ops"
+FEED_ROWS = "tree/feed_rows"
+FEED_LIVE_ROWS = "tree/feed_live_rows"
+PARTITION_ROWS = "tree/partition_rows"
 
 # Canonical sparse-store counters (docs/Sparse.md), the nnz-scaling
 # evidence behind the sparse-vs-dense CTR A/B:
@@ -215,7 +228,8 @@ ROUTER_REHASHES = "router/rehashes"
 CANONICAL_COUNTERS = (
     HIST_ROWS_TOUCHED, HIST_EXCHANGE_BYTES, SPLIT_RECORDS_BYTES,
     HIST_ROWS_DOWNGRADES, TREE_ROUNDS, HIST_PASSES, HIST_SLOTS,
-    HIST_LIVE_SLOTS, HIST_MXU_OPS, SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
+    HIST_LIVE_SLOTS, HIST_MXU_OPS, FEED_ROWS, FEED_LIVE_ROWS,
+    PARTITION_ROWS, SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,
     SERVE_QUANTIZE_BYTES_IN, SERVE_BINNED_REQUESTS,

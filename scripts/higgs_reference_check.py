@@ -1,0 +1,205 @@
+"""What the timed path trains at Higgs 10.5M x 28, against a plain
+reference, on the chip (needs a TPU; about six minutes cold).
+
+The benchmark's `correct` compares what the trained model *predicts* with
+a plain walk.  This compares what the cell `higgs.full` *trains*, at the
+timed size and through the objects the timed path uses (the cell's binned
+training set, `lgb.Booster`, its learner's own build program):
+
+1. the root pass of tree 1 — `hist_multileaf_masked` over the learner's
+   device store with the arguments `build_tree_rounds` gives it — against
+   NumPy: the same int8-quantised gradient and hessian values
+   (`ops/histogram._quantize_gh`, redone in NumPy float32), summed per
+   (column, bin) in int64 over the binned store in blocks of a million
+   rows.  The kernel sums exact products in int32, so every cell has to
+   be equal to the unit;
+2. the root split of tree 1 as the learner's build grew it (feature,
+   threshold bin) against the best split of the reference histogram,
+   found in float64 by the textbook gain;
+3. tree 1 under `hist_rows=masked` against tree 1 under the feed the
+   program resolved, node for node.  At tree 1 of the binary objective
+   every gradient is +-0.5 and every hessian 0.25, so each pass
+   quantises them exactly whatever rows it scales over, and both feeds
+   sum the same integers: anything but equality is a fault.
+
+One JSON line per check, `{"ok": ...}` last; exit code 1 if any failed.
+"""
+import json
+import os
+import sys
+import time
+
+import numpy as np
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+CELL = "higgs.full"
+BLOCK = 1_000_000
+TREE_FIELDS = ("split_feature", "threshold_bin", "left_child", "right_child",
+               "leaf_value", "leaf_count", "leaf_depth", "internal_count")
+
+
+def say(**facts):
+    print(json.dumps(facts, default=float), flush=True)
+
+
+def quantize(v: np.ndarray):
+    """`_quantize_gh` for one row of values, in NumPy float32."""
+    scale = np.maximum(np.max(np.abs(v)), np.float32(1e-30)) / np.float32(127)
+    return np.round(v / scale).astype(np.int64), np.float32(scale)
+
+
+def reference_hist(store: np.ndarray, gq, hq, num_bins_padded: int):
+    """[F, 3, B] int64 sums of (grad, hess, count) per store column and
+    bin, block by block."""
+    F, N = store.shape
+    out = np.zeros((F, 3, num_bins_padded), np.int64)
+    for lo in range(0, N, BLOCK):
+        hi = min(N, lo + BLOCK)
+        w = (gq[lo:hi].astype(np.float64), hq[lo:hi].astype(np.float64))
+        for f in range(F):
+            b = store[f, lo:hi]
+            # float64 weights hold these integers exactly (sums < 2^53)
+            out[f, 0] += np.bincount(b, w[0], num_bins_padded).astype(np.int64)
+            out[f, 1] += np.bincount(b, w[1], num_bins_padded).astype(np.int64)
+            out[f, 2] += np.bincount(b, minlength=num_bins_padded)
+    return out
+
+
+def reference_split(hist, num_bins, min_data: int, min_hess: float):
+    """Best (feature, threshold bin, gain) of a [F, 3, B] float64
+    histogram: left = bins <= t, gain = GL^2/HL + GR^2/HR - G^2/H, over
+    thresholds that leave both sides min_data rows and min_hess hessian;
+    first maximum in (feature, bin) order, as a flat argmax takes it."""
+    G, H, C = (hist[0, k].sum() for k in range(3))
+    best = (-1, -1, -np.inf)
+    for f in range(hist.shape[0]):
+        nb = int(num_bins[f])
+        if nb < 2:
+            continue
+        GL, HL, CL = (np.cumsum(hist[f, k, :nb - 1]) for k in range(3))
+        GR, HR, CR = G - GL, H - HL, C - CL
+        ok = ((CL >= min_data) & (CR >= min_data)
+              & (HL >= min_hess) & (HR >= min_hess))
+        with np.errstate(divide="ignore", invalid="ignore"):
+            gain = np.where(ok, GL * GL / HL + GR * GR / HR - G * G / H,
+                            -np.inf)
+        t = int(np.argmax(gain))
+        if gain[t] > best[2]:
+            best = (f, t, float(gain[t]))
+    return best
+
+
+def tree_of(learner, grad, hess) -> dict:
+    """Tree 1 as the learner's own build program grows it, as numpy."""
+    import jax
+    _, _, arrs = learner.train_device(grad, hess, None, None)
+    got = jax.device_get(arrs)
+    n = int(got.num_leaves)
+    return dict({k: np.asarray(getattr(got, k)) for k in TREE_FIELDS},
+                num_leaves=n)
+
+
+def load_cell():
+    from benchmark.run import load_json
+    cell = load_json("workloads", CELL + ".json")
+    return load_json("configs", cell["config"] + ".json"), cell
+
+
+def main() -> int:
+    from benchmark.harness import dataset
+    from lightgbm_tpu.jaxutil import enable_compile_cache, require_accelerator
+    dev = require_accelerator()
+    enable_compile_cache()
+    import jax
+    import jax.numpy as jnp
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.ops.histogram import hist_multileaf_masked
+
+    config, cell = load_cell()
+    params = {**config["params"], **cell.get("params", {})}
+    t0 = time.perf_counter()
+    train, facts = dataset.binned_train_set(config, params)
+    bst = lgb.Booster(params, train)
+    learner = bst._gbdt.learner
+    say(check="setup", device=dev, dataset=facts["how"],
+        learner=type(learner).__name__, hist_rows=learner.hist_rows,
+        store=list(learner.bins_dev.shape), seconds=time.perf_counter() - t0)
+    failed = []
+
+    # -- 1: the root histogram ----------------------------------------------
+    grad, hess = (a.reshape(-1) for a in bst._gbdt.boosting_gradients())
+    N, B = learner.N, learner.B
+    if learner.Np != N or learner.mesh is not None:
+        raise SystemExit("the check is written for one device's unpadded rows")
+    # as RoundsTreeLearner resolves it
+    backend = "pallas" if jax.default_backend() == "tpu" else "xla"
+    gh8 = (jnp.zeros((8, N), jnp.float32).at[0].set(grad).at[1].set(hess)
+           .at[2].set(1.0))
+    path = np.asarray(hist_multileaf_masked(
+        learner.bins_dev, jnp.zeros(N, jnp.int32), gh8,
+        jnp.zeros(1, jnp.int32), num_bins_padded=B, backend=backend,
+        input_dtype=params["histogram_dtype"],
+        max_num_bin=int(learner.dataset.max_num_bin)))[0]       # [F, 3, B]
+    store = np.asarray(learner.bins_dev)
+    g_np, h_np = np.asarray(grad), np.asarray(hess)
+    gq, sg = quantize(g_np)
+    hq, sh = quantize(h_np)
+    t0 = time.perf_counter()
+    ref = reference_hist(store, gq, hq, B)
+    scale = np.array([sg, sh, np.float32(1)], np.float32)[None, :, None]
+    # the kernel's own last step: int32 sums to float32, times the scale
+    ref_f32 = ref.astype(np.float32) * scale
+    # (bitwise equality of that is the check; back in units it reads 0
+    # wherever a sum is under 2^24, which float32 holds exactly)
+    units = np.abs(np.rint(path.astype(np.float64) / scale) - ref)
+    hist_ok = bool(np.array_equal(path, ref_f32))
+    say(check="root_histogram", ok=hist_ok, cells=int(ref.size),
+        rows=int(ref[0, 2].sum()), largest_sum=int(np.abs(ref).max()),
+        cells_off=int((path != ref_f32).sum()), max_off_units=float(units.max()),
+        grad_levels=np.unique(gq).tolist(), hess_levels=np.unique(hq).tolist(),
+        reference_seconds=time.perf_counter() - t0)
+    if not hist_ok:
+        failed.append("root_histogram")
+
+    # -- 2: the root split --------------------------------------------------
+    tree = tree_of(learner, grad, hess)
+    want = reference_split(
+        ref.astype(np.float64) * scale.astype(np.float64),
+        np.asarray(learner.num_bins_dev), int(params["min_data_in_leaf"]),
+        float(params["min_sum_hessian_in_leaf"]))
+    got = (int(tree["split_feature"][0]), int(tree["threshold_bin"][0]))
+    split_ok = got == want[:2]
+    say(check="root_split", ok=split_ok, path=got, reference=want[:2],
+        reference_gain=want[2], leaves=tree["num_leaves"])
+    if not split_ok:
+        failed.append("root_split")
+
+    # -- 3: tree 1 under the other feed -------------------------------------
+    other = "masked" if learner.hist_rows != "masked" else "gathered"
+    t0 = time.perf_counter()
+    learner2 = lgb.Booster(dict(params, hist_rows=other), train)._gbdt.learner
+    tree2 = tree_of(learner2, grad, hess)
+    diff = {}
+    for k in TREE_FIELDS:
+        a, b = tree[k], tree2[k]
+        if not np.array_equal(a, b):
+            diff[k] = {"nodes": int((a != b).sum()),
+                       "max_abs": float(np.abs(a.astype(np.float64)
+                                               - b.astype(np.float64)).max())}
+    same = tree["num_leaves"] == tree2["num_leaves"] and not diff
+    say(check="tree_1_other_feed", ok=same, feeds=[learner.hist_rows,
+                                                  learner2.hist_rows],
+        leaves=[tree["num_leaves"], tree2["num_leaves"]], differs=diff,
+        seconds=time.perf_counter() - t0)
+    if not same:
+        failed.append("tree_1_other_feed")
+
+    say(ok=not failed, failed=failed)
+    return 1 if failed else 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -130,6 +130,45 @@ def test_fused_partition(shape, F, bins_dtype):
                      shape((7, 256), jnp.float32))
 
 
+def test_higgs_build_program(shape):
+    """The whole build step of the benchmark cell `higgs.full`, as
+    RoundsTreeLearner jits it on the chip: 10.5M rows by the 28 columns
+    of the int32 store (which the store leaves unpadded; the kernels pad
+    them to 32), 255 leaves, int8 operands, the gathered feed at the
+    three capacity tiers of ceil(N/2) = 5,250,048 rows, the per-leaf
+    histogram cache, the Pallas partition.  One program of four slot
+    chunks a round and fourteen kernel instances, whose temporaries
+    (2.4 GB) and arguments (1.5 GB) are most of the 4 GB the cell holds."""
+    import functools
+    from lightgbm_tpu.config import config_from_params
+    from lightgbm_tpu.learner.common import (gather_capacity_tiers,
+                                             gather_scratch_capacity,
+                                             make_split_kw)
+    from lightgbm_tpu.learner.rounds import build_tree_rounds
+    n, F = 10_500_000, 28
+    assert gather_capacity_tiers(gather_scratch_capacity(n)) == (
+        328_064, 1_312_512, 5_250_048)
+    cfg = config_from_params({"objective": "binary", "num_leaves": 255,
+                              "min_data_in_leaf": 1,
+                              "min_sum_hessian_in_leaf": 100.0,
+                              "verbose": -1})
+    build = functools.partial(
+        build_tree_rounds, num_leaves=255, num_bins_padded=B,
+        max_num_bin=255, split_kw=make_split_kw(cfg), max_depth=-1,
+        min_data_in_leaf=cfg.min_data_in_leaf,
+        min_sum_hessian_in_leaf=cfg.min_sum_hessian_in_leaf,
+        backend="pallas", input_dtype="int8", hist_rows="gathered",
+        cache_parent_hist=True)
+    compiled = compile_for_chip(
+        build, shape((F, n), jnp.int32), shape((n,), jnp.float32),
+        shape((n,), jnp.float32), shape((n,), jnp.float32),
+        shape((F,), jnp.int32), shape((F,), jnp.bool_), shape((F,), jnp.bool_))
+    mem = compiled.memory_analysis()
+    held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert held < 8e9, held        # half the chip; 3.9 GB today
+
+
 def test_table_lookup_kernel(shape):
     from lightgbm_tpu.ops.lookup import _lookup_pallas
     compile_for_chip(_lookup_pallas, shape((7, 256), jnp.float32),

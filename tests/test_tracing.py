@@ -133,6 +133,7 @@ def _hand_count(trees, n_rows, feed):
     a round after the root's, each for the round's splitting leaves."""
     F, B, Kc = 5, 256, 7
     rounds_, passes, slots, live, ops, rows = 0, 0, 0, 0, 0.0, 0.0
+    fed, fed_live = 0, 0
     caps = rounds.gather_capacity_tiers(
         rounds.gather_scratch_capacity(n_rows))
     for t in trees:
@@ -147,11 +148,16 @@ def _hand_count(trees, n_rows, feed):
                 c = n_rows
             else:       # the smallest capacity tier that holds the rows
                 c = next(cap for cap in caps if small_rows <= cap)
+                # what the launch copies, and what of it is a leaf's
+                fed, fed_live = fed + c, fed_live + small_rows
             ops += 2.0 * c * 3 * Kc * F * B
             rows += c
     return {"tree/rounds": rounds_, "tree/hist_passes": passes,
             "tree/hist_slots": slots, "tree/hist_live_slots": live,
-            "tree/hist_mxu_ops": ops, "tree/hist_rows_touched": rows}
+            "tree/hist_mxu_ops": ops, "tree/hist_rows_touched": rows,
+            "tree/feed_rows": fed, "tree/feed_live_rows": fed_live,
+            # every round rewrites every row's leaf id
+            "tree/partition_rows": rounds_ * n_rows}
 
 
 def _rounds_of(tree):
@@ -342,7 +348,7 @@ def test_count_deferred_vector_drains_like_the_per_name_path(monkeypatch):
 
 def test_the_learner_feeds_every_stats_counter_as_one_vector():
     assert set(rounds.STATS_COUNTERS) <= set(profiling.CANONICAL_COUNTERS)
-    assert len(rounds.STATS_COUNTERS) == 9
+    assert len(rounds.STATS_COUNTERS) == 12
     X, y = _problem(300, 4)
     profiling.reset()
     bst = lgb.Booster({"objective": "binary", "verbose": -1, "num_leaves": 4,
@@ -350,6 +356,6 @@ def test_the_learner_feeds_every_stats_counter_as_one_vector():
                       lgb.Dataset(X, y))
     bst.update()
     assert list(profiling._deferred) == [rounds.STATS_COUNTERS]
-    assert profiling._deferred[rounds.STATS_COUNTERS].shape == (9,)
+    assert profiling._deferred[rounds.STATS_COUNTERS].shape == (12,)
     assert set(profiling.counters("tree/")) >= set(rounds.STATS_COUNTERS)
     profiling.reset()
