@@ -53,8 +53,8 @@ CTR_QUERY = int(os.environ.get("BENCH_CTR_QUERY", 20))
 SPARSE_STORE = os.environ.get("BENCH_SPARSE_STORE", "")
 BIN_BUDGET = int(os.environ.get("BENCH_BIN_BUDGET", "0") or 0)
 # row feed of the histogram passes: "" keeps the config default (auto =
-# gathered on single-device TPU, masked elsewhere); set gathered|masked
-# for the ordered-histograms A/B (docs/Readme.md "Row partition")
+# masked); set gathered|masked for the ordered-histograms A/B
+# (docs/Readme.md "Row partition")
 HIST_ROWS = os.environ.get("BENCH_HIST_ROWS", "")
 # growth schedule override ("" keeps the config default: rounds on TPU)
 TREE_GROWTH = os.environ.get("BENCH_TREE_GROWTH", "")

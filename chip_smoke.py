@@ -4,7 +4,7 @@ Drives the main path once, through the entry points a user calls, at the
 full width of the north-star model (BASELINE.md): synthetic Higgs
 10.5M x 28 from `bench.synth_higgs(seed)`, 255 leaves, 255 bins,
 `lgb.Dataset` -> `lgb.train` for a few iterations with the default
-`tree_growth` / `hist_rows` (rounds learner, Pallas kernels, gathered row
+`tree_growth` / `hist_rows` (rounds learner, Pallas kernels, masked row
 feed), once with the stock `histogram_dtype` and once with `int8`; then
 `Booster.predict` on the device and an in-process `PredictionServer`
 answering a few `POST /predict` requests, both compared with the host
@@ -166,7 +166,7 @@ def check_learner(bst, dtype):
              "bins_dtype": str(lr.bins_dev.dtype),
              "pallas_in_lowered_step": lowered_has_kernel(lr)}
     check(facts["learner"] == "RoundsTreeLearner"
-          and facts["hist_rows"] == "gathered"
+          and facts["hist_rows"] == "masked"
           and facts["histogram_dtype"] == dtype,
           f"not the default chip path: {facts}")
     check(facts["pallas_in_lowered_step"],

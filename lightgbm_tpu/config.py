@@ -607,9 +607,10 @@ class Config:
     # device-resident row partition (the reference's DataPartition +
     # ordered-gradients design, data_partition.hpp) and histograms only
     # the leaf-contiguous segments each round needs — bagged/GOSS-dropped
-    # rows never enter the permutation.  "auto" = gathered on TPU
-    # (single-device AND data-parallel shard-map — the partition is
-    # per-shard local state), masked on the CPU tier.
+    # rows never enter the permutation.  "auto" = masked: the stream
+    # measured faster in every benchmark cell (Higgs 10.5M x 28 by 13x,
+    # Epsilon 400k x 2000 by 4 % and, at 63 bins, 23 %; PERF.md section
+    # 6, PR 32); "gathered" runs on request, per shard under shard-map.
     hist_rows: str = "auto"
     # data-parallel histogram exchange: "psum" all-reduces the full
     # [K, F, 3, B] histogram onto every device; "psum_scatter"

@@ -118,24 +118,32 @@ def gathered_scratch_fits(num_columns: int, np_rows: int,
     return scratch <= 0.15 * limit_bytes
 
 
-def resolve_hist_rows(cfg: Config, *, backend: str,
-                      num_columns: int, np_rows: int,
+def resolve_hist_rows(cfg: Config, *, num_columns: int, np_rows: int,
                       bins_itemsize: int = 4) -> str:
     """Resolve the `hist_rows` knob to the mode a rounds learner runs.
 
-    "masked" streams the full [F, N] bin store every histogram pass;
-    "gathered" maintains the device-resident row partition and feeds
-    the kernels only the leaf-contiguous segments they need.  "auto"
-    picks gathered on TPU (the bandwidth-bound regime the optimization
-    targets) — including multi-device data-parallel meshes, where the
-    permutation, (offset, count) table, and gather scratch are per-shard
-    locals inside the shard_map body (`np_rows` is then the PER-SHARD
-    row count and sizes the scratch budget) — and masked on the CPU
-    tier unless opted in."""
+    "masked" streams the full [F, N] bin store every histogram pass, at
+    the slot tier that holds the round's leaves; "gathered" maintains
+    the device-resident row partition and feeds the kernels only the
+    leaf-contiguous segments they need.  "auto" is masked, on the chip
+    as on the CPU tier: with both feeds measured in the benchmark's
+    three cells (Higgs 10.5M x 28, Epsilon 400k x 2000 at 255 and at 63
+    bins, int8 operands, one TPU v5e) the stream was faster in each, by
+    13x, 23 % and 4 % — the gather costs about 125 ns a row and pass in
+    computed-index accesses and saves less kernel time than that
+    (PERF.md section 6, PR 32).  No shape was measured on the gathered
+    side, so `auto` has no rule that sends one there; bagging and GOSS,
+    whose dropped rows never enter the permutation, are where one may
+    lie (PERF.md section 7).  An explicit "gathered" still runs it,
+    also under shard_map, where the permutation, the (offset, count)
+    table and the scratch are shard-local and `np_rows` is the
+    PER-SHARD row count — behind the scratch-memory gate: a gathered
+    feed whose scratch would not fit beside the store runs masked, and
+    `tree/hist_rows_downgrades` counts it."""
     mode = getattr(cfg, "hist_rows", "auto")
     from .. import log, profiling
     if mode == "auto":
-        mode = "gathered" if backend == "pallas" else "masked"
+        mode = "masked"
     if mode == "gathered" and not gathered_scratch_fits(
             num_columns, np_rows, bins_itemsize):
         log.warning("hist_rows=gathered scratch would not fit the device "

@@ -190,7 +190,17 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     capacity tiers (static at ceil(N_local/2)) — is per-shard local
     state over the shard's row block; per-shard counts diverge, but the
     tier lax.cond branches contain no collectives, so shards may pick
-    different tiers freely.  "masked" is the original full-stream path.
+    different tiers freely.  "masked" streams every row in every pass,
+    at the slot tier (8 / 32 / K) that holds the round's leaves, and
+    keeps no permutation.  Both feeds run the same kernel and grow the
+    same tree from the same gradients; `hist_rows=auto` is the stream
+    (common.resolve_hist_rows): the ~125 ns a row and pass that the
+    gather costs exceeded the kernel time it saved in all three
+    benchmark cells.  With int8 operands a pass quantises by the
+    largest gradient of the rows it is over — all rows when masked,
+    the launch's own when gathered — so from the second tree on the
+    two feeds' sums differ in their last digits (< 1/254 of the
+    largest gradient a row).
 
     hist_exchange="psum_scatter" (static; with data_axis set and
     num_devices the data-axis size) replaces the full [K, F, 3, B]
@@ -1018,7 +1028,8 @@ class RoundsTreeLearner:
         self.cache_parent_hist = use_parent_hist_cache(cfg, cache_cols,
                                                        self.B)
         # row feed: gathered (ordered histograms over the device-resident
-        # row partition) vs masked full-stream — see build_tree_rounds.
+        # row partition) vs masked full-stream — see build_tree_rounds;
+        # `auto` is the stream.
         # Under shard_map the partition is per-shard local state, so the
         # scratch budget is sized from the PER-SHARD row count.  The
         # sparse store defaults to masked (its window entry streams are
@@ -1036,8 +1047,7 @@ class RoundsTreeLearner:
             self.hist_rows = "masked" if hr == "auto" else hr
         else:
             self.hist_rows = resolve_hist_rows(
-                cfg, backend=backend,
-                num_columns=self.Fpad,
+                cfg, num_columns=self.Fpad,
                 np_rows=max(1, self.Np // max(nsh, 1)),
                 bins_itemsize=int(bins_np.dtype.itemsize))
         kw = dict(num_leaves=cfg.num_leaves, num_bins_padded=self.B,

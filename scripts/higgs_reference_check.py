@@ -22,8 +22,21 @@ training set, `lgb.Booster`, its learner's own build program):
    quantises them exactly whatever rows it scales over, and both feeds
    sum the same integers: anything but equality is a fault.
 
+With `--auc-trees N` it makes one other check instead, the one that needs
+many trees (at N = 100 about 25 minutes, 20 of them the gathered feed's),
+on the cell `--cell` names (`higgs.full` unless given; `epsilon.full`
+takes about 12 minutes):
+
+4. the AUC on the cell's test split after `quality_iters` and after N trees
+   under each row feed.  From tree 2 on the int8 path quantises a pass by
+   the largest gradient of the rows it is over — all rows under the masked
+   feed, each launch's own under the gathered one — so the trees differ in
+   the last digits of their sums; the models have to be as good as each
+   other: both AUCs agree to `AUC_TOL`.
+
 One JSON line per check, `{"ok": ...}` last; exit code 1 if any failed.
 """
+import argparse
 import json
 import os
 import sys
@@ -37,6 +50,7 @@ if ROOT not in sys.path:
 
 CELL = "higgs.full"
 BLOCK = 1_000_000
+AUC_TOL = 1e-3
 TREE_FIELDS = ("split_feature", "threshold_bin", "left_child", "right_child",
                "leaf_value", "leaf_count", "leaf_depth", "internal_count")
 
@@ -102,13 +116,55 @@ def tree_of(learner, grad, hess) -> dict:
                 num_leaves=n)
 
 
-def load_cell():
+def load_cell(name=CELL):
     from benchmark.run import load_json
-    cell = load_json("workloads", CELL + ".json")
+    cell = load_json("workloads", name + ".json")
     return load_json("configs", cell["config"] + ".json"), cell
 
 
-def main() -> int:
+def auc_of_feeds(params, cell, config, train, trees: int) -> bool:
+    """Check 4: `trees` iterations under each feed, the AUC on the test
+    split after `quality_iters` trees and after all of them."""
+    import lightgbm_tpu as lgb
+    from benchmark.harness import dataset, walk
+    Xv, yv = dataset.test_split(config, int(cell["valid_rows"]))
+    early = int(cell["quality_iters"])
+    aucs = {}
+
+    def wait(bst):
+        """One value fetch: the device has finished every update."""
+        return float(bst._gbdt.train_score.score.sum())
+
+    for feed in ("masked", "gathered"):
+        bst = lgb.Booster(dict(params, hist_rows=feed), train)
+        bst.update()                    # compiles; timed from tree 2 on
+        wait(bst)
+        t0 = time.perf_counter()
+        for _ in range(trees - 1):
+            bst.update()
+        wait(bst)
+        s_per_iter = (time.perf_counter() - t0) / max(trees - 1, 1)
+        aucs[feed] = [walk.auc(yv, bst.predict(Xv, raw_score=True,
+                                               num_iteration=n))
+                      for n in (early, trees)]
+        say(check="auc_of_feed", feed=bst._gbdt.learner.hist_rows,
+            trees=[early, trees], valid_auc=aucs[feed],
+            s_per_iter=s_per_iter)
+        del bst
+    gap = [abs(a - b) for a, b in zip(aucs["masked"], aucs["gathered"])]
+    ok = max(gap) <= AUC_TOL
+    say(check="auc_both_feeds", ok=ok, trees=[early, trees], gap=gap,
+        tolerance=AUC_TOL, **{"valid_auc_" + k: v for k, v in aucs.items()})
+    return ok
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--auc-trees", type=int, default=0,
+                    help="run check 4 alone, over this many trees")
+    ap.add_argument("--cell", default=CELL,
+                    help="the cell whose job --auc-trees trains")
+    args = ap.parse_args(argv)
     from benchmark.harness import dataset
     from lightgbm_tpu.jaxutil import enable_compile_cache, require_accelerator
     dev = require_accelerator()
@@ -118,10 +174,14 @@ def main() -> int:
     import lightgbm_tpu as lgb
     from lightgbm_tpu.ops.histogram import hist_multileaf_masked
 
-    config, cell = load_cell()
+    config, cell = load_cell(args.cell if args.auc_trees else CELL)
     params = {**config["params"], **cell.get("params", {})}
     t0 = time.perf_counter()
     train, facts = dataset.binned_train_set(config, params)
+    if args.auc_trees:
+        ok = auc_of_feeds(params, cell, config, train, args.auc_trees)
+        say(ok=ok, failed=[] if ok else ["auc_both_feeds"], device=dev)
+        return 0 if ok else 1
     bst = lgb.Booster(params, train)
     learner = bst._gbdt.learner
     say(check="setup", device=dev, dataset=facts["how"],

@@ -267,37 +267,53 @@ def test_efb_bundled_store_gathered_matches_masked():
                                rtol=1e-6, atol=1e-7)
 
 
-def test_resolve_hist_rows_and_capacity_model():
+def test_gather_capacity_model_and_the_key():
     from lightgbm_tpu.config import config_from_params
     from lightgbm_tpu.learner.common import (gather_capacity_tiers,
-                                             gather_scratch_capacity,
-                                             resolve_hist_rows)
+                                             gather_scratch_capacity)
     cap = gather_scratch_capacity(10_500_000)
     assert cap >= (10_500_000 + 1) // 2 and cap % 128 == 0
     tiers = gather_capacity_tiers(cap)
+    assert tiers == (328_064, 1_312_512, 5_250_048)     # Higgs, one chip
     assert tiers[-1] == cap and len(tiers) == 3
     assert all(t % 128 == 0 for t in tiers)
     assert list(tiers) == sorted(tiers)
     # tiny shapes collapse to fewer tiers but never below one lane tile
     assert gather_capacity_tiers(128) == (128,)
-    kw = dict(num_columns=28, np_rows=100_000, bins_itemsize=4)
-    cfg = config_from_params({"verbose": -1})
-    assert cfg.hist_rows == "auto"
-    assert resolve_hist_rows(cfg, backend="xla", **kw) == "masked"
-    # auto resolves to gathered on TPU — single-device AND data-parallel
-    # shard_map (per-shard local compaction; np_rows is the per-shard
-    # row count there)
-    assert resolve_hist_rows(cfg, backend="pallas", **kw) == "gathered"
-    cfg_g = config_from_params({"verbose": -1, "hist_rows": "gathered"})
-    assert resolve_hist_rows(cfg_g, backend="xla", **kw) == "gathered"
-    # masked stays reachable by explicit request
-    cfg_m = config_from_params({"verbose": -1, "hist_rows": "masked"})
-    assert resolve_hist_rows(cfg_m, backend="pallas", **kw) == "masked"
+    assert config_from_params({"verbose": -1}).hist_rows == "auto"
     with pytest.raises(ValueError):
         config_from_params({"hist_rows": "bogus", "verbose": -1})
     # alias
     assert config_from_params(
         {"ordered_histograms": "masked", "verbose": -1}).hist_rows == "masked"
+
+
+# the store of each benchmark cell as RoundsTreeLearner hands it to the
+# resolver (per-shard rows; int32 bins)
+HIGGS = dict(num_columns=28, np_rows=10_500_000)
+HIGGS_SHARD = dict(HIGGS, np_rows=2_625_000)            # higgs.data4
+EPSILON = dict(num_columns=2000, np_rows=400_000)       # .full and .b63
+
+
+@pytest.mark.parametrize("hist_rows,dtype,store,want", [
+    # auto is the stream: it measured faster in all three cells (13x,
+    # by 23 %, by 4 %: PERF.md section 6, PR 32) and, with float32
+    # operands, in chip_smoke.py's Higgs run (2.4x)
+    ("auto", "int8", HIGGS, "masked"),
+    ("auto", "int8", EPSILON, "masked"),
+    ("auto", "int8", HIGGS_SHARD, "masked"),
+    ("auto", "float32", HIGGS, "masked"),
+    # an explicit value wins, both ways
+    ("gathered", "int8", HIGGS, "gathered"),
+    ("gathered", "int8", EPSILON, "gathered"),
+    ("masked", "int8", EPSILON, "masked"),
+])
+def test_resolve_hist_rows(hist_rows, dtype, store, want):
+    from lightgbm_tpu.config import config_from_params
+    from lightgbm_tpu.learner.common import resolve_hist_rows
+    cfg = config_from_params({"hist_rows": hist_rows, "verbose": -1,
+                              "histogram_dtype": dtype})
+    assert resolve_hist_rows(cfg, bins_itemsize=4, **store) == want
 
 
 def test_feature_importance_split_dtype_int32():
@@ -335,11 +351,15 @@ def test_gathered_downgrade_is_counted():
     cfg = config_from_params({"hist_rows": "gathered", "verbose": -1})
     c0 = profiling.counter_value(profiling.HIST_ROWS_DOWNGRADES)
     # 50M rows x 2000 int32 columns: a 200 GB scratch fits no device
-    assert resolve_hist_rows(cfg, backend="pallas", num_columns=2000,
+    assert resolve_hist_rows(cfg, num_columns=2000,
                              np_rows=50_000_000) == "masked"
     assert profiling.counter_value(profiling.HIST_ROWS_DOWNGRADES) == c0 + 1
-    assert resolve_hist_rows(cfg, backend="pallas", num_columns=28,
-                             np_rows=10_500_000) == "gathered"
+    assert resolve_hist_rows(cfg, **HIGGS) == "gathered"
+    assert profiling.counter_value(profiling.HIST_ROWS_DOWNGRADES) == c0 + 1
+    # a feed that was never gathered is no downgrade
+    auto = config_from_params({"verbose": -1})
+    assert resolve_hist_rows(auto, num_columns=2000,
+                             np_rows=50_000_000) == "masked"
     assert profiling.counter_value(profiling.HIST_ROWS_DOWNGRADES) == c0 + 1
 
 

@@ -88,14 +88,17 @@ def test_masked_histogram_every_tier(shape, dtype, K):
     ("float32", 32, jnp.int8, 84, B, 255), ("int8", 32, jnp.int8, 84, B, 255),
     ("int8", 2000, jnp.int32, 84, B, 255), ("int8", 2000, jnp.int32, 1, B, 255),
     ("int8", 2000, jnp.int32, 84, 128, 63),
-    ("int8", 2000, jnp.int32, 1, 128, 63)])
+    ("int8", 2000, jnp.int32, 1, 128, 63),
+    ("int8", 2000, jnp.int32, 8, B, 255), ("int8", 2000, jnp.int32, 32, B, 255),
+    ("int8", 2000, jnp.int32, 8, 128, 63),
+    ("int8", 2000, jnp.int32, 32, 128, 63)])
 def test_masked_histogram_other_layouts(shape, dtype, F, bins_dtype, K, bins,
                                         max_num_bin):
     """bf16 operands and int8-stored bins (G=32 feature blocks, 128-lane
     bin windows) at the full K=84 pass; and every launch of the two
-    Epsilon cells: the root (K=1) and the K=84 pass over a 2000-wide
-    store, at 256 bins and at max_bin=63 (two 64-bin columns packed into
-    one 128-lane block)."""
+    Epsilon cells: the root (K=1) and the masked feed's slot tiers
+    (K = 8, 32, 84) over a 2000-wide store, at 256 bins and at
+    max_bin=63 (two 64-bin columns packed into one 128-lane block)."""
     n = N if F < 100 else N // 8
     compile_for_chip(masked(dtype, bins, max_num_bin),
                      *masked_args(shape, F, bins_dtype, K, n))
@@ -103,8 +106,8 @@ def test_masked_histogram_other_layouts(shape, dtype, F, bins_dtype, K, bins,
 
 @pytest.mark.parametrize("dtype", ["float32", "int8"])
 def test_gathered_histogram(shape, dtype):
-    """hist_multileaf_gathered — the default row feed on the chip — at the
-    ceil(N/2) smaller-child capacity."""
+    """hist_multileaf_gathered — the row feed of `hist_rows=gathered` —
+    at the ceil(N/2) smaller-child capacity."""
     from lightgbm_tpu.ops.histogram import hist_multileaf_gathered
 
     def f(bins, gh, perm, off, cnt):
@@ -130,34 +133,38 @@ def test_fused_partition(shape, F, bins_dtype):
                      shape((7, 256), jnp.float32))
 
 
-def test_higgs_build_program(shape):
+@pytest.mark.parametrize("hist_rows", ["auto", "gathered"])
+def test_higgs_build_program(shape, hist_rows):
     """The whole build step of the benchmark cell `higgs.full`, as
     RoundsTreeLearner jits it on the chip: 10.5M rows by the 28 columns
     of the int32 store (which the store leaves unpadded; the kernels pad
-    them to 32), 255 leaves, int8 operands, the gathered feed at the
-    three capacity tiers of ceil(N/2) = 5,250,048 rows, the per-leaf
-    histogram cache, the Pallas partition.  One program of four slot
-    chunks a round and fourteen kernel instances, whose temporaries
-    (2.4 GB) and arguments (1.5 GB) are most of the 4 GB the cell holds."""
+    them to 32), 255 leaves, int8 operands, the per-leaf histogram
+    cache, the Pallas partition, and the row feed that `hist_rows`
+    resolves — under `auto`, the cell's, the stream: every launch over
+    all the rows at the slot tier (8 / 32 / 84) that holds the round's
+    leaves, no permutation and no scratch; under `gathered`, which no
+    cell runs but a user may ask for, the three capacity tiers of
+    ceil(N/2) = 5,250,048 rows.  One program of four slot chunks a
+    round, whose arguments (1.5 GB) and temporaries (2.4 GB) are what
+    the cell holds."""
     import functools
     from lightgbm_tpu.config import config_from_params
-    from lightgbm_tpu.learner.common import (gather_capacity_tiers,
-                                             gather_scratch_capacity,
-                                             make_split_kw)
+    from lightgbm_tpu.learner.common import make_split_kw, resolve_hist_rows
     from lightgbm_tpu.learner.rounds import build_tree_rounds
     n, F = 10_500_000, 28
-    assert gather_capacity_tiers(gather_scratch_capacity(n)) == (
-        328_064, 1_312_512, 5_250_048)
     cfg = config_from_params({"objective": "binary", "num_leaves": 255,
                               "min_data_in_leaf": 1,
                               "min_sum_hessian_in_leaf": 100.0,
-                              "verbose": -1})
+                              "histogram_dtype": "int8",
+                              "hist_rows": hist_rows, "verbose": -1})
+    feed = resolve_hist_rows(cfg, num_columns=F, np_rows=n, bins_itemsize=4)
+    assert feed == ("masked" if hist_rows == "auto" else "gathered")
     build = functools.partial(
         build_tree_rounds, num_leaves=255, num_bins_padded=B,
         max_num_bin=255, split_kw=make_split_kw(cfg), max_depth=-1,
         min_data_in_leaf=cfg.min_data_in_leaf,
         min_sum_hessian_in_leaf=cfg.min_sum_hessian_in_leaf,
-        backend="pallas", input_dtype="int8", hist_rows="gathered",
+        backend="pallas", input_dtype=cfg.histogram_dtype, hist_rows=feed,
         cache_parent_hist=True)
     compiled = compile_for_chip(
         build, shape((F, n), jnp.int32), shape((n,), jnp.float32),
@@ -166,7 +173,7 @@ def test_higgs_build_program(shape):
     mem = compiled.memory_analysis()
     held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes)
-    assert held < 8e9, held        # half the chip; 3.9 GB today
+    assert held < 8e9, held        # half the chip
 
 
 def test_table_lookup_kernel(shape):
