@@ -52,10 +52,6 @@ CTR_DENSITY = float(os.environ.get("BENCH_CTR_DENSITY", 0.01))
 CTR_QUERY = int(os.environ.get("BENCH_CTR_QUERY", 20))
 SPARSE_STORE = os.environ.get("BENCH_SPARSE_STORE", "")
 BIN_BUDGET = int(os.environ.get("BENCH_BIN_BUDGET", "0") or 0)
-# row feed of the histogram passes: "" keeps the config default (auto =
-# masked); set gathered|masked for the ordered-histograms A/B
-# (docs/Readme.md "Row partition")
-HIST_ROWS = os.environ.get("BENCH_HIST_ROWS", "")
 # growth schedule override ("" keeps the config default: rounds on TPU)
 TREE_GROWTH = os.environ.get("BENCH_TREE_GROWTH", "")
 # data-parallel histogram exchange override: "" keeps the config default
@@ -269,8 +265,6 @@ def main():
         params["sparse_store"] = SPARSE_STORE
     if BIN_BUDGET:
         params["bin_budget"] = BIN_BUDGET
-    if HIST_ROWS:
-        params["hist_rows"] = HIST_ROWS
     if TREE_GROWTH:
         params["tree_growth"] = TREE_GROWTH
     if HIST_EXCHANGE:
@@ -312,8 +306,8 @@ def main():
     float(bst._gbdt.train_score.score.sum())
     dt = time.perf_counter() - t0
     s_per_iter = dt / ITERS
-    # histogram-kernel row traffic over the same window (the live-rows
-    # metric of the gathered-vs-masked A/B; 0 for non-rounds learners)
+    # histogram-kernel row traffic over the same window (0 for
+    # non-rounds learners)
     rows_per_iter = (profiling.counter_value(profiling.HIST_ROWS_TOUCHED)
                      - rows_t0) / ITERS
     # data-parallel comms traffic per iteration (per-device payload of
@@ -391,9 +385,7 @@ def main():
         "value": round(s_per_iter, 4),
         "unit": "s/iter",
         "vs_baseline": round(vs, 4),
-        # the row feed that ACTUALLY ran (auto resolves per topology)
-        # and its measured histogram row traffic
-        "hist_rows": getattr(bst._gbdt.learner, "hist_rows", "n/a"),
+        # measured histogram row traffic
         "rows_touched_per_iter": round(rows_per_iter, 1),
         # the histogram exchange that ran (auto resolves per payload/
         # topology) and its measured per-device comms traffic
@@ -404,8 +396,6 @@ def main():
         "hist_dtype": params["histogram_dtype"],
         "learner": type(bst._gbdt.learner).__name__,
         "device": device,
-        "hist_rows_downgrades": profiling.counter_value(
-            profiling.HIST_ROWS_DOWNGRADES),
         "bundling": bundling,
     }
     if WORKLOAD == "ctr" or inner.sparse is not None:

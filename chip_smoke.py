@@ -4,8 +4,8 @@ Drives the main path once, through the entry points a user calls, at the
 full width of the north-star model (BASELINE.md): synthetic Higgs
 10.5M x 28 from `bench.synth_higgs(seed)`, 255 leaves, 255 bins,
 `lgb.Dataset` -> `lgb.train` for a few iterations with the default
-`tree_growth` / `hist_rows` (rounds learner, Pallas kernels, masked row
-feed), once with the stock `histogram_dtype` and once with `int8`; then
+`tree_growth` (rounds learner, Pallas kernels), once with the stock
+`histogram_dtype` and once with `int8`; then
 `Booster.predict` on the device and an in-process `PredictionServer`
 answering a few `POST /predict` requests, both compared with the host
 walk predictor (numpy, no JAX) on a row sample.
@@ -152,21 +152,19 @@ def post_predict(host, port, X, raw_score=False):
 
 def fallback_counters():
     from lightgbm_tpu import profiling
-    names = (profiling.HIST_ROWS_DOWNGRADES, profiling.SPARSE_FALLBACKS,
-             profiling.SERVE_CHUNK_RETRIES, profiling.SERVE_REPLICA_FAILURES,
-             profiling.SERVE_REPLICA_BROKEN,
+    names = (profiling.SPARSE_FALLBACKS, profiling.SERVE_CHUNK_RETRIES,
+             profiling.SERVE_REPLICA_FAILURES, profiling.SERVE_REPLICA_BROKEN,
              profiling.REGISTRY_SWAP_FAILURES)
     return {n: profiling.counter_value(n) for n in names}
 
 
 def check_learner(bst, dtype):
     lr = bst._gbdt.learner
-    facts = {"learner": type(lr).__name__, "hist_rows": lr.hist_rows,
+    facts = {"learner": type(lr).__name__,
              "histogram_dtype": bst._gbdt.config.histogram_dtype,
              "bins_dtype": str(lr.bins_dev.dtype),
              "pallas_in_lowered_step": lowered_has_kernel(lr)}
     check(facts["learner"] == "RoundsTreeLearner"
-          and facts["hist_rows"] == "masked"
           and facts["histogram_dtype"] == dtype,
           f"not the default chip path: {facts}")
     check(facts["pallas_in_lowered_step"],

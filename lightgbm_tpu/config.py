@@ -382,9 +382,6 @@ PARAM_ALIASES: Dict[str, str] = {
     "is_enable_bundle": "enable_bundle",
     "max_conflict": "max_conflict_rate",
     "bundle_conflict_rate": "max_conflict_rate",
-    # row partition / ordered histograms (docs/Readme.md)
-    "ordered_histograms": "hist_rows",
-    "row_partition": "hist_rows",
     # data-parallel histogram exchange (docs/Readme.md "Histogram exchange")
     "histogram_reduce": "hist_exchange",
     "hist_exchange_threshold": "hist_exchange_min_bytes",
@@ -602,16 +599,6 @@ class Config:
     # bfloat16 (fast).  The reference GPU learner has the same dial as
     # gpu_use_dp (config.h:206, single vs double) with single the default.
     histogram_dtype: str = "float32"
-    # row feed of the batched-rounds histogram passes: "masked" streams
-    # the full [F, N] bin store every pass; "gathered" keeps a
-    # device-resident row partition (the reference's DataPartition +
-    # ordered-gradients design, data_partition.hpp) and histograms only
-    # the leaf-contiguous segments each round needs — bagged/GOSS-dropped
-    # rows never enter the permutation.  "auto" = masked: the stream
-    # measured faster in every benchmark cell (Higgs 10.5M x 28 by 13x,
-    # Epsilon 400k x 2000 by 4 % and, at 63 bins, 23 %; PERF.md section
-    # 6, PR 32); "gathered" runs on request, per shard under shard-map.
-    hist_rows: str = "auto"
     # data-parallel histogram exchange: "psum" all-reduces the full
     # [K, F, 3, B] histogram onto every device; "psum_scatter"
     # reduce-scatters over the feature axis so each device owns only its
@@ -637,11 +624,7 @@ class Config:
     time_out: int = 120
     machine_list_file: str = ""
 
-    # -- tpu-specific knobs (new in this framework)
-    hist_dtype: str = "float32"      # accumulation dtype for histograms
-    hist_input_dtype: str = "bfloat16"  # MXU input dtype for one-hot matmul
-    fused_tree: bool = False         # force fully-jitted tree builder
-    mesh_shape: Tuple[int, ...] = tuple()  # override device mesh
+    # start the scores from the label average (gbdt.cpp BoostFromAverage)
     boost_from_average: bool = True
 
     # prediction
@@ -836,7 +819,7 @@ class Config:
 
 
 _FIELD_TYPES = {f.name: f.type for f in dataclasses.fields(Config)}
-_TUPLE_INT_FIELDS = {"ndcg_eval_at", "mesh_shape"}
+_TUPLE_INT_FIELDS = {"ndcg_eval_at"}
 _TUPLE_FLOAT_FIELDS = {"label_gain"}
 _TUPLE_STR_FIELDS = {"valid_data", "metric", "serve_models",
                      "route_backends"}
@@ -928,8 +911,6 @@ def check_param_conflict(cfg: Config) -> None:
         raise ValueError(f"unknown tree_learner: {cfg.tree_learner}")
     if cfg.tree_growth not in ("auto", "exact", "rounds"):
         raise ValueError(f"unknown tree_growth: {cfg.tree_growth}")
-    if cfg.hist_rows not in ("auto", "gathered", "masked"):
-        raise ValueError(f"unknown hist_rows: {cfg.hist_rows}")
     if cfg.hist_exchange not in ("auto", "psum", "psum_scatter"):
         raise ValueError(f"unknown hist_exchange: {cfg.hist_exchange}")
     if cfg.hist_exchange_min_bytes < -1:

@@ -769,7 +769,7 @@ class _Dataflow:
                     return False                       # host-returning API
             # only jit ROOTS reliably return device arrays; a merely
             # reachable-from-jit helper called with host args at trace
-            # time returns host values (gather_scratch_capacity etc.)
+            # time returns host values (padded_bin_count etc.)
             target = self.pkg.resolve_callee(self.mi, self.fi.qualname,
                                              expr.func)
             if target is not None and target.is_jit_root:

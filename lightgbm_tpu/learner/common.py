@@ -62,8 +62,8 @@ def device_bytes_limit() -> Optional[float]:
     if jax.default_backend() == "tpu":
         raise RuntimeError(
             f"{dev} reports no memory_stats()['bytes_limit']; the "
-            "histogram pool, the gathered scratch and the bin storage "
-            "layout are sized from it")
+            "histogram pool and the bin storage layout are sized from "
+            "it")
     return None
 
 
@@ -74,83 +74,6 @@ def _default_pool_budget() -> float:
     path); backends without memory stats keep the conservative 1.5 GB."""
     limit = device_bytes_limit()
     return 1.5e9 if limit is None else max(1.5e9, 0.25 * limit)
-
-
-def gather_scratch_capacity(np_rows: int) -> int:
-    """Static row capacity of the gathered-histogram scratch for the
-    smaller-child passes: in any round the smaller children of all
-    splits partition subsets of their parents, so their sizes sum to
-    <= ceil(N/2) by construction (the same bound that makes the
-    reference's smaller/larger subtraction trick work,
-    serial_tree_learner.cpp:344-422).  128-aligned so every tier is a
-    whole lane tile."""
-    cap = (np_rows + 1) // 2
-    return max(128, 128 * int(math.ceil(cap / 128)))
-
-
-def gather_capacity_tiers(cap: int) -> tuple:
-    """Ascending static capacities for the gathered passes (full, /4,
-    /16 of `cap`, deduped).  The per-pass capacity is picked at run time
-    as the smallest tier holding the round's live rows — late rounds
-    with small leaves drop to the small tiers, so the kernel cost
-    tracks the live-row count instead of the static bound.  Three tiers
-    bound the compile count (each tier is one kernel specialization,
-    shared across call sites by the jit cache)."""
-    full = max(128, 128 * int(math.ceil(cap / 128)))
-    tiers = {full}
-    for d in (4, 16):
-        tiers.add(max(128, 128 * ((cap // d) // 128)))
-    return tuple(sorted(tiers))
-
-
-def gathered_scratch_fits(num_columns: int, np_rows: int,
-                          bins_itemsize: int = 4,
-                          limit_bytes: float = 0.0) -> bool:
-    """Budget gate for the gathered path's transient scratch (the
-    [F, cap] gathered bins plus [8, cap] vals materialized per pass —
-    the analog of the HistogramPool cap for this buffer): it must fit
-    comfortably next to the bin store and scores, so refuse when it
-    would exceed ~15% of device memory."""
-    cap = gather_scratch_capacity(np_rows)
-    scratch = float(cap) * (num_columns * bins_itemsize + 8 * 4)
-    if limit_bytes <= 0:
-        limit_bytes = device_bytes_limit() or CPU_TIER_BYTES_LIMIT
-    return scratch <= 0.15 * limit_bytes
-
-
-def resolve_hist_rows(cfg: Config, *, num_columns: int, np_rows: int,
-                      bins_itemsize: int = 4) -> str:
-    """Resolve the `hist_rows` knob to the mode a rounds learner runs.
-
-    "masked" streams the full [F, N] bin store every histogram pass, at
-    the slot tier that holds the round's leaves; "gathered" maintains
-    the device-resident row partition and feeds the kernels only the
-    leaf-contiguous segments they need.  "auto" is masked, on the chip
-    as on the CPU tier: with both feeds measured in the benchmark's
-    three cells (Higgs 10.5M x 28, Epsilon 400k x 2000 at 255 and at 63
-    bins, int8 operands, one TPU v5e) the stream was faster in each, by
-    13x, 23 % and 4 % — the gather costs about 125 ns a row and pass in
-    computed-index accesses and saves less kernel time than that
-    (PERF.md section 6, PR 32).  No shape was measured on the gathered
-    side, so `auto` has no rule that sends one there; bagging and GOSS,
-    whose dropped rows never enter the permutation, are where one may
-    lie (PERF.md section 7).  An explicit "gathered" still runs it,
-    also under shard_map, where the permutation, the (offset, count)
-    table and the scratch are shard-local and `np_rows` is the
-    PER-SHARD row count — behind the scratch-memory gate: a gathered
-    feed whose scratch would not fit beside the store runs masked, and
-    `tree/hist_rows_downgrades` counts it."""
-    mode = getattr(cfg, "hist_rows", "auto")
-    from .. import log, profiling
-    if mode == "auto":
-        mode = "masked"
-    if mode == "gathered" and not gathered_scratch_fits(
-            num_columns, np_rows, bins_itemsize):
-        log.warning("hist_rows=gathered scratch would not fit the device "
-                    "memory budget at this shape; using masked")
-        profiling.count(profiling.HIST_ROWS_DOWNGRADES)
-        return "masked"
-    return mode
 
 
 def use_parent_hist_cache(cfg: Config, num_features: int,

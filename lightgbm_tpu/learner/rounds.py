@@ -10,7 +10,7 @@ rows.  This learner restructures the SAME split math into rounds:
   `num_leaves` cap binds, the top-gain leaves win — the greedy criterion
   applied per round instead of per split);
 - the smaller children of all K splits in a round are histogrammed in ONE
-  multi-leaf pass (`ops/histogram.hist_multileaf`): vals rows are
+  multi-leaf pass (`ops/histogram.hist_multileaf_masked`): vals rows are
   (grad·mask_k, hess·mask_k, mask_k) for K leaves → an [M=3K, C] @ [C, B]
   MXU matmul at M≈128, with the one-hot generation amortized over the
   whole round; larger children come from parent-histogram subtraction
@@ -30,8 +30,13 @@ shapes — by `lax.psum_scatter` over the store-column axis, where each
 device reduces and keeps only its F/ndev feature slice, split-searches
 it, and all_gathers the per-leaf best-split records (the reference's
 Network::ReduceScatter ownership model, data_parallel_tree_learner.cpp:
-118-160; `hist_exchange` knob).  The gathered row partition is per-shard
-local state, so `hist_rows=gathered` composes with both exchanges.
+118-160; `hist_exchange` knob).
+
+Every histogram pass streams the whole store with the leaf mask built in
+the kernel, at the slot tier (8 / 32 / K) that holds the round's leaves.
+A feed that copied out only the rounds' rows through a row permutation cost
+about 125 ns a row and pass beyond its kernel and lost to the stream in
+every benchmark cell, by 13x, 23 % and 4 % (PERF.md section 6, PR 32).
 """
 from __future__ import annotations
 
@@ -49,14 +54,12 @@ from ..sharded.mesh import (check_scatter_divisible, check_tree_divergence,
                             mesh_axes, pad_cols_to_ndev,
                             resolve_hist_exchange)
 from .common import (CPU_TIER_BYTES_LIMIT, device_bytes_limit,
-                     gather_capacity_tiers, gather_scratch_capacity,
-                     make_split_kw, padded_bin_count, resolve_hist_rows,
-                     sentinel_bins_t, use_parent_hist_cache)
+                     make_split_kw, padded_bin_count, sentinel_bins_t,
+                     use_parent_hist_cache)
 from .fused import TreeArrays, tree_arrays_to_host
 from .. import profiling
 from ..jaxutil import bag_mask_dev, pad_rows_dev, slice_rows_dev
-from ..ops.histogram import (hist_multileaf_gathered, hist_multileaf_masked,
-                             hist_sparse_gathered, hist_sparse_multileaf,
+from ..ops.histogram import (hist_multileaf_masked, hist_sparse_multileaf,
                              masked_hist_mxu_ops, sparse_window_streams)
 from ..ops.partition import partition_rows, partition_rows_sparse
 from ..ops.split import (best_split, bundle_predicate_params,
@@ -72,10 +75,10 @@ STATS_COUNTERS = (
     profiling.HIST_ROWS_TOUCHED, profiling.HIST_EXCHANGE_BYTES,
     profiling.SPLIT_RECORDS_BYTES, profiling.SPARSE_NNZ_TOUCHED,
     profiling.TREE_ROUNDS, profiling.HIST_PASSES, profiling.HIST_SLOTS,
-    profiling.HIST_LIVE_SLOTS, profiling.HIST_MXU_OPS, profiling.FEED_ROWS,
-    profiling.FEED_LIVE_ROWS, profiling.PARTITION_ROWS)
+    profiling.HIST_LIVE_SLOTS, profiling.HIST_MXU_OPS,
+    profiling.PARTITION_ROWS)
 (S_ROWS, S_EXCHANGE, S_RECORDS, S_NNZ, S_ROUNDS, S_PASSES, S_SLOTS, S_LIVE,
- S_OPS, S_FEED, S_FEED_LIVE, S_PARTITION) = range(len(STATS_COUNTERS))
+ S_OPS, S_PARTITION) = range(len(STATS_COUNTERS))
 
 # Every phase of build_tree_rounds runs under a jax.named_scope
 # "lgbt.<phase>", so that an operation in a profiler trace says which
@@ -153,54 +156,30 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                       input_dtype: str = "float32",
                       max_rounds: int = 0,
                       cache_parent_hist: bool = True,
-                      hist_rows: str = "masked",
                       hist_exchange: str = "psum",
                       num_devices: int = 1,
                       num_feature_shards: int = 1,
                       leaves_per_batch: int = 0,
                       sparse: bool = False):
     """Grow one tree in batched rounds.  Shapes as learner/fused.build_tree.
-    Returns (TreeArrays, leaf_id, stats) — stats is a [12] f32 vector in
+    Returns (TreeArrays, leaf_id, stats) — stats is a [10] f32 vector in
     the order of STATS_COUNTERS: rows processed by histogram kernels
-    (global across shards — the live-traffic metric behind the
-    gathered-vs-masked A/B); per-device histogram-exchange payload
+    (global across shards); per-device histogram-exchange payload
     bytes; per-device best-split-record allgather bytes; stored sparse
     entries processed (global, 0 on the dense path); rounds of the
     loop; histogram kernel launches, the root's included; the slots
     those launches were made for (each launch's K) and the slots among
-    them that held a leaf; and the operations their contractions
-    perform (ops/histogram.masked_hist_mxu_ops per dense launch, global
-    across shards; the sparse kernels add 0); the scratch rows the
-    gathered launches copy (each launch's capacity tier) and the rows
-    among them that belong to a leaf (both 0 under the masked feed);
-    and the rows whose leaf id and place in the permutation the rounds
-    rewrite (all Nloc in every round) — the last three global across
-    shards.  Every one is a scalar add where the launch or the round
-    is made, on values the build already has.
+    them that held a leaf; the operations their contractions perform
+    (ops/histogram.masked_hist_mxu_ops per dense launch, global across
+    shards; the sparse kernels add 0); and the rows whose leaf id the
+    rounds rewrite (all Nloc in every round, global across shards).
+    Every one is a scalar add where the launch or the round is made,
+    on values the build already has.
 
-    hist_rows="gathered" maintains a device-resident row partition
-    inside the while_loop: a [N] row permutation grouped by leaf plus
-    per-leaf (offset, count), stably compacted after each round's
-    partition_rows exactly like the reference's DataPartition::Split
-    (data_partition.hpp:80-130).  Histogram passes then gather only the
-    leaf-contiguous segments they need into a static scratch (sum of
-    smaller children <= N/2 by construction) instead of streaming all N
-    rows; bagged/GOSS-dropped rows never enter the permutation.  Under
-    shard_map everything — permutation, (offset, count) table, scratch,
-    capacity tiers (static at ceil(N_local/2)) — is per-shard local
-    state over the shard's row block; per-shard counts diverge, but the
-    tier lax.cond branches contain no collectives, so shards may pick
-    different tiers freely.  "masked" streams every row in every pass,
-    at the slot tier (8 / 32 / K) that holds the round's leaves, and
-    keeps no permutation.  Both feeds run the same kernel and grow the
-    same tree from the same gradients; `hist_rows=auto` is the stream
-    (common.resolve_hist_rows): the ~125 ns a row and pass that the
-    gather costs exceeded the kernel time it saved in all three
-    benchmark cells.  With int8 operands a pass quantises by the
-    largest gradient of the rows it is over — all rows when masked,
-    the launch's own when gathered — so from the second tree on the
-    two feeds' sums differ in their last digits (< 1/254 of the
-    largest gradient a row).
+    Every pass streams all Nloc rows of the store, at the slot tier
+    (8 / 32 / K) that holds the round's leaves; bagged or GOSS-dropped
+    rows carry a zero row_mask.  With int8 operands a pass quantises by
+    the largest gradient of all rows.
 
     hist_exchange="psum_scatter" (static; with data_axis set and
     num_devices the data-axis size) replaces the full [K, F, 3, B]
@@ -255,10 +234,9 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     stored entries, with the zero bin reconstructed from per-leaf
     totals.  The reduced histogram keeps the dense [K, F, 3, B] layout,
     so hist_exchange (psum / psum_scatter slice ownership) and the
-    round/compaction logic compose unchanged; gathered mode permutes
-    the ELL row segments exactly like dense rows.  The stats vector
-    gains a 4th element: stored entries touched by histogram kernels
-    (global across shards — the tree/sparse_nnz_touched counter)."""
+    round logic compose unchanged.  The stats vector's S_NNZ element
+    counts the stored entries touched by histogram kernels (global
+    across shards — the tree/sparse_nnz_touched counter)."""
     if sparse:
         sp_cols, sp_bins, sp_zb = bins[0], bins[1], bins[2]
         # stream leaves arrive stacked with a leading shard axis (one
@@ -276,7 +254,6 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     B = num_bins_padded
     K = leaves_per_batch or LEAVES_PER_BATCH
     n_chunks = (L + K - 1) // K
-    gathered = hist_rows == "gathered"
     # rows shard over every mesh axis present; under psum_scatter the
     # store-column axis scatters over ONE of them — the feature axis on
     # a 2-D (data x feature) mesh, else the data axis (1-D)
@@ -333,22 +310,6 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         the psum_scatter path exchanges records)."""
         return 4.0 * nd * k2 * 11 if hx else 0.0
 
-    if gathered:
-        # static capacity tiers: smaller-child passes are bounded by
-        # ceil(N/2); direct large-child passes (bounded-memory mode) by N
-        tiers_all = gather_capacity_tiers(Nloc)
-        tiers_small = gather_capacity_tiers(gather_scratch_capacity(Nloc))
-        if row_axes is not None:
-            # the ceil(N/2) smaller-child bound is GLOBAL: smaller/larger
-            # is decided on global counts, so one shard's local share of
-            # the globally-smaller children can reach ALL of its rows.
-            # Keep the N/2 tier (it catches the typical balanced pass,
-            # preserving the rows-touched win) but make the full-Nloc
-            # tier reachable so a skewed shard never overflows the
-            # scratch and silently drops rows.
-            tiers_small = tuple(sorted(set(tiers_small + tiers_all)))
-    else:
-        tiers_all = tiers_small = None
     if ftbl is None:
         ftbl = identity_feat_table(num_bins)
     # Termination is governed by the while_loop predicate (no positive gain
@@ -378,15 +339,11 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             num_bins_padded=B, backend=backend, input_dtype=input_dtype,
             max_num_bin=max_num_bin)
 
-    def launch_stats(rows, nnz, slots, live, ops, feed_live=None):
-        """The stats vector of one histogram kernel launch; a gathered
-        launch says how many of its `rows` scratch rows hold a row of a
-        leaf (`feed_live`)."""
+    def launch_stats(rows, nnz, slots, live, ops):
+        """The stats vector of one histogram kernel launch."""
         v = [0.0] * len(STATS_COUNTERS)
         v[S_ROWS], v[S_NNZ], v[S_PASSES] = rows, nnz, 1.0
         v[S_SLOTS], v[S_LIVE], v[S_OPS] = slots, live, ops
-        if feed_live is not None:
-            v[S_FEED], v[S_FEED_LIVE] = rows, feed_live
         return jnp.stack([jnp.float32(x) for x in v])
 
     def hist_masked(lid_, sl_):
@@ -471,25 +428,8 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             root_sums = jnp.stack([sum_g, sum_h, cnt])
 
         leaf_id = jnp.zeros(Nloc, jnp.int32)
-        if gathered:
-            # initial permutation: live (mask > 0) rows first in row order —
-            # root's segment — with sampled-out rows parked past n_active,
-            # outside every leaf segment forever (they still carry leaf ids
-            # and are moved by partition_rows, but no histogram reads them)
-            posn0 = jax.lax.iota(jnp.int32, Nloc)
-            live0 = (row_mask > 0).astype(jnp.int32)
-            ecs0 = jnp.cumsum(live0) - live0           # lives before each row
-            n_active = jnp.sum(live0)
-            dest0 = jnp.where(live0 > 0, ecs0, n_active + (posn0 - ecs0))
-            perm = jnp.zeros(Nloc, jnp.int32).at[dest0].set(posn0)
-            leaf_off = jnp.zeros(L, jnp.int32)
-            leaf_cnt = jnp.zeros(L, jnp.int32).at[0].set(n_active)
-        else:
-            perm = jnp.zeros(0, jnp.int32)
-            leaf_off = jnp.zeros(0, jnp.int32)
-            leaf_cnt = jnp.zeros(0, jnp.int32)
-        # the root contributes one masked full-stream launch for one
-        # slot + one exchange
+        # the root contributes one full-stream launch for one slot + one
+        # exchange
         stats = (launch_stats(Nloc, nnz_pass if sparse else 0, 1, 1,
                               mxu_ops(Nloc, 1))
                  .at[S_EXCHANGE].set(_exchange_bytes(1))
@@ -524,7 +464,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
 
     def round_body(st):
         (rnd, leaf_id, leaf_best, leaf_depth, leaf_parent, leaf_side,
-         leaf_hist, perm, leaf_off, leaf_cnt, stats, arrs) = st
+         leaf_hist, stats, arrs) = st
         n_leaves = arrs.num_leaves
 
         # ---- select this round's splits (top-gain within the cap) ---------
@@ -580,43 +520,6 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 leaf_id2 = partition_rows(binsf, leaf_id, tbl,
                                           num_slots=L + 1, backend=backend,
                                           num_bins_padded=B)
-
-            # ---- stable row compaction (DataPartition::Split, vectorized) -----
-            # Each splitting leaf's contiguous segment of `perm` divides into
-            # a stay-prefix (rows keeping the parent id, original order) and
-            # a moved-suffix (rows taking the new id) — O(N) with one cumsum
-            # and a scatter, no sort.  Parked (sampled-out) rows sit past
-            # n_active and keep their positions.
-            if gathered:
-                posn = jax.lax.iota(jnp.int32, Nloc)
-                n_act = jnp.sum(leaf_cnt)
-                ol = jnp.take(leaf_id, perm)                 # old leaf per slot
-                nl = jnp.take(leaf_id2, perm)                # new leaf per slot
-                stay = nl == ol
-                csp = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                       jnp.cumsum(stay.astype(jnp.int32))])
-                soff = jnp.take(leaf_off, ol)                # segment starts
-                seg_stays = jnp.take(csp, soff)
-                rstay = csp[:Nloc] - seg_stays               # stays before pos
-                ns_row = jnp.take(csp, soff + jnp.take(leaf_cnt, ol)) - seg_stays
-                dest = soff + jnp.where(stay, rstay,
-                                        ns_row + (posn - soff) - rstay)
-                dest = jnp.where(posn >= n_act, posn, dest)
-                perm2 = jnp.zeros_like(perm).at[dest].set(perm)
-                # split each parent's (offset, count): parent keeps the
-                # stay-prefix, the new leaf takes the moved suffix
-                ns_leaf = (jnp.take(csp, leaf_off + leaf_cnt)
-                           - jnp.take(csp, leaf_off))        # [L] stay counts
-                ns_p = jnp.take(ns_leaf, pl_)
-                nii = jnp.where(do, new_leaf, L)
-                pii = jnp.where(do, pl_, L)
-                leaf_off2 = leaf_off.at[nii].set(
-                    jnp.take(leaf_off, pl_) + ns_p, mode="drop")
-                leaf_cnt2 = (leaf_cnt.at[nii].set(
-                    jnp.take(leaf_cnt, pl_) - ns_p, mode="drop")
-                    .at[pii].set(ns_p, mode="drop"))
-            else:
-                perm2, leaf_off2, leaf_cnt2 = perm, leaf_off, leaf_cnt
 
         # ---- tree arrays (batched Tree::Split) ----------------------------
         with jax.named_scope("lgbt.tree_arrays"):
@@ -679,34 +582,21 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         K_SMALL = min(8, K)
         K_MID = min(32, K)
 
-        def hist_tiered(slv, dk, Kc):
-            """Masked histogram of the slots' leaves over all rows, at
-            the narrowest slot tier that holds the active slots.
-            Returns ([Kc, F, 3, B] hists, the launch's stats vector)."""
-            live = jnp.sum(dk.astype(jnp.float32))
-
-            def full_call(slv_k):
-                if sparse:
-                    return hist_sparse_multileaf(
-                        spt, leaf_id2, gh8, slv_k, num_columns_padded=F,
-                        num_bins_padded=B, backend=backend,
-                        input_dtype=input_dtype)
-                return hist_multileaf_masked(
-                    binsf, leaf_id2, gh8, slv_k, num_bins_padded=B,
-                    backend=backend, input_dtype=input_dtype,
-                    max_num_bin=max_num_bin)
+        def hist_pass(slv, dk):
+            """One histogram launch for the slots `slv` (-1 = empty, dk
+            the active ones) over all rows, at the narrowest slot tier
+            that holds the active slots.  Returns ([Kc, F, 3, B] hists,
+            the launch's stats vector)."""
+            Kc = slv.shape[0]
 
             def at(Kt):
-                h = full_call(slv[:Kt])
+                h = hist_masked(leaf_id2, slv[:Kt])
                 if Kt < Kc:
                     h = jnp.concatenate(
                         [h, jnp.zeros((Kc - Kt,) + h.shape[1:], h.dtype)],
                         axis=0)
                 return h, launch_stats(Nloc, nnz_pass if sparse else 0, Kt,
                                        live, mxu_ops(Nloc, Kt))
-
-            if Kc <= K_SMALL:
-                return at(Kc)
 
             def full_or_mid(_):
                 if Kc <= K_MID:
@@ -718,58 +608,12 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                                     lambda _: at(K_MID),
                                     lambda _: at(Kc), None)
 
-            return jax.lax.cond(~jnp.any(dk[K_SMALL:]),
-                                lambda _: at(K_SMALL), full_or_mid, None)
-
-        def hist_gathered_tiered(slv, tiers):
-            """Gathered histogram of the slots' leaf segments at the
-            smallest static capacity tier holding this pass's live rows
-            (lax.cond picks the tier at run time; every tier is one
-            fixed-shape kernel, so nothing retraces round to round).
-            Returns ([Kc, F, 3, B] hists, the launch's stats vector:
-            the tier's capacity as rows processed)."""
-            Kc = slv.shape[0]
-            sc = jnp.clip(slv, 0, L - 1)
-            act = slv >= 0
-            with jax.named_scope("lgbt.feed"):
-                so = jnp.where(act, jnp.take(leaf_off2, sc), 0)
-                sn = jnp.where(act, jnp.take(leaf_cnt2, sc), 0)
-                total = jnp.sum(sn)
-            live = jnp.sum(act.astype(jnp.float32))
-
-            def call(cap):
-                def f(_):
-                    if sparse:
-                        h, nz = hist_sparse_gathered(
-                            (sp_cols, sp_bins, sp_zb), gh8, perm2, so,
-                            sn, capacity=cap, num_columns_padded=F,
-                            num_bins_padded=B)
-                    else:
-                        h, nz = hist_multileaf_gathered(
-                            binsf, gh8, perm2, so, sn, capacity=cap,
-                            num_bins_padded=B, backend=backend,
-                            input_dtype=input_dtype,
-                            max_num_bin=max_num_bin), 0
-                    return h, launch_stats(cap, nz, Kc, live,
-                                           mxu_ops(cap, Kc), feed_live=total)
-                return f
-
-            def pick(i):
-                if i == len(tiers) - 1:
-                    return call(tiers[i])
-                return lambda _: jax.lax.cond(
-                    total <= tiers[i], call(tiers[i]), pick(i + 1), None)
-
-            return pick(0)(None)
-
-        def hist_pass(slv, dk, tiers):
-            """One histogram launch for the slots `slv` (-1 = empty, dk
-            the active ones) through the resolved row feed; `tiers` are
-            the gathered feed's capacities."""
             with jax.named_scope("lgbt.hist"):
-                if gathered:
-                    return hist_gathered_tiered(slv, tiers)
-                return hist_tiered(slv, dk, slv.shape[0])
+                live = jnp.sum(dk.astype(jnp.float32))
+                if Kc <= K_SMALL:
+                    return at(Kc)
+                return jax.lax.cond(~jnp.any(dk[K_SMALL:]),
+                                    lambda _: at(K_SMALL), full_or_mid, None)
 
         leaf_best2 = leaf_best
         leaf_hist2 = leaf_hist
@@ -791,7 +635,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                     sil = small_is_left[s:s + Kc, None]
                     li = jnp.where(dk, pl_[s:s + Kc], L)
                     ni = jnp.where(dk, new_leaf[s:s + Kc], L)
-                h_small, launch = hist_pass(slv, dk, tiers_small)
+                h_small, launch = hist_pass(slv, dk)
                 with jax.named_scope("lgbt.exchange"):
                     h_small = exchange(h_small)    # [Kc, F|Fs, 3, B]
                     stv = (stv + launch).at[S_EXCHANGE].add(
@@ -800,7 +644,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                     with jax.named_scope("lgbt.subtract"):
                         h_large = leaf_hist2[pl_[s:s + Kc]] - h_small
                 else:
-                    h_large, launch = hist_pass(llv, dk, tiers_all)
+                    h_large, launch = hist_pass(llv, dk)
                     with jax.named_scope("lgbt.exchange"):
                         h_large = exchange(h_large)
                         stv = (stv + launch).at[S_EXCHANGE].add(
@@ -836,8 +680,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 (leaf_best2, leaf_hist2, stats2))
 
         return (rnd2, leaf_id2, leaf_best2, leaf_depth2, leaf_parent2,
-                leaf_side2, leaf_hist2, perm2, leaf_off2, leaf_cnt2,
-                stats2, arrs2)
+                leaf_side2, leaf_hist2, stats2, arrs2)
 
     def round_cond(st):
         rnd, leaf_best, leaf_depth, arrs = st[0], st[2], st[3], st[-1]
@@ -848,17 +691,15 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                     & jnp.any(gated > 0))
 
     st = (jnp.int32(0), leaf_id, leaf_best, leaf_depth, leaf_parent,
-          leaf_side, leaf_hist, perm, leaf_off, leaf_cnt, stats,
-          arrs)
+          leaf_side, leaf_hist, stats, arrs)
     st = jax.lax.while_loop(round_cond, round_body, st)
-    # rows (histogrammed, fed, partitioned), sparse entries and
-    # contraction operations are summed across shards (global traffic);
+    # rows (histogrammed, partitioned), sparse entries and contraction
+    # operations are summed across shards (global traffic);
     # the byte counters and the round, launch and slot counts stay
     # per-device (passes are uniform, so every shard agrees)
     with jax.named_scope("lgbt.pack"):
         stv = st[-2]
-        glob = jnp.asarray([S_ROWS, S_NNZ, S_OPS, S_FEED, S_FEED_LIVE,
-                            S_PARTITION])
+        glob = jnp.asarray([S_ROWS, S_NNZ, S_OPS, S_PARTITION])
         stv = stv.at[glob].set(_psum(stv[glob], row_axes))
     return st[-1], st[1], stv
 
@@ -1027,29 +868,6 @@ class RoundsTreeLearner:
                       else self.Fpad)
         self.cache_parent_hist = use_parent_hist_cache(cfg, cache_cols,
                                                        self.B)
-        # row feed: gathered (ordered histograms over the device-resident
-        # row partition) vs masked full-stream — see build_tree_rounds;
-        # `auto` is the stream.
-        # Under shard_map the partition is per-shard local state, so the
-        # scratch budget is sized from the PER-SHARD row count.  The
-        # sparse store defaults to masked (its window entry streams are
-        # static store order — every masked pass is already nnz-scaled);
-        # explicit gathered composes on the XLA path, where the ELL row
-        # segments gather exactly like dense rows.
-        if self.sparse:
-            hr = getattr(cfg, "hist_rows", "auto")
-            if hr == "gathered" and backend == "pallas":
-                from .. import log
-                log.warning("hist_rows=gathered over the sparse store "
-                            "runs the XLA scatter path; using masked "
-                            "on TPU")
-                hr = "masked"
-            self.hist_rows = "masked" if hr == "auto" else hr
-        else:
-            self.hist_rows = resolve_hist_rows(
-                cfg, num_columns=self.Fpad,
-                np_rows=max(1, self.Np // max(nsh, 1)),
-                bins_itemsize=int(bins_np.dtype.itemsize))
         kw = dict(num_leaves=cfg.num_leaves, num_bins_padded=self.B,
                   max_num_bin=int(dataset.max_num_bin),
                   split_kw=self.split_kw, max_depth=int(cfg.max_depth),
@@ -1057,7 +875,6 @@ class RoundsTreeLearner:
                   min_sum_hessian_in_leaf=float(cfg.min_sum_hessian_in_leaf),
                   backend=backend,
                   cache_parent_hist=self.cache_parent_hist,
-                  hist_rows=self.hist_rows,
                   hist_exchange=self.hist_exchange,
                   num_devices=self.dd,
                   num_feature_shards=self.df,

@@ -43,7 +43,7 @@ def hist_xla(gb: jax.Array, vals: jax.Array, *, num_bins_padded: int,
 
     Parameters
     ----------
-    gb : [C, F] integer bin ids of the gathered rows (sentinel rows have
+    gb : [C, F] integer bin ids of the rows taken (sentinel rows have
          arbitrary bins but zero vals).
     vals : [3, C] float32 rows (grad, hess, count-mask).
     Returns [F, 3, B] float32.
@@ -290,17 +290,6 @@ def hist_multileaf_xla(gb_t: jax.Array, vals: jax.Array, *,
     vs = vals.reshape(M, n_chunks, chunk).transpose(1, 0, 2)
     acc, _ = jax.lax.scan(body, acc0, (gbs, vs))
     return acc
-
-
-def hist_multileaf(gb_t: jax.Array, vals: jax.Array, *, num_bins_padded: int,
-                   backend: str = "xla",
-                   input_dtype: str = "float32") -> jax.Array:
-    if backend == "pallas":
-        return hist_pallas_multileaf(gb_t, vals,
-                                     num_bins_padded=num_bins_padded,
-                                     input_dtype=input_dtype)
-    return hist_multileaf_xla(gb_t, vals, num_bins_padded=num_bins_padded,
-                              input_dtype=input_dtype)
 
 
 def _simple_onehot(gb, B, input_dtype):
@@ -786,87 +775,6 @@ def histogram_from_indices(bins_t: jax.Array, grad_pad: jax.Array,
                     num_bins_padded=num_bins_padded, input_dtype=input_dtype)
 
 
-def gather_segments(perm: jax.Array, seg_off: jax.Array,
-                    seg_cnt: jax.Array, *, capacity: int):
-    """Concatenate K contiguous segments of the row permutation `perm`
-    into one static scratch layout (the reference's ordered-gradients
-    read: DataPartition keeps each leaf's rows contiguous and the
-    histogram kernel walks exactly that span,
-    data_partition.hpp:80-130).
-
-    perm : [N] int32 row permutation (rows grouped by leaf).
-    seg_off, seg_cnt : [K] int32 — segment start/length per slot inside
-        `perm` (cnt 0 = empty slot).
-    capacity : static scratch length; must satisfy sum(seg_cnt) <=
-        capacity (callers size it from the N/2 smaller-child bound).
-
-    Returns (idx [capacity] int32 row ids — clamped-but-arbitrary for
-    unused scratch slots, slot [capacity] int32 slot id per scratch
-    position with -2 marking unused slots, total int32 scalar).
-    """
-    K = seg_off.shape[0]
-    base = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                            jnp.cumsum(seg_cnt.astype(jnp.int32))])  # [K+1]
-    total = base[K]
-    j = jax.lax.iota(jnp.int32, capacity)
-    # scratch position j belongs to the slot whose cumulative span
-    # contains it; empty slots span nothing and are never selected
-    slot = jnp.searchsorted(base[1:], j, side="right").astype(jnp.int32)
-    valid = j < total
-    sc = jnp.minimum(slot, K - 1)
-    pos = seg_off[sc] + (j - base[sc])
-    pos = jnp.clip(pos, 0, perm.shape[0] - 1)
-    idx = jnp.take(perm, pos)
-    return idx, jnp.where(valid, sc, -2), total
-
-
-def hist_multileaf_gathered(bins_fn: jax.Array, gh8: jax.Array,
-                            perm: jax.Array, seg_off: jax.Array,
-                            seg_cnt: jax.Array, *, capacity: int,
-                            num_bins_padded: int, backend: str = "xla",
-                            input_dtype: str = "float32",
-                            interpret: bool = False,
-                            max_num_bin: int = 0) -> jax.Array:
-    """Histogram K leaf-contiguous row segments in one pass over a
-    static [capacity] scratch — the "ordered" alternative to
-    hist_multileaf_masked that touches only the rows the round needs
-    instead of streaming all N.
-
-    bins_fn : [F, N] int bins (int8 = value-128 storage, kept narrow
-        through the gather); gh8 : [8, N] f32 (grad·rm, hess·rm, rm,
-        pads); perm/seg_off/seg_cnt as gather_segments.  Everything here
-        is shard-local: under shard_map the caller passes its own row
-        block's permutation and segment tables, and the returned local
-        histograms are exchanged (psum / psum_scatter) afterwards.
-
-    Returns [K, F, 3, B] f32 — slot k holds segment k's histogram
-    (exactly hist_multileaf_masked's output for the same leaf when the
-    segment contains that leaf's live rows; empty slots are zero).
-
-    The heavy lifting reuses the masked kernel pair (incl. the int8
-    one-hot Pallas path) on the compacted rows: scratch slot ids play
-    the leaf-id role, so nothing about the VMEM mask-building or the
-    quantized int32 accumulation changes — only C collapses from N to
-    `capacity`.  `capacity` is static, so repeated calls at the same
-    tier never retrace.  On the int8 path the per-pass quantization
-    scales derive from the gathered rows only (a tighter bound than the
-    masked kernel's all-rows max — strictly less rounding error)."""
-    K = seg_off.shape[0]
-    with jax.named_scope("lgbt.feed"):
-        idx, slot, _ = gather_segments(perm, seg_off, seg_cnt,
-                                       capacity=capacity)
-        gbg = jnp.take(bins_fn, idx, axis=1)         # [F, capacity]
-        live = (slot >= 0)
-        ghg = (jnp.take(gh8, idx, axis=1)
-               * live[None, :].astype(jnp.float32))
-        sl = jax.lax.iota(jnp.int32, K)
-    return hist_multileaf_masked(gbg, slot, ghg, sl,
-                                 num_bins_padded=num_bins_padded,
-                                 backend=backend, input_dtype=input_dtype,
-                                 interpret=interpret,
-                                 max_num_bin=max_num_bin)
-
-
 # ----------------------------------------------------------------------------
 # Sparse (nonzero-iterating) histogram pair — docs/Sparse.md
 #
@@ -1180,7 +1088,7 @@ def hist_sparse_pallas(e_row: jax.Array, e_flat: jax.Array,
                        interpret: bool = False) -> jax.Array:
     """Pallas sparse histogram over slot-segmented entry streams
     (sparse_window_streams).  Per-pass state (leaf ids, gradients) is
-    gathered per entry OUTSIDE the kernel — nnz-sized XLA gathers —
+    looked up per entry OUTSIDE the kernel — nnz-sized XLA gathers —
     then the grid runs (windows, entry-chunks) and the per-slot
     partial histograms fold back to columns (unscatter_slot_hist).
     Returns [K, Cp, 3, B] f32 with the zero bin reconstructed.
@@ -1272,39 +1180,6 @@ def hist_sparse_multileaf(sp, lid: jax.Array, gh8: jax.Array,
                            num_columns_padded=num_columns_padded,
                            num_bins_padded=num_bins_padded,
                            input_dtype=input_dtype)
-
-
-def hist_sparse_gathered(sp, gh8: jax.Array, perm: jax.Array,
-                         seg_off: jax.Array, seg_cnt: jax.Array, *,
-                         capacity: int, num_columns_padded: int,
-                         num_bins_padded: int,
-                         input_dtype: str = "float32"):
-    """Gathered (ordered) sparse histogram: compact the K leaf-contiguous
-    row segments of the device row partition into the static scratch
-    (gather_segments — CSR row segments permute exactly like dense
-    rows), gather their ELL entries, and histogram only those.  Returns
-    ([K, Cp, 3, B] hists, f32 stored entries touched) — the nnz-scaled
-    analog of hist_multileaf_gathered, XLA path (the window streams are
-    store-order static and cannot be re-sorted per pass)."""
-    cols, binsv, zero_bin = sp[0], sp[1], sp[2]
-    K = seg_off.shape[0]
-    Cp = num_columns_padded
-    idx, slot, _ = gather_segments(perm, seg_off, seg_cnt,
-                                   capacity=capacity)
-    cg = jnp.take(cols, idx, axis=0)                     # [cap, R]
-    bg = jnp.take(binsv, idx, axis=0)
-    live = (slot >= 0)
-    # dead scratch slots: zero vals AND sentinel entries, so neither
-    # the totals nor the scatter see them
-    cg = jnp.where(live[:, None], cg, Cp)
-    ghg = jnp.take(gh8, idx, axis=1) * live[None, :].astype(jnp.float32)
-    sl = jax.lax.iota(jnp.int32, K)
-    h = hist_sparse_xla(cg, bg, zero_bin, slot, ghg, sl,
-                        num_columns_padded=Cp,
-                        num_bins_padded=num_bins_padded,
-                        input_dtype=input_dtype)
-    nnz = jnp.sum((cg < Cp).astype(jnp.float32))
-    return h, nnz
 
 
 def histogram_full_masked(bins: jax.Array, grad: jax.Array, hess: jax.Array,

@@ -55,7 +55,7 @@ _deferred: Dict[Tuple[str, ...], object] = {}
 # layer, fed through count_deferred (device-side accumulation, no sync
 # on the pipelined path) and read by bench.py / the MULTICHIP dryrun:
 #  - HIST_ROWS_TOUCHED: rows processed by histogram kernels (global sum
-#    across shards — the gathered-vs-masked live-traffic metric).
+#    across shards).
 #  - HIST_EXCHANGE_BYTES: PER-DEVICE histogram-collective payload —
 #    bytes of reduced histogram each device materializes per pass (the
 #    full [K, F, 3, B] tensor under psum, its F/ndev slice under
@@ -69,15 +69,9 @@ _deferred: Dict[Tuple[str, ...], object] = {}
 # the replicated tree state) and sanitize/divergences (bitwise
 # mismatches — the hard-fail condition); bench.py and the MULTICHIP
 # dryrun record both beside the retrace/transfer counters.
-#  - HIST_ROWS_DOWNGRADES: learners whose gathered row feed (asked
-#    for, or what `auto` picks on TPU) was refused by the scratch
-#    memory gate and replaced by the masked full stream — a slower
-#    path than the one the run was configured for, so it is counted
-#    (chip_smoke.py asserts 0).
 HIST_ROWS_TOUCHED = "tree/hist_rows_touched"
 HIST_EXCHANGE_BYTES = "tree/hist_exchange_bytes"
 SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
-HIST_ROWS_DOWNGRADES = "tree/hist_rows_downgrades"
 
 # Work counters of the rounds build (learner/rounds.build_tree_rounds
 # adds them on the device, where each launch is made; they ride its
@@ -93,24 +87,24 @@ HIST_ROWS_DOWNGRADES = "tree/hist_rows_downgrades"
 #    contractions perform, padding included (ops/histogram.
 #    masked_hist_mxu_ops), summed across shards.  The sparse kernels
 #    add 0: their contraction runs over entry blocks, not rows.
-#  - FEED_ROWS: scratch rows the gathered launches copy out of the
-#    store — each launch's capacity tier, whatever part of it holds a
-#    row; FEED_LIVE_ROWS: the rows among them that belong to a leaf
-#    (gather_segments' total).  live / rows is how full the scratch
-#    ran.  Both stay 0 under the masked feed, which copies nothing.
-#  - PARTITION_ROWS: rows whose place in the leaf-id vector and the
-#    row permutation a round rewrites, as executed: every row of the
-#    shard in every round today (a partition that moved only the
-#    split leaves' segments would count fewer).
-#    All three are summed across shards.
+#  - PARTITION_ROWS: rows whose place in the leaf-id vector a round
+#    rewrites, as executed: every row of the shard in every round
+#    today (a partition that moved only the split leaves' rows would
+#    count fewer), summed across shards.
 TREE_ROUNDS = "tree/rounds"
 HIST_PASSES = "tree/hist_passes"
 HIST_SLOTS = "tree/hist_slots"
 HIST_LIVE_SLOTS = "tree/hist_live_slots"
 HIST_MXU_OPS = "tree/hist_mxu_ops"
+PARTITION_ROWS = "tree/partition_rows"
+# Nothing increments these three since the row feed they counted went;
+# they stay, at 0 from the start, only for benchmark/ (jobs/train.py and
+# the feed_rows_per_iter metric read them) until ROADMAP B0.5 drops it.
+HIST_ROWS_DOWNGRADES = "tree/hist_rows_downgrades"
 FEED_ROWS = "tree/feed_rows"
 FEED_LIVE_ROWS = "tree/feed_live_rows"
-PARTITION_ROWS = "tree/partition_rows"
+_RETIRED_COUNTERS = (HIST_ROWS_DOWNGRADES, FEED_ROWS, FEED_LIVE_ROWS)
+_counters.update(dict.fromkeys(_RETIRED_COUNTERS, 0.0))
 
 # Canonical sparse-store counters (docs/Sparse.md), the nnz-scaling
 # evidence behind the sparse-vs-dense CTR A/B:
@@ -405,6 +399,7 @@ def reset() -> None:
     with _lock:
         _totals.clear()
         _counters.clear()
+        _counters.update(dict.fromkeys(_RETIRED_COUNTERS, 0.0))
         _samples.clear()
         _deferred.clear()
 

@@ -39,11 +39,6 @@ ALLOWLIST = {
     "gpu_device_id": "OpenCL selector kept for config compatibility",
     "gpu_use_dp": "OpenCL precision dial; histogram_dtype is the analog",
     "time_out": "socket-network timeout; collectives have no knob here",
-    # declared TPU knobs awaiting implementation
-    "hist_dtype": "accumulation dtype override not yet implemented",
-    "hist_input_dtype": "superseded by histogram_dtype; kept for compat",
-    "fused_tree": "forced fused builder selection not yet implemented",
-    "mesh_shape": "explicit mesh override not yet implemented",
 }
 
 
@@ -73,7 +68,7 @@ def _code_only(src: str) -> str:
     (matched by token position against the AST docstring spans — a
     value-based replace() silently no-ops whenever the docstring
     contains an escape sequence).  Non-docstring strings survive:
-    getattr(cfg, "hist_rows") style consumption must still count."""
+    getattr(cfg, "hist_exchange") style consumption must still count."""
     spans = _docstring_spans(src)
     out = []
     try:

@@ -98,8 +98,7 @@ def main(want):
             mesh = Mesh(np.asarray(topo.devices).reshape(4, 1),
                         ("data", "feature"))
             lr = rounds.RoundsTreeLearner(ds, cfg, mesh)
-            print(f"  hist_rows={lr.hist_rows} "
-                  f"hist_exchange={lr.hist_exchange}")
+            print(f"  hist_exchange={lr.hist_exchange}")
             lower(name, lr, ROWS[-1], NamedSharding(mesh, P("data")),
                   NamedSharding(mesh, P()),
                   NamedSharding(mesh, P(None, "data")))

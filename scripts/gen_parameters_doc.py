@@ -76,26 +76,6 @@ def main():
         "- `tree_learner`: `serial` | `feature` | `data` | `voting` | "
         "`data2d` — the distributed axes map onto a `jax.sharding.Mesh` "
         "instead of socket/MPI machine lists.",
-        "- `hist_rows` (default `auto`, aliases `ordered_histograms`, "
-        "`row_partition`): row feed of the batched-rounds histogram "
-        "passes. `masked` streams the full `[features, rows]` bin store "
-        "every pass; `gathered` maintains a device-resident row "
-        "partition (a row permutation grouped by leaf plus per-leaf "
-        "offset/count — the reference's `DataPartition` + ordered-"
-        "gradients design) and histograms only the leaf-contiguous "
-        "segments each round needs, so bagged/GOSS-dropped rows are "
-        "never read. `auto` = masked: on a TPU v5e with "
-        "`histogram_dtype=int8` the stream measured faster at every "
-        "benchmark shape (Higgs 10.5M x 28: 0.905 s an iteration "
-        "against 12.15; Epsilon 400k x 2000: 2.73 against 2.85, and "
-        "1.16 against 1.50 at `max_bin=63`), because the gather costs "
-        "about 125 ns a row and pass in computed-index accesses and "
-        "saves less kernel time than that. `gathered` runs on request "
-        "(per shard under shard-map, where the partition and scratch "
-        "are shard-local); a scratch that would not fit the device "
-        "runs masked and is counted. "
-        "See docs/Readme.md "
-        "\"Row partition / ordered histograms\".",
         "- `hist_exchange` (default `auto`, alias `histogram_reduce`): "
         "data-parallel histogram collective. `psum` all-reduces the "
         "full `[K, F, 3, B]` histogram onto every device; "

@@ -15,24 +15,16 @@ training set, `lgb.Booster`, its learner's own build program):
    be equal to the unit;
 2. the root split of tree 1 as the learner's build grew it (feature,
    threshold bin) against the best split of the reference histogram,
-   found in float64 by the textbook gain;
-3. tree 1 under `hist_rows=masked` against tree 1 under the feed the
-   program resolved, node for node.  At tree 1 of the binary objective
-   every gradient is +-0.5 and every hessian 0.25, so each pass
-   quantises them exactly whatever rows it scales over, and both feeds
-   sum the same integers: anything but equality is a fault.
+   found in float64 by the textbook gain.
 
 With `--auc-trees N` it makes one other check instead, the one that needs
-many trees (at N = 100 about 25 minutes, 20 of them the gathered feed's),
-on the cell `--cell` names (`higgs.full` unless given; `epsilon.full`
-takes about 12 minutes):
+many trees, on the cell `--cell` names (`higgs.full` unless given):
 
-4. the AUC on the cell's test split after `quality_iters` and after N trees
-   under each row feed.  From tree 2 on the int8 path quantises a pass by
-   the largest gradient of the rows it is over — all rows under the masked
-   feed, each launch's own under the gathered one — so the trees differ in
-   the last digits of their sums; the models have to be as good as each
-   other: both AUCs agree to `AUC_TOL`.
+3. the AUC on the cell's test split after `quality_iters` and after N
+   trees.  From tree 2 on the int8 path quantises a pass by the largest
+   gradient of all rows, so the sums carry rounding the first tree does
+   not have; the model has to go on learning through it: the AUC after N
+   trees is above the one after `quality_iters`.
 
 One JSON line per check, `{"ok": ...}` last; exit code 1 if any failed.
 """
@@ -50,9 +42,6 @@ if ROOT not in sys.path:
 
 CELL = "higgs.full"
 BLOCK = 1_000_000
-AUC_TOL = 1e-3
-TREE_FIELDS = ("split_feature", "threshold_bin", "left_child", "right_child",
-               "leaf_value", "leaf_count", "leaf_depth", "internal_count")
 
 
 def say(**facts):
@@ -106,14 +95,12 @@ def reference_split(hist, num_bins, min_data: int, min_hess: float):
     return best
 
 
-def tree_of(learner, grad, hess) -> dict:
-    """Tree 1 as the learner's own build program grows it, as numpy."""
+def tree_of(learner, grad, hess):
+    """Tree 1 as the learner's own build program grows it (TreeArrays
+    on the host)."""
     import jax
     _, _, arrs = learner.train_device(grad, hess, None, None)
-    got = jax.device_get(arrs)
-    n = int(got.num_leaves)
-    return dict({k: np.asarray(getattr(got, k)) for k in TREE_FIELDS},
-                num_leaves=n)
+    return jax.device_get(arrs)
 
 
 def load_cell(name=CELL):
@@ -122,46 +109,38 @@ def load_cell(name=CELL):
     return load_json("configs", cell["config"] + ".json"), cell
 
 
-def auc_of_feeds(params, cell, config, train, trees: int) -> bool:
-    """Check 4: `trees` iterations under each feed, the AUC on the test
-    split after `quality_iters` trees and after all of them."""
+def auc_after(params, cell, config, train, trees: int) -> bool:
+    """Check 3: `trees` iterations, the AUC on the test split after
+    `quality_iters` trees and after all of them."""
     import lightgbm_tpu as lgb
     from benchmark.harness import dataset, walk
     Xv, yv = dataset.test_split(config, int(cell["valid_rows"]))
     early = int(cell["quality_iters"])
-    aucs = {}
 
     def wait(bst):
         """One value fetch: the device has finished every update."""
         return float(bst._gbdt.train_score.score.sum())
 
-    for feed in ("masked", "gathered"):
-        bst = lgb.Booster(dict(params, hist_rows=feed), train)
-        bst.update()                    # compiles; timed from tree 2 on
-        wait(bst)
-        t0 = time.perf_counter()
-        for _ in range(trees - 1):
-            bst.update()
-        wait(bst)
-        s_per_iter = (time.perf_counter() - t0) / max(trees - 1, 1)
-        aucs[feed] = [walk.auc(yv, bst.predict(Xv, raw_score=True,
-                                               num_iteration=n))
-                      for n in (early, trees)]
-        say(check="auc_of_feed", feed=bst._gbdt.learner.hist_rows,
-            trees=[early, trees], valid_auc=aucs[feed],
-            s_per_iter=s_per_iter)
-        del bst
-    gap = [abs(a - b) for a, b in zip(aucs["masked"], aucs["gathered"])]
-    ok = max(gap) <= AUC_TOL
-    say(check="auc_both_feeds", ok=ok, trees=[early, trees], gap=gap,
-        tolerance=AUC_TOL, **{"valid_auc_" + k: v for k, v in aucs.items()})
+    bst = lgb.Booster(params, train)
+    bst.update()                    # compiles; timed from tree 2 on
+    wait(bst)
+    t0 = time.perf_counter()
+    for _ in range(trees - 1):
+        bst.update()
+    wait(bst)
+    s_per_iter = (time.perf_counter() - t0) / max(trees - 1, 1)
+    aucs = [walk.auc(yv, bst.predict(Xv, raw_score=True, num_iteration=n))
+            for n in (early, trees)]
+    ok = bool(np.isfinite(aucs).all() and aucs[1] > aucs[0])
+    say(check="auc_after_trees", ok=ok, trees=[early, trees], valid_auc=aucs,
+        s_per_iter=s_per_iter)
     return ok
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--auc-trees", type=int, default=0,
-                    help="run check 4 alone, over this many trees")
+                    help="run check 3 alone, over this many trees")
     ap.add_argument("--cell", default=CELL,
                     help="the cell whose job --auc-trees trains")
     args = ap.parse_args(argv)
@@ -179,13 +158,13 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     train, facts = dataset.binned_train_set(config, params)
     if args.auc_trees:
-        ok = auc_of_feeds(params, cell, config, train, args.auc_trees)
-        say(ok=ok, failed=[] if ok else ["auc_both_feeds"], device=dev)
+        ok = auc_after(params, cell, config, train, args.auc_trees)
+        say(ok=ok, failed=[] if ok else ["auc_after_trees"], device=dev)
         return 0 if ok else 1
     bst = lgb.Booster(params, train)
     learner = bst._gbdt.learner
     say(check="setup", device=dev, dataset=facts["how"],
-        learner=type(learner).__name__, hist_rows=learner.hist_rows,
+        learner=type(learner).__name__,
         store=list(learner.bins_dev.shape), seconds=time.perf_counter() - t0)
     failed = []
 
@@ -230,32 +209,12 @@ def main(argv=None) -> int:
         ref.astype(np.float64) * scale.astype(np.float64),
         np.asarray(learner.num_bins_dev), int(params["min_data_in_leaf"]),
         float(params["min_sum_hessian_in_leaf"]))
-    got = (int(tree["split_feature"][0]), int(tree["threshold_bin"][0]))
+    got = (int(tree.split_feature[0]), int(tree.threshold_bin[0]))
     split_ok = got == want[:2]
     say(check="root_split", ok=split_ok, path=got, reference=want[:2],
-        reference_gain=want[2], leaves=tree["num_leaves"])
+        reference_gain=want[2], leaves=int(tree.num_leaves))
     if not split_ok:
         failed.append("root_split")
-
-    # -- 3: tree 1 under the other feed -------------------------------------
-    other = "masked" if learner.hist_rows != "masked" else "gathered"
-    t0 = time.perf_counter()
-    learner2 = lgb.Booster(dict(params, hist_rows=other), train)._gbdt.learner
-    tree2 = tree_of(learner2, grad, hess)
-    diff = {}
-    for k in TREE_FIELDS:
-        a, b = tree[k], tree2[k]
-        if not np.array_equal(a, b):
-            diff[k] = {"nodes": int((a != b).sum()),
-                       "max_abs": float(np.abs(a.astype(np.float64)
-                                               - b.astype(np.float64)).max())}
-    same = tree["num_leaves"] == tree2["num_leaves"] and not diff
-    say(check="tree_1_other_feed", ok=same, feeds=[learner.hist_rows,
-                                                  learner2.hist_rows],
-        leaves=[tree["num_leaves"], tree2["num_leaves"]], differs=diff,
-        seconds=time.perf_counter() - t0)
-    if not same:
-        failed.append("tree_1_other_feed")
 
     say(ok=not failed, failed=failed)
     return 1 if failed else 0

@@ -155,28 +155,6 @@ def main():
         "ms": round(t1 * 1e3, 2)}
     print(f"hist_multileaf_masked K=1 (root): {t1*1e3:.1f} ms")
 
-    # gathered ("ordered") kernel vs the masked full-stream pass: K
-    # leaf-contiguous segments summing to the N/2 smaller-child bound
-    # (learner/rounds.py hist_rows=gathered) — same MXU math, C
-    # collapses from N to the scratch capacity
-    from lightgbm_tpu.ops.histogram import hist_multileaf_gathered
-    from lightgbm_tpu.learner.common import gather_scratch_capacity
-    perm = jnp.asarray(rng.permutation(N).astype(np.int32))
-    cap = gather_scratch_capacity(N)
-    seg_off = jnp.asarray((np.arange(K) * (N // K)).astype(np.int32))
-    seg_cnt = jnp.asarray(np.full(K, cap // K, np.int32))
-    tg = timeit(lambda: hist_multileaf_gathered(
-        bins, gh8, perm, seg_off, seg_cnt, capacity=cap,
-        num_bins_padded=B, backend=backend, input_dtype="int8",
-        max_num_bin=MB))
-    rec["kernels"][f"hist_multileaf_gathered_K{K}_int8"] = {
-        "ms": round(tg * 1e3, 2), "capacity": int(cap),
-        "rows_vs_masked": round(cap / N, 3)}
-    masked_ms = rec["kernels"][f"hist_multileaf_masked_K{K}_int8"]["ms"]
-    rec["gathered_vs_masked_pass_speedup"] = round(masked_ms / (tg * 1e3), 3)
-    print(f"hist_multileaf_gathered K={K} int8 cap={cap}: {tg*1e3:.1f} ms "
-          f"({masked_ms / (tg * 1e3):.2f}x vs masked full-stream)")
-
     t2 = timeit(lambda: select_bin_by_feature(bins, lid % F))
     rec["kernels"]["select_bin_by_feature"] = {"ms": round(t2 * 1e3, 2)}
     print(f"select_bin_by_feature: {t2*1e3:.1f} ms")

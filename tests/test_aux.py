@@ -58,10 +58,10 @@ def test_profiling_timers(tmp_path):
     profiling.reset()
 
 
-def test_native_loader_matches_numpy():
+def test_native_loader_matches_numpy(examples_dir):
     from lightgbm_tpu import native
     import lightgbm_tpu.dataset as dsm
-    path = "/root/reference/examples/lambdarank/rank.train"  # libsvm
+    path = f"{examples_dir}/lambdarank/rank.train"  # libsvm
     res = native.parse_text_native(path, False, 0)
     if res is None:
         pytest.skip("native library not built")

@@ -75,6 +75,48 @@ def test_multileaf_histogram_oracle():
                                        atol=1e-4)
 
 
+@pytest.mark.parametrize("live_frac,amp,int8_store", [
+    (1.0, None, False),          # all rows live
+    (0.6, None, False),          # rows a bag dropped: zero row mask
+    (1.0, 2.0, False),           # GOSS-amplified gradients
+    (0.8, 2.0, True),            # int8 value-128 store (the chip's layout)
+])
+def test_masked_histogram_equals_numpy_exactly(live_frac, amp, int8_store):
+    """All three channels of `hist_multileaf_masked` against `np.add.at`,
+    to the bit: gradients on an integer grid over 64, hessians over 128
+    and a power-of-two amplification make every float32 partial sum
+    exact in any order.  Dropped rows reach the kernel as rows whose
+    value rows are zero, an empty slot (-1) has to come back all zero,
+    and the odd row count leaves a ragged last chunk."""
+    rng = np.random.RandomState(11)
+    n, f, b, L, B = 4097, 9, 250, 12, 256
+    bins = rng.randint(0, b, size=(f, n)).astype(np.int32)
+    lid = rng.randint(0, L, size=n).astype(np.int32)
+    live = (rng.rand(n) < live_frac).astype(np.float32)
+    gh8 = np.zeros((8, n), np.float32)
+    gh8[0] = rng.randint(-512, 512, size=n) / 64.0
+    gh8[1] = rng.randint(0, 256, size=n) / 128.0
+    if amp is not None:
+        gh8[:2, rng.rand(n) < 0.5] *= amp
+    gh8[2] = live
+    gh8[:2] *= live
+    store = ((bins.astype(np.int16) - 128).astype(np.int8)
+             if int8_store else bins)
+    sl = np.array([3, 7, -1, 0], np.int32)
+    out = np.asarray(hist_multileaf_masked(
+        jnp.asarray(store), jnp.asarray(lid), jnp.asarray(gh8),
+        jnp.asarray(sl), num_bins_padded=B, backend="xla",
+        input_dtype="float32"))
+    want = np.zeros((len(sl), f, 3, B), np.float64)
+    for k, leaf in enumerate(sl):
+        rows = np.flatnonzero(lid == leaf)
+        for j in range(f):
+            for c in range(3):
+                np.add.at(want[k, j, c], bins[j, rows], gh8[c, rows])
+    np.testing.assert_array_equal(out, want.astype(np.float32))
+    assert out[2].max() == 0.0 and out[:, :, 2].sum() > 0
+
+
 def test_best_split_oracle():
     """Exhaustive scan oracle for one feature."""
     rng = np.random.RandomState(4)

@@ -35,9 +35,9 @@ def _one_hot_data(n=1500, groups=40, card=6, seed=0, noise=0.3):
 
 
 def _train(X, y, enable_bundle, tree_growth="exact", rounds=6, **extra):
-    params = dict(objective="binary", num_leaves=15, min_data_in_leaf=5,
-                  verbose=-1, enable_bundle=enable_bundle,
-                  tree_growth=tree_growth, **extra)
+    params = dict(dict(objective="binary", num_leaves=15, min_data_in_leaf=5,
+                       verbose=-1, enable_bundle=enable_bundle,
+                       tree_growth=tree_growth), **extra)
     ds = lgb.Dataset(X, y, params=params)
     bst = lgb.Booster(params, ds)
     for _ in range(rounds):
@@ -135,6 +135,28 @@ def test_zero_conflict_parity(growth):
     assert _structure(a) == _structure(b)
     pa, pb = a.predict(X), b.predict(X)
     np.testing.assert_allclose(pa, pb, atol=1e-5)
+
+
+def test_bundled_rounds_trees_are_the_exact_learners():
+    """The stream over a bundled store against learner/serial.py over
+    the same store: min_data_in_leaf keeps every tree under the cap of
+    31 leaves, where the rounds schedule grows the leaf-wise tree."""
+    X, y = _one_hot_data(n=1200, groups=20, card=6, seed=1)
+    kw = dict(num_leaves=31, min_data_in_leaf=60, rounds=4)
+    a, dsa = _train(X, y, True, "rounds", **kw)
+    b, _ = _train(X, y, True, "exact", **kw)
+    assert dsa._inner.bundle_plan is not None
+    assert dsa._inner.num_store_columns < X.shape[1]
+    assert type(a._gbdt.learner).__name__ == "RoundsTreeLearner"
+    assert type(b._gbdt.learner).__name__ == "SerialTreeLearner"
+    assert all(3 < t.num_leaves < 31 for t in a._gbdt.models)
+
+    def splits(bst):
+        return [sorted(zip(t.split_feature[: t.num_leaves - 1].tolist(),
+                           t.threshold[: t.num_leaves - 1].tolist()))
+                for t in bst._gbdt.models]
+    assert splits(a) == splits(b)
+    np.testing.assert_allclose(a.predict(X), b.predict(X), atol=1e-5)
 
 
 def test_mixed_dense_and_sparse_features_parity():

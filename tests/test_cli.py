@@ -1,6 +1,7 @@
-"""CLI application tests: the reference examples' config files run
-unmodified (reference test strategy: examples as integration tests,
-SURVEY.md §4)."""
+"""CLI application tests: config files and data in the layout of the
+reference's examples (tests/conftest.py `examples_dir`) run through the
+application entry point (reference test strategy: examples as
+integration tests, SURVEY.md §4)."""
 import os
 import numpy as np
 import pytest
@@ -8,10 +9,9 @@ import pytest
 from lightgbm_tpu.application import main, Predictor
 import lightgbm_tpu as lgb
 
-EX = "/root/reference/examples"
 
-
-def test_train_predict_cycle(tmp_path, binary_example):
+def test_train_predict_cycle(tmp_path, binary_example, examples_dir):
+    EX = examples_dir
     model = tmp_path / "model.txt"
     out = tmp_path / "preds.txt"
     rc = main([
@@ -45,14 +45,15 @@ def test_cli_error_paths(tmp_path):
 
 
 @pytest.mark.slow
-def test_cli_continue_training(tmp_path, regression_example):
+def test_cli_continue_training(tmp_path, regression_example, examples_dir):
     """Regression: input_model must actually load and replay the model
     (create_boosting used to only sniff the first line for the type)."""
     X, y, Xt, yt = regression_example
     m1 = tmp_path / "m1.txt"
     m2 = tmp_path / "m2.txt"
     base = [
-        f"data={EX}/regression/regression.train", "objective=regression",
+        f"data={examples_dir}/regression/regression.train",
+        "objective=regression",
         "verbosity=-1", "min_data_in_leaf=20",
     ]
     assert main(base + ["num_trees=5", f"output_model={m1}"]) == 0
@@ -66,7 +67,8 @@ def test_cli_continue_training(tmp_path, regression_example):
     assert mse2 < mse1
 
 
-def test_regression_example_conf(tmp_path):
+def test_regression_example_conf(tmp_path, examples_dir):
+    EX = examples_dir
     model = tmp_path / "model.txt"
     rc = main([
         f"config={EX}/regression/train.conf",

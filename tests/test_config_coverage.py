@@ -49,10 +49,10 @@ def test_consumption_ignores_comments_and_docstrings():
     mod = _load_checker()
     code = mod._code_only(
         'x = 1  # the future cfg.fused_tree override\n'
-        'y = getattr(cfg, "hist_rows", "auto")\n'
+        'y = getattr(cfg, "hist_exchange", "auto")\n'
         'def f():\n'
         '    """line one.\\nmentions mesh_shape in prose."""\n'
         '    return 1\n')
     assert "fused_tree" not in code     # comment stripped
     assert "mesh_shape" not in code     # escaped docstring stripped
-    assert "hist_rows" in code          # string literals still count
+    assert "hist_exchange" in code      # string literals still count

@@ -293,7 +293,8 @@ def test_int8_histogram_trains_end_to_end():
 
 
 @pytest.mark.slow
-def test_original_length_guards(binary_example, regression_example, tmp_path):
+def test_original_length_guards(binary_example, regression_example,
+                                examples_dir, tmp_path):
     """Original-length versions of the checks the default tier shortened
     for the <300s budget (cv@8x3, sklearn@20 estimators, CLI continue
     @8+8): full sensitivity lives here."""
@@ -318,7 +319,7 @@ def test_original_length_guards(binary_example, regression_example, tmp_path):
     from lightgbm_tpu.application import main
     m1 = str(tmp_path / "m1.txt")
     m2 = str(tmp_path / "m2.txt")
-    base = ["data=/root/reference/examples/regression/regression.train",
+    base = [f"data={examples_dir}/regression/regression.train",
             "objective=regression", "verbosity=-1", "min_data_in_leaf=20"]
     assert main(base + ["num_trees=8", f"output_model={m1}"]) == 0
     assert main(base + ["num_trees=8", f"input_model={m1}",
@@ -346,3 +347,16 @@ def test_int8_histogram_integration():
               verbose_eval=False)
     ll = ev["valid_0"]["binary_logloss"]
     assert ll[-1] < ll[0] - 0.1, ll
+
+
+def test_feature_importance_split_dtype_int32():
+    """Reference C API returns int importance for 'split' (dtype parity,
+    ADVICE.md round 5)."""
+    rng = np.random.RandomState(2)
+    X = rng.randn(400, 5)
+    y = (X[:, 0] > 0).astype(np.float64)
+    bst = lgb.train({"objective": "binary", "verbose": -1,
+                     "num_leaves": 7, "min_data_in_leaf": 10},
+                    lgb.Dataset(X, y), num_boost_round=3)
+    assert bst.feature_importance("split").dtype == np.int32
+    assert bst.feature_importance("gain").dtype == np.float64

@@ -1,5 +1,5 @@
 """Data-parallel histogram exchange (hist_exchange=psum|psum_scatter)
-and per-shard row compaction under shard_map — the comms layer of
+under shard_map — the comms layer of
 learner/rounds.py and learner/fused.py on the virtual 8-device CPU mesh
 (conftest.py).
 
@@ -135,54 +135,54 @@ def test_fused_trees_identical_psum_vs_psum_scatter():
             assert _splits(t) == _splits(t_ref), (lt, hx)
 
 
-def test_gathered_equals_masked_under_shard_map_with_bagging_goss():
-    """Acceptance (b): per-shard local row compaction — under the
-    8-device shard_map the gathered learner must grow the IDENTICAL
-    tree to masked (bitwise-equal histograms on dyadic gradients)
-    with bagged-out rows and GOSS-style amplified gradients, under
-    both exchanges, and the per-shard rows-touched reduction >= 2x."""
+def _one_device_and_sharded(X, y, g, h, params, bag=None):
+    """The same build on one device and, under both exchanges, on the
+    8-device data mesh: {"one": ..., "psum": ..., "psum_scatter": ...}
+    -> (tree, leaf ids)."""
+    bag_args = () if bag is None else (jnp.asarray(bag), len(bag))
+    out = {}
+    for key, mesh in (("one", None), ("psum", make_mesh("data")),
+                      ("psum_scatter", make_mesh("data"))):
+        cfg = config_from_params(dict(
+            params, **({} if mesh is None else {"hist_exchange": key})))
+        ds = RawDataset(X, y, config=cfg)
+        t, lid = RoundsTreeLearner(ds, cfg, mesh=mesh).train(g, h, *bag_args)
+        out[key] = (ds, t, np.asarray(lid))
+    return out
+
+
+def test_sharded_stream_grows_the_one_device_tree_under_bagging_goss():
+    """Rows a bag drops carry a zero row mask on whichever shard holds
+    them, and GOSS-style amplified gradients (a power of two, so every
+    sum stays exact) ride the same value rows: under the 8-device
+    shard_map, with both exchanges, the stream must grow the tree one
+    device grows, leaf id for leaf id."""
     X, y, g, h = _dyadic_problem()
     rng = np.random.RandomState(11)
     N = len(y)
-    # GOSS-style: amplify a random half by 2 (power of two = exact)
-    amp = rng.rand(N) < 0.5
-    g = jnp.asarray(np.where(amp, 2.0, 1.0).astype(np.float32)
-                    * np.asarray(g))
-    h = jnp.asarray(np.where(amp, 2.0, 1.0).astype(np.float32)
-                    * np.asarray(h))
+    amp = np.where(rng.rand(N) < 0.5, 2.0, 1.0).astype(np.float32)
+    g, h = jnp.asarray(amp * np.asarray(g)), jnp.asarray(amp * np.asarray(h))
     bag = np.sort(rng.choice(N, size=int(N * 0.6),
                              replace=False)).astype(np.int32)
-    mesh = make_mesh("data")
-    out = {}
-    for hr in ("masked", "gathered"):
-        for hx in ("psum", "psum_scatter"):
-            cfg = config_from_params({
-                "objective": "binary", "num_leaves": 31,
-                "min_data_in_leaf": 5, "verbose": -1,
-                "hist_rows": hr, "hist_exchange": hx})
-            ds = RawDataset(X, y, config=cfg)
-            lrn = RoundsTreeLearner(ds, cfg, mesh=mesh)
-            assert lrn.hist_rows == hr
-            profiling.reset()
-            t, lid = lrn.train(g, h, jnp.asarray(bag), len(bag))
-            out[(hr, hx)] = (
-                t, np.asarray(lid),
-                profiling.counter_value(profiling.HIST_ROWS_TOUCHED))
-    t0, l0, rows_m = out[("masked", "psum")]
+    out = _one_device_and_sharded(
+        X, y, g, h, {"objective": "binary", "num_leaves": 31,
+                     "min_data_in_leaf": 5, "verbose": -1}, bag)
+    _, t0, l0 = out["one"]
     assert t0.num_leaves > 1
-    for key, (t, lid, _) in out.items():
+    # only the bag's rows are counted
+    assert t0.leaf_count[: t0.num_leaves].sum() == len(bag)
+    for key, (_, t, lid) in out.items():
         assert _splits(t) == _splits(t0), key
         np.testing.assert_array_equal(lid, l0)
-    rows_g = out[("gathered", "psum")][2]
-    assert rows_g > 0
-    assert rows_m / rows_g >= 2.0, (rows_m, rows_g)
+        np.testing.assert_array_equal(t.leaf_count[: t.num_leaves],
+                                      t0.leaf_count[: t0.num_leaves])
 
 
-def test_gathered_equals_masked_under_shard_map_with_efb():
-    """Acceptance (b), EFB variant: a bundled store under shard_map —
-    gathered == masked and psum == psum_scatter, with the per-shard
-    unbundle (ops/split.unbundle_hist_local) reconstructing original-
-    feature histograms from each shard's column slice."""
+def test_sharded_stream_grows_the_one_device_tree_under_efb():
+    """A bundled store under shard_map: psum == psum_scatter == one
+    device, with the per-shard unbundle (ops/split.unbundle_hist_local)
+    reconstructing original-feature histograms from each shard's column
+    slice."""
     rng = np.random.RandomState(21)
     n, groups, card = 2000, 8, 4
     codes = rng.randint(0, card, size=(n, groups))
@@ -193,23 +193,15 @@ def test_gathered_equals_masked_under_shard_map_with_efb():
     y = (X @ w > 0).astype(np.float64)
     g = jnp.asarray(np.where(y > 0, -1.0, 1.0).astype(np.float32))
     h = jnp.asarray(np.full(n, 0.5, np.float32))
-    mesh = make_mesh("data")
-    out = {}
-    for hr in ("masked", "gathered"):
-        for hx in ("psum", "psum_scatter"):
-            cfg = config_from_params({
-                "objective": "binary", "num_leaves": 15,
-                "min_data_in_leaf": 10, "verbose": -1,
-                "enable_bundle": True, "hist_rows": hr,
-                "hist_exchange": hx})
-            ds = RawDataset(X, y, config=cfg)
-            assert ds.bundle_plan is not None
-            assert ds.bins.shape[0] < groups * card
-            t, _ = RoundsTreeLearner(ds, cfg, mesh=mesh).train(g, h)
-            out[(hr, hx)] = t
-    base = out[("masked", "psum")]
+    out = _one_device_and_sharded(
+        X, y, g, h, {"objective": "binary", "num_leaves": 15,
+                     "min_data_in_leaf": 10, "verbose": -1,
+                     "enable_bundle": True})
+    _, base, _ = out["one"]
     assert base.num_leaves > 1
-    for key, t in out.items():
+    for key, (ds, t, _) in out.items():
+        assert ds.bundle_plan is not None
+        assert ds.bins.shape[0] < groups * card
         assert _splits(t) == _splits(base), key
 
 

@@ -3,7 +3,7 @@
 under the configuration's own parameters — 255 leaves, `max_bin=255`,
 `min_data_in_leaf=1`, `min_sum_hessian_in_leaf=100`.
 
-What is new beside tests/test_gathered.py (1,200-4,000 rows by 6-10
+What is new beside tests/test_rounds.py (1,200-3,000 rows by 6-10
 columns, 9-31 leaves, one tree from hand-made gradients) is the cell's
 shape ratio and leaf count through `lgb.train`: hundreds of rows a column,
 a leaf table of 255 (four slot chunks of K = 84 a round, most of them
@@ -26,10 +26,9 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 ROWS, FEATURES, ITERS = 20_000, 28, 3
-FEEDS = ("gathered", "masked")
 
 # Leaf values, as tests/test_rounds.py holds them (rtol 1e-4, atol 1e-6):
-# the plain learner sums a leaf's gathered rows in float32 and the rounds
+# the plain learner sums a leaf's own rows in float32 and the rounds
 # learner sums all rows under a mask, gets the larger child by
 # subtraction, and from the second tree on both start from scores that
 # already differ by such roundings; leaf values are ratios of those sums.
@@ -51,19 +50,17 @@ def rows():
 @pytest.fixture(scope="module")
 def trained(rows):
     """`lgb.train` under the cell's parameters with the learner pinned:
-    (growth, feed, histogram dtype, iterations) -> the booster and how far
+    (growth, histogram dtype, iterations) -> the booster and how far
     the `tree/` counters moved over the run; one run per key."""
     import lightgbm_tpu as lgb
     from lightgbm_tpu import profiling
     X, y = rows
     runs = {}
 
-    def run(growth, feed, dtype, iters):
-        key = (growth, feed, dtype, iters)
+    def run(growth, dtype, iters):
+        key = (growth, dtype, iters)
         if key not in runs:
             params = cell_params(tree_growth=growth, histogram_dtype=dtype)
-            if feed is not None:
-                params["hist_rows"] = feed
             before = profiling.counters("tree/")
             bst = lgb.train(params, lgb.Dataset(X, y), num_boost_round=iters)
             bst._gbdt._flush_pending()      # the last tree is fetched lazily
@@ -108,43 +105,37 @@ def assert_same_trees(got, want):
                                    [pb[k][0] for k in keys], **LEAF_TOL)
 
 
-@pytest.mark.parametrize("feed", FEEDS)
-def test_float32_trees_are_the_plain_learners(trained, feed):
+def test_float32_trees_are_the_plain_learners(trained):
     """Three boosting iterations, tree for tree, leaf for leaf."""
     from lightgbm_tpu.learner.rounds import RoundsTreeLearner
     from lightgbm_tpu.learner.serial import SerialTreeLearner
-    plain, _ = trained("exact", None, "float32", ITERS)
-    bst, _ = trained("rounds", feed, "float32", ITERS)
+    plain, _ = trained("exact", "float32", ITERS)
+    bst, _ = trained("rounds", "float32", ITERS)
     assert isinstance(plain._gbdt.learner, SerialTreeLearner)
     assert isinstance(bst._gbdt.learner, RoundsTreeLearner)
-    assert bst._gbdt.learner.hist_rows == feed
     assert_same_trees(bst._gbdt.models, plain._gbdt.models)
 
 
-@pytest.mark.parametrize("feed", FEEDS)
-def test_int8_first_tree_is_the_plain_learners(trained, feed):
+def test_int8_first_tree_is_the_plain_learners(trained):
     """`histogram_dtype=int8` quantises the gradients of each pass by the
-    largest of the rows it is over: all rows under the masked feed, the
-    gathered rows under the gathered one.  At the first tree of the binary
-    objective every gradient is +-0.5 and every hessian 0.25, which any
-    such scale takes to +-127 exactly, so both feeds hand their kernels
-    what the plain learner gets and must grow its tree."""
-    plain, _ = trained("exact", None, "float32", ITERS)
-    bst, _ = trained("rounds", feed, "int8", 1)
-    assert bst._gbdt.learner.hist_rows == feed
+    largest of all rows.  At the first tree of the binary objective every
+    gradient is +-0.5 and every hessian 0.25, which that scale takes to
+    +-127 exactly, so the kernels get what the plain learner gets and the
+    build must grow its tree."""
+    plain, _ = trained("exact", "float32", ITERS)
+    bst, _ = trained("rounds", "int8", 1)
     assert_same_trees(bst._gbdt.models, plain._gbdt.models[:1])
 
 
-def test_int8_masked_later_trees_on_the_same_quantised_gradients(rows, trained):
+def test_int8_later_trees_on_the_same_quantised_gradients(rows, trained):
     """Past the first tree the gradients take many values.  The plain
-    learner can be given the masked feed's quantised values — one scale
-    over all rows, `ops/histogram._quantize_gh` — but not the gathered
-    feed's, which rescales every launch by its own rows.  Both learners
+    learner can be given the int8 path's quantised values — one scale
+    over all rows, `ops/histogram._quantize_gh`.  Both learners
     get the int8 levels themselves (whole numbers up to +-127, which the
     int8 path requantises to themselves at a scale of exactly 1, and
     whose float32 sums are exact in any order), with the hessian floor
     in the same units: on the gradients of the second and third
-    iteration the int8 masked build grows the plain learner's tree."""
+    iteration the int8 build grows the plain learner's tree."""
     import jax.numpy as jnp
     from lightgbm_tpu.config import config_from_params
     from lightgbm_tpu.dataset import Dataset as RawDataset
@@ -152,7 +143,7 @@ def test_int8_masked_later_trees_on_the_same_quantised_gradients(rows, trained):
     from lightgbm_tpu.learner.serial import SerialTreeLearner
     from lightgbm_tpu.ops.histogram import _quantize_gh
     X, y = rows
-    plain, _ = trained("exact", None, "float32", ITERS)
+    plain, _ = trained("exact", "float32", ITERS)
     floor = cell_params()["min_sum_hessian_in_leaf"]
     got, want = [], []
     for done in (1, 2):
@@ -166,7 +157,7 @@ def test_int8_masked_later_trees_on_the_same_quantised_gradients(rows, trained):
         assert len(np.unique(np.asarray(g))) > 30           # not two levels
         assert float(jnp.abs(g).max()) == float(h.max()) == 127.0
         cfg = config_from_params(cell_params(
-            tree_growth="rounds", hist_rows="masked",
+            tree_growth="rounds",
             min_sum_hessian_in_leaf=floor / float(sh)))
         assert cfg.histogram_dtype == "int8"
         ds = RawDataset(X, y, config=cfg)
@@ -175,27 +166,13 @@ def test_int8_masked_later_trees_on_the_same_quantised_gradients(rows, trained):
     assert_same_trees(got, want)
 
 
-@pytest.mark.parametrize("feed", FEEDS)
-def test_feed_and_partition_counters(trained, feed):
-    """`tree/feed_rows`, `tree/feed_live_rows`, `tree/partition_rows` over
-    the three iterations of the float32 run."""
-    from lightgbm_tpu.learner.common import (gather_capacity_tiers,
-                                             gather_scratch_capacity)
-    _, moved = trained("rounds", feed, "float32", ITERS)
+def test_pass_and_partition_counters(trained):
+    """`tree/hist_rows_touched` and `tree/partition_rows` over the three
+    iterations of the float32 run: every launch streams all rows, every
+    round rewrites the leaf id of every row, and no row is copied."""
+    _, moved = trained("rounds", "float32", ITERS)
     rounds, passes = moved["tree/rounds"], moved["tree/hist_passes"]
     assert rounds >= 6 * ITERS and passes >= rounds + ITERS
-    # every round rewrites the leaf id (and, gathered, the place in the
-    # permutation) of every row
     assert moved["tree/partition_rows"] == rounds * ROWS
-    fed, live = moved["tree/feed_rows"], moved["tree/feed_live_rows"]
-    if feed == "masked":
-        assert fed == 0 and live == 0
-        assert moved["tree/hist_rows_touched"] == passes * ROWS
-        return
-    # the root streams all rows; every other launch copies its tier
-    assert moved["tree/hist_rows_touched"] == ITERS * ROWS + fed
-    tiers = gather_capacity_tiers(gather_scratch_capacity(ROWS))
-    assert tiers[0] * (passes - ITERS) <= fed <= tiers[-1] * (passes - ITERS)
-    assert fed % 128 == 0
-    # the smaller children of a round hold at most half the rows
-    assert 0 < live <= fed and live <= rounds * ((ROWS + 1) // 2)
+    assert moved["tree/hist_rows_touched"] == passes * ROWS
+    assert moved["tree/feed_rows"] == 0 and moved["tree/feed_live_rows"] == 0

@@ -104,7 +104,7 @@ def test_tpu_without_memory_stats_is_an_error(monkeypatch):
 
     monkeypatch.setattr(jax, "local_devices", lambda: [FakeDev()])
     assert common.device_bytes_limit() is None            # CPU tier
-    assert common.gathered_scratch_fits(28, 10_500_000)   # 16e9 constant
+    assert common._default_pool_budget() == 1.5e9         # its floor
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(RuntimeError, match="bytes_limit"):
         common.device_bytes_limit()

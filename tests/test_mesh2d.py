@@ -84,16 +84,27 @@ def test_2d_mesh_trees_identical_to_1d(hx):
 
 
 @pytest.mark.skipif(NDEV < 8, reason="needs 8 virtual devices")
-def test_2d_mesh_gathered_rows_identical():
+def test_2d_mesh_grows_the_one_device_tree_under_bagging():
+    """Rows shard over both axes, so a bag's dropped rows (zero row
+    mask) fall on every device of the 4x2 mesh: the tree, its counts and
+    the leaf ids must be the one-device build's."""
     X, y, g, h = _problem(n=8192)
+    bag = np.sort(np.random.RandomState(4).choice(
+        len(y), size=int(len(y) * 0.6), replace=False)).astype(np.int32)
     cfg = config_from_params({"objective": "binary", "num_leaves": 31,
                               "min_data_in_leaf": 5, "verbose": -1,
-                              "hist_exchange": "psum_scatter",
-                              "hist_rows": "gathered"})
+                              "hist_exchange": "psum_scatter"})
     ds = RawDataset(X, y, config=cfg)
-    t_uns, _ = RoundsTreeLearner(ds, cfg, None).train(g, h)
-    t_2d, _ = RoundsTreeLearner(ds, cfg, mesh=_mesh2d(4, 2)).train(g, h)
+    t_uns, lid_uns = RoundsTreeLearner(ds, cfg, None).train(
+        g, h, jnp.asarray(bag), len(bag))
+    t_2d, lid_2d = RoundsTreeLearner(ds, cfg, mesh=_mesh2d(4, 2)).train(
+        g, h, jnp.asarray(bag), len(bag))
+    assert t_uns.num_leaves > 1
+    assert t_uns.leaf_count[: t_uns.num_leaves].sum() == len(bag)
     assert _splits(t_2d) == _splits(t_uns)
+    np.testing.assert_array_equal(t_2d.leaf_count[: t_2d.num_leaves],
+                                  t_uns.leaf_count[: t_uns.num_leaves])
+    np.testing.assert_array_equal(np.asarray(lid_2d), np.asarray(lid_uns))
 
 
 @pytest.mark.skipif(NDEV < 8, reason="needs 8 virtual devices")

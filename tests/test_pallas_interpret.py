@@ -14,8 +14,7 @@ import pytest
 
 import jax.numpy as jnp
 
-from lightgbm_tpu.ops.histogram import (hist_multileaf_gathered,
-                                        hist_pallas, hist_pallas_multileaf,
+from lightgbm_tpu.ops.histogram import (hist_pallas, hist_pallas_multileaf,
                                         hist_multileaf_masked,
                                         hist_multileaf_xla, hist_xla)
 
@@ -342,60 +341,6 @@ def test_hist_masked_int8_padded_rows_and_top_leaf():
     assert np.asarray(h_n)[1].max() == 0.0
 
 
-@pytest.mark.parametrize("input_dtype,int8_store", [
-    ("float32", False), ("int8", False), ("bfloat16", True),
-    ("int8", True)])
-def test_hist_multileaf_gathered_pallas(input_dtype, int8_store):
-    """Gathered-segment histograms through the PALLAS masked kernel
-    (interpret mode) vs the XLA gathered path: slot-id masks built in
-    VMEM over the compacted scratch, incl. the int8 value-128 bin store
-    and the quantized int8 one-hot path the rounds learner runs on
-    chip.  Also pins the gathered result against the masked kernel
-    over the full row stream (exact for counts in every dtype)."""
-    rng = np.random.RandomState(17)
-    n, f, b, L = 5003, 9, 250, 10               # odd n: scratch padding
-    B = 256
-    bins = rng.randint(0, b, size=(f, n)).astype(np.int32)
-    lid = rng.randint(0, L, size=n).astype(np.int32)
-    live = rng.rand(n) < 0.8                     # bagged-out rows
-    gh8 = np.zeros((8, n), np.float32)
-    gh8[0] = rng.randn(n)
-    gh8[1] = rng.rand(n)
-    gh8[2] = live.astype(np.float32)
-    gh8[0] *= gh8[2]
-    gh8[1] *= gh8[2]
-    live_idx = np.flatnonzero(live)
-    order = live_idx[np.argsort(lid[live_idx], kind="stable")]
-    perm = np.arange(n, dtype=np.int32)
-    perm[: len(order)] = order
-    perm[len(order):] = np.setdiff1d(np.arange(n), order)
-    cnt = np.bincount(lid[live_idx], minlength=L).astype(np.int32)
-    off = (np.cumsum(cnt) - cnt).astype(np.int32)
-    leaves = np.array([4, 9, 0], np.int32)
-    store = ((bins.astype(np.int16) - 128).astype(np.int8)
-             if int8_store else bins)
-    args = (jnp.asarray(gh8), jnp.asarray(perm),
-            jnp.asarray(off[leaves]), jnp.asarray(cnt[leaves]))
-    kw = dict(capacity=4096, num_bins_padded=B, input_dtype=input_dtype)
-    h_pl = hist_multileaf_gathered(jnp.asarray(store), *args,
-                                   backend="pallas", interpret=True, **kw)
-    h_x = hist_multileaf_gathered(jnp.asarray(store), *args,
-                                  backend="xla", **kw)
-    tol = 2e-2 if input_dtype == "bfloat16" else 1e-4
-    np.testing.assert_allclose(np.asarray(h_pl), np.asarray(h_x),
-                               rtol=0, atol=tol)
-    h_m = hist_multileaf_masked(
-        jnp.asarray(bins), jnp.asarray(lid), jnp.asarray(gh8),
-        jnp.asarray(leaves), num_bins_padded=B, backend="xla",
-        input_dtype=input_dtype)
-    # counts are exact in every dtype and summation order
-    np.testing.assert_array_equal(np.asarray(h_pl)[:, :, 2],
-                                  np.asarray(h_m)[:, :, 2])
-    if input_dtype == "float32":
-        np.testing.assert_allclose(np.asarray(h_pl), np.asarray(h_m),
-                                   rtol=0, atol=1e-4)
-
-
 def test_hist_pallas_bf16_onehot():
     """Gather-fed kernels with bf16 operands (_simple_onehot): must
     match the XLA bf16 formulation."""
@@ -419,3 +364,29 @@ def test_hist_pallas_bf16_onehot():
                                num_bins_padded=256, input_dtype="bfloat16")
     np.testing.assert_allclose(np.asarray(h_ml), np.asarray(h_mlx),
                                rtol=2e-2, atol=2e-2)
+
+
+def test_gather_chunk_cap_respects_vmem_budget():
+    """ADVICE round 5: the 512-row floor let padded B >= 2048 exceed the
+    stated 4 MB budget; the floor is now one 128-lane tile."""
+    from lightgbm_tpu.ops.histogram import _gather_chunk_cap
+    for B in (128, 256, 1024, 2048, 4096):
+        ck = _gather_chunk_cap(B, 4)
+        assert ck % 128 == 0 and ck >= 128
+        if ck > 128:          # above the floor the budget must hold
+            assert ck * B * 4 <= int(4e6)
+
+
+@pytest.mark.parametrize("bins_itemsize,dtype,want", [
+    (4, "int8", 8192), (4, "bfloat16", 8192), (4, "float32", 2048),
+    (1, "int8", 2048), (1, "float32", 1024)])
+def test_masked_chunk_is_the_compile_validated_table(bins_itemsize, dtype,
+                                                     want):
+    """The masked kernels' row chunk comes from the table that
+    tests/test_tpu_compile.py validates against the TPU compiler, and
+    shrinks in proportion for value-row blocks taller than K=84's."""
+    from lightgbm_tpu.ops.histogram import _masked_chunk
+    for Mp in (8, 24, 96, 256):
+        assert _masked_chunk(Mp, bins_itemsize, dtype) == want
+    tall = _masked_chunk(512, bins_itemsize, dtype)
+    assert tall == want // 2 and tall % 128 == 0

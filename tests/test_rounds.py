@@ -45,6 +45,50 @@ def test_rounds_equals_exact_when_cap_loose(problem):
     np.testing.assert_array_equal(counts, tr.leaf_count[: tr.num_leaves])
 
 
+@pytest.mark.parametrize("cache", [True, False], ids=["cache", "no_cache"])
+def test_bagged_multichunk_tree_is_the_serial_learners(monkeypatch, cache):
+    """The build against learner/serial.py where the schedule is least
+    like it: LEAVES_PER_BATCH=5 cuts the 13-slot leaf table into three
+    chunks with a short last one, a bag drops 40 % of the rows (they
+    stream through every pass under a zero row mask), and without the
+    parent-histogram cache both children of a split are histogrammed
+    directly where the serial learner subtracts.  min_data_in_leaf keeps
+    the tree under 13 leaves, so the cap never binds and the two
+    schedules must grow one tree; +-1 gradients and constant hessians
+    make every histogram sum exact."""
+    from lightgbm_tpu.learner import rounds as rounds_mod
+    monkeypatch.setattr(rounds_mod, "LEAVES_PER_BATCH", 5)
+    rng = np.random.RandomState(3)
+    N = 3000
+    X = rng.randn(N, 10)
+    y = (X[:, 0] + 0.6 * X[:, 1] * X[:, 2] > 0).astype(np.float64)
+    g = jnp.asarray(np.where(y > 0, -1.0, 1.0).astype(np.float32))
+    h = jnp.asarray(np.full(N, 0.5, np.float32))
+    bag = jnp.asarray(np.sort(rng.choice(
+        N, size=int(N * 0.6), replace=False)).astype(np.int32))
+    cfg = config_from_params({
+        "objective": "binary", "num_leaves": 13, "min_data_in_leaf": 150,
+        "verbose": -1, **({} if cache else {"histogram_pool_size": 0.001})})
+    ds = RawDataset(X, y, config=cfg)
+    lrn = RoundsTreeLearner(ds, cfg, None)
+    assert lrn.cache_parent_hist == cache
+    tr, lid = lrn.train(g, h, bag, len(bag))
+    ts, lid_s = SerialTreeLearner(ds, cfg).train(g, h, bag, len(bag))
+    assert 5 < tr.num_leaves == ts.num_leaves < 13
+    assert _splits(tr) == _splits(ts)
+    np.testing.assert_array_equal(np.sort(tr.leaf_count[: tr.num_leaves]),
+                                  np.sort(ts.leaf_count[: ts.num_leaves]))
+    assert tr.leaf_count[: tr.num_leaves].sum() == len(bag)
+    np.testing.assert_allclose(
+        np.sort(tr.leaf_value[: tr.num_leaves]),
+        np.sort(ts.leaf_value[: ts.num_leaves]), rtol=1e-6)
+    # the bag's rows sit in the leaves the serial learner put them in
+    inbag = np.asarray(bag)
+    by_rounds = np.asarray(tr.leaf_value)[np.asarray(lid)[inbag]]
+    by_serial = np.asarray(ts.leaf_value)[np.asarray(lid_s)[inbag]]
+    np.testing.assert_allclose(by_rounds, by_serial, rtol=1e-6)
+
+
 def test_rounds_sharded_matches_unsharded(problem):
     ds, cfg, g, h = problem
     tr, _ = RoundsTreeLearner(ds, cfg, None).train(g, h)

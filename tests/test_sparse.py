@@ -199,16 +199,34 @@ def test_sparse_trees_bitwise_identical_dyadic_efb():
         trees["csr"].leaf_value[: trees["csr"].num_leaves])
 
 
-def test_sparse_gathered_composes_with_masked():
+def test_sparse_stream_grows_the_dense_tree_under_bagging_goss():
+    """Rows a bag drops carry a zero row mask, which has to clear both
+    their stored entries and their share of the per-leaf totals the zero
+    bin is rebuilt from; GOSS-style amplified gradients (a power of two,
+    sums stay exact) ride the same value rows.  The nonzero-iterating
+    stream must grow the dense stream's tree, leaf id for leaf id."""
     X, y = _sparse_X()
     g, h = _dyadic_gh(y)
+    rng = np.random.RandomState(11)
+    amp = np.where(rng.rand(len(y)) < 0.5, 2.0, 1.0).astype(np.float32)
+    g, h = jnp.asarray(amp * np.asarray(g)), jnp.asarray(amp * np.asarray(h))
+    bag = np.sort(rng.choice(len(y), size=int(len(y) * 0.6),
+                             replace=False)).astype(np.int32)
     trees = {}
-    for hr in ("masked", "gathered"):
-        cfg = _cfg(sparse_store="csr", hist_rows=hr)
+    for store in ("dense", "csr"):
+        cfg = _cfg(sparse_store=store)
         ds = RawDataset(X, y, config=cfg)
-        t, _ = RoundsTreeLearner(ds, cfg).train(g, h)
-        trees[hr] = t
-    assert _splits(trees["masked"]) == _splits(trees["gathered"])
+        lrn = RoundsTreeLearner(ds, cfg)
+        assert lrn.sparse == (store == "csr")
+        t, lid = lrn.train(g, h, jnp.asarray(bag), len(bag))
+        trees[store] = (t, np.asarray(lid))
+    td, ts = trees["dense"][0], trees["csr"][0]
+    assert td.num_leaves == ts.num_leaves > 1
+    assert td.leaf_count[: td.num_leaves].sum() == len(bag)
+    assert _splits(td) == _splits(ts)
+    np.testing.assert_array_equal(
+        td.leaf_value[: td.num_leaves], ts.leaf_value[: ts.num_leaves])
+    np.testing.assert_array_equal(trees["dense"][1], trees["csr"][1])
 
 
 @pytest.mark.parametrize("objective", ["binary", "lambdarank"])

@@ -96,27 +96,12 @@ def test_masked_histogram_other_layouts(shape, dtype, F, bins_dtype, K, bins,
                                         max_num_bin):
     """bf16 operands and int8-stored bins (G=32 feature blocks, 128-lane
     bin windows) at the full K=84 pass; and every launch of the two
-    Epsilon cells: the root (K=1) and the masked feed's slot tiers
+    Epsilon cells: the root (K=1) and the stream's slot tiers
     (K = 8, 32, 84) over a 2000-wide store, at 256 bins and at
     max_bin=63 (two 64-bin columns packed into one 128-lane block)."""
     n = N if F < 100 else N // 8
     compile_for_chip(masked(dtype, bins, max_num_bin),
                      *masked_args(shape, F, bins_dtype, K, n))
-
-
-@pytest.mark.parametrize("dtype", ["float32", "int8"])
-def test_gathered_histogram(shape, dtype):
-    """hist_multileaf_gathered — the row feed of `hist_rows=gathered` —
-    at the ceil(N/2) smaller-child capacity."""
-    from lightgbm_tpu.ops.histogram import hist_multileaf_gathered
-
-    def f(bins, gh, perm, off, cnt):
-        return hist_multileaf_gathered(
-            bins, gh, perm, off, cnt, capacity=N // 2, num_bins_padded=B,
-            backend="pallas", input_dtype=dtype, max_num_bin=255)
-    compile_for_chip(f, shape((28, N), jnp.int32), shape((8, N), jnp.float32),
-                     shape((N,), jnp.int32), shape((84,), jnp.int32),
-                     shape((84,), jnp.int32))
 
 
 @pytest.mark.parametrize("F,bins_dtype", [(28, jnp.int32), (32, jnp.int8),
@@ -133,38 +118,30 @@ def test_fused_partition(shape, F, bins_dtype):
                      shape((7, 256), jnp.float32))
 
 
-@pytest.mark.parametrize("hist_rows", ["auto", "gathered"])
-def test_higgs_build_program(shape, hist_rows):
+def test_higgs_build_program(shape):
     """The whole build step of the benchmark cell `higgs.full`, as
     RoundsTreeLearner jits it on the chip: 10.5M rows by the 28 columns
     of the int32 store (which the store leaves unpadded; the kernels pad
     them to 32), 255 leaves, int8 operands, the per-leaf histogram
-    cache, the Pallas partition, and the row feed that `hist_rows`
-    resolves — under `auto`, the cell's, the stream: every launch over
-    all the rows at the slot tier (8 / 32 / 84) that holds the round's
-    leaves, no permutation and no scratch; under `gathered`, which no
-    cell runs but a user may ask for, the three capacity tiers of
-    ceil(N/2) = 5,250,048 rows.  One program of four slot chunks a
-    round, whose arguments (1.5 GB) and temporaries (2.4 GB) are what
-    the cell holds."""
+    cache, the Pallas partition, and every launch over all the rows at
+    the slot tier (8 / 32 / 84) that holds the round's leaves.  One
+    program of four slot chunks a round, whose arguments (1.5 GB) and
+    temporaries (2.4 GB) are what the cell holds."""
     import functools
     from lightgbm_tpu.config import config_from_params
-    from lightgbm_tpu.learner.common import make_split_kw, resolve_hist_rows
+    from lightgbm_tpu.learner.common import make_split_kw
     from lightgbm_tpu.learner.rounds import build_tree_rounds
     n, F = 10_500_000, 28
     cfg = config_from_params({"objective": "binary", "num_leaves": 255,
                               "min_data_in_leaf": 1,
                               "min_sum_hessian_in_leaf": 100.0,
-                              "histogram_dtype": "int8",
-                              "hist_rows": hist_rows, "verbose": -1})
-    feed = resolve_hist_rows(cfg, num_columns=F, np_rows=n, bins_itemsize=4)
-    assert feed == ("masked" if hist_rows == "auto" else "gathered")
+                              "histogram_dtype": "int8", "verbose": -1})
     build = functools.partial(
         build_tree_rounds, num_leaves=255, num_bins_padded=B,
         max_num_bin=255, split_kw=make_split_kw(cfg), max_depth=-1,
         min_data_in_leaf=cfg.min_data_in_leaf,
         min_sum_hessian_in_leaf=cfg.min_sum_hessian_in_leaf,
-        backend="pallas", input_dtype=cfg.histogram_dtype, hist_rows=feed,
+        backend="pallas", input_dtype=cfg.histogram_dtype,
         cache_parent_hist=True)
     compiled = compile_for_chip(
         build, shape((F, n), jnp.int32), shape((n,), jnp.float32),

@@ -30,11 +30,10 @@ def _problem(n=1200, f=8, seed=7):
     return X, y
 
 
-def _compiled_build(mesh, hist_rows):
+def _compiled_build(mesh):
     X, y = _problem()
     cfg = config_from_params({"objective": "binary", "num_leaves": 31,
-                              "min_data_in_leaf": 25, "verbose": -1,
-                              "hist_rows": hist_rows})
+                              "min_data_in_leaf": 25, "verbose": -1})
     lr = rounds.RoundsTreeLearner(RawDataset(X, y, config=cfg), cfg, mesh)
     g = jnp.zeros(len(y), jnp.float32)
     mask, fmask = lr._masks(None)
@@ -86,9 +85,8 @@ def _while_body_instructions(text):
     return max((reach(b) for b in bodies), key=len)
 
 
-@pytest.mark.parametrize("hist_rows", ["masked", "gathered"])
-def test_build_step_is_named_and_scoped(hist_rows):
-    text = _compiled_build(None, hist_rows)
+def test_build_step_is_named_and_scoped():
+    text = _compiled_build(None)
     assert text.startswith("HloModule jit_build_tree_rounds,")
     total = scoped = 0
     for line in _while_body_instructions(text):
@@ -107,7 +105,7 @@ def test_build_step_is_named_and_scoped(hist_rows):
 def test_every_scope_is_in_the_sharded_build():
     """On a mesh the exchange and the closing reduction of the counters
     are operations too, so every scope of the list has something to name."""
-    text = _compiled_build(make_mesh("data"), "gathered")
+    text = _compiled_build(make_mesh("data"))
     assert text.startswith("HloModule jit_build_tree_rounds")
     named = {scope for op_name in re.findall(r'op_name="([^"]*)"', text)
              for scope in re.findall(r"lgbt\.(\w+)", op_name)}
@@ -126,77 +124,56 @@ def test_gradients_program_is_named_after_the_objective():
 
 # ---- the work counters against a hand count ---------------------------------
 
-def _hand_count(trees, n_rows, feed):
+def _hand_count(trees, n_rows):
     """rounds, launches, slots, live slots and operations of growing
     `trees` with num_leaves=7 on the XLA backend: one chunk of Kc=7
     slots (7 <= 8, the narrowest tier, so no tier is skipped), a launch
     a round after the root's, each for the round's splitting leaves."""
     F, B, Kc = 5, 256, 7
     rounds_, passes, slots, live, ops, rows = 0, 0, 0, 0, 0.0, 0.0
-    fed, fed_live = 0, 0
-    caps = rounds.gather_capacity_tiers(
-        rounds.gather_scratch_capacity(n_rows))
     for t in trees:
         # root: every row, one slot
         passes, slots, live = passes + 1, slots + 1, live + 1
         ops += 2.0 * n_rows * 3 * 1 * F * B
         rows += n_rows
-        for split_leaves, small_rows in t:
+        for split_leaves in t:
             rounds_ += 1
             passes, slots, live = passes + 1, slots + Kc, live + split_leaves
-            if feed == "masked":
-                c = n_rows
-            else:       # the smallest capacity tier that holds the rows
-                c = next(cap for cap in caps if small_rows <= cap)
-                # what the launch copies, and what of it is a leaf's
-                fed, fed_live = fed + c, fed_live + small_rows
-            ops += 2.0 * c * 3 * Kc * F * B
-            rows += c
+            ops += 2.0 * n_rows * 3 * Kc * F * B
+            rows += n_rows
     return {"tree/rounds": rounds_, "tree/hist_passes": passes,
             "tree/hist_slots": slots, "tree/hist_live_slots": live,
             "tree/hist_mxu_ops": ops, "tree/hist_rows_touched": rows,
-            "tree/feed_rows": fed, "tree/feed_live_rows": fed_live,
             # every round rewrites every row's leaf id
             "tree/partition_rows": rounds_ * n_rows}
 
 
 def _rounds_of(tree):
-    """[(leaves split, rows of their smaller children)] per round of a
-    grown tree, from its node depths and counts: the rounds learner
-    splits, in round r, exactly the internal nodes of depth r."""
+    """Leaves split per round of a grown tree, from its node depths:
+    the rounds learner splits, in round r, exactly the internal nodes
+    of depth r."""
     k = tree.num_leaves - 1
     depth = np.zeros(k, int)
     for node in range(k):
         for child in (tree.left_child[node], tree.right_child[node]):
             if child >= 0:
                 depth[child] = depth[node] + 1
-
-    def count(c):
-        return tree.internal_count[c] if c >= 0 else tree.leaf_count[~c]
-    out = []
-    for r in range(depth.max() + 1):
-        nodes = [n for n in range(k) if depth[n] == r]
-        small = sum(min(count(tree.left_child[n]), count(tree.right_child[n]))
-                    for n in nodes)
-        out.append((len(nodes), int(small)))
-    return out
+    return [int((depth == r).sum()) for r in range(depth.max() + 1)]
 
 
-@pytest.mark.parametrize("feed", ["masked", "gathered"])
-def test_work_counters_match_a_hand_count(feed):
+def test_work_counters_match_a_hand_count():
     X, y = _problem(600, 5, seed=0)
     profiling.reset()
     bst = lgb.Booster({"objective": "binary", "verbose": -1, "num_leaves": 7,
-                       "min_data_in_leaf": 5, "tree_growth": "rounds",
-                       "hist_rows": feed}, lgb.Dataset(X, y))
+                       "min_data_in_leaf": 5, "tree_growth": "rounds"},
+                      lgb.Dataset(X, y))
     for _ in range(2):
         bst.update()
-    assert bst._gbdt.learner.hist_rows == feed
     got = profiling.counters("tree/")
     bst._gbdt._flush_pending()
     trees = [_rounds_of(t) for t in bst._gbdt.models]
-    assert all(sum(n for n, _ in t) == 6 for t in trees)     # 7 leaves
-    want = _hand_count(trees, 600, feed)
+    assert all(sum(t) == 6 for t in trees)                   # 7 leaves
+    want = _hand_count(trees, 600)
     assert {k: got[k] for k in want} == want
     assert got["tree/hist_live_slots"] == 2 * 7
     profiling.reset()
@@ -318,14 +295,14 @@ def test_a_served_predict_times_its_phase_and_opens_no_span(monkeypatch):
 # ---- the vector-wise deferred counter ---------------------------------------
 
 def test_count_deferred_vector_drains_like_the_per_name_path(monkeypatch):
-    names = ("tree/a", "tree/b", "tree/c")
+    names = ("vec/a", "vec/b", "vec/c")
     vecs = [jnp.asarray([1.0, 10.0, 100.0]), jnp.asarray([2.0, 20.0, 200.0]),
             jnp.asarray([3.0, 30.0, 300.0])]
     profiling.reset()
     for v in vecs:                       # the path it replaces
         for i, n in enumerate(names):
             profiling.count_deferred((n,), v[i:i + 1])
-    per_name = profiling.counters("tree/")
+    per_name = profiling.counters("vec/")
 
     profiling.reset()
     fetches = []
@@ -335,20 +312,20 @@ def test_count_deferred_vector_drains_like_the_per_name_path(monkeypatch):
     for v in vecs:
         profiling.count_deferred(names, v)
     assert fetches == []                              # no sync on the way
-    assert profiling.counters_nosync("tree/") == {}   # nor on this read
+    assert profiling.counters_nosync("vec/") == {}    # nor on this read
     assert len(profiling._deferred) == 1              # one live buffer
     assert isinstance(profiling._deferred[names], jax.Array)
-    assert profiling.counters("tree/") == per_name == {
-        "tree/a": 6.0, "tree/b": 60.0, "tree/c": 600.0}
+    assert profiling.counters("vec/") == per_name == {
+        "vec/a": 6.0, "vec/b": 60.0, "vec/c": 600.0}
     assert fetches == [1]                             # one fetch, at the drain
-    assert profiling.counter_value("tree/b") == 60.0
+    assert profiling.counter_value("vec/b") == 60.0
     assert fetches == [1]                             # nothing left pending
     profiling.reset()
 
 
 def test_the_learner_feeds_every_stats_counter_as_one_vector():
     assert set(rounds.STATS_COUNTERS) <= set(profiling.CANONICAL_COUNTERS)
-    assert len(rounds.STATS_COUNTERS) == 12
+    assert len(rounds.STATS_COUNTERS) == 10
     X, y = _problem(300, 4)
     profiling.reset()
     bst = lgb.Booster({"objective": "binary", "verbose": -1, "num_leaves": 4,
@@ -356,6 +333,11 @@ def test_the_learner_feeds_every_stats_counter_as_one_vector():
                       lgb.Dataset(X, y))
     bst.update()
     assert list(profiling._deferred) == [rounds.STATS_COUNTERS]
-    assert profiling._deferred[rounds.STATS_COUNTERS].shape == (12,)
-    assert set(profiling.counters("tree/")) >= set(rounds.STATS_COUNTERS)
+    assert profiling._deferred[rounds.STATS_COUNTERS].shape == (10,)
+    got = profiling.counters("tree/")
+    assert set(got) >= set(rounds.STATS_COUNTERS)
+    # the benchmark's feed_rows_per_iter reads this name; no launch
+    # copies a row, and the vector has no slot for it
+    assert got[profiling.FEED_ROWS] == 0
+    assert profiling.FEED_ROWS not in rounds.STATS_COUNTERS
     profiling.reset()
