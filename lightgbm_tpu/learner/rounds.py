@@ -60,8 +60,11 @@ from .fused import TreeArrays, tree_arrays_to_host
 from .. import profiling
 from ..jaxutil import bag_mask_dev, pad_rows_dev, slice_rows_dev
 from ..ops.histogram import (hist_multileaf_masked, hist_sparse_multileaf,
-                             masked_hist_mxu_ops, sparse_window_streams)
-from ..ops.partition import partition_rows, partition_rows_sparse
+                             masked_hist_mxu_ops, masked_store_copy_rows,
+                             quantize_gh, sparse_window_streams,
+                             store_alignment)
+from ..ops.partition import (partition_rows, partition_rows_sparse,
+                             partition_store_copy_rows)
 from ..ops.split import (best_split, bundle_predicate_params,
                          combine_sharded_records, identity_feat_table,
                          leaf_output, maybe_unbundle, sharded_slice_search)
@@ -76,9 +79,9 @@ STATS_COUNTERS = (
     profiling.SPLIT_RECORDS_BYTES, profiling.SPARSE_NNZ_TOUCHED,
     profiling.TREE_ROUNDS, profiling.HIST_PASSES, profiling.HIST_SLOTS,
     profiling.HIST_LIVE_SLOTS, profiling.HIST_MXU_OPS,
-    profiling.PARTITION_ROWS)
+    profiling.PARTITION_ROWS, profiling.STORE_COPY_ROWS)
 (S_ROWS, S_EXCHANGE, S_RECORDS, S_NNZ, S_ROUNDS, S_PASSES, S_SLOTS, S_LIVE,
- S_OPS, S_PARTITION) = range(len(STATS_COUNTERS))
+ S_OPS, S_PARTITION, S_COPY) = range(len(STATS_COUNTERS))
 
 # Every phase of build_tree_rounds runs under a jax.named_scope
 # "lgbt.<phase>", so that an operation in a profiler trace says which
@@ -162,7 +165,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                       leaves_per_batch: int = 0,
                       sparse: bool = False):
     """Grow one tree in batched rounds.  Shapes as learner/fused.build_tree.
-    Returns (TreeArrays, leaf_id, stats) — stats is a [10] f32 vector in
+    Returns (TreeArrays, leaf_id, stats) — stats is a [11] f32 vector in
     the order of STATS_COUNTERS: rows processed by histogram kernels
     (global across shards); per-device histogram-exchange payload
     bytes; per-device best-split-record allgather bytes; stored sparse
@@ -171,15 +174,20 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     those launches were made for (each launch's K) and the slots among
     them that held a leaf; the operations their contractions perform
     (ops/histogram.masked_hist_mxu_ops per dense launch, global across
-    shards; the sparse kernels add 0); and the rows whose leaf id the
-    rounds rewrite (all Nloc in every round, global across shards).
+    shards; the sparse kernels add 0); the rows whose leaf id the
+    rounds rewrite (all Nloc in every round, global across shards); and
+    the rows of the store that the partitions and the launches copy
+    into a padded form (0 for a store laid out to the kernels' tiles,
+    as RoundsTreeLearner lays it out; rounds x Nloc + launches x Nloc
+    where neither wrapper can tile `bins` as it stands).
     Every one is a scalar add where the launch or the round is made,
     on values the build already has.
 
     Every pass streams all Nloc rows of the store, at the slot tier
     (8 / 32 / K) that holds the round's leaves; bagged or GOSS-dropped
-    rows carry a zero row_mask.  With int8 operands a pass quantises by
-    the largest gradient of all rows.
+    rows, and the rows a learner pads the store with, carry a zero
+    row_mask.  With int8 operands the gradients are quantised once a
+    tree, by the largest of all rows, and every pass reads that.
 
     hist_exchange="psum_scatter" (static; with data_axis set and
     num_devices the data-axis size) replaces the full [K, F, 3, B]
@@ -330,20 +338,28 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         with jax.named_scope("lgbt.feed"):
             binsf = bins.astype(jnp.int32)
 
-    def mxu_ops(rows: int, k: int) -> float:
-        """S_OPS of one dense launch over `rows` rows for k slots."""
-        if sparse:
-            return 0.0
-        return masked_hist_mxu_ops(
-            F, rows, k, bins_itemsize=binsf.dtype.itemsize,
-            num_bins_padded=B, backend=backend, input_dtype=input_dtype,
-            max_num_bin=max_num_bin)
+    if not sparse:
+        # what the counters of a dense launch are computed from
+        # (ops/histogram: the launch's layout at these static shapes)
+        lay_kw = dict(bins_itemsize=binsf.dtype.itemsize, num_bins_padded=B,
+                      backend=backend, input_dtype=input_dtype,
+                      max_num_bin=max_num_bin)
+    # rows of the store one round's partition copies before its kernel
+    partition_copy = 0 if sparse else partition_store_copy_rows(
+        F, Nloc, bins_itemsize=binsf.dtype.itemsize, num_slots=L + 1,
+        backend=backend, num_bins_padded=B)
 
-    def launch_stats(rows, nnz, slots, live, ops):
-        """The stats vector of one histogram kernel launch."""
+    def launch_stats(k: int, live):
+        """The stats vector of one histogram kernel launch over all
+        Nloc rows for k slots, `live` of which hold a leaf."""
         v = [0.0] * len(STATS_COUNTERS)
-        v[S_ROWS], v[S_NNZ], v[S_PASSES] = rows, nnz, 1.0
-        v[S_SLOTS], v[S_LIVE], v[S_OPS] = slots, live, ops
+        v[S_ROWS], v[S_PASSES] = Nloc, 1.0
+        v[S_SLOTS], v[S_LIVE] = k, live
+        if sparse:
+            v[S_NNZ] = nnz_pass
+        else:
+            v[S_OPS] = masked_hist_mxu_ops(F, Nloc, k, **lay_kw)
+            v[S_COPY] = masked_store_copy_rows(F, Nloc, k, **lay_kw)
         return jnp.stack([jnp.float32(x) for x in v])
 
     def hist_masked(lid_, sl_):
@@ -357,7 +373,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 input_dtype=input_dtype)
         return hist_multileaf_masked(
             binsf, lid_, gh8, sl_, num_bins_padded=B, backend=backend,
-            input_dtype=input_dtype, max_num_bin=max_num_bin)
+            input_dtype=input_dtype, max_num_bin=max_num_bin, ghq=ghq)
 
     def find_best_batch(hists, sums):
         """hists [K2, C, 3, B] reduced STORE histograms (C = F, or this
@@ -407,6 +423,9 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         gh8 = jnp.zeros((8, Nloc), jnp.float32)
         gh8 = gh8.at[0].set(grad * row_mask).at[1].set(hess * row_mask)
         gh8 = gh8.at[2].set(row_mask)
+        # every launch of the tree is over these rows: quantise once
+        ghq = (quantize_gh(gh8) if input_dtype == "int8" and not sparse
+               else None)
     with jax.named_scope("lgbt.root"):
         lid0 = jnp.zeros(Nloc, jnp.int32)
         h0 = hist_masked(lid0, jnp.zeros(1, jnp.int32))
@@ -430,8 +449,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         leaf_id = jnp.zeros(Nloc, jnp.int32)
         # the root contributes one full-stream launch for one slot + one
         # exchange
-        stats = (launch_stats(Nloc, nnz_pass if sparse else 0, 1, 1,
-                              mxu_ops(Nloc, 1))
+        stats = (launch_stats(1, 1)
                  .at[S_EXCHANGE].set(_exchange_bytes(1))
                  .at[S_RECORDS].set(_records_bytes(1)))
         leaf_best = jnp.full((L, 11), NEG_INF, jnp.float32).at[0].set(
@@ -595,8 +613,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                     h = jnp.concatenate(
                         [h, jnp.zeros((Kc - Kt,) + h.shape[1:], h.dtype)],
                         axis=0)
-                return h, launch_stats(Nloc, nnz_pass if sparse else 0, Kt,
-                                       live, mxu_ops(Nloc, Kt))
+                return h, launch_stats(Kt, live)
 
             def full_or_mid(_):
                 if Kc <= K_MID:
@@ -619,8 +636,9 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         leaf_hist2 = leaf_hist
         with jax.named_scope("lgbt.select"):
             rnd2 = rnd + 1
-            stats2 = stats.at[S_ROUNDS].add(1.0).at[S_PARTITION].add(
-                float(Nloc))
+            stats2 = (stats.at[S_ROUNDS].add(1.0)
+                      .at[S_PARTITION].add(float(Nloc))
+                      .at[S_COPY].add(float(partition_copy)))
         for c in range(n_chunks):
             s = c * K
             Kc = min(K, L - s)                               # last chunk short
@@ -693,15 +711,21 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     st = (jnp.int32(0), leaf_id, leaf_best, leaf_depth, leaf_parent,
           leaf_side, leaf_hist, stats, arrs)
     st = jax.lax.while_loop(round_cond, round_body, st)
-    # rows (histogrammed, partitioned), sparse entries and contraction
-    # operations are summed across shards (global traffic);
+    # rows (histogrammed, partitioned, copied), sparse entries and
+    # contraction operations are summed across shards (global traffic);
     # the byte counters and the round, launch and slot counts stay
     # per-device (passes are uniform, so every shard agrees)
     with jax.named_scope("lgbt.pack"):
         stv = st[-2]
-        glob = jnp.asarray([S_ROWS, S_NNZ, S_OPS, S_PARTITION])
+        glob = jnp.asarray([S_ROWS, S_NNZ, S_OPS, S_PARTITION, S_COPY])
         stv = stv.at[glob].set(_psum(stv[glob], row_axes))
     return st[-1], st[1], stv
+
+
+def _kernel_backend() -> str:
+    """The kernels the learner builds its program from: Pallas on the
+    chip, XLA elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
 def _jit_build(fn):
@@ -749,7 +773,8 @@ class RoundsTreeLearner:
             self.Np = int(nsh * math.ceil(self.N / max(nsh, 1)))
             self._local_np = self.Np
 
-        backend = ("pallas" if jax.default_backend() == "tpu" else "xla")
+        backend = _kernel_backend()
+        input_dtype = getattr(config, "histogram_dtype", "float32")
         nbv = dataset.num_bins.astype(np.int32)      # ORIGINAL [F]
         icv = np.asarray(dataset.is_categorical)     # ORIGINAL [F]
         plan = dataset.bundle_plan
@@ -766,44 +791,58 @@ class RoundsTreeLearner:
             bins_np = None
             self.Cstore = dataset.sparse.num_columns
             self.Fpad = self.Cstore
+            col_mult = 1
         else:
             store = dataset.dense_bins(
                 site="rounds_feed")                  # [C, N] (bundled: C<F)
             self.Cstore = store.shape[0]
-            if backend == "pallas" and dataset.max_num_bin <= 256 \
-                    and self._want_int8_bins():
-                # int8 HBM layout (value - 128): 4x less device memory and
-                # bandwidth than int32 — what fits Expo's 11M x 700 store
-                # (7.7 GB vs 30.8 GB) on one v5e chip.  Memory-gated: the
-                # G=32 block layout it forces measured ~60% slower than the
-                # int32 G=8 layout on wide 255-bin data (Epsilon shape), so
-                # narrow storage is chosen only when int32 bins would crowd
-                # the device (see _want_int8_bins).
-                bins_np = (store.astype(np.int16) - 128).astype(np.int8)
-                # pad columns to the int8 kernel's 32-sublane group on the
-                # HOST: a device-side pad would briefly hold a second full
-                # copy of the bins array.  Padded columns are trivial
-                # (1 bin, fmask False) and can never be selected.
-                self.Fpad = 32 * int(math.ceil(self.Cstore / 32))
-            else:
-                bins_np = store.astype(np.int32)
-                self.Fpad = self.Cstore
+            # int8 HBM layout (value - 128): 4x less device memory and
+            # bandwidth than int32 — what fits Expo's 11M x 700 store
+            # (7.7 GB vs 30.8 GB) on one v5e chip.  Memory-gated: the
+            # G=32 block layout it forces measured ~60% slower than the
+            # int32 G=8 layout on wide 255-bin data (Epsilon shape), so
+            # narrow storage is chosen only when int32 bins would crowd
+            # the device (see _want_int8_bins).
+            bins_dtype = (np.int8 if backend == "pallas"
+                          and dataset.max_num_bin <= 256
+                          and self._want_int8_bins() else np.int32)
+            # The store is laid out ONCE, here on the host, to the tiles
+            # of the two kernels that read it in every round — columns
+            # to the feature group (8; 32 for int8 bins), each shard's
+            # rows to the histogram kernel's row chunk, which the
+            # partition's chunk divides — so that neither wrapper pads
+            # it, or lid / gh8 beside it, in any round or launch: a pad
+            # on the device is a whole-store copy an iteration cannot
+            # change, and a second store's worth of temporaries.  Padded
+            # columns are trivial (1 bin, fmask False) and can never be
+            # selected; padded rows carry row_mask 0 and add exact zeros
+            # to every sum.  A shard of under one row chunk is one block
+            # as it stands; the XLA kernels (off the chip) tile nothing;
+            # multi-host rows keep MultiHostRows' own size and the
+            # wrappers' pads.
+            col_mult, row_mult = (
+                store_alignment(np.dtype(bins_dtype).itemsize, self.B,
+                                input_dtype, int(dataset.max_num_bin))
+                if backend == "pallas" else (1, 1))
+            self.Fpad = col_mult * int(math.ceil(self.Cstore / col_mult))
+            per_shard = self.Np // max(nsh, 1)
+            if self.mh is None and per_shard > row_mult:
+                self.Np = self._local_np = int(
+                    nsh * row_mult * math.ceil(per_shard / row_mult))
         # data-parallel histogram exchange: resolve the collective from
         # the per-pass payload, then (for psum_scatter) align the store
         # columns so the [K, F, 3, B] histogram tiles the data axis —
         # each device owns an F/ndev store-column slice (the sparse
         # path's REDUCED histogram keeps the dense column layout, so
-        # the same alignment applies).  Alignment keeps the int8
-        # kernel's 32-sublane grouping.
+        # the same alignment applies).  Alignment keeps the kernels'
+        # feature group.
         K_pass = min(LEAVES_PER_BATCH, int(config.num_leaves))
         self.hist_exchange = resolve_hist_exchange(
             config, ndev=nsh,
             payload_bytes=4.0 * K_pass * self.Fpad * 3 * self.B)
         if self.hist_exchange == "psum_scatter" and nsh > 1:
             self.Fpad = pad_cols_to_ndev(
-                self.Fpad, self._nd_sc,
-                align=32 if (bins_np is not None
-                             and bins_np.dtype == np.int8) else 1)
+                self.Fpad, self._nd_sc, align=col_mult)
         if self.sparse:
             sps = dataset.sparse
             cols_np = sps.cols.astype(np.int32)
@@ -823,17 +862,17 @@ class RoundsTreeLearner:
             streams = self._build_sparse_streams(cols_np, ell_np, nsh,
                                                  backend)
         else:
-            # pad value must be an in-range bin; padded rows/features
-            # carry zero mask so their bin never matters
-            pad_val = -128 if bins_np.dtype == np.int8 else 0
-            if self.Fpad > self.Cstore:
-                fp = self.Fpad - self.Cstore
-                bins_np = np.pad(bins_np, ((0, fp), (0, 0)),
-                                 constant_values=pad_val)
-            if self._local_np > self.N:
-                bins_np = np.pad(bins_np,
-                                 ((0, 0), (0, self._local_np - self.N)),
-                                 constant_values=pad_val)
+            # one pass over the store: convert into the padded array.
+            # The pad value must be an in-range bin (bin 0; -128 in the
+            # int8 layout); padded rows/features carry zero mask so
+            # their bin never matters
+            bins_np = np.zeros((self.Fpad, self._local_np), bins_dtype)
+            real = bins_np[: self.Cstore, : self.N]
+            if bins_dtype == np.int8:
+                bins_np.fill(-128)
+                real[...] = store.astype(np.int16) - 128
+            else:
+                real[...] = store
         if plan is None:
             # unbundled: split metadata mirrors the (padded) store columns
             fp = self.Fpad - self.F
@@ -879,7 +918,7 @@ class RoundsTreeLearner:
                   num_devices=self.dd,
                   num_feature_shards=self.df,
                   ftbl=ftbl, unb=unb, sparse=self.sparse,
-                  input_dtype=getattr(cfg, "histogram_dtype", "float32"))
+                  input_dtype=input_dtype)
         if mesh is None:
             self._build = _jit_build(
                 functools.partial(build_tree_rounds, **kw))

@@ -22,7 +22,7 @@ Two implementations:
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -468,10 +468,12 @@ def _hist_kernel_masked_q(sl_ref, gb_ref, lid_ref, ghq_ref, out_ref, *,
             vals, oh, _CONTRACT_ROWS, preferred_element_type=jnp.int32)
 
 
-def _quantize_gh(gh8):
-    """Per-pass symmetric int8 quantization of the grad/hess rows.
-    Returns (ghq [8, C] int32 holding int8-ranged values, scale_g,
-    scale_h).  The mask row is carried through exactly (0/1)."""
+def quantize_gh(gh8):
+    """Symmetric int8 quantization of the grad/hess rows.  Returns
+    (ghq [8, C] int32 holding int8-ranged values, scale_g, scale_h).
+    The mask row is carried through exactly (0/1).  Every launch of a
+    tree is over the same gh8, so the rounds learner quantises once a
+    tree and hands the triple to hist_multileaf_masked (`ghq=`)."""
     sg = jnp.maximum(jnp.max(jnp.abs(gh8[0])), 1e-30) / 127.0
     sh = jnp.maximum(jnp.max(jnp.abs(gh8[1])), 1e-30) / 127.0
     ghq = jnp.concatenate([
@@ -492,6 +494,10 @@ def _quantize_gh(gh8):
 # two that the TPU compiler accepts for a v5e at EVERY tier the rounds
 # learner runs (K = 1, 3, 8, 32, 84; B = 256) — tests/test_tpu_compile.py
 # holds the main-path cases, so a change here is checked without a chip.
+# The rounds learner lays its store out to these lengths once, on the
+# host (store_alignment), so that no launch pads it: a retuned entry
+# moves the store's padded row count with it, and has to stay a length
+# that ops/partition.py can tile too (tree/store_copy_rows says if not).
 _MASKED_CHUNK = {
     (4, False): 8192,   # int32 bins, bf16 / int8 operands
     (4, True): 2048,    # int32 bins, float32 operands
@@ -565,6 +571,38 @@ def _masked_layout(F: int, C: int, K: int, bins_itemsize: int, B: int,
     return _MaskedLayout(G, G // pack, pack, bins_sub, Mp, Kp, Bs, Ck, Cp, Fg)
 
 
+def store_alignment(bins_itemsize: int, num_bins_padded: int,
+                    input_dtype: str, max_num_bin: int = 0
+                    ) -> Tuple[int, int]:
+    """(column multiple, row multiple) of a [F, C] store that every
+    masked launch takes as it is: at such a shape _masked_layout gives
+    Fg == F and Cp == C at every slot tier up to K = 84 (a taller value
+    block shrinks the row chunk, _masked_chunk), so hist_multileaf_masked
+    compiles to no pad.  The feature group is also the sublane tile that
+    ops/partition.py aligns the columns to, and the row chunk a length
+    its own chunk divides.  A store of fewer rows than the row multiple
+    is one block as it stands (Ck = C)."""
+    # read off the layout of a launch over more rows than any chunk
+    lay = _masked_layout(1, 1 << 30, 1, bins_itemsize, num_bins_padded,
+                         input_dtype, max_num_bin)
+    return lay.G, lay.Ck
+
+
+def masked_store_copy_rows(F: int, C: int, K: int, *, bins_itemsize: int,
+                           num_bins_padded: int, backend: str,
+                           input_dtype: str, max_num_bin: int = 0) -> int:
+    """Rows of the store that one `hist_multileaf_masked` launch over
+    [F, C] bins copies into a padded form before its kernel: all C when
+    the columns do not fill the feature groups or the rows the row
+    chunks, none for a store laid out by store_alignment (and none on
+    the XLA fallback, which pads nothing)."""
+    if backend != "pallas":
+        return 0
+    lay = _masked_layout(F, C, K, bins_itemsize, num_bins_padded,
+                         input_dtype, max_num_bin)
+    return C if (lay.Cp > C or lay.Fg > F) else 0
+
+
 def masked_hist_mxu_ops(F: int, C: int, K: int, *, bins_itemsize: int,
                         num_bins_padded: int, backend: str,
                         input_dtype: str, max_num_bin: int = 0) -> float:
@@ -590,12 +628,22 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
                           backend: str = "xla",
                           input_dtype: str = "float32",
                           interpret: bool = False,
-                          max_num_bin: int = 0) -> jax.Array:
+                          max_num_bin: int = 0, ghq=None) -> jax.Array:
     """Histogram K leaves in one pass, masks built on the fly.
 
     gb_t: [F, C] int bins; lid: [C] int32 leaf ids; gh8: [8, C] f32
     (grad·rm, hess·rm, rm, pads); sl: [K] int32 leaf ids to histogram
     (-1 = empty slot).  Returns [K, F, 3, B] f32.
+
+    ghq: quantize_gh(gh8), for a caller that launches many passes over
+    one gh8 (every launch of a tree) and quantises it once; None
+    quantises here.  Read only with int8 operands.
+
+    On the pallas path a store whose shape is not a multiple of
+    store_alignment's is padded here, with lid, gh8 and ghq, in every
+    launch (masked_store_copy_rows counts it); the conditions are
+    static, so a store laid out to those multiples compiles to a
+    reshape and no copy.
 
     max_num_bin (static; 0 = unknown) enables feature packing on the
     pallas path when all bins fit a 16/32/64-lane sub-block.
@@ -640,7 +688,7 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
             if bin_offset:
                 gb_t = gb_t.astype(jnp.int32) + bin_offset
             if quant:
-                ghq, sg, sh = _quantize_gh(gh8)
+                ghq, sg, sh = ghq if ghq is not None else quantize_gh(gh8)
                 gh8 = jnp.concatenate([
                     ghq[0:1].astype(jnp.float32) * sg,
                     ghq[1:2].astype(jnp.float32) * sh,
@@ -658,11 +706,19 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
         F, C, K, gb_t.dtype.itemsize, B, input_dtype, max_num_bin)
     nB = B // Bs
     with jax.named_scope("lgbt.feed"):
+        if quant:
+            ghq, sg, sh = ghq if ghq is not None else quantize_gh(gh8)
         if Cp > C:
+            # the fallback for a caller whose rows are not laid out to
+            # the row chunk: padded rows match no slot and carry zeros
+            # (which quantise to zeros and leave the scales alone)
             pad = Cp - C
             gb_t = jnp.pad(gb_t, ((0, 0), (0, pad)))
             lid = jnp.pad(lid, (0, pad), constant_values=-2)
-            gh8 = jnp.pad(gh8, ((0, 0), (0, pad)))
+            if quant:
+                ghq = jnp.pad(ghq, ((0, 0), (0, pad)))
+            else:
+                gh8 = jnp.pad(gh8, ((0, 0), (0, pad)))
             C = Cp
         if Fg > F:
             gb_t = jnp.pad(gb_t, ((0, Fg - F), (0, 0)))
@@ -671,8 +727,6 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
             gb_g = gb_g.astype(jnp.int32)
         sl2 = jnp.broadcast_to(
             jnp.pad(sl, (0, Kp - K), constant_values=-1)[:, None], (Kp, 128))
-        if quant:
-            ghq, sg, sh = _quantize_gh(gh8)
     if nB > 1:
         grid = (Fg // G, nB, C // Ck)
         in_specs = [
@@ -879,7 +933,7 @@ def hist_sparse_xla(cols: jax.Array, binsv: jax.Array, zero_bin: jax.Array,
     the sparse store.
 
     input_dtype "int8" selects per-pass symmetric gradient quantization
-    (_quantize_gh — the dense masked kernel's discipline) with the whole
+    (quantize_gh — the dense masked kernel's discipline) with the whole
     accumulation held in INTEGER lanes: int32 scatter-add of the
     quantized entries, int32 slot totals, int32 zero-bin residual, ONE
     dequantizing scale at the end.  That makes the XLA path
@@ -892,7 +946,7 @@ def hist_sparse_xla(cols: jax.Array, binsv: jax.Array, zero_bin: jax.Array,
     Cp, B = num_columns_padded, num_bins_padded
     quant = _sparse_quant_ok(input_dtype, N)
     if quant:
-        gh_acc, sg, sh = _quantize_gh(gh8)               # [8, N] int32
+        gh_acc, sg, sh = quantize_gh(gh8)               # [8, N] int32
     else:
         gh_acc = gh8
     srow = _slot_of_rows(lid, sl)                        # [N]
@@ -1110,7 +1164,7 @@ def hist_sparse_pallas(e_row: jax.Array, e_flat: jax.Array,
     WB = W * B
     Eblk = min(Ew, SPARSE_CHUNK)
     if quant:
-        gh_src, sg, sh = _quantize_gh(gh8)               # [8, N] int32
+        gh_src, sg, sh = quantize_gh(gh8)               # [8, N] int32
         acc_dt = jnp.int32
         kern = functools.partial(_hist_kernel_sparse_q, WB=WB, K=K)
     else:

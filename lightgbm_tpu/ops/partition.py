@@ -43,9 +43,11 @@ import jax.numpy as jnp
 
 from .lookup import table_lookup, select_bin_by_feature
 
-# row-chunk ceiling per grid cell; the VMEM model in _partition_pallas
+# row-chunk ceiling per grid cell; the VMEM model in _partition_layout
 # shrinks it for wide stores
 _PARTITION_CHUNK = 8192
+# budget of that model, bytes of VMEM per grid cell
+_PARTITION_VMEM = int(10e6)
 
 
 def _augment_tbl(tbl: jax.Array) -> jax.Array:
@@ -98,6 +100,64 @@ def _partition_kernel(tbl_ref, gb_ref, lid_ref, out_ref, *, S: int,
     out_ref[0, :] = jnp.where((nli > 0) & ~gl, nli, lidv)
 
 
+def _slot_lanes(num_slots: int) -> int:
+    """The slot axis as the kernel holds it: padded to whole lanes."""
+    return 256 if num_slots > 128 else 128
+
+
+def _partition_layout(F: int, C: int, isz: int, num_slots: int):
+    """(Fp, Ck, Cp) of one fused launch over [F, C] bins of `isz` bytes:
+    columns as padded to the sublane tile (int8 tiles are (32, 128)),
+    the row chunk, rows as padded to it.
+
+    VMEM model: bins block Fp*Ck*isz, its int32 widen Fp*Ck*4, the
+    [S, Ck] one-hot — keep under ~10 MB, at most _PARTITION_CHUNK rows
+    and at least 512.  Under that ceiling the chunk is the longest
+    multiple of 128 rows that divides C, so that a store laid out to the
+    histogram kernel's row chunk (ops/histogram.store_alignment: 8192,
+    2048 or 1024 rows) is tiled as it stands, whatever its width; only
+    where no such length of 512 or more divides C are the rows padded to
+    the ceiling."""
+    sub = 32 if isz == 1 else 8
+    Fp = F + (-F) % sub
+    per_row = Fp * (isz + 4) + num_slots
+    cap = min(C, _PARTITION_CHUNK,
+              max(512, (_PARTITION_VMEM // per_row) // 128 * 128))
+    Ck = cap
+    if C % cap:
+        Ck = next((ck for ck in range(cap // 128 * 128, 511, -128)
+                   if C % ck == 0), cap)
+    return Fp, Ck, C + (-C) % Ck
+
+
+def _fused_fits(F: int, isz: int, num_slots: int, backend: str,
+                num_bins_padded: int) -> bool:
+    """Whether partition_rows takes the fused kernel: the int8 encodings
+    are exact (slots <= 256, bins <= 256) and the kernel, which holds
+    ALL F feature rows (bins + their int32 widen) per block, gets a
+    chunk of 512 rows or more from the VMEM model — that bounds F at
+    ~3.8k int8 / ~2.4k int32 features; larger goes to the XLA path."""
+    f_fits = 512 * (F * (isz + 4) + 256) <= _PARTITION_VMEM
+    return (backend == "pallas" and num_slots <= 256
+            and 0 < num_bins_padded <= 256 and f_fits)
+
+
+def partition_store_copy_rows(F: int, C: int, *, bins_itemsize: int,
+                              num_slots: int, backend: str,
+                              num_bins_padded: int) -> int:
+    """Rows of the store that one partition_rows call over [F, C] bins
+    copies into a padded form before its kernel: all C when the columns
+    do not fill the sublane tile or no chunk divides the rows, none for
+    a store laid out by ops/histogram.store_alignment (and none on the
+    XLA path, which pads nothing)."""
+    if not _fused_fits(F, bins_itemsize, num_slots, backend,
+                       num_bins_padded):
+        return 0
+    Fp, _, Cp = _partition_layout(F, C, bins_itemsize,
+                                  _slot_lanes(num_slots))
+    return C if (Fp > F or Cp > C) else 0
+
+
 @functools.partial(jax.jit, static_argnames=("num_slots", "interpret"))
 def _partition_pallas(tbl8, gb_t, lid, *, num_slots: int,
                       interpret: bool = False):
@@ -105,24 +165,17 @@ def _partition_pallas(tbl8, gb_t, lid, *, num_slots: int,
 
     F, C = gb_t.shape
     bin_offset = 128 if gb_t.dtype == jnp.int8 else 0
-    isz = jnp.dtype(gb_t.dtype).itemsize
-    # sublane-align the feature axis (int8 tiles are (32, 128)); padded
-    # feature rows are never selected — fi always names a real feature
-    sub = 32 if isz == 1 else 8
-    if F % sub:
-        gb_t = jnp.pad(gb_t, ((0, sub - F % sub), (0, 0)))
-        F = gb_t.shape[0]
-    # VMEM model: bins block F*Ck*isz, its int32 widen F*Ck*4, the
-    # [S, Ck] one-hot — keep under ~10 MB
-    Ck = min(C, _PARTITION_CHUNK)
-    per_row = F * (isz + 4) + num_slots
-    Ck = min(Ck, max(512, (int(10e6) // per_row) // 128 * 128))
-    if C % Ck:
-        pad = Ck - C % Ck
-        gb_t = jnp.pad(gb_t, ((0, 0), (0, pad)))
-        # pad rows sit in slot 0; their lid2 is discarded by the caller
-        lid = jnp.pad(lid, (0, pad))
-        C += pad
+    Fp, Ck, Cp = _partition_layout(F, C, jnp.dtype(gb_t.dtype).itemsize,
+                                   num_slots)
+    # the fallback for a caller whose store is not laid out to the
+    # tiles (static conditions: an aligned store compiles to no pad).
+    # Padded feature rows are never selected — fi always names a real
+    # feature; pad rows sit in slot 0 and their lid2 is discarded by
+    # the caller
+    if Fp > F or Cp > C:
+        gb_t = jnp.pad(gb_t, ((0, Fp - F), (0, Cp - C)))
+        lid = jnp.pad(lid, (0, Cp - C))
+        F, C = Fp, Cp
     grid = (C // Ck,)
     out = pl.pallas_call(
         functools.partial(_partition_kernel, S=num_slots,
@@ -160,15 +213,8 @@ def partition_rows(bins_fn: jax.Array, leaf_id: jax.Array,
     digits); otherwise composes the XLA one-hot lookups.
     """
     tbl = _augment_tbl(tbl)
-    F = bins_fn.shape[0]
-    # the kernel holds ALL F feature rows (bins + their int32 widen) per
-    # block — the VMEM model must admit Ck >= 512, which bounds F at
-    # ~3.8k int8 / ~2.4k int32 features; larger goes to the XLA path
-    isz = jnp.dtype(bins_fn.dtype).itemsize
-    f_fits = 512 * (F * (isz + 4) + 256) <= int(10e6)
-    fits = (backend == "pallas" and num_slots <= 256
-            and 0 < num_bins_padded <= 256 and f_fits)
-    if not fits:
+    if not _fused_fits(bins_fn.shape[0], jnp.dtype(bins_fn.dtype).itemsize,
+                       num_slots, backend, num_bins_padded):
         r = table_lookup(tbl, leaf_id, num_slots=num_slots)
         fi = r[0].astype(jnp.int32)
         ti = r[1].astype(jnp.int32)
@@ -183,7 +229,7 @@ def partition_rows(bins_fn: jax.Array, leaf_id: jax.Array,
         gl = jnp.where((vi >= lo) & (vi <= hi1), gl, dl)
         return jnp.where((nli > 0) & ~gl, nli, leaf_id)
 
-    S = 256 if num_slots > 128 else 128          # lane-pad the slot axis
+    S = _slot_lanes(num_slots)
     # pad the slot axis BEFORE the -128 shifts: padded slots must decode
     # to thr=0/nli=0 ("stay"), matching the XLA path's zero table rows —
     # padding the shifted rows with 0 would decode to thr=128/nli=128 and

@@ -91,12 +91,20 @@ SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
 #    rewrites, as executed: every row of the shard in every round
 #    today (a partition that moved only the split leaves' rows would
 #    count fewer), summed across shards.
+#  - STORE_COPY_ROWS: rows of the bin store that the build program
+#    copies into a padded form in front of a kernel — the partition of
+#    each round and each histogram launch whose wrapper cannot tile
+#    the store as it stands (ops/partition.partition_store_copy_rows,
+#    ops/histogram.masked_store_copy_rows), summed across shards.  0
+#    for the store as RoundsTreeLearner lays it out on the chip; a
+#    retuned row chunk that the layout no longer fits shows here.
 TREE_ROUNDS = "tree/rounds"
 HIST_PASSES = "tree/hist_passes"
 HIST_SLOTS = "tree/hist_slots"
 HIST_LIVE_SLOTS = "tree/hist_live_slots"
 HIST_MXU_OPS = "tree/hist_mxu_ops"
 PARTITION_ROWS = "tree/partition_rows"
+STORE_COPY_ROWS = "tree/store_copy_rows"
 # Nothing increments these three since the row feed they counted went;
 # they stay, at 0 from the start, only for benchmark/ (jobs/train.py and
 # the feed_rows_per_iter metric read them) until ROADMAP B0.5 drops it.
@@ -223,7 +231,7 @@ CANONICAL_COUNTERS = (
     HIST_ROWS_TOUCHED, HIST_EXCHANGE_BYTES, SPLIT_RECORDS_BYTES,
     HIST_ROWS_DOWNGRADES, TREE_ROUNDS, HIST_PASSES, HIST_SLOTS,
     HIST_LIVE_SLOTS, HIST_MXU_OPS, FEED_ROWS, FEED_LIVE_ROWS,
-    PARTITION_ROWS, SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
+    PARTITION_ROWS, STORE_COPY_ROWS, SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,
     SERVE_QUANTIZE_BYTES_IN, SERVE_BINNED_REQUESTS,

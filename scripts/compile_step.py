@@ -15,6 +15,7 @@ that passes is not a chip run.
 prints one line per step: seconds, the number of `tpu_custom_call`s, the
 compiler's memory analysis per device and the collectives it put in.
 """
+import math
 import os
 import sys
 import time
@@ -47,6 +48,7 @@ def main(want):
     # steer the code that asks the backend (see the module docstring)
     jax.default_backend = lambda: "tpu"
     from lightgbm_tpu.learner import common, fused, rounds
+    from lightgbm_tpu.ops.histogram import store_alignment
     common.device_bytes_limit = rounds.device_bytes_limit = \
         lambda: V5E_BYTES_LIMIT
     real_put = jax.device_put
@@ -64,9 +66,17 @@ def main(want):
     X = rng.randn(4096, 28)
     y = (X[:, 0] > 0).astype(np.float64)
 
-    def lower(name, learner, n, rows_sh, rep_sh, bins_sh):
+    def lower(name, learner, n, rows_sh, rep_sh, bins_sh, shards=1):
         def s(dims, dtype, sh):
             return jax.ShapeDtypeStruct(dims, dtype, sharding=sh)
+        if isinstance(learner, rounds.RoundsTreeLearner):
+            # n rows as the learner lays them out: each shard's to the
+            # histogram kernel's row chunk (the 4096 rows it was built
+            # on are one block and stay as they are)
+            _, row = store_alignment(learner.bins_dev.dtype.itemsize,
+                                     learner.B, learner.config.histogram_dtype,
+                                     int(learner.dataset.max_num_bin))
+            n = shards * row * math.ceil(math.ceil(n / shards) / row)
         args = (s((learner.bins_dev.shape[0], n), learner.bins_dev.dtype,
                   bins_sh),
                 s((n,), jnp.float32, rows_sh), s((n,), jnp.float32, rows_sh),
@@ -78,7 +88,7 @@ def main(want):
         compiled = learner._build.lower(*args).compile()
         text = compiled.as_text()
         ma = compiled.memory_analysis()
-        print(f"{name}: {time.time() - t0:.0f}s "
+        print(f"{name} ({n} rows): {time.time() - t0:.0f}s "
               f"tpu_custom_call={text.count('tpu_custom_call')} "
               f"temp={ma.temp_size_in_bytes / 1e9:.2f}GB "
               f"args={ma.argument_size_in_bytes / 1e9:.2f}GB "
@@ -101,7 +111,7 @@ def main(want):
             print(f"  hist_exchange={lr.hist_exchange}")
             lower(name, lr, ROWS[-1], NamedSharding(mesh, P("data")),
                   NamedSharding(mesh, P()),
-                  NamedSharding(mesh, P(None, "data")))
+                  NamedSharding(mesh, P(None, "data")), shards=4)
     name = f"fused one-chip float32 N={ROWS[0]}"
     if want(name):
         cfg = config_from_params(PARAMS)

@@ -9,7 +9,7 @@ training set, `lgb.Booster`, its learner's own build program):
 1. the root pass of tree 1 — `hist_multileaf_masked` over the learner's
    device store with the arguments `build_tree_rounds` gives it — against
    NumPy: the same int8-quantised gradient and hessian values
-   (`ops/histogram._quantize_gh`, redone in NumPy float32), summed per
+   (`ops/histogram.quantize_gh`, redone in NumPy float32), summed per
    (column, bin) in int64 over the binned store in blocks of a million
    rows.  The kernel sums exact products in int32, so every cell has to
    be equal to the unit;
@@ -49,7 +49,7 @@ def say(**facts):
 
 
 def quantize(v: np.ndarray):
-    """`_quantize_gh` for one row of values, in NumPy float32."""
+    """`quantize_gh` for one row of values, in NumPy float32."""
     scale = np.maximum(np.max(np.abs(v)), np.float32(1e-30)) / np.float32(127)
     return np.round(v / scale).astype(np.int64), np.float32(scale)
 
@@ -170,19 +170,22 @@ def main(argv=None) -> int:
 
     # -- 1: the root histogram ----------------------------------------------
     grad, hess = (a.reshape(-1) for a in bst._gbdt.boosting_gradients())
-    N, B = learner.N, learner.B
-    if learner.Np != N or learner.mesh is not None:
-        raise SystemExit("the check is written for one device's unpadded rows")
+    N, B, F = learner.N, learner.B, learner.Cstore
+    if learner.mesh is not None:
+        raise SystemExit("the check is written for one device")
     # as RoundsTreeLearner resolves it
     backend = "pallas" if jax.default_backend() == "tpu" else "xla"
-    gh8 = (jnp.zeros((8, N), jnp.float32).at[0].set(grad).at[1].set(hess)
-           .at[2].set(1.0))
+    # the learner's store is laid out to the kernel's tiles: its padded
+    # rows carry row_mask 0, its padded columns are cut off the result
+    rows = learner._pad_rows
+    gh8 = (jnp.zeros((8, learner.Np), jnp.float32).at[0].set(rows(grad))
+           .at[1].set(rows(hess)).at[2].set(jnp.asarray(learner._row_mask)))
     path = np.asarray(hist_multileaf_masked(
-        learner.bins_dev, jnp.zeros(N, jnp.int32), gh8,
+        learner.bins_dev, jnp.zeros(learner.Np, jnp.int32), gh8,
         jnp.zeros(1, jnp.int32), num_bins_padded=B, backend=backend,
         input_dtype=params["histogram_dtype"],
-        max_num_bin=int(learner.dataset.max_num_bin)))[0]       # [F, 3, B]
-    store = np.asarray(learner.bins_dev)
+        max_num_bin=int(learner.dataset.max_num_bin)))[0, :F]   # [F, 3, B]
+    store = np.asarray(learner.bins_dev)[:F, :N]
     g_np, h_np = np.asarray(grad), np.asarray(hess)
     gq, sg = quantize(g_np)
     hq, sh = quantize(h_np)
@@ -207,7 +210,7 @@ def main(argv=None) -> int:
     tree = tree_of(learner, grad, hess)
     want = reference_split(
         ref.astype(np.float64) * scale.astype(np.float64),
-        np.asarray(learner.num_bins_dev), int(params["min_data_in_leaf"]),
+        np.asarray(learner.num_bins_dev)[:F], int(params["min_data_in_leaf"]),
         float(params["min_sum_hessian_in_leaf"]))
     got = (int(tree.split_feature[0]), int(tree.threshold_bin[0]))
     split_ok = got == want[:2]

@@ -332,7 +332,7 @@ def test_original_length_guards(binary_example, regression_example,
 
 
 def test_int8_histogram_integration():
-    """Default-tier int8 plumbing check (rounds learner + _quantize_gh +
+    """Default-tier int8 plumbing check (rounds learner + quantize_gh +
     dequant): training converges; the fuller f32-comparison lives in the
     slow-tier test_int8_histogram_trains_end_to_end."""
     rng = np.random.RandomState(11)

@@ -130,7 +130,7 @@ def test_int8_first_tree_is_the_plain_learners(trained):
 def test_int8_later_trees_on_the_same_quantised_gradients(rows, trained):
     """Past the first tree the gradients take many values.  The plain
     learner can be given the int8 path's quantised values — one scale
-    over all rows, `ops/histogram._quantize_gh`.  Both learners
+    over all rows, `ops/histogram.quantize_gh`.  Both learners
     get the int8 levels themselves (whole numbers up to +-127, which the
     int8 path requantises to themselves at a scale of exactly 1, and
     whose float32 sums are exact in any order), with the hessian floor
@@ -141,7 +141,7 @@ def test_int8_later_trees_on_the_same_quantised_gradients(rows, trained):
     from lightgbm_tpu.dataset import Dataset as RawDataset
     from lightgbm_tpu.learner.rounds import RoundsTreeLearner
     from lightgbm_tpu.learner.serial import SerialTreeLearner
-    from lightgbm_tpu.ops.histogram import _quantize_gh
+    from lightgbm_tpu.ops.histogram import quantize_gh
     X, y = rows
     plain, _ = trained("exact", "float32", ITERS)
     floor = cell_params()["min_sum_hessian_in_leaf"]
@@ -152,7 +152,7 @@ def test_int8_later_trees_on_the_same_quantised_gradients(rows, trained):
         gh8 = (jnp.zeros((8, ROWS), jnp.float32)
                .at[0].set((p - y).astype(np.float32))
                .at[1].set((p * (1 - p)).astype(np.float32)))
-        ghq, _, sh = _quantize_gh(gh8)
+        ghq, _, sh = quantize_gh(gh8)
         g, h = ghq[0].astype(jnp.float32), ghq[1].astype(jnp.float32)
         assert len(np.unique(np.asarray(g))) > 30           # not two levels
         assert float(jnp.abs(g).max()) == float(h.max()) == 127.0

@@ -190,7 +190,7 @@ def test_hist_masked_int8_equals_integer_histogram(K, B, max_nb):
     the full K=84 launch, at 256 bins and at the packed 63-bin layout
     (two columns a lane block), over two row chunks of which the second
     is mostly padding, with an odd feature count and empty slots."""
-    from lightgbm_tpu.ops.histogram import _quantize_gh
+    from lightgbm_tpu.ops.histogram import quantize_gh
     C, F = 8192 + 301, 11
     rng, gb = _rand(C, F, max_nb, seed=30)
     lid = rng.randint(0, 2 * K + 1, size=C).astype(np.int32)
@@ -204,7 +204,7 @@ def test_hist_masked_int8_equals_integer_histogram(K, B, max_nb):
         jnp.asarray(gb), jnp.asarray(lid), jnp.asarray(gh8),
         jnp.asarray(sl), num_bins_padded=B, backend="pallas",
         input_dtype="int8", interpret=True, max_num_bin=max_nb)
-    ghq, sg, sh = _quantize_gh(jnp.asarray(gh8))
+    ghq, sg, sh = quantize_gh(jnp.asarray(gh8))
     ghq = np.asarray(ghq)
     assert np.abs(ghq[:2]).max() == 127
     want = np.zeros((K, F, 3, B), np.int64)
@@ -217,6 +217,33 @@ def test_hist_masked_int8_equals_integer_histogram(K, B, max_nb):
     assert h.shape == want.shape
     np.testing.assert_array_equal(
         np.asarray(h), want.astype(np.float32) * scale)
+
+
+@pytest.mark.parametrize("backend", ["pallas", "xla"])
+@pytest.mark.parametrize("C", [2 * 8192, 8192 + 301],
+                         ids=["aligned_rows", "padded_rows"])
+def test_hist_masked_takes_the_trees_quantisation(backend, C):
+    """The rounds learner quantises gh8 once a tree and hands every
+    launch the triple: the histogram is the one a launch that quantises
+    for itself gives, bit for bit — over rows that fill the row chunks
+    and over rows the wrapper pads (ghq is padded beside gh8)."""
+    from lightgbm_tpu.ops.histogram import quantize_gh
+    F, K, B = 8, 8, 128
+    rng, gb = _rand(C, F, 63, seed=34)
+    lid = jnp.asarray(rng.randint(0, K, size=C).astype(np.int32))
+    gh8 = np.zeros((8, C), np.float32)
+    gh8[2] = (rng.rand(C) < 0.7)
+    gh8[0] = rng.randn(C) * gh8[2]
+    gh8[1] = rng.rand(C) * gh8[2]
+    gh8 = jnp.asarray(gh8)
+    kw = dict(num_bins_padded=B, backend=backend, input_dtype="int8",
+              max_num_bin=63, interpret=backend == "pallas")
+    sl = jnp.arange(K, dtype=jnp.int32)
+    own = hist_multileaf_masked(jnp.asarray(gb), lid, gh8, sl, **kw)
+    given = hist_multileaf_masked(jnp.asarray(gb), lid, gh8, sl,
+                                  ghq=quantize_gh(gh8), **kw)
+    np.testing.assert_array_equal(np.asarray(own), np.asarray(given))
+    assert float(np.abs(np.asarray(own)).sum()) > 0
 
 
 @pytest.mark.parametrize("input_dtype", ["float32", "bfloat16", "int8"])

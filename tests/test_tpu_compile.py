@@ -118,20 +118,48 @@ def test_fused_partition(shape, F, bins_dtype):
                      shape((7, 256), jnp.float32))
 
 
+def store_copies(compiled, cells: int):
+    """Instructions of an optimised program that write an s32 buffer of
+    `cells` elements or more — a padded or relaid copy of the bin store
+    — as (name, opcode) pairs.  Views (bitcast, get-tuple-element) and
+    the parameter itself write nothing."""
+    import math
+    import re
+    out = []
+    for m in re.finditer(r"^\s*(?:ROOT )?(%\S+) = s32\[([\d,]+)\]\S* ([\w-]+)\(",
+                         compiled.as_text(), re.M):
+        name, dims, opcode = m.groups()
+        if (math.prod(int(d) for d in dims.split(",")) >= cells
+                and opcode not in ("parameter", "bitcast",
+                                   "get-tuple-element")):
+            out.append((name, opcode))
+    return out
+
+
 def test_higgs_build_program(shape):
     """The whole build step of the benchmark cell `higgs.full`, as
-    RoundsTreeLearner jits it on the chip: 10.5M rows by the 28 columns
-    of the int32 store (which the store leaves unpadded; the kernels pad
-    them to 32), 255 leaves, int8 operands, the per-leaf histogram
-    cache, the Pallas partition, and every launch over all the rows at
-    the slot tier (8 / 32 / 84) that holds the round's leaves.  One
-    program of four slot chunks a round, whose arguments (1.5 GB) and
-    temporaries (2.4 GB) are what the cell holds."""
+    RoundsTreeLearner jits it on the chip: 10,502,144 rows by 32 columns
+    of the int32 store — 10.5M by 28 as the learner lays them out on the
+    host, to the histogram kernel's row chunk and feature group — 255
+    leaves, int8 operands, the per-leaf histogram cache, the Pallas
+    partition, and every launch over all the rows at the slot tier
+    (8 / 32 / 84) that holds the round's leaves.  One program of four
+    slot chunks a round, whose arguments (1.5 GB) and temporaries
+    (0.55 GB) are what the cell holds.
+
+    The store is an argument and nothing else: no instruction writes a
+    store-sized buffer (each wrapper's pad was one, in every round and
+    launch), and the temporaries stand 1.86 GB under the 2,405,195,776 B
+    of the program that padded (PR 33) — both padded copies, 1.34 GB
+    each as tiled, less what had shared their space."""
     import functools
     from lightgbm_tpu.config import config_from_params
     from lightgbm_tpu.learner.common import make_split_kw
     from lightgbm_tpu.learner.rounds import build_tree_rounds
-    n, F = 10_500_000, 28
+    from lightgbm_tpu.ops.histogram import store_alignment
+    col, row = store_alignment(4, B, "int8", 255)
+    F, n = 28 + (-28) % col, 10_500_000 + (-10_500_000) % row
+    assert (F, n) == (32, 10_502_144)
     cfg = config_from_params({"objective": "binary", "num_leaves": 255,
                               "min_data_in_leaf": 1,
                               "min_sum_hessian_in_leaf": 100.0,
@@ -147,10 +175,44 @@ def test_higgs_build_program(shape):
         build, shape((F, n), jnp.int32), shape((n,), jnp.float32),
         shape((n,), jnp.float32), shape((n,), jnp.float32),
         shape((F,), jnp.int32), shape((F,), jnp.bool_), shape((F,), jnp.bool_))
+    assert store_copies(compiled, 28 * 10_500_000) == []
     mem = compiled.memory_analysis()
+    # read: 547,270,144 B
+    assert mem.temp_size_in_bytes < 2_405_195_776 - 1.34e9 - 4e8, (
+        mem.temp_size_in_bytes)
     held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes)
-    assert held < 8e9, held        # half the chip
+    assert held < 2.2e9, held      # an eighth of the chip
+
+
+@pytest.mark.parametrize("F,n,bins,max_num_bin,copies", [
+    (32, 10_502_144, B, 255, False), (2000, 401_408, B, 255, False),
+    (2000, 401_408, 128, 63, False),
+    # the stores as the datasets have them: each wrapper pads
+    (28, 10_500_000, B, 255, True), (2000, 400_000, B, 255, True)])
+def test_a_round_copies_no_aligned_store(shape, F, n, bins, max_num_bin,
+                                         copies):
+    """One round's partition and one K = 8 launch over a cell's store:
+    laid out to store_alignment's multiples, both kernels read the
+    argument through bitcasts and the program's temporaries are the
+    launch's operands (a few [8, n] rows); as the dataset has it, each
+    wrapper writes a padded copy first."""
+    from lightgbm_tpu.ops.partition import partition_rows
+
+    def f(gb, lid, gh, sl, tbl):
+        lid2 = partition_rows(gb, lid, tbl, num_slots=256,
+                              backend="pallas", num_bins_padded=bins)
+        return masked("int8", bins, max_num_bin)(gb, lid2, gh, sl)
+    compiled = compile_for_chip(
+        f, *masked_args(shape, F, jnp.int32, 8, n),
+        shape((7, 256), jnp.float32))
+    written = store_copies(compiled, F * n)
+    assert bool(written) == copies, written
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if copies:
+        assert temp > 4 * F * n, temp
+    else:
+        assert temp < 0.5 * 4 * F * n, temp
 
 
 def test_table_lookup_kernel(shape):
