@@ -11,6 +11,7 @@ serialize on TPU.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Optional
 
@@ -174,8 +175,14 @@ def _add_leaf_to_row_jit(score, leaf_id, leaf_values, *, tree_id: int,
     # table runs at <1 GB/s on TPU (see ops/lookup.py) and cost ~65 ms per
     # iteration at N=4M; the matmul is exact for f32 leaf values
     lv = leaf_values.astype(jnp.float32)
-    val = table_lookup(lv[None], leaf_id, num_slots=lv.shape[0],
-                       spmd=spmd)[0]
+    # spmd: the leaf ids come sharded over the learner's mesh and the
+    # score is replicated, so XLA gathers them to every device in front
+    # of the lookup; the scope names that gather and the lookup behind
+    # it in a trace (metadata only, and only on a mesh)
+    with (jax.named_scope("lgbt.score_gather") if spmd
+          else contextlib.nullcontext()):
+        val = table_lookup(lv[None], leaf_id, num_slots=lv.shape[0],
+                           spmd=spmd)[0]
     return score.at[tree_id].set(score[tree_id] + val)
 
 

@@ -79,9 +79,10 @@ STATS_COUNTERS = (
     profiling.SPLIT_RECORDS_BYTES, profiling.SPARSE_NNZ_TOUCHED,
     profiling.TREE_ROUNDS, profiling.HIST_PASSES, profiling.HIST_SLOTS,
     profiling.HIST_LIVE_SLOTS, profiling.HIST_MXU_OPS,
-    profiling.PARTITION_ROWS, profiling.STORE_COPY_ROWS)
+    profiling.PARTITION_ROWS, profiling.STORE_COPY_ROWS,
+    profiling.EXCHANGE_COLLECTIVES)
 (S_ROWS, S_EXCHANGE, S_RECORDS, S_NNZ, S_ROUNDS, S_PASSES, S_SLOTS, S_LIVE,
- S_OPS, S_PARTITION, S_COPY) = range(len(STATS_COUNTERS))
+ S_OPS, S_PARTITION, S_COPY, S_COLLECTIVES) = range(len(STATS_COUNTERS))
 
 # Every phase of build_tree_rounds runs under a jax.named_scope
 # "lgbt.<phase>", so that an operation in a profiler trace says which
@@ -165,7 +166,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                       leaves_per_batch: int = 0,
                       sparse: bool = False):
     """Grow one tree in batched rounds.  Shapes as learner/fused.build_tree.
-    Returns (TreeArrays, leaf_id, stats) — stats is a [11] f32 vector in
+    Returns (TreeArrays, leaf_id, stats) — stats is a [12] f32 vector in
     the order of STATS_COUNTERS: rows processed by histogram kernels
     (global across shards); per-device histogram-exchange payload
     bytes; per-device best-split-record allgather bytes; stored sparse
@@ -175,11 +176,14 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     them that held a leaf; the operations their contractions perform
     (ops/histogram.masked_hist_mxu_ops per dense launch, global across
     shards; the sparse kernels add 0); the rows whose leaf id the
-    rounds rewrite (all Nloc in every round, global across shards); and
-    the rows of the store that the partitions and the launches copy
+    rounds rewrite (all Nloc in every round, global across shards); the
+    rows of the store that the partitions and the launches copy
     into a padded form (0 for a store laid out to the kernels' tiles,
     as RoundsTreeLearner lays it out; rounds x Nloc + launches x Nloc
-    where neither wrapper can tile `bins` as it stands).
+    where neither wrapper can tile `bins` as it stands); and the
+    collectives launched across the mesh (per device: the exchange's
+    legs, the record all_gathers and the root's psum of the leaf
+    totals; 0 without a mesh).
     Every one is a scalar add where the launch or the round is made,
     on values the build already has.
 
@@ -318,6 +322,18 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         the psum_scatter path exchanges records)."""
         return 4.0 * nd * k2 * 11 if hx else 0.0
 
+    def count_collectives(stv, exchanges: int, searches: int, more: int = 0):
+        """`stv` with the collectives added that one device launches
+        for `exchanges` histogram exchanges and `searches` batched split
+        searches (+ `more`): an exchange is one psum or psum_scatter
+        (two legs on a 2-D mesh's scatter); a search all_gathers its
+        records under psum_scatter only.  Without a mesh the slot
+        stays 0 and the program gains no operation."""
+        if row_axes is None:
+            return stv
+        return stv.at[S_COLLECTIVES].add(float(
+            exchanges * hx_legs + (searches if hx else 0) + more))
+
     if ftbl is None:
         ftbl = identity_feat_table(num_bins)
     # Termination is governed by the while_loop predicate (no positive gain
@@ -448,10 +464,10 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
 
         leaf_id = jnp.zeros(Nloc, jnp.int32)
         # the root contributes one full-stream launch for one slot + one
-        # exchange
-        stats = (launch_stats(1, 1)
-                 .at[S_EXCHANGE].set(_exchange_bytes(1))
-                 .at[S_RECORDS].set(_records_bytes(1)))
+        # exchange; under psum_scatter also the psum of the leaf totals
+        stats = count_collectives(
+            launch_stats(1, 1).at[S_EXCHANGE].set(_exchange_bytes(1))
+            .at[S_RECORDS].set(_records_bytes(1)), 1, 1, more=int(hx))
         leaf_best = jnp.full((L, 11), NEG_INF, jnp.float32).at[0].set(
             find_best_batch(hist0[None], root_sums[None])[0])
         leaf_depth = jnp.zeros(L, jnp.int32)
@@ -670,7 +686,9 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 with jax.named_scope("lgbt.split"):
                     rec_s = find_best_batch(h_small, small_sums[s:s + Kc])
                     rec_l = find_best_batch(h_large, large_sums[s:s + Kc])
-                    stv = stv.at[S_RECORDS].add(2 * _records_bytes(Kc))
+                    stv = count_collectives(
+                        stv.at[S_RECORDS].add(2 * _records_bytes(Kc)),
+                        1 if cache_parent_hist else 2, 2)
                     recL = jnp.where(sil, rec_s, rec_l)
                     recR = jnp.where(sil, rec_l, rec_s)
                     lb = leaf_best2.at[li].set(recL, mode="drop").at[ni].set(
@@ -958,8 +976,11 @@ class RoundsTreeLearner:
                                   put(zb_np, P()))
                                  + tuple(put(s, P(da)) for s in streams))
             else:
+                # straight from the host array, a shard to each device:
+                # through jnp.asarray the whole store would first land
+                # on one of them (15.5 GB at 54M rows by 72 columns)
                 self.bins_dev = jax.device_put(
-                    jnp.asarray(bins_np), NamedSharding(mesh, P(None, da)))
+                    bins_np, NamedSharding(mesh, P(None, da)))
         # replicated metadata stays host numpy in multi-process mode
         # (nbv/icv already carry the int8 feature padding)
         self.num_bins_dev = nbv if self.mh is not None else jnp.asarray(nbv)

@@ -98,6 +98,13 @@ SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
 #    ops/histogram.masked_store_copy_rows), summed across shards.  0
 #    for the store as RoundsTreeLearner lays it out on the chip; a
 #    retuned row chunk that the layout no longer fits shows here.
+#  - EXCHANGE_COLLECTIVES: collectives the build launches across the
+#    mesh, as executed: the legs of each histogram exchange (one psum
+#    or psum_scatter a pass, two on a 2-D mesh's scatter), the
+#    all_gather of each batch of best-split records under psum_scatter,
+#    and the root's psum of the leaf totals there.  Per device (every
+#    shard launches the same ones); 0 without a mesh.  The closing psum
+#    of this vector's own global slots is not counted.
 TREE_ROUNDS = "tree/rounds"
 HIST_PASSES = "tree/hist_passes"
 HIST_SLOTS = "tree/hist_slots"
@@ -105,6 +112,7 @@ HIST_LIVE_SLOTS = "tree/hist_live_slots"
 HIST_MXU_OPS = "tree/hist_mxu_ops"
 PARTITION_ROWS = "tree/partition_rows"
 STORE_COPY_ROWS = "tree/store_copy_rows"
+EXCHANGE_COLLECTIVES = "tree/exchange_collectives"
 # Nothing increments these three since the row feed they counted went;
 # they stay, at 0 from the start, only for benchmark/ (jobs/train.py and
 # the feed_rows_per_iter metric read them) until ROADMAP B0.5 drops it.
@@ -231,7 +239,8 @@ CANONICAL_COUNTERS = (
     HIST_ROWS_TOUCHED, HIST_EXCHANGE_BYTES, SPLIT_RECORDS_BYTES,
     HIST_ROWS_DOWNGRADES, TREE_ROUNDS, HIST_PASSES, HIST_SLOTS,
     HIST_LIVE_SLOTS, HIST_MXU_OPS, FEED_ROWS, FEED_LIVE_ROWS,
-    PARTITION_ROWS, STORE_COPY_ROWS, SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
+    PARTITION_ROWS, STORE_COPY_ROWS, EXCHANGE_COLLECTIVES,
+    SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,
     SERVE_QUANTIZE_BYTES_IN, SERVE_BINNED_REQUESTS,

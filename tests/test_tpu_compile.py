@@ -185,6 +185,72 @@ def test_higgs_build_program(shape):
     assert held < 2.2e9, held      # an eighth of the chip
 
 
+def test_criteo_tb_build_program_on_four_chips(topo):
+    """The whole build step of the benchmark cell `criteo_tb.data4` as
+    RoundsTreeLearner jits it on a four-chip host: under shard_map over
+    a described v5e:2x2, each chip a shard `[72, 13,500,416]` of the
+    int32 store — 54M rows by 67 columns as the learner lays them out
+    (columns to the feature group, which the four-way scatter of 18
+    columns keeps; each shard's rows to the row chunk) — 255 leaves,
+    int8 operands, the psum_scatter exchange and its record all_gather.
+
+    The prediction on memory, checked without a chip: a shard is
+    3.89 GB of arguments, no instruction writes a store-sized buffer,
+    and the temporaries stay under 1.2 GB (read: see the assert)."""
+    import functools
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.config import config_from_params
+    from lightgbm_tpu.learner.common import make_split_kw
+    from lightgbm_tpu.learner.fused import TreeArrays
+    from lightgbm_tpu.learner.rounds import build_tree_rounds
+    from lightgbm_tpu.ops.histogram import store_alignment
+    from lightgbm_tpu.sharded.mesh import pad_cols_to_ndev
+    col, row = store_alignment(4, B, "int8", 255)
+    shard = -(-54_000_000 // 4)
+    shard += (-shard) % row
+    F = pad_cols_to_ndev(67 + (-67) % col, 4, align=col)
+    assert (F, shard) == (72, 13_500_416)
+    n = 4 * shard
+    cfg = config_from_params({"objective": "binary", "num_leaves": 255,
+                              "min_data_in_leaf": 1,
+                              "min_sum_hessian_in_leaf": 100.0,
+                              "histogram_dtype": "int8", "verbose": -1})
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("data", "feature"))
+    build = functools.partial(
+        build_tree_rounds, num_leaves=255, num_bins_padded=B,
+        max_num_bin=255, split_kw=make_split_kw(cfg), max_depth=-1,
+        min_data_in_leaf=cfg.min_data_in_leaf,
+        min_sum_hessian_in_leaf=cfg.min_sum_hessian_in_leaf,
+        backend="pallas", input_dtype=cfg.histogram_dtype,
+        cache_parent_hist=True, hist_exchange="psum_scatter",
+        num_devices=4, data_axis="data")
+    out_specs = (jax.tree_util.tree_map(lambda _: P(), TreeArrays(
+        *[0] * len(TreeArrays._fields))), P("data"), P())
+    step = jax.shard_map(
+        build, mesh=mesh, out_specs=out_specs, check_vma=False,
+        in_specs=(P(None, "data"), P("data"), P("data"), P("data"),
+                  P(), P(), P()))
+
+    def s(dims, dtype, spec):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+    compiled = compile_for_chip(
+        step, s((F, n), jnp.int32, P(None, "data")),
+        s((n,), jnp.float32, P("data")), s((n,), jnp.float32, P("data")),
+        s((n,), jnp.float32, P("data")), s((F,), jnp.int32, P()),
+        s((F,), jnp.bool_, P()), s((F,), jnp.bool_, P()))
+    text = compiled.as_text()
+    assert "reduce-scatter" in text or "all-reduce" in text
+    assert "all-gather" in text
+    assert store_copies(compiled, 67 * 13_500_000) == []
+    mem = compiled.memory_analysis()           # per device
+    assert 3.88e9 < mem.argument_size_in_bytes < 4.2e9, (
+        mem.argument_size_in_bytes)
+    # read: see PERF.md section 4
+    assert mem.temp_size_in_bytes < 1.2e9, mem.temp_size_in_bytes
+
+
 @pytest.mark.parametrize("F,n,bins,max_num_bin,copies", [
     (32, 10_502_144, B, 255, False), (2000, 401_408, B, 255, False),
     (2000, 401_408, 128, 63, False),

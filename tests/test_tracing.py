@@ -325,7 +325,7 @@ def test_count_deferred_vector_drains_like_the_per_name_path(monkeypatch):
 
 def test_the_learner_feeds_every_stats_counter_as_one_vector():
     assert set(rounds.STATS_COUNTERS) <= set(profiling.CANONICAL_COUNTERS)
-    assert len(rounds.STATS_COUNTERS) == 11
+    assert len(rounds.STATS_COUNTERS) == 12
     X, y = _problem(300, 4)
     profiling.reset()
     bst = lgb.Booster({"objective": "binary", "verbose": -1, "num_leaves": 4,
@@ -333,11 +333,14 @@ def test_the_learner_feeds_every_stats_counter_as_one_vector():
                       lgb.Dataset(X, y))
     bst.update()
     assert list(profiling._deferred) == [rounds.STATS_COUNTERS]
-    assert profiling._deferred[rounds.STATS_COUNTERS].shape == (11,)
+    assert profiling._deferred[rounds.STATS_COUNTERS].shape == (12,)
     got = profiling.counters("tree/")
     assert set(got) >= set(rounds.STATS_COUNTERS)
     # the benchmark's feed_rows_per_iter reads this name; no launch
     # copies a row, and the vector has no slot for it
     assert got[profiling.FEED_ROWS] == 0
     assert profiling.FEED_ROWS not in rounds.STATS_COUNTERS
+    # one device launches no collective
+    assert got[profiling.EXCHANGE_COLLECTIVES] == 0
+    assert got[profiling.HIST_EXCHANGE_BYTES] == 0
     profiling.reset()
