@@ -265,7 +265,7 @@ def four_chips(args, X, y):
     par, f_par = train(ds, args.iters, tree_learner="data")
     f_par.update(check_learner(par, "float32"))
     lr = par._gbdt.learner
-    score = par._gbdt.train_score.score
+    score = par._gbdt.train_score.rows     # [K, Np] in the row layout
     f_par.update(
         mesh=dict(zip(lr.mesh.axis_names, lr.mesh.devices.shape)),
         hist_exchange=lr.hist_exchange,

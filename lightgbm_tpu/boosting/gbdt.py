@@ -157,15 +157,23 @@ class GBDT:
         self.train_set = train_set
         self.num_data = train_set.num_data
         self.objective = objective or create_objective(cfg)
-        self.objective.init(train_set.metadata, self.num_data)
-        self.K = self.objective.num_tree_per_iteration
+        # the learner first: where it keeps its rows (their padded count
+        # and, on a mesh, their sharding; None from a learner that lays
+        # out none) is where the train score, the label, the weights
+        # and the gradients live through an iteration, so that score
+        # update and gradients are per-row work on the chip that holds
+        # the row.  Everything outside that hot path reads the [K, N]
+        # view (ScoreUpdater.score)
         self.learner = create_tree_learner(train_set, cfg)
+        layout = getattr(self.learner, "row_layout", None)
+        self.objective.init(train_set.metadata, self.num_data, layout)
+        self.K = self.objective.num_tree_per_iteration
         # bins_t resolves LAZILY (sparse stores materialize the dense
         # transpose only if a consumer actually walks trees over it)
         self.train_score = ScoreUpdater(
             lambda: self.learner.bins_t, self.num_data, self.K,
             train_set.metadata.init_score,
-            feat_tbl=train_set.bundle_feat_table())
+            feat_tbl=train_set.bundle_feat_table(), layout=layout)
         # continued training (input_model): replay the loaded model onto
         # the fresh training scores (the reference re-scores via a
         # Predictor closure during loading, application.cpp:106-113) —
@@ -292,6 +300,12 @@ class GBDT:
         self.bag_cnt = cnt
 
     def boosting_gradients(self) -> Tuple[jax.Array, jax.Array]:
+        """[K, Np] in the learner's row layout from an objective that
+        holds its label there; [K, N] from any other (lambdarank, a
+        learner with no layout), which `learner.train` pads and
+        places."""
+        if self.objective.layout is not None:
+            return self.objective.get_gradients(self.train_score.rows)
         return self.objective.get_gradients(self.train_score.score)
 
     def _shrink_dev(self) -> jax.Array:

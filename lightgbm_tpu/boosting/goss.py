@@ -90,8 +90,13 @@ class GOSS(GBDT):
             cnt = top_k + other_k
             cap = min(1 << max(cnt - 1, 1).bit_length(), n)
             cap = max(cap, cnt)
+            # top-k over the real rows only: the [K, N] view of what
+            # came in the learner's row layout (the learner pads and
+            # places the selection's gradients again)
+            view = self.train_score.layout.view
             bag, gradient, hessian = _goss_select(
-                gradient, hessian, sub, top_k=top_k, other_k=other_k, cap=cap)
+                view(gradient), view(hessian), sub, top_k=top_k,
+                other_k=other_k, cap=cap)
             self.bag_idx = bag
             self.bag_cnt = cnt
             self.need_bagging = True

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import profiling
+from ..jaxutil import RowLayout
 from ..ops.lookup import select_bin_by_feature, table_lookup
 from ..ops.predict import sparse_bin_lookup
 
@@ -168,25 +170,56 @@ def _add_raw(score, raw):
     return score + raw
 
 
-@functools.partial(jax.jit, static_argnames=("tree_id", "spmd"))
-def _add_leaf_to_row_jit(score, leaf_id, leaf_values, *, tree_id: int,
-                         spmd: bool):
+def _leaf_values_by_row(leaf_id, leaf_values, *, spmd: bool = False):
     # one-hot matmul, not table gather: XLA's [N] gather from a leaf-sized
     # table runs at <1 GB/s on TPU (see ops/lookup.py) and cost ~65 ms per
     # iteration at N=4M; the matmul is exact for f32 leaf values
     lv = leaf_values.astype(jnp.float32)
-    # spmd: the leaf ids come sharded over the learner's mesh and the
-    # score is replicated, so XLA gathers them to every device in front
-    # of the lookup; the scope names that gather and the lookup behind
-    # it in a trace (metadata only, and only on a mesh)
+    return table_lookup(lv[None], leaf_id, num_slots=lv.shape[0],
+                        spmd=spmd)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("tree_id", "spmd"))
+def _add_leaf_to_row_jit(score, leaf_id, leaf_values, *, tree_id: int,
+                         spmd: bool):
+    # spmd: the leaf ids come sharded over a mesh and the score is not
+    # laid out like them (a learner that reports no row layout), so XLA
+    # gathers them to every device in front of the lookup; the scope
+    # names that gather and the lookup behind it in a trace (metadata
+    # only, and only there)
     with (jax.named_scope("lgbt.score_gather") if spmd
           else contextlib.nullcontext()):
-        val = table_lookup(lv[None], leaf_id, num_slots=lv.shape[0],
-                           spmd=spmd)[0]
+        val = _leaf_values_by_row(leaf_id, leaf_values, spmd=spmd)
     return score.at[tree_id].set(score[tree_id] + val)
 
 
-def _add_leaf_to_row(score, leaf_id, leaf_values, *, tree_id: int):
+@functools.lru_cache(maxsize=None)
+def _add_leaf_to_row_sharded(sharding, tree_id: int):
+    """The same update with score and leaf ids in one row layout over a
+    mesh: under shard_map each device looks its own rows up (the Mosaic
+    lookup on the chip, which XLA cannot partition under plain jit) and
+    adds them to its own shard of the score — no collective, no row
+    leaves its chip.  jax.jit names a program after its function and a
+    shard_map closure has none, hence the names: `jit_` and not
+    `jit_build_tree`, so a trace counts it under the boosting layer."""
+    P = jax.sharding.PartitionSpec
+    rows = sharding.spec
+
+    def update(score, leaf_id, leaf_values):
+        val = _leaf_values_by_row(leaf_id, leaf_values)
+        return score.at[tree_id].set(score[tree_id] + val)
+
+    step = jax.shard_map(update, mesh=sharding.mesh,
+                         in_specs=(P(None, *rows), rows, P()),
+                         out_specs=P(None, *rows), check_vma=False)
+
+    def add_leaf_to_row_sharded(score, leaf_id, leaf_values):
+        return step(score, leaf_id, leaf_values)
+    return jax.jit(add_leaf_to_row_sharded)
+
+
+def _add_leaf_to_row(score, leaf_id, leaf_values, *, tree_id: int,
+                     sharding=None):
     """score[tree_id] += leaf_values[leaf_id], all inside ONE program.
     Eager `score[tree_id]` / `score.at[tree_id].set(...)` lower to
     dynamic_slice/scatter whose start index is uploaded host→device on
@@ -194,10 +227,20 @@ def _add_leaf_to_row(score, leaf_id, leaf_values, *, tree_id: int):
     sanitizer's guard; a STATIC tree_id is a trace constant (the jit
     cache holds K entries, K = trees per iteration).
 
-    A data-parallel learner hands back a `leaf_id` sharded over its
-    mesh; this program runs under plain jit, so the lookup must then be
-    one XLA can partition (table_lookup spmd=True)."""
-    spmd = len(leaf_id.sharding.device_set) > 1
+    `sharding` is the row sharding that score and leaf ids share on a
+    mesh (RowLayout): the per-shard program above.  Without one, a
+    learner that reports no layout may still hand back a `leaf_id`
+    sharded over its mesh; that program runs under plain jit, so the
+    lookup must then be one XLA can partition (table_lookup spmd=True),
+    and `tree/score_gather_rows` counts the rows whose ids it fetches
+    from another device's memory (host arithmetic on the two
+    shardings, no sync; 0 registers the key)."""
+    spmd = sharding is None and len(leaf_id.sharding.device_set) > 1
+    profiling.count(profiling.SCORE_GATHER_ROWS,
+                    leaf_id.shape[0] if spmd else 0)
+    if sharding is not None:
+        return _add_leaf_to_row_sharded(sharding, tree_id)(
+            score, leaf_id, leaf_values)
     return _add_leaf_to_row_jit(score, leaf_id, leaf_values,
                                 tree_id=tree_id, spmd=spmd)
 
@@ -216,10 +259,19 @@ def select_class_row(x, *, k: int):
 
 
 class ScoreUpdater:
-    """Holds [K, N] float32 raw scores for one dataset."""
+    """Holds [K, N] float32 raw scores for one dataset.
+
+    The train set's updater is given its learner's row layout, if the
+    learner reports one, and keeps `rows`, a [K, Np] array in that
+    layout (the real rows first, padding at the tail, sharded like the
+    store's rows on a mesh): gradients and the leaf-id update read and
+    write it where it lies.  `score` is the [K, N] view every other
+    reader takes; the rows past N hold anything finite and nothing
+    reads them."""
 
     def __init__(self, bins_t, num_data: int, K: int,
-                 init_score: Optional[np.ndarray] = None, feat_tbl=None):
+                 init_score: Optional[np.ndarray] = None, feat_tbl=None,
+                 layout: Optional[RowLayout] = None):
         # bins_t: [N+1, C] array, the sparse ELL triple (cols, binsv,
         # zero_bin), None, or a ZERO-ARG CALLABLE resolved on first
         # traversal.  Sparse stores hand the triple so every traversal
@@ -242,7 +294,12 @@ class ScoreUpdater:
                 score[:] = init_score[None, :].astype(np.float32)
             else:
                 raise ValueError("init score size mismatch")
-        self.score = jnp.asarray(score)
+        self.layout = layout or RowLayout(num_data, num_data, None)
+        self.rows = self.layout.place(score)
+
+    @property
+    def score(self) -> jax.Array:
+        return self.layout.view(self.rows)
 
     @property
     def bins_t(self):
@@ -252,8 +309,8 @@ class ScoreUpdater:
         return src
 
     def add_constant(self, val: float, tree_id: int) -> None:
-        self.score = _add_const_to_row(
-            self.score, jax.device_put(np.float32(val)), tree_id=tree_id)
+        self.rows = _add_const_to_row(
+            self.rows, jax.device_put(np.float32(val)), tree_id=tree_id)
 
     def _tree_leaf_idx(self, tree) -> jax.Array:
         d = tree.as_device_arrays()
@@ -275,8 +332,15 @@ class ScoreUpdater:
         lv = jax.device_put(
             tree.leaf_value[: tree.max_leaves].astype(np.float32)
             * np.float32(scale))
-        self.score = _add_leaf_to_row(self.score, leaf_idx, lv,
-                                      tree_id=tree_id)
+        self._add_by_leaf_id(leaf_idx, lv, tree_id)
+
+    def _add_by_leaf_id(self, leaf_id, leaf_values, tree_id: int) -> None:
+        # ids over the N real rows (a walk's) are padded with -1, which
+        # matches no leaf and adds 0.0, and placed; a learner's own come
+        # in the layout already
+        self.rows = _add_leaf_to_row(
+            self.rows, self.layout.place(leaf_id, fill=-1), leaf_values,
+            tree_id=tree_id, sharding=self.layout.sharding)
 
     def add_trees(self, trees, K: int, kernel: str = "auto") -> None:
         """Replay a WHOLE model onto the scores (add_valid / continued-
@@ -310,7 +374,7 @@ class ScoreUpdater:
         else:
             raw = predict_ensemble_binned(stack, bt, self.feat_tbl,
                                           meta=meta)            # [K, N]
-        self.score = _add_raw(self.score, raw)
+        self.rows = _add_raw(self.rows, self.layout.place(raw))
 
     def add_tree_arrays_dev(self, arrs, leaf_values: jax.Array,
                             tree_id: int) -> None:
@@ -321,8 +385,7 @@ class ScoreUpdater:
             self.bins_t, arrs.split_feature, arrs.threshold_bin,
             arrs.is_cat, arrs.left_child, arrs.right_child, arrs.num_leaves,
             self.feat_tbl)
-        self.score = _add_leaf_to_row(self.score, leaf_idx, leaf_values,
-                                      tree_id=tree_id)
+        self._add_by_leaf_id(leaf_idx, leaf_values, tree_id)
 
     def add_tree_by_leaf_id_dev(self, leaf_id: jax.Array,
                                 leaf_values: jax.Array, tree_id: int
@@ -330,8 +393,7 @@ class ScoreUpdater:
         """Leaf-partition score update with DEVICE leaf values (shrinkage
         pre-applied) — no host tree needed; used by the pipelined
         training path."""
-        self.score = _add_leaf_to_row(self.score, leaf_id, leaf_values,
-                                      tree_id=tree_id)
+        self._add_by_leaf_id(leaf_id, leaf_values, tree_id)
 
     def add_tree_by_leaf_id(self, tree, leaf_id: jax.Array, tree_id: int
                             ) -> None:
@@ -341,8 +403,7 @@ class ScoreUpdater:
         add_tree for OOB when bagging."""
         lv = jax.device_put(
             tree.leaf_value[: tree.max_leaves].astype(np.float32))
-        self.score = _add_leaf_to_row(self.score, leaf_id, lv,
-                                      tree_id=tree_id)
+        self._add_by_leaf_id(leaf_id, lv, tree_id)
 
     def get(self) -> np.ndarray:
         """Fetch the whole [K, N] score to host — the ONE deliberate

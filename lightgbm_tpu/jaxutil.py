@@ -15,9 +15,11 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def enable_compile_cache() -> str:
@@ -59,9 +61,64 @@ def pad_rows_dev(x: jax.Array, *, pad: int) -> jax.Array:
 
 @functools.partial(jax.jit, static_argnames=("n",))
 def slice_rows_dev(x: jax.Array, *, n: int) -> jax.Array:
-    """x[:n] with a trace-constant bound (the eager slice lowers to
-    dynamic_slice and uploads its start index per call)."""
-    return x[:n]
+    """The first n of the trailing row axis, with a trace-constant bound
+    (the eager slice lowers to dynamic_slice and uploads its start index
+    per call)."""
+    return x[..., :n]
+
+
+class RowLayout(NamedTuple):
+    """Where a learner keeps its rows: `num_rows` of them, the dataset's
+    `num_data` first and zero padding at the tail of the global order,
+    and on a mesh the sharding of a `[num_rows]` array over it (None on
+    one device).  Whatever is elementwise in the row — score, label,
+    weights, gradients, leaf ids — lives in this layout through a
+    boosting iteration, so that no row crosses a chip outside the tree
+    build; every other reader takes `view`, the `num_data` real rows."""
+    num_data: int
+    num_rows: int
+    sharding: Optional[jax.sharding.NamedSharding]
+
+    def sharding_of(self, ndim: int):
+        """The sharding of an `ndim`-D array whose LAST axis is the
+        rows (None on one device)."""
+        if self.sharding is None or ndim == 1:
+            return self.sharding
+        P = jax.sharding.PartitionSpec
+        return jax.sharding.NamedSharding(
+            self.sharding.mesh, P(*(None,) * (ndim - 1), *self.sharding.spec))
+
+    def place(self, x, fill=0) -> jax.Array:
+        """`x` with `num_data` trailing rows, padded with `fill` and
+        placed in the layout.  A host array goes a shard to each device
+        (through jnp.asarray all of it would first land on one); a
+        device array is padded by one program.  Paths that write rows
+        through the `[.., num_data]` view come through here, outside an
+        iteration's hot path; an array already `num_rows` long is
+        returned as it is."""
+        pad = self.num_rows - x.shape[-1]
+        if isinstance(x, np.ndarray):
+            if pad:
+                x = np.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)],
+                           constant_values=fill)
+            return jax.device_put(x, self.sharding_of(x.ndim))
+        if not pad:
+            return x
+        return _pad_rows_placed(pad, fill, self.sharding_of(x.ndim))(x)
+
+    def view(self, x: jax.Array) -> jax.Array:
+        """The `num_data` real rows of an array in the layout."""
+        if x.shape[-1] == self.num_data:
+            return x
+        return slice_rows_dev(x, n=self.num_data)
+
+
+@functools.lru_cache(maxsize=None)
+def _pad_rows_placed(pad: int, fill, sharding):
+    def pad_rows(x):
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)],
+                       constant_values=fill)
+    return jax.jit(pad_rows, out_shardings=sharding)
 
 
 @jax.jit

@@ -52,13 +52,13 @@ from ..config import Config
 from ..dataset import Dataset
 from ..sharded.mesh import (check_scatter_divisible, check_tree_divergence,
                             mesh_axes, pad_cols_to_ndev,
-                            resolve_hist_exchange)
+                            resolve_hist_exchange, row_shard_axes)
 from .common import (CPU_TIER_BYTES_LIMIT, device_bytes_limit,
                      make_split_kw, padded_bin_count, sentinel_bins_t,
                      use_parent_hist_cache)
 from .fused import TreeArrays, tree_arrays_to_host
 from .. import profiling
-from ..jaxutil import bag_mask_dev, pad_rows_dev, slice_rows_dev
+from ..jaxutil import RowLayout, bag_mask_dev
 from ..ops.histogram import (hist_multileaf_masked, hist_sparse_multileaf,
                              masked_hist_mxu_ops, masked_store_copy_rows,
                              quantize_gh, sparse_window_streams,
@@ -911,6 +911,14 @@ class RoundsTreeLearner:
             unb = dataset.unbundle_tables(self.B, self.Fpad)
         self._row_mask = np.pad(np.ones(self.N, np.float32),
                                 (0, self._local_np - self.N))
+        # what train / train_device take grad and hess in and hand the
+        # leaf ids back in: Np rows, sharded like the store's on a mesh.
+        # Multi-process rows are sized and assembled on the host
+        # (MultiHostRows): no layout to report, callers keep N rows
+        self.row_layout = None if self.mh is not None else RowLayout(
+            self.N, self.Np, None if mesh is None else
+            jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec(
+                row_shard_axes(self.dd, self.df))))
         self._row_mask_dev = None     # lazy device cache (no bagging path)
         self._fmask_dev = None        # lazy device cache (no sampling path)
         cfg = config
@@ -948,7 +956,6 @@ class RoundsTreeLearner:
                 self.bins_dev = jnp.asarray(bins_np)
         else:
             from jax.sharding import PartitionSpec as P, NamedSharding
-            from ..sharded.mesh import row_shard_axes
             fn = functools.partial(
                 build_tree_rounds, **kw,
                 data_axis="data" if self.dd > 1 else None,
@@ -1060,14 +1067,15 @@ class RoundsTreeLearner:
         # per-iteration host draw is the design; the upload is explicit
         return m if self.mh is not None else jax.device_put(m)
 
-    def _pad_rows(self, x: jax.Array):
+    def _rows_in(self, x: jax.Array):
+        """grad / hess as the build takes them.  The boosting loop hands
+        them in `row_layout` already and this is a length check; N rows
+        (a caller outside that loop) are padded with zeros and placed."""
         if self.mh is not None:
             from jax.sharding import PartitionSpec as P
             return self.mh.put_rows(
                 self.mh.pad_local(np.asarray(x, np.float32)), P("data"))
-        if self.Np == self.N:
-            return x
-        return pad_rows_dev(x, pad=self.Np - self.N)
+        return self.row_layout.place(x)
 
     def _masks(self, bag_idx):
         if self.mh is not None:
@@ -1084,7 +1092,7 @@ class RoundsTreeLearner:
                      else self._base_fmask)
             return mask, fmask
         if self._row_mask_dev is None:
-            self._row_mask_dev = jax.device_put(self._row_mask)
+            self._row_mask_dev = self.row_layout.place(self._row_mask)
         mask = self._row_mask_dev
         if bag_idx is not None:
             mask = bag_mask_dev(bag_idx, mask)
@@ -1101,18 +1109,19 @@ class RoundsTreeLearner:
                      bag_count: Optional[int] = None):
         """Device-only train: (packed tree vector, leaf_id, TreeArrays)
         with NO device→host sync — callers pipeline the tree fetch and can
-        score valid sets straight from the device TreeArrays."""
+        score valid sets straight from the device TreeArrays.  `leaf_id`
+        comes back in `row_layout`: Np rows, the padded ones at the tail."""
         from .fused import pack_tree_arrays
         mask, fmask = self._masks(bag_idx)
         arrs, leaf_id, stats = self._build(
-            self.bins_dev, self._pad_rows(grad), self._pad_rows(hess), mask,
+            self.bins_dev, self._rows_in(grad), self._rows_in(hess), mask,
             self.num_bins_dev, self.is_cat_dev, fmask)
         # device scalars, folded into the counters at the next metrics
         # read — no sync on the pipelined path
         self._record_stats(stats)
         packed = pack_tree_arrays(arrs)
         check_tree_divergence("rounds/tree", arrs, packed)
-        return packed, slice_rows_dev(leaf_id, n=self.N), arrs
+        return packed, leaf_id, arrs
 
     def _record_stats(self, stats) -> None:
         # the whole vector against its counters: one device add per
@@ -1124,11 +1133,11 @@ class RoundsTreeLearner:
               bag_count: Optional[int] = None) -> Tuple[Tree, jax.Array]:
         mask, fmask = self._masks(bag_idx)
         arrs, leaf_id, stats = self._build(
-            self.bins_dev, self._pad_rows(grad), self._pad_rows(hess), mask,
+            self.bins_dev, self._rows_in(grad), self._rows_in(hess), mask,
             self.num_bins_dev, self.is_cat_dev, fmask)
         self._record_stats(stats)
         check_tree_divergence("rounds/tree", arrs)
         tree = tree_arrays_to_host(arrs, self.dataset, self.config.num_leaves)
         if self.mh is not None:
             return tree, jnp.asarray(self.mh.local_rows(leaf_id))
-        return tree, slice_rows_dev(leaf_id, n=self.N)
+        return tree, leaf_id

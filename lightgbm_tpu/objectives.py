@@ -24,6 +24,7 @@ import numpy as np
 
 from .config import Config
 from .dataset import Metadata
+from .jaxutil import RowLayout
 
 
 def softmax(x, axis=-1):
@@ -33,21 +34,42 @@ def softmax(x, axis=-1):
 
 
 class Objective:
-    """Base objective.  get_gradients: [K, N] score -> ([K, N], [K, N])."""
+    """Base objective.  get_gradients: [K, N] score -> ([K, N], [K, N]).
+
+    A row-local objective (a row's gradient depends on that row's score,
+    label and weight alone: all but lambdarank) can be given the tree
+    learner's row layout at init.  It then keeps label and weights in
+    that layout — padded to Np rows, sharded like the store's rows on a
+    mesh — takes the [K, Np] score where it lies and returns [K, Np]
+    gradients there, exactly 0.0 in the padded rows."""
 
     name = "regression"
     num_tree_per_iteration = 1
     is_constant_hessian = False
     boost_from_average = False
+    row_local = True
+    layout: Optional[RowLayout] = None
 
     def __init__(self, config: Config):
         self.config = config
 
-    def init(self, metadata: Metadata, num_data: int) -> None:
+    def init(self, metadata: Metadata, num_data: int,
+             layout: Optional[RowLayout] = None) -> None:
         self.num_data = num_data
-        self.label = jnp.asarray(metadata.label, jnp.float32)
+        self.layout = layout if self.row_local else None
+        self.label = self._rows(metadata.label, np.float32)
         self.weights = (None if metadata.weights is None
-                        else jnp.asarray(metadata.weights, jnp.float32))
+                        else self._rows(metadata.weights, np.float32))
+
+    def _rows(self, x, dtype) -> jax.Array:
+        """A per-row host array on the device: in the layout when there
+        is one (a shard straight to each device), else as it is."""
+        x = np.asarray(x, dtype)
+        return jnp.asarray(x) if self.layout is None else self.layout.place(x)
+
+    def _host_rows(self, x: jax.Array, dtype=np.float64) -> np.ndarray:
+        """The real rows of `_rows`' result, back on the host."""
+        return jax.device_get(x)[: self.num_data].astype(dtype)
 
     def get_gradients(self, score: jax.Array) -> Tuple[jax.Array, jax.Array]:
         raise NotImplementedError
@@ -66,7 +88,20 @@ class Objective:
     def _jit_gradients(self, f):
         """jit the gradient function under the objective's name: its XLA
         module reads `jit_gradients_<objective>` in a trace, not
-        `jit_f`."""
+        `jit_f`.  In a layout with padded rows the program also zeroes
+        their gradient and hessian (fused, free).  That is not hygiene:
+        the learner's int8 quantisation scales by the largest |g| and
+        |h| over ALL rows it is handed, so a padded row's sigmoid of 0.5
+        against label 0 would move a shard's scale, and with it levels,
+        near-ties and trees."""
+        n, layout = self.num_data, self.layout
+        if layout is not None and layout.num_rows > n:
+            grads = f
+
+            def f(score, *rest):
+                g, h = grads(score, *rest)
+                real = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1) < n
+                return jnp.where(real, g, 0.0), jnp.where(real, h, 0.0)
         f.__name__ = f.__qualname__ = f"gradients_{self.name}"
         return jax.jit(f)
 
@@ -85,8 +120,8 @@ class RegressionL2(Objective):
     def is_constant_hessian(self):
         return self.weights is None
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
 
         def f(score, label, weights):
             g = score - label[None, :]
@@ -101,9 +136,9 @@ class RegressionL2(Objective):
         return self._f(score, self.label, self.weights)
 
     def initial_score(self) -> float:
-        lab = jax.device_get(self.label).astype(np.float64)
+        lab = self._host_rows(self.label)
         if self.weights is not None:
-            w = jax.device_get(self.weights).astype(np.float64)
+            w = self._host_rows(self.weights)
             return float((lab * w).sum() / w.sum())
         return float(lab.mean())
 
@@ -112,8 +147,8 @@ class RegressionL1(Objective):
     name = "regression_l1"
     boost_from_average = True
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
         eta = self.config.gaussian_eta
 
         def f(score, label, weights):
@@ -129,7 +164,7 @@ class RegressionL1(Objective):
         return self._f(score, self.label, self.weights)
 
     def initial_score(self) -> float:
-        return float(np.median(jax.device_get(self.label).astype(np.float64)))
+        return float(np.median(self._host_rows(self.label)))
 
 
 def _gaussian_hessian(y, t, g, eta, w):
@@ -146,8 +181,8 @@ class RegressionHuber(Objective):
     name = "huber"
     boost_from_average = True
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
         delta = self.config.huber_delta
         eta = self.config.gaussian_eta
 
@@ -168,15 +203,15 @@ class RegressionHuber(Objective):
         return self._f(score, self.label, self.weights)
 
     def initial_score(self) -> float:
-        return float(np.mean(jax.device_get(self.label).astype(np.float64)))
+        return float(np.mean(self._host_rows(self.label)))
 
 
 class RegressionFair(Objective):
     name = "fair"
     boost_from_average = True
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
         c = self.config.fair_c
 
         def f(score, label, weights):
@@ -191,15 +226,15 @@ class RegressionFair(Objective):
         return self._f(score, self.label, self.weights)
 
     def initial_score(self) -> float:
-        return float(np.mean(jax.device_get(self.label).astype(np.float64)))
+        return float(np.mean(self._host_rows(self.label)))
 
 
 class RegressionPoisson(Objective):
     name = "poisson"
     boost_from_average = True
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
         mds = self.config.poisson_max_delta_step
 
         def f(score, label, weights):
@@ -215,7 +250,7 @@ class RegressionPoisson(Objective):
         return self._f(score, self.label, self.weights)
 
     def initial_score(self) -> float:
-        return float(np.mean(jax.device_get(self.label).astype(np.float64)))
+        return float(np.mean(self._host_rows(self.label)))
 
 
 class BinaryLogloss(Objective):
@@ -225,8 +260,8 @@ class BinaryLogloss(Objective):
         super().__init__(config)
         self.sigmoid = config.sigmoid
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
         lab = np.asarray(metadata.label)
         is_pos = lab > 0
         cnt_pos, cnt_neg = int(is_pos.sum()), int((~is_pos).sum())
@@ -275,13 +310,13 @@ class MulticlassSoftmax(Objective):
         self.num_class = config.num_class
         self.num_tree_per_iteration = config.num_class
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
         lab = np.asarray(metadata.label).astype(np.int32)
         if lab.min() < 0 or lab.max() >= self.num_class:
             raise ValueError(
                 f"Label must be in [0, {self.num_class}) for multiclass")
-        self._label_int = jnp.asarray(lab)
+        self._label_int = self._rows(lab, np.int32)
 
         def f(score, label_int, weights):
             p = softmax(score, axis=0)                       # [K, N]
@@ -315,10 +350,10 @@ class MulticlassOVA(Objective):
         self.num_tree_per_iteration = config.num_class
         self.sigmoid = config.sigmoid
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
         lab = np.asarray(metadata.label).astype(np.int32)
-        self._label_int = jnp.asarray(lab)
+        self._label_int = self._rows(lab, np.int32)
         sigmoid = self.sigmoid
 
         def f(score, label_int, weights):
@@ -347,9 +382,10 @@ class MulticlassOVA(Objective):
 
 class LambdarankNDCG(Objective):
     name = "lambdarank"
+    row_local = False       # gathers scores by doc_idx across a query
 
-    def init(self, metadata, num_data):
-        super().init(metadata, num_data)
+    def init(self, metadata, num_data, layout=None):
+        super().init(metadata, num_data, layout)
         if metadata.query_boundaries is None:
             raise ValueError("Lambdarank tasks require query information")
         qb = np.asarray(metadata.query_boundaries, np.int64)

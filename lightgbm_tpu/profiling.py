@@ -113,6 +113,14 @@ HIST_MXU_OPS = "tree/hist_mxu_ops"
 PARTITION_ROWS = "tree/partition_rows"
 STORE_COPY_ROWS = "tree/store_copy_rows"
 EXCHANGE_COLLECTIVES = "tree/exchange_collectives"
+# Outside the build, counted on the host by count() at each leaf-id
+# update of a score (boosting/score_updater._add_leaf_to_row):
+#  - SCORE_GATHER_ROWS: rows whose leaf ids that update has to fetch
+#    from another device's memory: 0 where score and leaf ids share a
+#    row layout or lie on one device, the id count where the ids come
+#    sharded over a mesh and the score does not.  Read off the two
+#    shardings, no sync; the 0 registers the key.
+SCORE_GATHER_ROWS = "tree/score_gather_rows"
 # Nothing increments these three since the row feed they counted went;
 # they stay, at 0 from the start, only for benchmark/ (jobs/train.py and
 # the feed_rows_per_iter metric read them) until ROADMAP B0.5 drops it.
@@ -240,6 +248,7 @@ CANONICAL_COUNTERS = (
     HIST_ROWS_DOWNGRADES, TREE_ROUNDS, HIST_PASSES, HIST_SLOTS,
     HIST_LIVE_SLOTS, HIST_MXU_OPS, FEED_ROWS, FEED_LIVE_ROWS,
     PARTITION_ROWS, STORE_COPY_ROWS, EXCHANGE_COLLECTIVES,
+    SCORE_GATHER_ROWS,
     SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,

@@ -79,8 +79,8 @@ def shard_root_pass(learner, grad, hess, params):
         in_specs=(P(None, "data"), P("data"), P("data"), P("data")),
         out_specs=(P("data"), P("data"))))
     mask, _ = learner._masks(None)
-    hist, scales = fn(learner.bins_dev, learner._pad_rows(grad),
-                      learner._pad_rows(hess), mask)
+    hist, scales = fn(learner.bins_dev, learner._rows_in(grad),
+                      learner._rows_in(hess), mask)
     return np.asarray(hist), np.asarray(scales)
 
 

@@ -177,7 +177,7 @@ def main(argv=None) -> int:
     backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     # the learner's store is laid out to the kernel's tiles: its padded
     # rows carry row_mask 0, its padded columns are cut off the result
-    rows = learner._pad_rows
+    rows = learner._rows_in
     gh8 = (jnp.zeros((8, learner.Np), jnp.float32).at[0].set(rows(grad))
            .at[1].set(rows(hess)).at[2].set(jnp.asarray(learner._row_mask)))
     path = np.asarray(hist_multileaf_masked(
@@ -186,7 +186,7 @@ def main(argv=None) -> int:
         input_dtype=params["histogram_dtype"],
         max_num_bin=int(learner.dataset.max_num_bin)))[0, :F]   # [F, 3, B]
     store = np.asarray(learner.bins_dev)[:F, :N]
-    g_np, h_np = np.asarray(grad), np.asarray(hess)
+    g_np, h_np = np.asarray(grad)[:N], np.asarray(hess)[:N]
     gq, sg = quantize(g_np)
     hq, sh = quantize(h_np)
     t0 = time.perf_counter()

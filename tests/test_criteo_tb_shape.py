@@ -130,15 +130,15 @@ def trained(rows):
 
     def run(name, iters=ITERS):
         name, iters = (name, 3) if name == "exact" else (name, iters)
-        if name not in runs:
+        if (name, iters) not in runs:
             before = profiling.counters("tree/")
             bst = lgb.train({**base, **recipes[name]}, lgb.Dataset(X, y),
                             num_boost_round=iters)
             bst._gbdt._flush_pending()
             after = profiling.counters("tree/")
-            runs[name] = bst, {k: v - before.get(k, 0.0)
-                               for k, v in after.items()}
-        return runs[name]
+            runs[name, iters] = bst, {k: v - before.get(k, 0.0)
+                                      for k, v in after.items()}
+        return runs[name, iters]
     return run
 
 
@@ -220,14 +220,195 @@ def test_the_cell_resolves_the_scattered_exchange_and_pads_the_columns(
     assert lr.hist_exchange == "psum_scatter"
     assert lr.bins_dev.shape == (68, DEVICES * 5_003)
     assert str(lr.bins_dev.dtype) == "int32"
-    score = bst._gbdt.train_score.score
-    assert len(score.sharding.device_set) == DEVICES      # replicated
-    assert score.sharding.is_fully_replicated
+    # the train score lies where the store's rows lie: the learner's row
+    # sharding, a quarter of the padded rows on each device; `score` is
+    # the view of the real rows that every reader outside the hot path
+    # takes
+    su, layout = bst._gbdt.train_score, lr.row_layout
+    assert (layout.num_data, layout.num_rows) == (ROWS, lr.Np)
+    assert su.rows.shape == (1, lr.Np)
+    assert su.rows.sharding.is_equivalent_to(layout.sharding_of(2), 2)
+    assert lr.bins_dev.sharding.spec[1] == layout.sharding.spec[0]
+    assert ([s.data.shape for s in su.rows.addressable_shards]
+            == [(1, lr.Np // DEVICES)] * DEVICES)
+    assert su.score.shape == (1, ROWS)
     # tree 1 of the int8 path is the one-device int8 learner's: at a
     # score of 0 the gradients are +-0.5 and the hessians 0.25 on every
     # shard, which each shard's own scale takes to +-127 exactly
     one, _ = trained("cell_one")
     assert_same_trees(bst._gbdt.models[:1], one._gbdt.models[:1])
+
+
+# ---- the train score in the learner's row layout -----------------------------
+
+@pytest.mark.parametrize("name", ["cell_one", "cell"])
+def test_the_score_view_is_the_models_margin_of_every_train_row(
+        rows, trained, name):
+    """After five iterations the [K, N] view of the padded (and, on the
+    mesh, sharded) score is what the model predicts for the training
+    rows, and no update fetched a leaf id from another device."""
+    X, _ = rows
+    bst, moved = trained(name, 5)
+    su = bst._gbdt.train_score
+    assert len(su.rows.sharding.device_set) == (DEVICES if name == "cell"
+                                                else 1)
+    assert su.score.shape == (1, ROWS)
+    np.testing.assert_allclose(np.asarray(su.score)[0],
+                               bst.predict(X, raw_score=True),
+                               rtol=0, atol=1e-6)
+    np.testing.assert_array_equal(su.get()[0], np.asarray(su.score)[0])
+    assert moved["tree/score_gather_rows"] == 0     # registered, and 0
+
+
+def test_padded_rows_carry_no_gradient_and_move_no_scale():
+    """A shard's int8 scale is its largest |g| and |h| over every row it
+    is handed, so the gradient program zeroes the padded rows whatever
+    their score holds: at score 3 and label 0 a padded row's gradient
+    would lead the shard."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.config import config_from_params
+    from lightgbm_tpu.dataset import Metadata
+    from lightgbm_tpu.jaxutil import RowLayout
+    from lightgbm_tpu.objectives import create_objective
+    from lightgbm_tpu.ops.histogram import quantize_gh
+    n, n_pad = 1_003, 1_024
+    mesh = Mesh(np.asarray(jax.devices()[:DEVICES]), ("data",))
+    layout = RowLayout(n, n_pad, NamedSharding(mesh, P("data")))
+    rng = np.random.RandomState(0)
+    meta = Metadata(n)
+    meta.label = (rng.rand(n) < 0.03).astype(np.float32)
+    meta.weights = rng.rand(n).astype(np.float32) + 0.5
+    obj = create_objective(config_from_params({"objective": "binary"}))
+    obj.init(meta, n, layout)
+    assert obj.label.shape == obj.weights.shape == (n_pad,)
+    assert obj.label.sharding.is_equivalent_to(layout.sharding, 1)
+    score = np.full((1, n_pad), 3.0, np.float32)
+    score[0, :n] = rng.randn(n)
+    g, h = obj.get_gradients(layout.place(score))
+    assert g.shape == h.shape == (1, n_pad)
+    assert g.sharding.is_equivalent_to(layout.sharding_of(2), 2)
+    g, h = np.asarray(g), np.asarray(h)
+    assert not g[0, n:].any() and not h[0, n:].any()        # exactly 0.0
+    assert np.abs(g[0, :n]).min() > 0
+
+    plain = create_objective(config_from_params({"objective": "binary"}))
+    plain.init(meta, n)
+    g1, h1 = plain.get_gradients(jnp.asarray(score[:, :n]))
+    np.testing.assert_array_equal(g[:, :n], np.asarray(g1))
+    np.testing.assert_array_equal(h[:, :n], np.asarray(h1))
+
+    def scales(g, h):
+        gh8 = jnp.zeros((8, g.shape[1]), jnp.float32).at[0].set(
+            g[0]).at[1].set(h[0])
+        return [float(s) for s in quantize_gh(gh8)[1:]]
+    assert scales(g, h) == scales(g[:, :n], h[:, :n])
+
+
+def test_no_program_of_an_iteration_but_the_build_crosses_devices(trained):
+    """Gradients, shrinkage, the score update and the [K, N] view's
+    readers aside, everything an iteration runs outside
+    jit_build_tree_rounds is per-row work on the device that holds the
+    row: compiled for the four devices, none holds a collective."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.boosting.score_updater import (
+        _add_const_to_row, _add_leaf_to_row_sharded, select_class_row,
+        shrink_clip_leaves)
+    from lightgbm_tpu.learner.fused import pack_tree_arrays
+    bst, _ = trained("cell")
+    g = bst._gbdt
+    su, obj, layout = g.train_score, g.objective, g.learner.row_layout
+    assert obj.layout is layout and su.layout is layout
+    ids = jax.ShapeDtypeStruct((layout.num_rows,), jnp.int32,
+                               sharding=layout.sharding)
+    mask, fmask = g.learner._masks(None)
+    assert mask.sharding.is_equivalent_to(layout.sharding, 1)
+    grad, hess = g.boosting_gradients()
+    _, leaf_id, arrs = g.learner.train_device(grad.reshape(-1),
+                                              hess.reshape(-1))
+    assert leaf_id.sharding.is_equivalent_to(layout.sharding, 1)
+    update = _add_leaf_to_row_sharded(layout.sharding, 0)
+    programs = {
+        "gradients": obj._f.lower(su.rows, obj.label, obj.weights),
+        "class_row": select_class_row.lower(grad, k=0),
+        "flat": jax.jit(lambda x: x.reshape(-1)).lower(grad),
+        "constant": _add_const_to_row.lower(su.rows, g._shrink_dev(),
+                                            tree_id=0),
+        "shrink": shrink_clip_leaves.lower(arrs.leaf_value, arrs.num_leaves,
+                                           g._shrink_dev()),
+        "update": update.lower(su.rows, ids, arrs.leaf_value),
+        "pack": jax.jit(pack_tree_arrays).lower(arrs),
+    }
+    for name, lowered in programs.items():
+        text = lowered.compile().as_text()
+        for op in ("all-gather", "all-reduce", "all-to-all",
+                   "collective-permute"):
+            assert op not in text, (name, op)
+    assert "add_leaf_to_row_sharded" in programs["update"].as_text()
+    assert not programs["update"].as_text().startswith(
+        "module @jit_build_tree")
+
+
+def _logistic(preds, train_set):
+    p = 1.0 / (1.0 + np.exp(-preds))
+    y = train_set.get_label()
+    return p - y, p * (1.0 - p)
+
+
+@pytest.mark.parametrize("name,params,iters", [
+    # learning_rate 0.5: GOSS samples from its third iteration on
+    ("goss", dict(boosting="goss", learning_rate=0.5, top_rate=0.3,
+                  other_rate=0.2), 4),
+    ("bagging", dict(bagging_fraction=0.6, bagging_freq=1), 3),
+    ("dart", dict(boosting="dart", drop_rate=0.6, skip_drop=0.0), 4),
+    ("fobj", dict(), 3),
+    ("multiclass", dict(objective="multiclass", num_class=3), 2),
+    ("rollback", dict(), 3),
+])
+def test_every_other_path_still_trains_on_the_mesh(rows, name, params,
+                                                   iters):
+    """The paths that read or write the score through its [K, N] view —
+    GOSS's selection over the real rows, the out-of-bag walk, DART's drop
+    and re-add, a custom objective's host gradients, K > 1, a rollback —
+    on four devices and on one: the view is the model's margin on every
+    training row on both, and the two models agree."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.learner.rounds import RoundsTreeLearner
+    X, y = rows
+    X, y = X[:4_003, 13:29], y[:4_003]
+    if name == "multiclass":    # three classes of two columns and noise
+        z = (X[:, 0] / X[:, 0].std() + X[:, 1] / X[:, 1].std()
+             + 0.5 * np.random.RandomState(3).randn(len(X)))
+        y = np.digitize(z, np.quantile(z, [1 / 3, 2 / 3])).astype(np.float32)
+    base = {**dict(objective="binary", num_leaves=7, min_data_in_leaf=100,
+                   learning_rate=0.1, tree_growth="rounds", verbose=-1,
+                   histogram_dtype="float32"), **params}
+    margins = {}
+    for side, extra in (("one", {}), ("four", dict(tree_learner="data",
+                                                   num_machines=DEVICES))):
+        bst = lgb.Booster({**base, **extra}, lgb.Dataset(X, y))
+        for _ in range(iters):
+            bst.update(fobj=_logistic if name == "fobj" else None)
+        if name == "rollback":
+            bst.rollback_one_iter()
+        g = bst._gbdt
+        g._flush_pending()
+        assert isinstance(g.learner, RoundsTreeLearner)
+        assert (g.learner.mesh is not None) == (side == "four")
+        su = g.train_score
+        assert su.rows.shape == (g.K, g.learner.Np)
+        assert len(su.rows.sharding.device_set) == (
+            DEVICES if side == "four" else 1)
+        assert g.current_iteration() == iters - (name == "rollback")
+        assert all(t.num_leaves > 1 for t in g.models[-g.K:])
+        raw = bst.predict(X, raw_score=True)
+        np.testing.assert_allclose(np.asarray(su.score).T.squeeze(), raw,
+                                   rtol=0, atol=2e-6)
+        margins[side] = raw
+    np.testing.assert_allclose(margins["four"], margins["one"], rtol=0,
+                               atol=1e-5)
 
 
 def test_exchange_counters_on_four_devices_and_on_one(trained):
@@ -331,6 +512,11 @@ def test_on_the_chip_the_sharded_store_is_72_columns_and_copies_nothing(
             np.testing.assert_array_equal(np.asarray(getattr(arrs, name)),
                                           np.asarray(getattr(one, name)),
                                           name)
-        np.testing.assert_array_equal(np.asarray(lid), np.asarray(lid1))
+        # leaf ids come back in each learner's row layout: four shards
+        # padded to the chunk on their own, one padded as a whole
+        assert lid.shape == (lr.Np,) and lid1.shape == (n + (-n) % CHUNK,)
+        assert lid.sharding.is_equivalent_to(lr.row_layout.sharding, 1)
+        np.testing.assert_array_equal(np.asarray(lid)[:n],
+                                      np.asarray(lid1)[:n])
     finally:
         jax.clear_caches()

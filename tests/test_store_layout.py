@@ -138,13 +138,15 @@ def _problem(n, f, max_bin, operands, seed):
 
 def _build(learner, g, h, bag=None):
     """One tree from the learner's own program: (TreeArrays, leaf ids
-    as the caller gets them, how far the tree/ counters moved)."""
+    of the real rows, how far the tree/ counters moved).  The ids come
+    back in the learner's row layout, padded rows at the tail."""
     before = profiling.counters("tree/")
     _, lid, arrs = learner.train_device(
         g, h, bag, None if bag is None else len(bag))
     after = profiling.counters("tree/")
-    return arrs, np.asarray(lid), {k: after[k] - before.get(k, 0.0)
-                                   for k in after}
+    assert lid.shape == (learner.row_layout.num_rows,) == (learner.Np,)
+    return arrs, np.asarray(lid)[: learner.N], {
+        k: after[k] - before.get(k, 0.0) for k in after}
 
 
 def _same_arrays(a, b):

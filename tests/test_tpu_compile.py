@@ -325,20 +325,63 @@ def test_binned_traversal_int16_record(shape):
 
 def test_score_update_with_row_sharded_leaf_ids(topo, monkeypatch):
     """The data-parallel learner returns leaf ids sharded over its mesh and
-    the score update runs under plain jit: with the Mosaic lookup XLA
-    refuses ("cannot be automatically partitioned" — what stopped the first
-    four-chip run), with table_lookup(spmd=True) it compiles.  The backend
-    question is steered here; the program has no option for it."""
+    the train score lies in the same row layout, so the update is one
+    shard_map over that mesh: each chip runs the Mosaic lookup (which XLA
+    cannot partition under plain jit: "cannot be automatically
+    partitioned" is what stopped the first four-chip run) over its own
+    13.5M ids and adds to its own shard, with no collective.  Compiled at
+    `criteo_tb.data4`'s shapes, beside the row-local programs of an
+    iteration on the same arrays: the gradients (padded rows masked) and
+    the [K, N] view that the closing fetch reads.  Where the ids are
+    sharded and the score is not (a learner that reports no layout), the
+    XLA lookup still compiles.  The backend question is steered here; the
+    program has no option for it."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    from lightgbm_tpu.boosting.score_updater import _add_leaf_to_row_jit
+    from lightgbm_tpu.boosting.score_updater import (
+        _add_leaf_to_row_jit, _add_leaf_to_row_sharded)
+    from lightgbm_tpu.config import config_from_params
+    from lightgbm_tpu.jaxutil import RowLayout, slice_rows_dev
+    from lightgbm_tpu.objectives import create_objective
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = Mesh(np.asarray(topo.devices).reshape(4), ("data",))
     rows, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
-    n = 10_500_000
-    args = (jax.ShapeDtypeStruct((1, n), jnp.float32, sharding=rep),
-            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=rows),
-            jax.ShapeDtypeStruct((255,), jnp.float32, sharding=rep))
+    n, n_pad = 54_000_000, 54_001_664
+    score = jax.ShapeDtypeStruct((1, n_pad), jnp.float32,
+                                 sharding=NamedSharding(mesh, P(None, "data")))
+    ids = jax.ShapeDtypeStruct((n_pad,), jnp.int32, sharding=rows)
+    values = jax.ShapeDtypeStruct((255,), jnp.float32, sharding=rep)
+
+    def no_collective(text):
+        return not any(op in text for op in (
+            "all-gather", "all-reduce", "all-to-all", "collective-permute"))
+
+    update = _add_leaf_to_row_sharded(rows, 0).lower(
+        score, ids, values).compile()
+    text = update.as_text()
+    assert "tpu_custom_call" in text and no_collective(text)
+    assert "add_leaf_to_row_sharded" in text.split("\n", 1)[0]
+    # a shard of the score in, a shard out, and the lookup's [8, rows]
+    # block between them: nothing the size of all 54M rows on a chip
+    assert update.memory_analysis().temp_size_in_bytes < 8 * 4 * n_pad // 4 * 2
+
+    obj = create_objective(config_from_params({"objective": "binary"}))
+    obj.num_data, obj.layout = n, RowLayout(n, n_pad, rows)
+    obj.weights = None
+
+    def f(score, label):
+        is_p = label[None, :] > 0
+        return jnp.where(is_p, score, -score), jnp.abs(score)
+    grads = obj._jit_gradients(f).lower(
+        score, jax.ShapeDtypeStruct((n_pad,), jnp.float32,
+                                    sharding=rows)).compile()
+    assert no_collective(grads.as_text())
+
+    slice_rows_dev.lower(score, n=n).compile()     # the [K, N] view
+
+    flat = (jax.ShapeDtypeStruct((1, n), jnp.float32, sharding=rep),
+            jax.ShapeDtypeStruct((n,), jnp.int32, sharding=NamedSharding(
+                mesh, P("data"))), values)
     with pytest.raises(NotImplementedError, match="shard_map"):
-        _add_leaf_to_row_jit.lower(*args, tree_id=0, spmd=False)
-    _add_leaf_to_row_jit.lower(*args, tree_id=0, spmd=True).compile()
+        _add_leaf_to_row_jit.lower(*flat, tree_id=0, spmd=False)
+    _add_leaf_to_row_jit.lower(*flat, tree_id=0, spmd=True).compile()

@@ -38,7 +38,7 @@ def _compiled_build(mesh):
     g = jnp.zeros(len(y), jnp.float32)
     mask, fmask = lr._masks(None)
     return lr._build.lower(
-        lr.bins_dev, lr._pad_rows(g), lr._pad_rows(g), mask,
+        lr.bins_dev, lr._rows_in(g), lr._rows_in(g), mask,
         lr.num_bins_dev, lr.is_cat_dev, fmask).compile().as_text()
 
 
