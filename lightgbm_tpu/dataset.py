@@ -26,7 +26,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .binning import (BinMapper, BundlePlan, find_bin_mappers,
-                      plan_bundles, CATEGORICAL)
+                      pack_bundle_column, plan_bundles, CATEGORICAL)
 from .config import Config
 
 # ----------------------------------------------------------------------------
@@ -840,14 +840,38 @@ class Dataset:
             X, self.mappers, self.used_features, self.bundle_plan,
             self.bins, row0)
 
-    def _bin_column_into(self, k: int, values: np.ndarray) -> None:
-        """Bin ONE used feature's full raw column into the store — the
-        column-streaming entry the scipy-CSC path uses so the dense
-        [N, F] matrix never materializes."""
-        from .quantize import bin_column_into
-        self.bundle_conflict_rows += bin_column_into(
-            k, values, self.mappers, self.used_features,
-            self.bundle_plan, self.bins)
+    def _bin_csc_into(self, indptr, indices, data) -> None:
+        """Fill the dense store from scipy CSC arrays by the STORED
+        entries alone: every store column starts at its zero bin (what
+        an absent cell bins to: the member's default bin, 0 for a packed
+        column), then each used feature, in inner order, bins
+        `data[s:e]` and writes the result at rows `indices[s:e]` under
+        quantize.bin_feature_column's rule — a singleton column takes
+        the bin, a packed member goes through binning.pack_bundle_column
+        over the column's cells at those rows (offset + slot where its
+        bin is not the default, last writer wins, rows already non-zero
+        counted as conflicts).  O(nnz + C x N) where a dense scratch column per
+        feature was O(F x N), and bitwise the same store and conflict
+        count: absent cells bin to the default either way, and a stored
+        0.0 or NaN goes through the same `value_to_bin`."""
+        plan, used, store = self.bundle_plan, self.used_features, self.bins
+        store[...] = store_zero_bins(self.mappers, used, plan)[:, None]
+        for k, i in enumerate(used):
+            s, e = int(indptr[i]), int(indptr[i + 1])
+            if s == e:
+                continue
+            rows = indices[s:e]
+            b = self.mappers[i].value_to_bin(data[s:e])
+            if plan is None or not plan.feat_packed[k]:
+                out = store[k if plan is None else int(plan.feat_col[k])]
+                out[rows] = b.astype(store.dtype)
+                continue
+            out = store[int(plan.feat_col[k])]
+            at_rows = out[rows]
+            self.bundle_conflict_rows += pack_bundle_column(
+                b, int(plan.feat_default[k]), int(plan.feat_offset[k]),
+                at_rows)
+            out[rows] = at_rows
 
     # -- streaming append path (online ingestion; ROADMAP items 1 + 5) ------
     #
@@ -1001,10 +1025,11 @@ class Dataset:
         sparse, the CSR/ELL store is built DIRECTLY from the CSC
         columns — one dense scratch column at a time, entries extracted
         per store column, so peak memory is sample + one column + the
-        nnz-scaled store.  Otherwise (the dense fallback) each column is
-        densified one at a time and binned into the dense [C, N] store,
-        which still avoids the full N×F float64 matrix but pays the
-        dense store's memory and histogram cost."""
+        nnz-scaled store.  Otherwise (the dense store, bundled or not)
+        the columns' stored entries alone are binned and written into
+        the [C, N] store over its zero bins (_bin_csc_into): set-up
+        costs by the non-zeros, and neither the N×F matrix nor a dense
+        column of it ever exists."""
         sp = sp_matrix.tocsc()
         n, num_raw = sp.shape
         # ---- dense row sample for FindBin ---------------------------------
@@ -1043,13 +1068,7 @@ class Dataset:
                                                       plan):
             ds._build_sparse_from_csc(indptr, indices, data)
         else:
-            # ---- stream one dense column at a time ----------------------
-            col = np.empty(n, np.float64)
-            for k, i in enumerate(used):
-                col[:] = 0.0
-                s, e = int(indptr[i]), int(indptr[i + 1])
-                col[indices[s:e]] = data[s:e]
-                ds._bin_column_into(k, col)
+            ds._bin_csc_into(indptr, indices, data)
         ds._check_realized_conflicts()
         md = metadata or Metadata()
         if label is not None:

@@ -757,6 +757,22 @@ def _jit_build(fn):
     return jax.jit(step)
 
 
+def search_counters(totals, trees, *, cells, slots_per_pass, bundled):
+    """tree/split_cells and tree/unbundle_gather_elems of `trees` builds
+    whose stats vectors sum to `totals`, on the host (count_deferred's
+    `fold`): both are static per searched slot, and the vector already
+    counts what is dynamic.  A tree searches its root's one slot and,
+    per executed chunk of a round, the chunk's slots twice (smaller and
+    larger children, live or not); the root is one of tree/hist_passes
+    and every other pass is `slots_per_pass` searched slots.  `cells` is
+    one slot's [features, B] as the search scans it; an unbundle in
+    front of it gathers that three times over (grad, hess, count)."""
+    slots = trees + slots_per_pass * (float(totals[S_PASSES]) - trees)
+    return ((profiling.SPLIT_CELLS, cells * slots),
+            (profiling.UNBUNDLE_GATHER_ELEMS,
+             3.0 * cells * slots if bundled else 0.0))
+
+
 class RoundsTreeLearner:
     """Single- or data-parallel learner using batched-rounds growth."""
 
@@ -945,6 +961,7 @@ class RoundsTreeLearner:
                   num_feature_shards=self.df,
                   ftbl=ftbl, unb=unb, sparse=self.sparse,
                   input_dtype=input_dtype)
+        self._fold_stats = self._search_counters(plan is not None)
         if mesh is None:
             self._build = _jit_build(
                 functools.partial(build_tree_rounds, **kw))
@@ -992,6 +1009,28 @@ class RoundsTreeLearner:
         # (nbv/icv already carry the int8 feature padding)
         self.num_bins_dev = nbv if self.mh is not None else jnp.asarray(nbv)
         self.is_cat_dev = icv if self.mh is not None else jnp.asarray(icv)
+
+    def _search_counters(self, bundled: bool):
+        """profiling.count_deferred's `fold` for this learner's build:
+        search_counters over its static shapes.  The search runs over
+        [features, B]: every original feature after an unbundle, else
+        the store's padded columns, a device's slice of them under
+        psum_scatter.  Chunks that can execute are all K = min(leaves
+        per batch, num_leaves) wide: a round splits at most half the
+        leaf slots, so a short last chunk never holds one; an executed
+        chunk is one histogram pass with the parent cache, two
+        without."""
+        K = min(LEAVES_PER_BATCH, int(self.config.num_leaves))
+        if bundled:
+            feats = self.F
+        elif self.hist_exchange == "psum_scatter" and self.dd * self.df > 1:
+            feats = self.Fpad // self._nd_sc
+        else:
+            feats = self.Fpad
+        return functools.partial(
+            search_counters, cells=float(feats * self.B),
+            slots_per_pass=2.0 * K / (1 if self.cache_parent_hist else 2),
+            bundled=bundled)
 
     def _build_sparse_streams(self, cols_np: np.ndarray,
                               ell_np: np.ndarray, nsh: int, backend: str):
@@ -1125,8 +1164,9 @@ class RoundsTreeLearner:
 
     def _record_stats(self, stats) -> None:
         # the whole vector against its counters: one device add per
-        # iteration and one fetch at the drain, no sync here
-        profiling.count_deferred(STATS_COUNTERS, stats)
+        # iteration and one fetch at the drain, no sync here; the two
+        # search counters are folded on the host at that drain
+        profiling.count_deferred(STATS_COUNTERS, stats, self._fold_stats)
 
     def train(self, grad: jax.Array, hess: jax.Array,
               bag_idx: Optional[jax.Array] = None,

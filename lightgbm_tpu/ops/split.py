@@ -141,7 +141,8 @@ def maybe_unbundle(hist: jax.Array, unb, totals: jax.Array) -> jax.Array:
     """unb is None (store is the original layout) or (src, dmask)."""
     if unb is None:
         return hist
-    return unbundle_hist(hist, unb[0], unb[1], totals)
+    with jax.named_scope("lgbt.unbundle"):
+        return unbundle_hist(hist, unb[0], unb[1], totals)
 
 
 def unbundle_hist_local(hist: jax.Array, src: jax.Array, dmask: jax.Array,
@@ -195,7 +196,8 @@ def sharded_slice_search(h, sums, *, off, nb_s, ic_s, fm_s,
                          sums[0], sums[1], sums[2], **skw)
         p = rec.packed()
         return p.at[1].add(jnp.asarray(off).astype(jnp.float32))
-    hF, owned = unbundle_hist_local(h, unb[0], unb[1], sums, off)
+    with jax.named_scope("lgbt.unbundle"):
+        hF, owned = unbundle_hist_local(h, unb[0], unb[1], sums, off)
     rec = best_split(hF, num_bins, is_cat, fmask & owned,
                      sums[0], sums[1], sums[2], **skw)
     return rec.packed()

@@ -46,10 +46,11 @@ _lock = threading.Lock()
 _counters: Dict[str, float] = defaultdict(float)
 _samples: Dict[str, Deque[float]] = {}
 _SAMPLE_CAP = 4096
-# one pending device vector per tuple of names (count_deferred
-# accumulates DEVICE-side, so an arbitrarily long training run holds
-# exactly one live buffer per tuple), folded into _counters on read
-_deferred: Dict[Tuple[str, ...], object] = {}
+# one pending (device vector, calls) per (names, fold) of
+# count_deferred, which accumulates DEVICE-side, so an arbitrarily long
+# training run holds exactly one live buffer per key; folded into
+# _counters on read
+_deferred: Dict[tuple, tuple] = {}
 
 # Canonical counter names of the data-parallel tree learners' comms
 # layer, fed through count_deferred (device-side accumulation, no sync
@@ -105,6 +106,20 @@ SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
 #    and the root's psum of the leaf totals there.  Per device (every
 #    shard launches the same ones); 0 without a mesh.  The closing psum
 #    of this vector's own global slots is not counted.
+#  - SPLIT_CELLS: histogram cells that split search scans, as padded
+#    and as executed: searched slots (1 at the root, twice a chunk's
+#    slots per executed chunk, live or not) x the features of the array
+#    the search runs over x padded bins — the store's padded columns
+#    (a device's slice under psum_scatter) on a store with no bundle
+#    plan, every original feature where a bundled histogram is first
+#    unbundled.  Per device.  Static per pass, so it does not ride the
+#    vector: RoundsTreeLearner folds it on the host from HIST_PASSES
+#    (learner/rounds.search_counters, count_deferred's `fold`); the
+#    other learners do not count it.
+#  - UNBUNDLE_GATHER_ELEMS: histogram elements that the unbundle in
+#    front of that search gathers through its [F, B] index table
+#    (ops/split.unbundle_hist): 3 x F x B per searched slot; 0 on a
+#    store with no plan.  Folded the same way.
 TREE_ROUNDS = "tree/rounds"
 HIST_PASSES = "tree/hist_passes"
 HIST_SLOTS = "tree/hist_slots"
@@ -113,6 +128,8 @@ HIST_MXU_OPS = "tree/hist_mxu_ops"
 PARTITION_ROWS = "tree/partition_rows"
 STORE_COPY_ROWS = "tree/store_copy_rows"
 EXCHANGE_COLLECTIVES = "tree/exchange_collectives"
+SPLIT_CELLS = "tree/split_cells"
+UNBUNDLE_GATHER_ELEMS = "tree/unbundle_gather_elems"
 # Outside the build, counted on the host by count() at each leaf-id
 # update of a score (boosting/score_updater._add_leaf_to_row):
 #  - SCORE_GATHER_ROWS: rows whose leaf ids that update has to fetch
@@ -248,7 +265,7 @@ CANONICAL_COUNTERS = (
     HIST_ROWS_DOWNGRADES, TREE_ROUNDS, HIST_PASSES, HIST_SLOTS,
     HIST_LIVE_SLOTS, HIST_MXU_OPS, FEED_ROWS, FEED_LIVE_ROWS,
     PARTITION_ROWS, STORE_COPY_ROWS, EXCHANGE_COLLECTIVES,
-    SCORE_GATHER_ROWS,
+    SPLIT_CELLS, UNBUNDLE_GATHER_ELEMS, SCORE_GATHER_ROWS,
     SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,
@@ -316,19 +333,28 @@ def count(name: str, inc: float = 1.0) -> None:
         _counters[name] += inc
 
 
-def count_deferred(names: Tuple[str, ...], values) -> None:
+def count_deferred(names: Tuple[str, ...], values, fold=None) -> None:
     """Accumulate a DEVICE vector against a tuple of counters, one
     element per name, without forcing a host sync (the pipelined
     trainer must not stall on a metrics fetch — the device→host
     transfer that motivates _train_one_iter_pipelined).  Accumulation
     happens device-side (`+` dispatches asynchronously): one device add
-    per call and one live buffer per tuple of names; the totals are
+    per call and one live buffer per (names, fold); the totals are
     converted and folded into the counters on the next
     counter_value()/counters() read, where the caller has chosen to pay
-    the sync."""
+    the sync.
+
+    `fold(totals, calls)`, if given, is called on the host at that read
+    with the fetched totals and the number of calls they sum, and
+    returns `(name, increment)` pairs: counters that are a function of
+    what the vector already counts cost the device nothing.  It is part
+    of the key, so hand over the same object every time, and one that
+    holds no device memory."""
+    key = (names, fold)
     with _lock:
-        prev = _deferred.get(names)
-        _deferred[names] = values if prev is None else prev + values
+        prev = _deferred.get(key)
+        _deferred[key] = ((values, 1) if prev is None
+                          else (prev[0] + values, prev[1] + 1))
 
 
 def _drain_deferred_locked() -> None:
@@ -339,11 +365,13 @@ def _drain_deferred_locked() -> None:
     if not _deferred:
         return
     import jax
-    keys = list(_deferred)
-    vals = jax.device_get([_deferred[k] for k in keys])
-    for names, vec in zip(keys, vals):
+    pending = list(_deferred.items())
+    vals = jax.device_get([total for _, (total, _) in pending])
+    for ((names, fold), (_, calls)), vec in zip(pending, vals):
         for name, v in zip(names, vec):
             _counters[name] += float(v)
+        for name, inc in (fold(vec, calls) if fold is not None else ()):
+            _counters[name] += inc
     _deferred.clear()
 
 

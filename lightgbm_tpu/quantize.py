@@ -5,7 +5,8 @@ three consumers so the mapper application can never drift between
 training and serving (ROADMAP item: binned int8 inference):
 
 - **Dataset construction** (`dataset.Dataset.__init__` / the two-round
-  loader / `from_csc`) and **online ingestion**
+  loader; `from_csc` applies the same mappers and packing rule to a
+  scipy column's stored entries) and **online ingestion**
   (`Dataset.streaming_from` → `append_rows`) both route their row
   chunks through `bin_rows_into` — the TRAIN policy: float64
   searchsorted against the mapper's float64 bounds, NaN mapped to the
@@ -112,27 +113,17 @@ def bin_rows_into(X: np.ndarray, mappers: Sequence[BinMapper],
     return conflicts
 
 
-def bin_column_into(k: int, values: np.ndarray,
-                    mappers: Sequence[BinMapper],
-                    used_features: Sequence[int], plan,
-                    store: np.ndarray) -> int:
-    """Bin ONE used feature's full raw column into the store (the
-    scipy-CSC column-streaming entry).  Returns realized conflicts."""
-    c = k if plan is None else int(plan.feat_col[k])
-    return bin_feature_column(k, values, mappers, used_features, plan,
-                              store[c])
-
-
 def bin_feature_column(k: int, values: np.ndarray,
                        mappers: Sequence[BinMapper],
                        used_features: Sequence[int], plan,
                        out: np.ndarray) -> int:
     """Bin ONE used feature's raw column into the [N] scratch row `out`
-    of its own store column — bin_column_into with the destination row
-    supplied by the caller, so the sparse CSR construction can fill a
+    of its own store column, so the sparse CSR construction can fill a
     per-column scratch without allocating the dense store.  EFB
     last-writer-wins packing semantics are identical to the dense
-    route.  Returns realized bundle conflicts."""
+    route (and to Dataset._bin_csc_into, which applies the same rule
+    to a CSC column's stored entries alone).  Returns realized bundle
+    conflicts."""
     b = mappers[used_features[k]].value_to_bin(values)
     if plan is None or not plan.feat_packed[k]:
         out[:] = b.astype(out.dtype)

@@ -185,6 +185,70 @@ def test_higgs_build_program(shape):
     assert held < 2.2e9, held      # an eighth of the chip
 
 
+def test_allstate_build_program(shape):
+    """The whole build step of the benchmark cell `allstate.full`, as
+    RoundsTreeLearner jits it on the chip: 4,228 one-hot and numeric
+    columns that EFB packs into under 48 store columns (the planner's plan
+    of 60,000 generated rows at the full width; the cell's own has the
+    same shape), 12,184,290 rows laid out as `[48, 12189696]` int32, 255
+    leaves, int8 operands, the per-leaf histogram cache of STORE columns —
+    and every histogram unbundled through `src [F, B]` to `[F, 3, B]` in
+    front of a split search over all F original features: the one build
+    whose search runs on a layout other than the store's.  The store is
+    an argument and nothing else (2.34 GB); the temporaries are the
+    unbundled `[84, F, 3, 256]` histograms of a chunk, 1.09 GB each and
+    several alive at once: between 3 and 7 GB, which with the store and
+    the row vectors is what the cell holds (PERF.md section 4)."""
+    import functools
+    from benchmark.generators import allstate
+    from lightgbm_tpu.config import config_from_params
+    from lightgbm_tpu.dataset import Dataset
+    from lightgbm_tpu.learner.common import make_split_kw
+    from lightgbm_tpu.learner.rounds import build_tree_rounds
+    from lightgbm_tpu.ops.histogram import store_alignment
+    import scipy.sparse as sps
+    cfg = config_from_params({"objective": "binary", "num_leaves": 255,
+                              "min_data_in_leaf": 1, "max_bin": 255,
+                              "min_sum_hessian_in_leaf": 100.0,
+                              "histogram_dtype": "int8", "verbose": -1})
+    X, y = allstate.make(60_000, 4228, (0, 0))
+    ds = Dataset.from_csc(sps.csr_matrix(X), y, cfg)
+    plan, Fo = ds.bundle_plan, ds.num_features
+    assert plan is not None and plan.num_columns <= 48 and Fo > 4000
+    col, row = store_alignment(4, B, "int8", 255)
+    C = plan.num_columns + (-plan.num_columns) % col
+    n = 12_184_290 + (-12_184_290) % row
+    assert (C, n) == (48, 12_189_696)
+    src, dmask = ds.unbundle_tables(B, C)
+    assert src.shape == dmask.shape == (Fo, B)
+    build = functools.partial(
+        build_tree_rounds, ftbl=plan.feat_table(), unb=(src, dmask),
+        num_leaves=255, num_bins_padded=B,
+        max_num_bin=255, split_kw=make_split_kw(cfg), max_depth=-1,
+        min_data_in_leaf=cfg.min_data_in_leaf,
+        min_sum_hessian_in_leaf=cfg.min_sum_hessian_in_leaf,
+        backend="pallas", input_dtype=cfg.histogram_dtype,
+        cache_parent_hist=True)
+    compiled = compile_for_chip(
+        build, shape((C, n), jnp.int32), shape((n,), jnp.float32),
+        shape((n,), jnp.float32), shape((n,), jnp.float32),
+        shape((Fo,), jnp.int32), shape((Fo,), jnp.bool_),
+        shape((Fo,), jnp.bool_))
+    assert store_copies(compiled, plan.num_columns * 12_184_290) == []
+    text = compiled.as_text()
+    assert f"f32[84,{Fo},3,256]" in text or f"f32[84,{Fo},256,3]" in text
+    mem = compiled.memory_analysis()
+    assert 2.3e9 < mem.argument_size_in_bytes < 2.6e9, (
+        mem.argument_size_in_bytes)
+    # read: see PERF.md section 4
+    assert 3e9 < mem.temp_size_in_bytes < 7e9, mem.temp_size_in_bytes
+    held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert held < 10e9, held       # fits the chip's 16 GB with room
+    print("allstate build: temp", mem.temp_size_in_bytes,
+          "args", mem.argument_size_in_bytes)
+
+
 def test_criteo_tb_build_program_on_four_chips(topo):
     """The whole build step of the benchmark cell `criteo_tb.data4` as
     RoundsTreeLearner jits it on a four-chip host: under shard_map over

@@ -314,12 +314,31 @@ def test_count_deferred_vector_drains_like_the_per_name_path(monkeypatch):
     assert fetches == []                              # no sync on the way
     assert profiling.counters_nosync("vec/") == {}    # nor on this read
     assert len(profiling._deferred) == 1              # one live buffer
-    assert isinstance(profiling._deferred[names], jax.Array)
+    assert isinstance(profiling._deferred[(names, None)][0], jax.Array)
     assert profiling.counters("vec/") == per_name == {
         "vec/a": 6.0, "vec/b": 60.0, "vec/c": 600.0}
     assert fetches == [1]                             # one fetch, at the drain
     assert profiling.counter_value("vec/b") == 60.0
     assert fetches == [1]                             # nothing left pending
+    profiling.reset()
+
+
+def test_count_deferred_folds_on_the_host_at_the_drain():
+    """A counter that is a function of what the vector counts: computed
+    from the fetched totals and the number of calls, no device work."""
+    seen = []
+
+    def fold(totals, calls):
+        seen.append((list(map(float, totals)), calls))
+        return (("vec/twice_b_less_calls", 2.0 * totals[1] - calls),)
+
+    profiling.reset()
+    for v in ([1.0, 10.0], [2.0, 20.0], [3.0, 30.0]):
+        profiling.count_deferred(("vec/a", "vec/b"), jnp.asarray(v), fold)
+    assert seen == [] and len(profiling._deferred) == 1
+    assert profiling.counters("vec/") == {
+        "vec/a": 6.0, "vec/b": 60.0, "vec/twice_b_less_calls": 117.0}
+    assert seen == [([6.0, 60.0], 3)]
     profiling.reset()
 
 
@@ -332,8 +351,11 @@ def test_the_learner_feeds_every_stats_counter_as_one_vector():
                        "min_data_in_leaf": 5, "tree_growth": "rounds"},
                       lgb.Dataset(X, y))
     bst.update()
-    assert list(profiling._deferred) == [rounds.STATS_COUNTERS]
-    assert profiling._deferred[rounds.STATS_COUNTERS].shape == (12,)
+    (names, fold), = list(profiling._deferred)
+    assert names == rounds.STATS_COUNTERS
+    # what is static per pass rides no slot: folded on the host
+    assert fold.func is rounds.search_counters
+    assert profiling._deferred[(names, fold)][0].shape == (12,)
     got = profiling.counters("tree/")
     assert set(got) >= set(rounds.STATS_COUNTERS)
     # the benchmark's feed_rows_per_iter reads this name; no launch
