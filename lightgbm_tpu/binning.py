@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -303,9 +303,30 @@ def find_bin_from_distinct(vals: np.ndarray, counts: np.ndarray,
 # [offset_f, offset_f + num_bin_f - 1).  Slot packing removes the default
 # bin from the middle of the range but keeps the bin ORDER, so a numerical
 # threshold maps to one contiguous slot interval (ops/split.py
-# bundle_predicate_params) and histograms unbundle by gather + a
-# total-minus-sum reconstruction of the default bin.
+# bundle_predicate_params), and a slot cell of a bundle's histogram is
+# one candidate threshold of one member: split search runs over the
+# store histogram's own cells (BundlePlan.search_tables), or — the
+# plain learner, and a plan that packs a categorical feature —
+# unbundles by gather + a total-minus-sum reconstruction of the
+# default bin (unbundle_tables).
 # ----------------------------------------------------------------------------
+
+class StoreCells(NamedTuple):
+    """Split search's view of a bundled store histogram [C, B], cell by
+    cell (BundlePlan.search_tables; ops/split.best_split_in_store).  A
+    bundle's slots are its members' non-default bins in bin order, so a
+    slot cell stands for one candidate threshold of one original
+    feature, and the left child's sums are a sum of cells of the same
+    member: a prefix ending at the cell (bins below the default bin) or
+    a suffix starting there (bins above it, left = leaf totals less
+    the suffix, the default rows going left)."""
+    feat: np.ndarray    # [C, B] int32 original feature; -1: no candidate
+    thr: np.ndarray     # [C, B] int32 original threshold bin
+    suffix: np.ndarray  # [C, B] bool  left sums = totals - suffix sum
+    lo: np.ndarray      # [C, B] int32 first cell of the prefix (own index
+    #                     where the cell is no prefix candidate)
+    hi: np.ndarray      # [C, B] int32 last cell of the suffix (likewise)
+
 
 @dataclass
 class BundlePlan:
@@ -387,6 +408,56 @@ class BundlePlan:
             if d < nb:
                 dmask[k, d] = True
         return src, dmask
+
+    def search_tables(self, num_bins: np.ndarray, is_cat: np.ndarray,
+                      B: int, num_columns_padded: int = 0
+                      ) -> Optional[StoreCells]:
+        """Per-cell tables over the padded store histogram [C, B] for a
+        split search in the store's own cells, with no [F, 3, B] array
+        (StoreCells; num_columns_padded as in unbundle_tables).
+
+        Original bin b != d of packed member k sits at slot
+        off_k + b - (b > d_k): the nb - 1 slots of a member are its
+        nb - 1 thresholds.  Bin b < d is threshold b, its left sums the
+        member's slots up to it; bin b > d is threshold b - 1, its left
+        sums the leaf totals less the member's slots from it on.  A
+        column of its own is one prefix over bins 0 .. nb - 2 if
+        numerical, and every bin by itself if categorical (one against
+        the rest).  Slot 0 of a packed column, bins past a column's last
+        slot and padded columns are no candidate.
+
+        None where the plan packs a categorical feature: its candidate
+        on the default bin has no slot, and callers keep the gather
+        (unbundle_tables) for such a plan."""
+        is_cat = np.asarray(is_cat, bool)
+        if np.any(self.feat_packed & is_cat):
+            return None
+        C = max(self.num_columns, int(num_columns_padded))
+        own = np.tile(np.arange(B, dtype=np.int32), (C, 1))
+        feat = np.full((C, B), -1, np.int32)
+        thr = np.zeros((C, B), np.int32)
+        suffix = np.zeros((C, B), bool)
+        lo, hi = own.copy(), own.copy()
+        for k in range(self.num_features):
+            nb = int(num_bins[k])
+            col = int(self.feat_col[k])
+            if not self.feat_packed[k]:
+                n = nb if is_cat[k] else nb - 1
+                feat[col, :n] = k
+                thr[col, :n] = own[col, :n]
+                if not is_cat[k]:
+                    lo[col, :n] = 0
+                continue
+            d = int(self.feat_default[k])
+            off = int(self.feat_offset[k])
+            below = off + np.arange(0, min(d, nb))       # bins b < d
+            above = off + np.arange(d, nb - 1)           # bins b > d
+            feat[col, off:off + nb - 1] = k
+            thr[col, off:off + nb - 1] = np.arange(nb - 1)
+            lo[col, below] = off
+            suffix[col, above] = True
+            hi[col, above] = off + nb - 2
+        return StoreCells(feat, thr, suffix, lo, hi)
 
 
 def plan_bundles(sample_bins: np.ndarray, num_bins: np.ndarray,

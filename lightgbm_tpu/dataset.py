@@ -1158,6 +1158,18 @@ class Dataset:
                                                 num_bins_padded,
                                                 num_columns_padded)
 
+    def search_tables(self, num_bins_padded: int,
+                      num_columns_padded: int = 0):
+        """StoreCells for a split search in the bundled store's own
+        cells (BundlePlan.search_tables), or None: no plan, or a plan
+        that packs a categorical feature, which is searched through
+        unbundle_tables."""
+        if self.bundle_plan is None:
+            return None
+        return self.bundle_plan.search_tables(
+            self.num_bins, self.is_categorical, num_bins_padded,
+            num_columns_padded)
+
     def unbundled_bins(self) -> np.ndarray:
         """Materialize the ORIGINAL [num_features, N] per-feature store
         from the bundled columns (feature-sharded learners need per-

@@ -44,12 +44,13 @@ from .common import (make_split_kw, padded_bin_count, sentinel_bins_t,
 from ..jaxutil import bag_mask_dev, pad_rows_dev, slice_rows_dev
 from ..ops.histogram import histogram_full_masked, histogram_full_sparse
 from ..ops.predict import sparse_bin_lookup
-from ..ops.split import (best_split, bundle_predicate_params,
-                         combine_sharded_records, identity_feat_table,
-                         leaf_output, maybe_unbundle, sharded_slice_search,
-                         store_go_left)
+from ..ops.split import (best_split, best_split_in_store,
+                         bundle_predicate_params, combine_sharded_records,
+                         identity_feat_table, leaf_output, maybe_unbundle,
+                         sharded_slice_search, store_go_left,
+                         store_search_operands)
 from ..tree import Tree, NUMERICAL_DECISION, CATEGORICAL_DECISION
-from ..binning import CATEGORICAL
+from ..binning import CATEGORICAL, StoreCells
 
 NEG_INF = -jnp.inf
 
@@ -102,9 +103,11 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
     num_bins/is_cat/fmask : per-ORIGINAL-feature metadata for this shard
     ftbl     : [5, F] feature→(col, offset, default, nslots, packed) table
                (identity when the store is unbundled)
-    unb      : None, or (src, dmask) unbundle-gather tables — then the
-               store is bundled (single feature shard only) and every
-               histogram is unbundled before split search
+    unb      : None, or what split search takes a BUNDLED store's
+               histogram through (single feature shard only): a
+               binning.StoreCells — searched in the store's own cells —
+               or the (src, dmask) gather tables of a plan that packs a
+               categorical feature, unbundled before split search
     Returns (TreeArrays, leaf_id [Nloc] int32).
     """
     sparse = isinstance(bins, (tuple, list))
@@ -170,6 +173,13 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
         gain = jnp.where(can & jnp.isfinite(p[0]) & (p[0] > 0), p[0], NEG_INF)
         return p.at[0].set(gain)
 
+    # a bundled store searched in its own cells: the feature mask in
+    # cell space once a tree, a device's own columns under psum_scatter
+    in_store = isinstance(unb, StoreCells)
+    if in_store:
+        search = store_search_operands(
+            unb, fmask, jax.lax.axis_index(data_axis) * Fs if hx else 0, Fs)
+
     def find_best(hist, sums):
         """Global best split record given this shard's histogram block
         (the reduce-scattered column slice under psum_scatter) and the
@@ -190,12 +200,16 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
             p = sharded_slice_search(
                 hist, sums, off=off, nb_s=nb_s, ic_s=ic_s, fm_s=fm_s,
                 num_bins=num_bins, is_cat=is_cat, fmask=fmask,
-                unb=unb, skw=skw)
+                unb=search if in_store else unb, skw=skw)
             p = combine_sharded_records(p, data_axis)
         else:
-            rec = best_split(maybe_unbundle(hist, unb, sums),
-                             num_bins, is_cat, fmask,
-                             sums[0], sums[1], sums[2], **skw)
+            if in_store:
+                rec = best_split_in_store(hist, search, sums[0],
+                                          sums[1], sums[2], **skw)
+            else:
+                rec = best_split(maybe_unbundle(hist, unb, sums),
+                                 num_bins, is_cat, fmask,
+                                 sums[0], sums[1], sums[2], **skw)
             p = rec.packed()
             p = p.at[1].add(f_off.astype(jnp.float32))
         if feature_axis is not None:
@@ -626,7 +640,9 @@ class FusedTreeLearner:
         # (shard_map-safe; a few hundred KB at worst)
         if self.use_bundle:
             ftbl = plan.feat_table()
-            unb = dataset.unbundle_tables(self.B, self.Cstore)
+            unb = dataset.search_tables(self.B, self.Cstore)
+            if unb is None:         # the plan packs a categorical feature
+                unb = dataset.unbundle_tables(self.B, self.Cstore)
         else:
             ftbl = np.asarray(identity_feat_table(nb))
             unb = None

@@ -65,9 +65,11 @@ from ..ops.histogram import (hist_multileaf_masked, hist_sparse_multileaf,
                              store_alignment)
 from ..ops.partition import (partition_rows, partition_rows_sparse,
                              partition_store_copy_rows)
-from ..ops.split import (best_split, bundle_predicate_params,
-                         combine_sharded_records, identity_feat_table,
-                         leaf_output, maybe_unbundle, sharded_slice_search)
+from ..binning import StoreCells
+from ..ops.split import (best_split, best_split_in_store,
+                         bundle_predicate_params, combine_sharded_records,
+                         identity_feat_table, leaf_output, maybe_unbundle,
+                         sharded_slice_search, store_search_operands)
 from ..tree import Tree
 
 NEG_INF = -jnp.inf
@@ -199,13 +201,14 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     each device reduces and keeps only its F/num_devices column slice
     (the reference's ReduceScatter ownership model,
     data_parallel_tree_learner.cpp:118-160), runs best-split search on
-    that slice only (bundle-aware: the slice is unbundled per shard via
+    that slice only (bundle-aware: the slice's own cells through its
+    rows of the StoreCells tables, or unbundled per shard via
     ops/split.unbundle_hist_local), then all_gathers the per-leaf
     packed records and combines them (max gain, ties to the smallest
     feature id — ops/split.combine_sharded_records).  Per-device comms
-    drop ~num_devices x always; split-search work drops too on the
-    identity store (the bundled path re-scans the full original-feature
-    layout per shard — EFB already shrank the histogrammed width).  The
+    drop ~num_devices x always; split-search work drops too (but where
+    a bundled store falls back to the gather, which re-scans the full
+    original-feature layout per shard).  The
     parent-histogram cache holds column SLICES in this mode
     (num_devices x less memory).  F must then divide evenly by
     num_devices (callers pad the store).
@@ -225,11 +228,14 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
 
     `bins` holds STORE columns (bundled under EFB); num_bins/is_cat/fmask
     are per-ORIGINAL-feature.  `ftbl` is the [5, F] feature→column table
-    (identity when unbundled) and `unb` the optional unbundle-gather
-    tables — every histogram is unbundled before split search, so split
-    records, TreeArrays, and leaf partitioning all speak original
-    (feature, threshold) space; only partition_rows sees store columns,
-    through the translated store-space predicate.
+    (identity when unbundled) and `unb` what split search takes a
+    bundled histogram through: a binning.StoreCells — the search runs
+    over the store histogram's own cells (ops/split.best_split_in_store)
+    — or, for a plan that packs a categorical feature, the (src, dmask)
+    gather tables that unbundle every histogram to [F, 3, B] first.
+    Either way split records, TreeArrays, and leaf partitioning all
+    speak original (feature, threshold) space; only partition_rows sees
+    store columns, through the translated store-space predicate.
 
     cache_parent_hist=False bounds tree-state memory (the analog of the
     reference HistogramPool cap, feature_histogram.hpp:313-475): instead
@@ -391,16 +397,27 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             binsf, lid_, gh8, sl_, num_bins_padded=B, backend=backend,
             input_dtype=input_dtype, max_num_bin=max_num_bin, ghq=ghq)
 
+    # a bundled store searched in its own cells: the tree's feature
+    # mask reaches cell space here, once, not once a searched slot; a
+    # device under psum_scatter takes its own columns' rows of the tables
+    in_store = isinstance(unb, StoreCells)
+    if in_store:
+        with jax.named_scope("lgbt.root"):
+            search = store_search_operands(
+                unb, fmask, jax.lax.axis_index(sc_axis) * Fs if hx else 0,
+                Fs)
+
     def find_best_batch(hists, sums):
         """hists [K2, C, 3, B] reduced STORE histograms (C = F, or this
         shard's Fs slice under psum_scatter), sums [K2, 3] → packed recs
-        [K2, 11] in ORIGINAL feature space (unbundled per leaf), with
-        the can-split gate applied (depth gate at selection time).
+        [K2, 11] in ORIGINAL feature space (a bundled store's through
+        its cell tables, or unbundled per leaf), with the can-split
+        gate applied (depth gate at selection time).
 
         psum_scatter: each shard split-searches only its column slice
-        (ops/split.sharded_slice_search — unbundled per shard, or the
-        identity store's metadata dynamic-slice), then the [nd, K2, 11]
-        record allgather picks each leaf's max gain with ties broken by
+        (ops/split.sharded_slice_search — in the slice's own cells,
+        unbundled per shard, or the identity store's metadata
+        dynamic-slice), then the [nd, K2, 11] record allgather picks each leaf's max gain with ties broken by
         smallest feature id (ops/split.combine_sharded_records — the
         full search's flat-argmax tie-break, shard-order independent)."""
         if hx:
@@ -417,7 +434,10 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 p = sharded_slice_search(
                     h, s, off=off, nb_s=nb_s, ic_s=ic_s, fm_s=fm_s,
                     num_bins=num_bins, is_cat=is_cat, fmask=fmask,
-                    unb=unb, skw=skw)
+                    unb=search if in_store else unb, skw=skw)
+            elif in_store:
+                p = best_split_in_store(h, search, s[0], s[1], s[2],
+                                        **skw).packed()
             else:
                 rec = best_split(maybe_unbundle(h, unb, s),
                                  num_bins, is_cat, fmask,
@@ -757,7 +777,7 @@ def _jit_build(fn):
     return jax.jit(step)
 
 
-def search_counters(totals, trees, *, cells, slots_per_pass, bundled):
+def search_counters(totals, trees, *, cells, slots_per_pass, unbundled):
     """tree/split_cells and tree/unbundle_gather_elems of `trees` builds
     whose stats vectors sum to `totals`, on the host (count_deferred's
     `fold`): both are static per searched slot, and the vector already
@@ -766,11 +786,12 @@ def search_counters(totals, trees, *, cells, slots_per_pass, bundled):
     larger children, live or not); the root is one of tree/hist_passes
     and every other pass is `slots_per_pass` searched slots.  `cells` is
     one slot's [features, B] as the search scans it; an unbundle in
-    front of it gathers that three times over (grad, hess, count)."""
+    front of it (`unbundled`) gathers that three times over (grad,
+    hess, count)."""
     slots = trees + slots_per_pass * (float(totals[S_PASSES]) - trees)
     return ((profiling.SPLIT_CELLS, cells * slots),
             (profiling.UNBUNDLE_GATHER_ELEMS,
-             3.0 * cells * slots if bundled else 0.0))
+             3.0 * cells * slots if unbundled else 0.0))
 
 
 class RoundsTreeLearner:
@@ -916,15 +937,19 @@ class RoundsTreeLearner:
             ftbl = None
             unb = None
         else:
-            # bundled: histograms unbundle to the ORIGINAL [F] layout
-            # before split search, so split metadata keeps original size.
-            # The sentinel in the gather tables must account for the
-            # int8 layout's 32-aligned column padding (histograms come
-            # back [K, Fpad, 3, B]) — a plan-sized sentinel would gather
-            # a padded column's bin-0 totals instead of zero
+            # bundled: split search names ORIGINAL features, so split
+            # metadata keeps original size.  It runs over the store
+            # histogram's own cells through per-cell tables; a plan that
+            # packs a categorical feature has none and unbundles every
+            # histogram to the [F] layout first.  Either table covers
+            # the PADDED columns (histograms come back [K, Fpad, 3, B]):
+            # a plan-sized gather sentinel would read a padded column's
+            # bin-0 totals instead of zero
             self._base_fmask = np.ones(self.F, bool)
             ftbl = plan.feat_table()
-            unb = dataset.unbundle_tables(self.B, self.Fpad)
+            unb = dataset.search_tables(self.B, self.Fpad)
+            if unb is None:         # the plan packs a categorical feature
+                unb = dataset.unbundle_tables(self.B, self.Fpad)
         self._row_mask = np.pad(np.ones(self.N, np.float32),
                                 (0, self._local_np - self.N))
         # what train / train_device take grad and hess in and hand the
@@ -961,7 +986,8 @@ class RoundsTreeLearner:
                   num_feature_shards=self.df,
                   ftbl=ftbl, unb=unb, sparse=self.sparse,
                   input_dtype=input_dtype)
-        self._fold_stats = self._search_counters(plan is not None)
+        self._fold_stats = self._search_counters(
+            unb is not None and not isinstance(unb, StoreCells))
         if mesh is None:
             self._build = _jit_build(
                 functools.partial(build_tree_rounds, **kw))
@@ -1010,18 +1036,20 @@ class RoundsTreeLearner:
         self.num_bins_dev = nbv if self.mh is not None else jnp.asarray(nbv)
         self.is_cat_dev = icv if self.mh is not None else jnp.asarray(icv)
 
-    def _search_counters(self, bundled: bool):
+    def _search_counters(self, unbundled: bool):
         """profiling.count_deferred's `fold` for this learner's build:
         search_counters over its static shapes.  The search runs over
-        [features, B]: every original feature after an unbundle, else
-        the store's padded columns, a device's slice of them under
-        psum_scatter.  Chunks that can execute are all K = min(leaves
-        per batch, num_leaves) wide: a round splits at most half the
+        [features, B]: every original feature after an unbundle (a
+        bundle plan that packs a categorical feature), else the
+        store's padded columns, bundled or not, a device's slice of
+        them under psum_scatter.  Chunks that can execute are all
+        K = min(leaves per batch, num_leaves) wide: a round splits at
+        most half the
         leaf slots, so a short last chunk never holds one; an executed
         chunk is one histogram pass with the parent cache, two
         without."""
         K = min(LEAVES_PER_BATCH, int(self.config.num_leaves))
-        if bundled:
+        if unbundled:
             feats = self.F
         elif self.hist_exchange == "psum_scatter" and self.dd * self.df > 1:
             feats = self.Fpad // self._nd_sc
@@ -1030,7 +1058,7 @@ class RoundsTreeLearner:
         return functools.partial(
             search_counters, cells=float(feats * self.B),
             slots_per_pass=2.0 * K / (1 if self.cache_parent_hist else 2),
-            bundled=bundled)
+            unbundled=unbundled)
 
     def _build_sparse_streams(self, cols_np: np.ndarray,
                               ell_np: np.ndarray, nsh: int, backend: str):

@@ -20,6 +20,13 @@ Tie-break: flat argmax over [F, B] picks the smallest feature id then the
 smallest threshold — matching the reference's deterministic tie-break
 (split_info.hpp:100-105; its right-to-left scan with strict `>` also keeps
 the smallest threshold).
+
+A store that Exclusive Feature Bundling packed (binning.BundlePlan) is
+searched in its own cells, `best_split_in_store`: a bundle's slots are its
+members' bins, so the same candidates are scored with the same formulas
+over [C, B] store cells instead of [F, B], and the same tie-break is taken
+from the cells' (feature, threshold) tables.  `unbundle_hist` + `best_split`
+stays for learner/serial.py and for a plan that packs a categorical feature.
 """
 from __future__ import annotations
 
@@ -28,6 +35,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from ..binning import StoreCells
 
 K_MIN_SCORE = -jnp.inf
 K_EPSILON = 1e-15  # reference meta.h kEpsilon
@@ -187,15 +196,22 @@ def sharded_slice_search(h, sums, *, off, nb_s, ic_s, fm_s,
     h : [Cs, 3, B] this shard's reduced column slice; off: the shard's
     first global column.  Identity store (unb None): nb_s/ic_s/fm_s are
     the shard's dynamic metadata slices and the record's feature id gets
-    `off` folded back in.  Bundled store: the slice is unbundled to the
-    full original-feature layout with non-owned features masked out of
-    the search.  Returns the packed [11] record in ORIGINAL feature
-    space; combine across shards with `combine_sharded_records`."""
+    `off` folded back in.  Bundled store searched in its own cells (unb
+    the tree's StoreSearch for the slice's columns): a member lies
+    whole in one column and the tables name original features, so the
+    slice is searched as it is.  Bundled store with gather tables: the
+    slice is unbundled to the full original-feature layout with
+    non-owned features masked out of the search.  Returns the packed
+    [11] record in ORIGINAL feature space; combine across shards with
+    `combine_sharded_records`."""
     if unb is None:
         rec = best_split(h, nb_s, ic_s, fm_s,
                          sums[0], sums[1], sums[2], **skw)
         p = rec.packed()
         return p.at[1].add(jnp.asarray(off).astype(jnp.float32))
+    if isinstance(unb, StoreSearch):
+        return best_split_in_store(h, unb, sums[0], sums[1], sums[2],
+                                   **skw).packed()
     with jax.named_scope("lgbt.unbundle"):
         hF, owned = unbundle_hist_local(h, unb[0], unb[1], sums, off)
     rec = best_split(hF, num_bins, is_cat, fmask & owned,
@@ -272,7 +288,23 @@ def split_gain_matrix(hist: jax.Array, num_bins: jax.Array, is_cat: jax.Array,
     CR = num_data - CL
 
     t_valid = jnp.where(is_cat[:, None], bin_idx < nb, bin_idx < nb - 1)
-    valid = (t_valid & feature_mask[:, None]
+    total_gain = _candidate_gains(
+        t_valid & feature_mask[:, None], GL, HL, CL, GR, HR, CR,
+        sum_grad, sum_hess, l1, l2, min_data_in_leaf,
+        min_sum_hessian_in_leaf, min_gain_to_split)
+    return total_gain, GL, HL, CL
+
+
+def _candidate_gains(cand, GL, HL, CL, GR, HR, CR, sum_grad, sum_hess,
+                     l1, l2, min_data_in_leaf, min_sum_hessian_in_leaf,
+                     min_gain_to_split):
+    """Total gain of every candidate with both children's sums given,
+    K_MIN_SCORE where `cand` is False, a child is under
+    min_data_in_leaf / min_sum_hessian_in_leaf or the gain does not
+    exceed the leaf's own plus min_gain_to_split: the one arithmetic of
+    the search over [F, B] and of the one over a bundled store's
+    cells."""
+    valid = (cand
              & (CL >= min_data_in_leaf) & (CR >= min_data_in_leaf)
              & (HL >= min_sum_hessian_in_leaf)
              & (HR >= min_sum_hessian_in_leaf))
@@ -280,9 +312,23 @@ def split_gain_matrix(hist: jax.Array, num_bins: jax.Array, is_cat: jax.Array,
     gain_shift = leaf_split_gain(sum_grad, sum_hess, l1, l2)
     min_gain_shift = gain_shift + min_gain_to_split
     total_gain = leaf_split_gain(GL, HL, l1, l2) + leaf_split_gain(GR, HR, l1, l2)
-    total_gain = jnp.where(valid & (total_gain > min_gain_shift),
-                           total_gain, K_MIN_SCORE)
-    return total_gain, GL, HL, CL
+    return jnp.where(valid & (total_gain > min_gain_shift),
+                     total_gain, K_MIN_SCORE)
+
+
+def _split_record(bg, bf, bt, glb, hlb, clb, sum_grad, sum_hess, num_data,
+                  gain_shift, l1, l2) -> SplitResult:
+    """The record of the winning candidate: its total gain `bg` (the
+    leaf's own, `gain_shift`, comes off), original feature and
+    threshold, and its left child's sums."""
+    grb, hrb, crb = sum_grad - glb, sum_hess - hlb, num_data - clb
+    return SplitResult(
+        gain=jnp.where(jnp.isfinite(bg), bg - gain_shift, K_MIN_SCORE),
+        feature=bf, threshold_bin=bt,
+        left_sum_grad=glb, left_sum_hess=hlb, left_count=clb,
+        right_sum_grad=grb, right_sum_hess=hrb, right_count=crb,
+        left_output=leaf_output(glb, hlb, l1, l2),
+        right_output=leaf_output(grb, hrb, l1, l2))
 
 
 @functools.partial(
@@ -320,11 +366,103 @@ def best_split(hist: jax.Array, num_bins: jax.Array, is_cat: jax.Array,
     bt = (best % B).astype(jnp.int32)
     bg = flat[best]
     glb, hlb, clb = GL.reshape(-1)[best], HL.reshape(-1)[best], CL.reshape(-1)[best]
-    grb, hrb, crb = sum_grad - glb, sum_hess - hlb, num_data - clb
-    return SplitResult(
-        gain=jnp.where(jnp.isfinite(bg), bg - gain_shift, K_MIN_SCORE),
-        feature=bf, threshold_bin=bt,
-        left_sum_grad=glb, left_sum_hess=hlb, left_count=clb,
-        right_sum_grad=grb, right_sum_hess=hrb, right_count=crb,
-        left_output=leaf_output(glb, hlb, l1, l2),
-        right_output=leaf_output(grb, hrb, l1, l2))
+    return _split_record(bg, bf, bt, glb, hlb, clb, sum_grad, sum_hess,
+                         num_data, gain_shift, l1, l2)
+
+
+class StoreSearch(NamedTuple):
+    """What best_split_in_store reads beside a histogram [C, 3, B]: the
+    rows of a StoreCells for those C columns and, made once a tree
+    (store_search_operands), the tree's feature mask in cell space and
+    the cells each candidate's sum runs over."""
+    feat: jax.Array      # [C, B] int32, -1: no candidate
+    thr: jax.Array       # [C, B] int32
+    suffix: jax.Array    # [C, B] bool
+    mask: jax.Array      # [C, B] bool: a candidate, its feature in the tree
+    member: jax.Array    # [C, B, B] f32 0/1: cell s' is in cell s's sum
+
+
+def store_search_operands(cells: StoreCells, feature_mask: jax.Array,
+                          start=0, size: int = 0) -> StoreSearch:
+    """StoreSearch for store columns [start, start + size) (all of them
+    by default; a shard's slice under psum_scatter, `start` traced — a
+    member lies whole in one column) under the tree's feature_mask [F]
+    over ORIGINAL features.  Once a tree, not once a searched slot."""
+    size = size or cells.feat.shape[0]
+    feat, thr, suffix, lo, hi = (
+        jax.lax.dynamic_slice_in_dim(jnp.asarray(t), start, size)
+        for t in cells)
+    mask = (feat >= 0) & jnp.asarray(feature_mask)[jnp.maximum(feat, 0)]
+    B = feat.shape[1]
+    sp = jax.lax.broadcasted_iota(jnp.int32, (1, B, 1), 1)
+    member = (sp >= lo[:, None, :]) & (sp <= hi[:, None, :])
+    return StoreSearch(feat, thr, suffix, mask,
+                       member.astype(jnp.float32))
+
+
+def _member_sums(hist: jax.Array, member: jax.Array) -> jax.Array:
+    """hist [C, 3, B] -> for every cell s the float32 sum of the cells
+    of its own member that StoreSearch.member marks: one contraction of
+    the bin axis against a 0/1 matrix, at float32 precision (HIGHEST:
+    on the chip's bf16 matrix unit a value goes through as three pieces
+    that add up to it exactly; the default, one piece, keeps 8 bits of
+    a sum).  Sums stay inside a member — a column-wide cumsum less its
+    base would cancel a rare level's slot against a prefix a thousand
+    times its size — and a one-slot member's sum is the slot itself.
+    Every output adds its terms in the one order of the contraction,
+    zeros included, so thresholds over a run of empty bins read the
+    same sum (and tie, to the smallest, as under a sequential
+    cumsum)."""
+    return jnp.einsum("cjs,cst->cjt", hist, member,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+def best_split_in_store(hist: jax.Array, search: StoreSearch,
+                        sum_grad: jax.Array,
+                        sum_hess: jax.Array, num_data: jax.Array, *,
+                        lambda_l1: float = 0.0, lambda_l2: float = 0.0,
+                        min_data_in_leaf: int = 20,
+                        min_sum_hessian_in_leaf: float = 1e-3,
+                        min_gain_to_split: float = 0.0) -> SplitResult:
+    """best_split of one leaf over a BUNDLED store histogram's own cells.
+
+    hist : [C, 3, B] f32 store histogram (C padded store columns, or a
+        shard's slice of them)
+    search : the tree's store_search_operands for those C columns
+
+    Scores exactly the (feature, threshold) candidates that
+    best_split(unbundle_hist(hist)) scores, with the same formulas, and
+    returns the same record in ORIGINAL feature space.  The left sums
+    are member-local sums of slots (for thresholds at or above the
+    member's default bin: the leaf totals less such a sum), so a
+    one-slot member's are the gathered search's bit for bit and any
+    other's differ by the order of a float32 sum.  The winner is the
+    maximum gain, then the smallest original feature, then the smallest
+    threshold — the flat argmax over [F, B]; cells of a column are not
+    in feature order, so it takes two reductions here."""
+    B = hist.shape[-1]
+    l1, l2 = lambda_l1, lambda_l2
+    feat, thr, suffix = search.feat, search.thr, search.suffix
+    part = _member_sums(hist, search.member)
+    GL = jnp.where(suffix, sum_grad - part[:, 0, :], part[:, 0, :])
+    HL = jnp.where(suffix, sum_hess - part[:, 1, :], part[:, 1, :])
+    CL = jnp.where(suffix, num_data - part[:, 2, :], part[:, 2, :])
+    GR = sum_grad - GL
+    HR = sum_hess - HL
+    CR = num_data - CL
+    total_gain = _candidate_gains(
+        search.mask, GL, HL, CL, GR, HR, CR, sum_grad, sum_hess, l1, l2,
+        min_data_in_leaf, min_sum_hessian_in_leaf, min_gain_to_split)
+
+    flat = total_gain.reshape(-1)
+    bg = jnp.max(flat)
+    order = jnp.where((flat == bg) & (feat.reshape(-1) >= 0),
+                      (feat * B + thr).reshape(-1),
+                      jnp.iinfo(jnp.int32).max)
+    best = jnp.argmin(order)
+    bf = jnp.maximum(feat.reshape(-1)[best], 0)
+    bt = thr.reshape(-1)[best]
+    glb, hlb, clb = GL.reshape(-1)[best], HL.reshape(-1)[best], CL.reshape(-1)[best]
+    return _split_record(bg, bf, bt, glb, hlb, clb, sum_grad, sum_hess,
+                         num_data, leaf_split_gain(sum_grad, sum_hess, l1, l2),
+                         l1, l2)

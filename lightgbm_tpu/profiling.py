@@ -110,16 +110,18 @@ SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
 #    and as executed: searched slots (1 at the root, twice a chunk's
 #    slots per executed chunk, live or not) x the features of the array
 #    the search runs over x padded bins — the store's padded columns
-#    (a device's slice under psum_scatter) on a store with no bundle
-#    plan, every original feature where a bundled histogram is first
+#    (a device's slice under psum_scatter), bundled or not: a bundled
+#    store is searched in its own cells (ops/split.best_split_in_store);
+#    every original feature only where a bundled histogram is first
 #    unbundled.  Per device.  Static per pass, so it does not ride the
 #    vector: RoundsTreeLearner folds it on the host from HIST_PASSES
 #    (learner/rounds.search_counters, count_deferred's `fold`); the
 #    other learners do not count it.
-#  - UNBUNDLE_GATHER_ELEMS: histogram elements that the unbundle in
+#  - UNBUNDLE_GATHER_ELEMS: histogram elements that an unbundle in
 #    front of that search gathers through its [F, B] index table
-#    (ops/split.unbundle_hist): 3 x F x B per searched slot; 0 on a
-#    store with no plan.  Folded the same way.
+#    (ops/split.unbundle_hist): 3 x F x B per searched slot where a
+#    bundle plan packs a categorical feature, the one store the rounds
+#    learner still unbundles; 0 on every other.  Folded the same way.
 TREE_ROUNDS = "tree/rounds"
 HIST_PASSES = "tree/hist_passes"
 HIST_SLOTS = "tree/hist_slots"

@@ -26,8 +26,10 @@ once the set is binned):
    shortfalls of all members add up to `bundle_conflict_rows`, and that
    count, for that reason, is the whole tolerance.
 2. the root split of tree 1 as the learner's build grew it (feature,
-   threshold bin, original space) against the float64 best split, by the
-   textbook gain, of the device's unbundled histogram: the same feature
+   threshold bin, original space; the build searches the store
+   histogram's own cells, `ops/split.best_split_in_store`, and unbundles
+   nothing) against the float64 best split, by the textbook gain, of the
+   device's histogram as step 1 unbundled it: the same feature
    and bin, or the same feature with a float64 gain within four float32
    steps of the sum the search compares (what float32 cannot tell apart;
    both gains and the step are printed).

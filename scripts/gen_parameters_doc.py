@@ -84,7 +84,7 @@ def main():
         "slice, and all_gathers the tiny per-leaf best-split records "
         "(the reference's `Network::ReduceScatter` ownership model) — "
         "per-device comms volume drops ~`ndev`x, and split-search work "
-        "too on unbundled stores. `auto` = psum_scatter when the "
+        "too. `auto` = psum_scatter when the "
         "per-pass payload "
         "reaches the `hist_exchange_min_bytes` crossover, psum below "
         "it. On a 2-D `data2d` mesh with the rounds learner, the "

@@ -346,9 +346,9 @@ def test_counters_read_what_the_shapes_say_on_a_bundled_store(toy, cache):
     chunks = (got[profiling.HIST_PASSES] - 1) / (1 if cache else 2)
     assert chunks >= 1 and chunks == int(chunks)
     slots = 1 + 2 * 15 * chunks          # the root, then K = 15 slots twice
-    assert got[profiling.SPLIT_CELLS] == slots * lr.F * lr.B
-    assert got[profiling.UNBUNDLE_GATHER_ELEMS] == 3 * slots * lr.F * lr.B
-    assert lr.F > lr.Fpad                # searched in feature space
+    assert got[profiling.SPLIT_CELLS] == slots * lr.Fpad * lr.B
+    assert got[profiling.UNBUNDLE_GATHER_ELEMS] == 0
+    assert lr.F > lr.Fpad                # searched in store space
 
 
 def test_counters_on_a_store_with_no_plan(toy):
@@ -361,24 +361,27 @@ def test_counters_on_a_store_with_no_plan(toy):
 
 def test_a_searched_slot_is_what_best_split_is_traced_with(toy, monkeypatch):
     """The fold's static half against the program: every search the build
-    traces is over [F, 3, B] per slot, K slots a chunk and one at the root."""
-    seen = []
-    real = rounds.best_split
+    traces is over the store's own [Fpad, 3, B] per slot, K slots a chunk
+    and one at the root, and no search over [F, 3, B] is traced at all."""
+    seen, gathered = [], []
+    real = rounds.best_split_in_store
 
     def spy(hist, *a, **kw):
         seen.append(tuple(hist.shape))
         return real(hist, *a, **kw)
 
-    monkeypatch.setattr(rounds, "best_split", spy)
+    monkeypatch.setattr(rounds, "best_split_in_store", spy)
+    monkeypatch.setattr(rounds, "best_split",
+                        lambda hist, *a, **kw: gathered.append(hist.shape))
     lr, got = counters_after_one_tree(toy)
-    assert set(seen) == {(lr.F, 3, lr.B)}
+    assert set(seen) == {(lr.Fpad, 3, lr.B)} and not gathered
     totals = np.zeros(len(rounds.STATS_COUNTERS))
     totals[rounds.S_PASSES] = 1                          # a root-only tree
     assert dict(lr._fold_stats(totals, 1))[profiling.SPLIT_CELLS] == (
-        lr.F * lr.B)
+        lr.Fpad * lr.B)
     totals[rounds.S_PASSES] = 3 + 2                      # two trees, 3 chunks
     assert dict(lr._fold_stats(totals, 2))[profiling.SPLIT_CELLS] == (
-        (2 + 3 * 2 * 15) * lr.F * lr.B)
+        (2 + 3 * 2 * 15) * lr.Fpad * lr.B)
 
 
 # ---- (e) a dense store's program does not know the counters -----------------

@@ -34,11 +34,13 @@ def _one_hot_data(n=1500, groups=40, card=6, seed=0, noise=0.3):
     return X, y
 
 
-def _train(X, y, enable_bundle, tree_growth="exact", rounds=6, **extra):
+def _train(X, y, enable_bundle, tree_growth="exact", rounds=6,
+           categorical_feature="auto", **extra):
     params = dict(dict(objective="binary", num_leaves=15, min_data_in_leaf=5,
                        verbose=-1, enable_bundle=enable_bundle,
                        tree_growth=tree_growth), **extra)
-    ds = lgb.Dataset(X, y, params=params)
+    ds = lgb.Dataset(X, y, params=params,
+                     categorical_feature=categorical_feature)
     bst = lgb.Booster(params, ds)
     for _ in range(rounds):
         bst.update()
@@ -482,3 +484,428 @@ def test_served_predict_parity_for_bundled_model(tmp_path):
         finally:
             conn.close()
     np.testing.assert_allclose(preds, bst.predict(X[:24]), atol=1e-6)
+
+
+# -- split search in the bundled store's own cells ------------------------
+#
+# ops/split.best_split_in_store against the oracle that stays:
+# best_split(unbundle_hist(...)), the search learner/serial.py runs.
+
+def _plan_of(columns, num_features):
+    """A BundlePlan by hand.  `columns` lists the store's columns in store
+    order: a list of (feature, num_bins, default_bin) members for a
+    bundle, one (feature, num_bins, is_cat) for a column of its own.
+    Returns (plan, num_bins [F], is_cat [F])."""
+    from lightgbm_tpu.binning import BundlePlan
+    F = num_features
+    col, off, dflt = (np.zeros(F, np.int32) for _ in range(3))
+    nb, packed, cat = np.ones(F, np.int32), np.zeros(F, bool), np.zeros(F, bool)
+    col_bins = []
+    for c, members in enumerate(columns):
+        if isinstance(members, tuple):
+            k, n, cat[k] = members
+            col[k], nb[k] = c, n
+            col_bins.append(n)
+            continue
+        o = 1
+        for k, n, d in members:
+            col[k], off[k], dflt[k], nb[k], packed[k] = c, o, d, n, True
+            o += n - 1
+        col_bins.append(o)
+    plan = BundlePlan(feat_col=col, feat_offset=off, feat_default=dflt,
+                      feat_nslots=nb - 1, feat_packed=packed,
+                      col_num_bins=np.asarray(col_bins, np.int32))
+    return plan, nb, cat
+
+
+def _store_hists(plan, Cpad, B, rows, leaves, rng, dyadic):
+    """[leaves, Cpad, 3, B] float32 store histograms of `leaves` random
+    leaves (row subsets) and their [leaves, 3] totals: every row lies in
+    exactly one bin of every column, so a column's bins sum to the leaf's
+    totals; padded columns hold every row at bin 0, as the kernel's do."""
+    C = plan.num_columns
+    if dyadic:
+        g = rng.integers(-64, 65, rows) / 64.0
+        h = rng.integers(1, 65, rows) / 64.0
+    else:
+        g, h = rng.standard_normal(rows), rng.random(rows) + 0.05
+    bins = np.zeros((Cpad, rows), np.int64)
+    for c in range(C):
+        # a skewed draw: bin 0 (every member at its default) is common
+        w = rng.random(plan.col_num_bins[c]) ** 3 + 1e-3
+        w[0] += w.sum()
+        bins[c] = rng.choice(len(w), rows, p=w / w.sum())
+    out = np.zeros((leaves, Cpad, 3, B))
+    sums = np.zeros((leaves, 3))
+    for i in range(leaves):
+        m = rng.random(rows) < rng.uniform(0.2, 0.9)
+        sums[i] = g[m].sum(), h[m].sum(), m.sum()
+        for c in range(Cpad):
+            for j, v in enumerate((g, h, np.ones(rows))):
+                out[i, c, j] = np.bincount(bins[c][m], v[m], minlength=B)
+    return out.astype(np.float32), sums.astype(np.float32)
+
+
+def _both_searches(plan, nb, cat, hists, sums, B, Cpad, fmask=None, **kw):
+    """Packed [leaves, 11] records: (store cells, gathered oracle)."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.split import (best_split, best_split_in_store,
+                                        store_search_operands,
+                                        unbundle_hist)
+    fmask = np.ones(len(nb), bool) if fmask is None else fmask
+    cells = plan.search_tables(nb, cat, B, Cpad)
+    src, dmask = plan.unbundle_tables(nb, B, Cpad)
+    search = store_search_operands(cells, fmask)
+
+    def store(h, s):
+        return best_split_in_store(h, search, s[0], s[1], s[2],
+                                   **kw).packed()
+
+    def oracle(h, s):
+        return best_split(unbundle_hist(h, src, dmask, s), jnp.asarray(nb),
+                          jnp.asarray(cat), jnp.asarray(fmask),
+                          s[0], s[1], s[2], **kw).packed()
+    hists, sums = jnp.asarray(hists), jnp.asarray(sums)
+    return (np.asarray(jax.vmap(store)(hists, sums)),
+            np.asarray(jax.vmap(oracle)(hists, sums)))
+
+
+def _assert_same_records(got, want, steps=0):
+    """Feature, threshold and both counts exactly; sums and gains within
+    `steps` float32 steps of the sums the search compares (0: bit for
+    bit).  A leaf with no valid candidate reads -inf on both sides."""
+    np.testing.assert_array_equal(np.isfinite(got[:, 0]),
+                                  np.isfinite(want[:, 0]))
+    ok = np.isfinite(want[:, 0])
+    assert ok.any()
+    got, want = got[ok], want[ok]
+    np.testing.assert_array_equal(got[:, [1, 2, 5, 8]], want[:, [1, 2, 5, 8]])
+    if steps == 0:
+        np.testing.assert_array_equal(got, want)
+        return
+    # a sum is off by float32 steps of the leaf's totals (left sums at or
+    # above a default bin are totals less a suffix); G^2/H moves with it
+    # by 2 G/H dG + (G/H)^2 dH on either side
+    w = want.astype(np.float64)
+    d = steps * np.spacing(np.abs(want[:, 3:9]).max(axis=1)).astype(np.float64)
+    assert (np.abs(got[:, 3:9] - w[:, 3:9]) <= d[:, None]).all()
+    out = np.abs(w[:, [3, 6]] / w[:, [4, 7]])               # |G/H|, L and R
+    assert (np.abs(got[:, 0] - w[:, 0])
+            <= ((2 * out + out ** 2).sum(axis=1) + 1) * d).all()
+    assert (np.abs(got[:, 9:] - w[:, 9:])
+            <= (1 + out) * d[:, None] / w[:, [4, 7]] + 1e-6).all()
+
+
+def _random_plan(rng, B=64):
+    """Bundles of multi-slot members whose default bins are anywhere
+    (first, middle, last), features numbered against the store order, a
+    numerical and a categorical column of their own between them."""
+    order = rng.permutation(14)
+    it = iter(order)
+    cols = []
+    for _ in range(3):
+        members, room = [], B - 1
+        for _ in range(4):
+            n = int(rng.integers(2, 9))
+            if n - 1 > room:
+                break
+            members.append((int(next(it)), n, int(rng.integers(0, n))))
+            room -= n - 1
+        cols.append(members)
+    cols.insert(1, (int(next(it)), 40, False))
+    cols.append((int(next(it)), 9, True))
+    used = [m for c in cols for m in ([c] if isinstance(c, tuple) else c)]
+    remap = {k: i for i, k in enumerate(sorted(m[0] for m in used))}
+    cols = [(remap[c[0]],) + c[1:] if isinstance(c, tuple)
+            else [(remap[k], n, d) for k, n, d in c] for c in cols]
+    return _plan_of(cols, len(used))
+
+
+SEARCH_KW = dict(lambda_l1=0.0, lambda_l2=0.0, min_data_in_leaf=1,
+                 min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0)
+
+
+SEARCH_CASES = ["defaults_anywhere", "padded_columns", "feature_mask",
+                "min_data_binds", "min_hessian_binds", "l1_l2_min_gain",
+                "float_sums"]
+
+
+@pytest.mark.parametrize("case", SEARCH_CASES)
+def test_store_search_is_the_gathered_search(case):
+    """Random plans, eight random leaves each: the record out of the
+    store's own cells is best_split(unbundle_hist(...))'s.  Dyadic
+    gradients make every float32 sum exact, so whatever order the two
+    searches add in they must agree to the bit; `float_sums` lets the
+    orders show and allows a few float32 steps."""
+    rng = np.random.default_rng(SEARCH_CASES.index(case))
+    B = 64
+    for _ in range(3):
+        plan, nb, cat = _random_plan(rng, B)
+        Cpad = plan.num_columns + (3 if case == "padded_columns" else 0)
+        kw, fmask = dict(SEARCH_KW), None
+        if case == "feature_mask":
+            fmask = rng.random(len(nb)) < 0.5
+            fmask[rng.integers(len(nb))] = True
+        elif case == "min_data_binds":
+            kw["min_data_in_leaf"] = 150
+        elif case == "min_hessian_binds":
+            kw["min_sum_hessian_in_leaf"] = 60.0
+        elif case == "l1_l2_min_gain":
+            kw.update(lambda_l1=0.5, lambda_l2=2.0, min_gain_to_split=0.01)
+        hists, sums = _store_hists(plan, Cpad, B, 2000, 8, rng,
+                                   dyadic=case != "float_sums")
+        got, want = _both_searches(plan, nb, cat, hists, sums, B, Cpad,
+                                   fmask, **kw)
+        _assert_same_records(got, want, steps=8 if case == "float_sums"
+                             else 0)
+        if fmask is not None:
+            assert fmask[want[:, 1].astype(int)].all()
+
+
+@pytest.mark.parametrize("default_bin", [0, 1])
+def test_one_slot_members_are_bit_for_bit(default_bin):
+    """A full bundle — 255 indicator members in a 256-bin column — beside
+    a second one, float gradients: a one-slot member's left sums are
+    `totals - slot` (default 0) or the slot (default 1) in both searches,
+    so the whole record agrees to the last bit, no tolerance."""
+    rng = np.random.default_rng(5 + default_bin)
+    B = 256
+    ids = rng.permutation(300)
+    cols = [[(int(k), 2, default_bin) for k in ids[:255]],
+            [(int(k), 2, default_bin) for k in ids[255:]]]
+    plan, nb, cat = _plan_of(cols, 300)
+    assert plan.col_num_bins.tolist() == [256, 46]
+    hists, sums = _store_hists(plan, 8, B, 4000, 8, rng, dyadic=False)
+    got, want = _both_searches(plan, nb, cat, hists, sums, B, 8,
+                               **SEARCH_KW)
+    _assert_same_records(got, want, steps=0)
+
+
+@pytest.mark.parametrize("first", ["larger_id_first", "smaller_id_first"])
+def test_a_tie_goes_to_the_smaller_feature_then_threshold(first):
+    """Two members of one column hold the same slots — an exact tie on
+    every threshold — and the column stores the larger feature id first:
+    the flat argmax over [F, B] takes the smaller id and its smallest
+    threshold, and so must the search whose cells are in store order."""
+    a, b = (7, 2) if first == "larger_id_first" else (2, 7)
+    cols = [[(a, 4, 0), (b, 4, 0)], (0, 6, False)]
+    plan, nb, cat = _plan_of(cols, 8)
+    nb[[1, 3, 4, 5, 6]] = 1            # features no column holds: trivial
+    B = 32
+    h = np.zeros((1, 2, 3, B), np.float32)
+    # the two members' three slots, twice the same; slots 1 and 2 tie too
+    slots = np.array([[4.0, 4.0, -1.0], [2.0, 2.0, 2.0], [8.0, 8.0, 8.0]])
+    h[0, 0, :, 1:4] = h[0, 0, :, 4:7] = slots
+    sums = np.array([[0.0, 16.0, 64.0]], np.float32)
+    h[0, 0, :, 0] = sums[0] - 2 * slots.sum(axis=1)
+    h[0, 1, :, :6] = (sums[0] / 6)[:, None]     # a flat column: no gain
+    got, want = _both_searches(plan, nb, cat, h, sums, B, 2, **SEARCH_KW)
+    _assert_same_records(got, want, steps=0)
+    assert got[0, 1] == 2 and got[0, 0] > 0
+
+
+@pytest.mark.parametrize("shards", [2, 4])
+def test_a_column_slice_searches_its_rows_of_the_tables(shards):
+    """sharded_slice_search over each shard's store-column slice, with
+    that slice's rows of the tables: the records, combined as
+    combine_sharded_records does (largest gain, then smallest feature),
+    are the one-device search's."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.split import (sharded_slice_search,
+                                        store_search_operands)
+    rng = np.random.default_rng(40 + shards)
+    B = 64
+    plan, nb, cat = _random_plan(rng, B)
+    Cpad = shards * -(-plan.num_columns // shards)
+    fmask = np.ones(len(nb), bool)
+    fmask[rng.integers(len(nb))] = False
+    hists, sums = _store_hists(plan, Cpad, B, 2000, 6, rng, dyadic=True)
+    got, want = _both_searches(plan, nb, cat, hists, sums, B, Cpad, fmask,
+                               **SEARCH_KW)
+    _assert_same_records(got, want, steps=0)
+    cells = plan.search_tables(nb, cat, B, Cpad)
+    Cs = Cpad // shards
+
+    def shard(off):
+        rows = store_search_operands(cells, fmask, jnp.int32(off), Cs)
+
+        def one(h, s):
+            return sharded_slice_search(
+                h, s, off=off, nb_s=None, ic_s=None, fm_s=None,
+                num_bins=None, is_cat=None, fmask=None, unb=rows,
+                skw=SEARCH_KW)
+        return np.asarray(jax.vmap(one)(
+            jnp.asarray(hists[:, off:off + Cs]), jnp.asarray(sums)))
+    recs = np.stack([shard(i * Cs) for i in range(shards)])   # [nd, K, 11]
+    gains = recs[..., 0]
+    cand = np.where(gains == gains.max(axis=0), recs[..., 1], np.inf)
+    best = cand.argmin(axis=0)
+    combined = recs[best, np.arange(recs.shape[1])]
+    np.testing.assert_array_equal(combined, got)
+
+
+def test_member_sums_keep_float32():
+    """What the search adds up, alone, at sizes a 12M-row leaf has: a sum
+    of one cell (a one-slot member, a cell that is no candidate) is the
+    cell to the last bit, a longer one is within float32 rounding of the
+    float64 sum of its cells — a contraction at the matrix unit's default
+    precision would keep 8 bits of either (it did, on the chip: PERF.md
+    section 6, PR 40)."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.split import _member_sums, store_search_operands
+    rng = np.random.default_rng(12)
+    B = 64
+    plan, nb, cat = _random_plan(rng, B)
+    Cpad = plan.num_columns + 1
+    hists, _ = _store_hists(plan, Cpad, B, 3000, 4, rng, dyadic=False)
+    hists = hists * np.float32(4099.0)
+    cells = plan.search_tables(nb, cat, B, Cpad)
+    member = store_search_operands(cells, np.ones(len(nb), bool)).member
+    got = np.asarray(jax.vmap(lambda h: _member_sums(h, member))(
+        jnp.asarray(hists)))
+    m = np.asarray(member, np.float64)
+    assert set(np.unique(m)) == {0.0, 1.0}
+    want = np.einsum("kcjs,cst->kcjt", hists.astype(np.float64), m)
+    room = np.einsum("kcjs,cst->kcjt", np.abs(hists).astype(np.float64), m)
+    assert (np.abs(got - want) <= 8 * 2.0 ** -24 * room).all()
+    alone = np.broadcast_to((cells.lo == cells.hi)[None, :, None, :],
+                            got.shape)
+    assert alone.sum() > got.size / 2
+    np.testing.assert_array_equal(got[alone], hists[alone])
+
+
+def test_search_tables_are_the_gather_tables_cell_by_cell():
+    """BundlePlan.search_tables against unbundle_tables: a candidate cell
+    (c, s) of feature k at threshold t is where src sends bin t of k (a
+    prefix candidate) or bin t + 1 (a suffix one, the default bin lying
+    at or under t); every threshold of every feature has exactly one
+    cell; a cell's sum stays inside its member; the rest is no
+    candidate."""
+    rng = np.random.default_rng(9)
+    B = 64
+    for _ in range(5):
+        plan, nb, cat = _random_plan(rng, B)
+        Cpad = plan.num_columns + 2
+        cells = plan.search_tables(nb, cat, B, Cpad)
+        src, dmask = plan.unbundle_tables(nb, B, Cpad)
+        seen = set()
+        for c, s in zip(*np.nonzero(cells.feat >= 0)):
+            k, t = int(cells.feat[c, s]), int(cells.thr[c, s])
+            b = t + int(cells.suffix[c, s])
+            assert src[k, b] == c * B + s and not dmask[k, b]
+            assert (k, t) not in seen
+            seen.add((k, t))
+            d = int(plan.feat_default[k]) if plan.feat_packed[k] else -1
+            assert cells.suffix[c, s] == (plan.feat_packed[k] and t >= d)
+            member = [int(x) % B for x in src[k] if x // B == c]
+            if cat[k]:
+                assert cells.lo[c, s] == cells.hi[c, s] == s
+            elif cells.suffix[c, s]:
+                assert cells.lo[c, s] == s and cells.hi[c, s] == max(member)
+            else:
+                assert cells.hi[c, s] == s and cells.lo[c, s] == min(member)
+        want = {(k, t) for k in range(len(nb))
+                for t in range(nb[k] if cat[k] else nb[k] - 1)}
+        assert seen == want
+        rest = cells.feat < 0
+        own = np.tile(np.arange(B), (Cpad, 1))
+        assert (cells.lo[rest] == own[rest]).all()
+        assert (cells.hi[rest] == own[rest]).all()
+        assert rest[plan.num_columns:].all()
+
+
+def _splits_of(tree):
+    """A tree's splits whatever order its nodes were numbered in (the
+    rounds schedule numbers them its own way)."""
+    n = tree.num_leaves - 1
+    return sorted(zip(tree.split_feature[:n].tolist(),
+                      tree.threshold_in_bin[:n].tolist(),
+                      tree.decision_type[:n].tolist()))
+
+
+def test_a_packed_categorical_feature_keeps_the_gather():
+    """One-vs-rest has a candidate on the default bin, which no slot
+    holds: a plan that packs a categorical feature has no cell tables,
+    the rounds learner unbundles for it as learner/serial.py does, counts
+    the gather, and grows the serial learner's trees."""
+    from lightgbm_tpu import profiling
+    rng = np.random.RandomState(4)
+    n = 1500
+    Xs, _ = _one_hot_data(n=n, groups=6, card=5, seed=4)
+    # three sparse categorical columns, mostly 0 (the default) and never
+    # set in the same row, so the planner packs them into one column
+    which = rng.randint(0, 6, n)
+    cats = np.where(which[:, None] == np.arange(3), rng.randint(1, 5, (n, 3)),
+                    0)
+    X = np.concatenate([cats.astype(float), Xs], axis=1)
+    y = ((cats[:, 0] == 2) | (Xs[:, 3] > 0) ^ (cats[:, 1] == 1)).astype(float)
+    kw = dict(num_leaves=31, min_data_in_leaf=40, rounds=3,
+              categorical_feature=[0, 1, 2])
+    profiling.reset()
+    a, dsa = _train(X, y, True, "rounds", **kw)
+    got = profiling.counters("tree/")
+    profiling.reset()
+    b, _ = _train(X, y, True, "exact", **kw)
+    inner = dsa._inner
+    plan = inner.bundle_plan
+    assert plan is not None
+    assert (plan.feat_packed & inner.is_categorical).any()
+    assert inner.search_tables(64) is None
+    assert got[profiling.UNBUNDLE_GATHER_ELEMS] == (
+        3 * got[profiling.SPLIT_CELLS]) > 0
+    sa = [_splits_of(t) for t in a._gbdt.models]
+    assert sa == [_splits_of(t) for t in b._gbdt.models]
+    assert any(f < 3 for t in sa for f, _, _ in t)      # a packed one won
+    np.testing.assert_allclose(a.predict(X), b.predict(X), atol=1e-5)
+
+
+@pytest.mark.parametrize("packs", ["indicators", "a_categorical_feature"])
+@pytest.mark.parametrize("learner", ["rounds", "fused"])
+def test_a_bundled_store_over_a_mesh_grows_the_serial_learners_tree(
+        learner, packs):
+    """Both learners, both searches of a bundled store — its own cells
+    (indicators only), the gather (a packed categorical feature) — on one
+    device and under psum_scatter on the 8-device mesh, where a device
+    holds a slice of the store's columns: the plain learner's tree, which
+    searches best_split(unbundle_hist(...)).  Dyadic gradients, so every
+    float32 sum is exact."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.binning import StoreCells
+    from lightgbm_tpu.learner.fused import FusedTreeLearner, make_mesh
+    from lightgbm_tpu.learner.rounds import RoundsTreeLearner
+    from lightgbm_tpu.learner.serial import SerialTreeLearner
+    rng = np.random.RandomState(31)
+    n = 2000
+    X, _ = _one_hot_data(n=n, groups=8, card=4, seed=21)
+    cats = ()
+    if packs == "a_categorical_feature":
+        which = rng.randint(0, 5, n)
+        c = np.where(which[:, None] == np.arange(2),
+                     rng.randint(1, 5, (n, 2)), 0)
+        X, cats = np.concatenate([c.astype(float), X], axis=1), (0, 1)
+    y = (X @ rng.randn(X.shape[1]) > 0).astype(np.float64)
+    g = jnp.asarray(rng.randint(-32, 33, n) / 32.0, jnp.float32)
+    h = jnp.asarray(rng.randint(8, 33, n) / 32.0, jnp.float32)
+    # min_data_in_leaf keeps the tree under the cap of 31 leaves, where
+    # the rounds schedule grows the leaf-wise tree
+    base = {"objective": "binary", "num_leaves": 31, "verbose": -1,
+            "min_data_in_leaf": 90, "enable_bundle": True}
+    cfg = config_from_params(base)
+    ds = InnerDataset(X, y, cfg, categorical_feature=cats)
+    assert ds.bundle_plan is not None
+    in_store = ds.search_tables(64) is not None
+    assert in_store == (packs == "indicators")
+    want, _ = SerialTreeLearner(ds, cfg).train(g, h)
+    assert 4 < want.num_leaves < 31
+    make = RoundsTreeLearner if learner == "rounds" else FusedTreeLearner
+    for mesh in (None, make_mesh("data")):
+        cfg_m = config_from_params(dict(
+            base, **({} if mesh is None else {"hist_exchange":
+                                              "psum_scatter"})))
+        lr = make(ds, cfg_m, mesh)
+        assert mesh is None or lr.hist_exchange == "psum_scatter"
+        got, _ = lr.train(g, h)
+        assert _splits_of(got) == _splits_of(want), mesh
