@@ -114,14 +114,14 @@ SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
 #    shard launches the same ones); 0 without a mesh.  The closing psum
 #    of this vector's own global slots is not counted.
 #  - SPLIT_CELLS: histogram cells that split search scans, as padded
-#    and as executed: searched slots (1 at the root, twice a chunk's
-#    slots per executed chunk, live or not) x the features of the array
-#    the search runs over x padded bins — the store's padded columns
-#    (a device's slice under psum_scatter), bundled or not: a bundled
+#    and as executed: searched slots (1 at the root, twice the slots of
+#    the tier an executed chunk ran at, live or not) x the features of
+#    the array the search runs over x padded bins — the store's padded
+#    columns (a device's slice under psum_scatter), bundled or not: a bundled
 #    store is searched in its own cells (ops/split.best_split_in_store);
 #    every original feature only where a bundled histogram is first
 #    unbundled.  Per device.  Static per pass, so it does not ride the
-#    vector: RoundsTreeLearner folds it on the host from HIST_PASSES
+#    vector: RoundsTreeLearner folds it on the host from HIST_SLOTS
 #    (learner/rounds.search_counters, count_deferred's `fold`); the
 #    other learners do not count it.
 #  - UNBUNDLE_GATHER_ELEMS: histogram elements that an unbundle in

@@ -338,6 +338,18 @@ def counters_after_one_tree(toy, **over):
     return lr, got
 
 
+def searched_slots(got, cache):
+    """The root's one slot, then the tier every executed chunk ran at
+    (8 or 15 of K = 15: rounds of up to 8 splits, then 15), as the
+    launches' tree/hist_slots count it: searched twice where the cache
+    gives the larger child, once a launch where a second launch does."""
+    launches = got[profiling.HIST_PASSES] - 1
+    chunk_slots = got[profiling.HIST_SLOTS] - 1
+    assert launches >= 1
+    assert 8 * launches <= chunk_slots <= 15 * launches
+    return 1 + (2 if cache else 1) * chunk_slots
+
+
 @pytest.mark.parametrize("cache", [True, False])
 def test_counters_read_what_the_shapes_say_on_a_bundled_store(toy, cache):
     over = {} if cache else {"histogram_pool_size": 1e-6}
@@ -345,8 +357,8 @@ def test_counters_read_what_the_shapes_say_on_a_bundled_store(toy, cache):
     assert lr.cache_parent_hist == cache and lr.dataset.bundle_plan is not None
     chunks = (got[profiling.HIST_PASSES] - 1) / (1 if cache else 2)
     assert chunks >= 1 and chunks == int(chunks)
-    slots = 1 + 2 * 15 * chunks          # the root, then K = 15 slots twice
-    assert got[profiling.SPLIT_CELLS] == slots * lr.Fpad * lr.B
+    assert got[profiling.SPLIT_CELLS] == (
+        searched_slots(got, cache) * lr.Fpad * lr.B)
     assert got[profiling.UNBUNDLE_GATHER_ELEMS] == 0
     assert lr.F > lr.Fpad                # searched in store space
 
@@ -354,20 +366,24 @@ def test_counters_read_what_the_shapes_say_on_a_bundled_store(toy, cache):
 def test_counters_on_a_store_with_no_plan(toy):
     lr, got = counters_after_one_tree(toy, enable_bundle=False)
     assert lr.dataset.bundle_plan is None
-    slots = 1 + 2 * 15 * (got[profiling.HIST_PASSES] - 1)
-    assert got[profiling.SPLIT_CELLS] == slots * lr.Fpad * lr.B
+    assert got[profiling.SPLIT_CELLS] == (
+        searched_slots(got, True) * lr.Fpad * lr.B)
     assert got[profiling.UNBUNDLE_GATHER_ELEMS] == 0     # the key is there
 
 
 def test_a_searched_slot_is_what_best_split_is_traced_with(toy, monkeypatch):
     """The fold's static half against the program: every search the build
-    traces is over the store's own [Fpad, 3, B] per slot, K slots a chunk
-    and one at the root, and no search over [F, 3, B] is traced at all."""
-    seen, gathered = [], []
+    traces is over the store's own [Fpad, 3, B] per slot, batched at the
+    root's one slot and at each tier a chunk can run at (8 and 15 where
+    K = 15), and no search over [F, 3, B] is traced at all."""
+    seen, widths, gathered = [], set(), []
     real = rounds.best_split_in_store
 
     def spy(hist, *a, **kw):
         seen.append(tuple(hist.shape))
+        # under vmap the slot axis is the batch tracer's
+        widths.add(hist.val.shape[hist.batch_dim]
+                   if hasattr(hist, "batch_dim") else None)
         return real(hist, *a, **kw)
 
     monkeypatch.setattr(rounds, "best_split_in_store", spy)
@@ -375,13 +391,15 @@ def test_a_searched_slot_is_what_best_split_is_traced_with(toy, monkeypatch):
                         lambda hist, *a, **kw: gathered.append(hist.shape))
     lr, got = counters_after_one_tree(toy)
     assert set(seen) == {(lr.Fpad, 3, lr.B)} and not gathered
+    assert widths == {1} | set(rounds.chunk_tiers(15)) == {1, 8, 15}
     totals = np.zeros(len(rounds.STATS_COUNTERS))
-    totals[rounds.S_PASSES] = 1                          # a root-only tree
+    totals[rounds.S_SLOTS] = 1                           # a root-only tree
     assert dict(lr._fold_stats(totals, 1))[profiling.SPLIT_CELLS] == (
         lr.Fpad * lr.B)
-    totals[rounds.S_PASSES] = 3 + 2                      # two trees, 3 chunks
+    # two trees: their roots, then chunks run at 8, 15 and 8 slots
+    totals[rounds.S_SLOTS] = 2 + 8 + 15 + 8
     assert dict(lr._fold_stats(totals, 2))[profiling.SPLIT_CELLS] == (
-        (2 + 3 * 2 * 15) * lr.Fpad * lr.B)
+        (2 + 2 * (8 + 15 + 8)) * lr.Fpad * lr.B)
 
 
 # ---- (e) a dense store's program does not know the counters -----------------

@@ -473,9 +473,12 @@ def test_exchange_counters_on_four_devices_and_on_one(trained):
         assert moved["tree/rounds"] == rounds_
         assert moved["tree/hist_passes"] == passes
         # _exchange_bytes summed over the passes: the root's one slot,
-        # then the chunk's K slots a round, whatever tier was launched
+        # then the tier (8, 32 or K) the round's chunk ran at
+        slots = moved["tree/hist_slots"]
+        assert slots == one["tree/hist_slots"]
+        assert ITERS + 8 * rounds_ <= slots < ITERS + K * rounds_
         assert moved["tree/hist_exchange_bytes"] == (
-            4.0 * cols * 3 * B * legs * (ITERS + K * rounds_))
+            4.0 * cols * 3 * B * legs * slots)
         # sums over the shards, counted once: 4 x 5,003 rows a pass
         assert moved["tree/hist_rows_touched"] == passes * DEVICES * 5_003
         assert moved["tree/partition_rows"] == rounds_ * DEVICES * 5_003
@@ -485,9 +488,10 @@ def test_exchange_counters_on_four_devices_and_on_one(trained):
     assert moved["tree/hist_mxu_ops"] == pytest.approx(
         one["tree/hist_mxu_ops"] * (DEVICES * 5_003) / ROWS, rel=1e-6)
     _, moved = trained("psum_scatter")
-    # a [4, k, 11] float32 gather for the root and two a round
+    # a [4, k, 11] float32 gather for the root and two a round, k the
+    # round's tier
     assert moved["tree/split_records_bytes"] == (
-        4.0 * DEVICES * 11 * (ITERS + 2 * K * rounds_))
+        4.0 * DEVICES * 11 * (ITERS + 2 * (slots - ITERS)))
     # root: leaf totals, scatter, records; a round: scatter, two gathers
     assert moved["tree/exchange_collectives"] == 3 * ITERS + 3 * rounds_
 

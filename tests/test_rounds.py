@@ -253,3 +253,95 @@ def test_rounds_num_leaves_past_int8_gates():
     acc = ((p > 0.5) == (y > 0.5)).mean()
     assert acc > 0.9, acc
     assert max(t.num_leaves for t in bst._gbdt.models) > 255
+
+
+def _tier_problem(store):
+    """(X, y, params) on which a tree of up to 100 leaves runs chunks of
+    LEAVES_PER_BATCH = 40 slots at every tier (8, 32, 40) and compiles a
+    short last chunk of 20: 16 numeric columns, and for the bundled
+    store six one-hot groups beside them that EFB packs into few
+    columns.  +-1 gradients and constant hessians keep every histogram
+    sum exact in any order."""
+    rng = np.random.RandomState(5)
+    N = 6000
+    X = rng.randn(N, 16)
+    y = (X[:, 0] + 0.7 * X[:, 1] * X[:, 2] + 0.5 * np.sin(3 * X[:, 3])
+         > 0).astype(np.float64)
+    params = {"objective": "binary", "num_leaves": 100, "verbose": -1,
+              "min_data_in_leaf": 12, "tree_growth": "rounds",
+              "min_sum_hessian_in_leaf": 1e-3}
+    if store == "bundled":
+        import scipy.sparse as sps
+        groups = []
+        for levels in (5, 7, 11, 13, 17, 19):
+            lv = rng.randint(levels, size=N)
+            groups.append(np.eye(levels)[lv])
+        X = sps.csr_matrix(np.hstack([X] + groups))
+        params.update(enable_bundle=True, sparse_store="dense")
+    elif store == "psum_scatter4":
+        params.update(tree_learner="data", num_machines=4,
+                      hist_exchange="psum_scatter")
+    return X, y, params
+
+
+@pytest.mark.parametrize("cache", [True, False], ids=["cache", "no_cache"])
+@pytest.mark.parametrize("store", ["dense", "bundled", "psum_scatter4"])
+def test_a_chunk_runs_whole_at_its_slot_tier(monkeypatch, store, cache):
+    """Every chunk of a round runs launch, exchange, subtraction, both
+    searches and the cache update at the narrowest tier that holds its
+    active slots (chunk_tiers).  The tree and every row's leaf are bit for
+    bit what the same build grows with each chunk at its full width — the
+    schedule before the tiers reached past the launch — and
+    tree/split_cells counts the searched slots from the launched ones."""
+    import jax
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import profiling
+    from lightgbm_tpu.learner import rounds as rounds_mod
+    monkeypatch.setattr(rounds_mod, "LEAVES_PER_BATCH", 40)
+    X, y, params = _tier_problem(store)
+    if not cache:
+        params["histogram_pool_size"] = 1e-6
+    launched = []
+    real = rounds_mod.hist_multileaf_masked
+
+    def spy(bins, lid, gh, slots, **kw):
+        # the width of each launch that RUNS, not of each one traced
+        jax.debug.callback(lambda s: launched.append(s.shape[0]), slots)
+        return real(bins, lid, gh, slots, **kw)
+
+    monkeypatch.setattr(rounds_mod, "hist_multileaf_masked", spy)
+    g = np.where(y > 0, -1.0, 1.0).astype(np.float32)
+    h = np.full(len(y), 0.5, np.float32)
+
+    def grow():
+        lr = lgb.Booster(params, lgb.Dataset(X, y))._gbdt.learner
+        assert isinstance(lr, RoundsTreeLearner)
+        assert lr.cache_parent_hist == cache
+        assert (lr.dataset.bundle_plan is not None) == (store == "bundled")
+        assert (lr.hist_exchange, lr.dd) == (
+            ("psum_scatter", 4) if store == "psum_scatter4" else ("psum", 1))
+        profiling.reset()
+        del launched[:]
+        _, lid, arrs = lr.train_device(jnp.asarray(g), jnp.asarray(h))
+        jax.effects_barrier()
+        got = profiling.counters("tree/")
+        profiling.reset()
+        return (jax.tree_util.tree_map(np.asarray, arrs), np.asarray(lid),
+                got, sorted(set(launched)), lr)
+
+    tiered, lid, got, widths, lr = grow()
+    assert widths == [1, 8, 32, 40]        # the root, then every tier ran
+    assert 40 < int(tiered.num_leaves) <= 100
+    monkeypatch.setattr(rounds_mod, "chunk_tiers", lambda chunk: (chunk,))
+    whole, lid_w, got_w, widths_w, _ = grow()
+    assert widths_w == [1, 40]
+    for name, a, b in zip(tiered._fields, tiered, whole):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    np.testing.assert_array_equal(lid, lid_w)
+    # the same launches, each at its tier: fewer slots, as many passes
+    assert got[profiling.HIST_PASSES] == got_w[profiling.HIST_PASSES]
+    assert got[profiling.HIST_SLOTS] < got_w[profiling.HIST_SLOTS]
+    cells = (lr.Fpad // (4 if store == "psum_scatter4" else 1)) * lr.B
+    slots = got[profiling.HIST_SLOTS]
+    assert got[profiling.SPLIT_CELLS] == cells * (
+        2 * slots - 1 if cache else slots)

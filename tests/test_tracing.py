@@ -355,6 +355,8 @@ def test_the_learner_feeds_every_stats_counter_as_one_vector():
     assert names == rounds.STATS_COUNTERS
     # what is static per pass rides no slot: folded on the host
     assert fold.func is rounds.search_counters
+    # searched slots from the launched ones: twice a chunk's with the cache
+    assert fold.keywords["cached"] is bst._gbdt.learner.cache_parent_hist
     assert profiling._deferred[(names, fold)][0].shape == (12,)
     got = profiling.counters("tree/")
     assert set(got) >= set(rounds.STATS_COUNTERS)
