@@ -13,7 +13,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
-from .config import Config, config_from_params
+from .config import Config, apply_aliases, config_from_params
 from .dataset import Dataset as _InnerDataset, Metadata
 from .boosting.gbdt import GBDT, create_boosting
 from .log import LightGBMError  # noqa: F401  (canonical error type)
@@ -130,10 +130,10 @@ def _apply_pandas_categorical(data, pandas_categorical):
     return df
 
 
-def _resolve_categorical(data, categorical_feature, feature_name):
+def _resolve_categorical(data, categorical_feature, feature_name, params=None):
     """pandas categorical columns -> codes + column index list
     (reference basic.py:192-260 pandas handling)."""
-    cat_cols: List[int] = []
+    cat_cols: List[int] = _params_categorical(params, data, feature_name)
     pandas_categorical = None
     if hasattr(data, "dtypes") and hasattr(data, "columns"):
         import pandas as pd  # type: ignore
@@ -197,7 +197,7 @@ class Dataset:
             self._raw_X = None
         else:
             data, cat_cols, self.pandas_categorical = _resolve_categorical(
-                self.data, self.categorical_feature, self.feature_name)
+                self.data, self.categorical_feature, self.feature_name, merged)
             y = None if self.label is None else _to_numpy(self.label).reshape(-1)
             md = Metadata()
             if self.weight is not None:
@@ -587,3 +587,25 @@ class Booster:
         # category lists travel inside the model text trailer
         self.pandas_categorical = _load_pandas_categorical(
             state["model_str"])
+
+
+def _params_categorical(params, data, feature_name) -> List[int]:
+    """The columns that params name as categorical (`categorical_feature`
+    or an alias): indices, or `name:` and feature names, as the file
+    loader reads them; a list of indices or names as the constructor's
+    argument takes them.  (Defined down here: the lines of the Booster
+    methods above are frames that the device programs' kernels record.)"""
+    spec = apply_aliases(dict(params or {})).get("categorical_column")
+    if spec is None or (isinstance(spec, str) and not spec.strip()):
+        return []
+    names = None
+    if feature_name not in (None, "auto"):
+        names = list(feature_name)
+    elif hasattr(data, "columns"):
+        names = [str(c) for c in data.columns]
+    if isinstance(spec, (list, tuple)):
+        return _resolve_categorical(None, spec, names)[1]
+    from .dataset import _parse_categorical_column
+    shape = np.shape(data)
+    return _parse_categorical_column(str(spec), names,
+                                     shape[1] if len(shape) > 1 else 1)

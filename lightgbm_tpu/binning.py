@@ -36,25 +36,44 @@ class BinMapper:
     # numerical
     bin_upper_bound: np.ndarray = field(default_factory=lambda: np.array([np.inf]))
     # categorical: bin i holds category bin_2_categorical[i] (the inverse
-    # map is the sorted lookup table value_to_bin builds lazily)
+    # map is the sorted lookup table value_to_bin builds lazily); where
+    # the binning dropped categories, one bin more than the list holds
+    # them all (`other_bin`)
     bin_2_categorical: List[int] = field(default_factory=list)
     min_val: float = 0.0
     max_val: float = 0.0
     default_bin: int = 0
     sparse_rate: float = 0.0
 
+    @property
+    def other_bin(self) -> bool:
+        """A categorical mapper whose last bin holds every category the
+        binning did not keep.  That bin is never a split threshold
+        (`split_num_bin`): the model text names a threshold by its one
+        category, and a row of a category it does not name goes right
+        in every predictor, so training sends it right too."""
+        return (self.bin_type == CATEGORICAL
+                and self.num_bin > len(self.bin_2_categorical))
+
+    @property
+    def split_num_bin(self) -> int:
+        """Bins a split search takes as thresholds: `num_bin` less the
+        `other_bin`."""
+        return self.num_bin - int(self.other_bin)
+
     def value_to_bin(self, values: np.ndarray) -> np.ndarray:
         """Vectorized value->bin (reference bin.h:418-440).  NaN maps to
         value 0 (v2.0-era missing handling; searchsorted would otherwise
-        return an out-of-range bin).  Unseen categories map to bin 0."""
+        return an out-of-range bin).  A category outside the kept list
+        maps to the `other_bin`, or to bin 0 where there is none."""
         values = np.asarray(values, dtype=np.float64)
         values = np.where(np.isnan(values), 0.0, values)
         if self.bin_type == NUMERICAL:
             return np.searchsorted(self.bin_upper_bound, values, side="left").astype(
                 np.int32)
         # categorical: one searchsorted over the sorted category table
-        # instead of a Python loop per category (Expo-scale data has
-        # hundreds of categories x millions of rows)
+        # instead of a Python loop per category (a store of a hundred
+        # million rows holds hundreds of categories in a column)
         cs = getattr(self, "_cat_sorted", None)
         # rebuild when the category list changed since the table was
         # built; the snapshot tuple compares by VALUE, so in-place
@@ -70,10 +89,11 @@ class BinMapper:
         iv = values.astype(np.int64)
         pos = np.clip(np.searchsorted(cats_sorted, iv), 0,
                       max(len(cats_sorted) - 1, 0))
+        miss = np.int32(self.num_bin - 1 if self.other_bin else 0)
         if len(cats_sorted) == 0:
-            return np.zeros(values.shape, np.int32)
+            return np.full(values.shape, miss, np.int32)
         return np.where(cats_sorted[pos] == iv, bins_sorted[pos],
-                        np.int32(0)).astype(np.int32)
+                        miss).astype(np.int32)
 
     def bin_to_value(self, b: int) -> float:
         """Real-valued threshold stored in the model text for bin `b`."""
@@ -276,9 +296,14 @@ def find_bin_from_distinct(vals: np.ndarray, counts: np.ndarray,
             m.bin_2_categorical.append(int(ivals_u[nb]))
             used_cnt += int(icounts[nb])
             nb += 1
-        m.num_bin = nb
         cnt_in_bin = [int(c) for c in icounts[:nb]]
-        cnt_in_bin[-1] += int(total_sample_cnt - used_cnt)
+        if nb < ivals_u.size:
+            # the dropped categories share one bin of their own, which
+            # no split takes as its threshold (BinMapper.other_bin)
+            cnt_in_bin.append(int(total_sample_cnt - used_cnt))
+        else:
+            cnt_in_bin[-1] += int(total_sample_cnt - used_cnt)
+        m.num_bin = len(cnt_in_bin)
 
     m.is_trivial = m.num_bin <= 1
     if not m.is_trivial and _need_filter(cnt_in_bin, total_sample_cnt,

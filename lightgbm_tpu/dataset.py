@@ -786,6 +786,10 @@ class Dataset:
         F = len(used)
         self.num_bins = np.array([self.mappers[i].num_bin for i in used],
                                  dtype=np.int32)
+        # what split search scans: a categorical feature's bin of the
+        # categories its binning dropped is stored, never a threshold
+        self.split_num_bins = np.array(
+            [self.mappers[i].split_num_bin for i in used], dtype=np.int32)
         self.is_categorical = np.array(
             [self.mappers[i].bin_type == CATEGORICAL for i in used],
             dtype=bool)
@@ -1166,8 +1170,10 @@ class Dataset:
         unbundle_tables."""
         if self.bundle_plan is None:
             return None
+        # a categorical member packs nothing here (None above), so its
+        # bins are its candidates: the split count, not the stored one
         return self.bundle_plan.search_tables(
-            self.num_bins, self.is_categorical, num_bins_padded,
+            self.split_num_bins, self.is_categorical, num_bins_padded,
             num_columns_padded)
 
     def unbundled_bins(self) -> np.ndarray:
@@ -1364,7 +1370,7 @@ class Dataset:
                 [m.min_val, m.max_val, m.sparse_rate], np.float64)
             arrays[f"m{i}_upper"] = np.asarray(m.bin_upper_bound, np.float64)
             arrays[f"m{i}_cats"] = np.asarray(m.bin_2_categorical, np.int64)
-        # stream straight to disk: at Expo scale (11M x 700) a BytesIO
+        # stream straight to disk: for a store of 100M rows a BytesIO
         # staging copy would add a multi-GB compressed buffer to peak
         # RSS at exactly the moment the raw matrix is also resident
         with open(path, "wb") as f:

@@ -509,10 +509,13 @@ def tree_arrays_to_host(arrs, dataset: Dataset, max_leaves: int) -> Tree:
     n = int(a.num_leaves)
     t = Tree(max_leaves)
     t.num_leaves = n
+    k = max(n - 1, 0)
+    from .. import profiling
+    profiling.count(profiling.CATEGORICAL_SPLITS,
+                    float(np.count_nonzero(a.is_cat[:k])))
     if n < 2:
         t.leaf_value[0] = float(a.leaf_value[0])
         return t
-    k = n - 1
     t.split_feature_inner[:k] = a.split_feature[:k]
     t.threshold_in_bin[:k] = a.threshold_bin[:k]
     t.decision_type[:k] = np.where(a.is_cat[:k], CATEGORICAL_DECISION,
@@ -629,7 +632,7 @@ class FusedTreeLearner:
                     bins_np = np.pad(bins_np,
                                      ((0, self.Fp - self.F),
                                       (0, self._local_np - self.N)))
-        nb = np.pad(dataset.num_bins.astype(np.int32),
+        nb = np.pad(dataset.split_num_bins.astype(np.int32),
                     (0, self.Fp - self.F), constant_values=1)
         ic = np.pad(dataset.is_categorical, (0, self.Fp - self.F))
         self._base_fmask = np.pad(np.ones(self.F, bool),

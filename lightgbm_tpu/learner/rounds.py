@@ -61,7 +61,7 @@ from .. import profiling
 from ..jaxutil import RowLayout, bag_mask_dev
 from ..ops.histogram import (hist_multileaf_masked, hist_sparse_multileaf,
                              kernel_launch_slots, masked_hist_mxu_ops,
-                             masked_store_copy_rows, quantize_gh,
+                             masked_store_copy_rows, int8_operands,
                              sparse_window_streams, store_alignment)
 from ..ops.partition import (partition_rows, partition_rows_sparse,
                              partition_store_copy_rows)
@@ -350,8 +350,8 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     skw = dict(split_kw)
     l1, l2 = skw["lambda_l1"], skw["lambda_l2"]
     # int8-stored bins (value-128, see ops/histogram bin_offset) stay
-    # narrow: a [F, N] int32 copy would be 4x the HBM (30.8 GB at Expo
-    # shape); every consumer widens in fused ops / kernel VMEM
+    # narrow: a [F, N] int32 copy would be 4x the HBM (12.8 GB for a
+    # [32, 100M] store); every consumer widens in fused ops / kernel VMEM
     if sparse:
         binsf = None
     elif bins.dtype == jnp.int8:
@@ -460,7 +460,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         gh8 = gh8.at[0].set(grad * row_mask).at[1].set(hess * row_mask)
         gh8 = gh8.at[2].set(row_mask)
         # every launch of the tree is over these rows: quantise once
-        ghq = (quantize_gh(gh8) if input_dtype == "int8" and not sparse
+        ghq = (int8_operands(gh8) if input_dtype == "int8" and not sparse
                else None)
     with jax.named_scope("lgbt.root"):
         lid0 = jnp.zeros(Nloc, jnp.int32)
@@ -818,7 +818,7 @@ class RoundsTreeLearner:
 
         backend = _kernel_backend()
         input_dtype = getattr(config, "histogram_dtype", "float32")
-        nbv = dataset.num_bins.astype(np.int32)      # ORIGINAL [F]
+        nbv = dataset.split_num_bins.astype(np.int32)  # ORIGINAL [F]
         icv = np.asarray(dataset.is_categorical)     # ORIGINAL [F]
         plan = dataset.bundle_plan
         # nonzero-iterating sparse path (docs/Sparse.md): single-process
@@ -840,12 +840,12 @@ class RoundsTreeLearner:
                 site="rounds_feed")                  # [C, N] (bundled: C<F)
             self.Cstore = store.shape[0]
             # int8 HBM layout (value - 128): 4x less device memory and
-            # bandwidth than int32 — what fits Expo's 11M x 700 store
-            # (7.7 GB vs 30.8 GB) on one v5e chip.  Memory-gated: the
-            # G=32 block layout it forces measured ~60% slower than the
-            # int32 G=8 layout on wide 255-bin data (Epsilon shape), so
-            # narrow storage is chosen only when int32 bins would crowd
-            # the device (see _want_int8_bins).
+            # bandwidth than int32, for a store whose int32 layout would
+            # crowd the chip (over a quarter of its memory: 100M rows of
+            # 13 columns, say).  Memory-gated: the G=32 block layout it
+            # forces measured ~60% slower than the int32 G=8 layout on
+            # wide 255-bin data (Epsilon shape), so narrow storage is
+            # chosen only then (see _want_int8_bins).
             bins_dtype = (np.int8 if backend == "pallas"
                           and dataset.max_num_bin <= 256
                           and self._want_int8_bins() else np.int32)
@@ -1074,11 +1074,11 @@ class RoundsTreeLearner:
                 np.stack(out_s))
 
     def _want_int8_bins(self) -> bool:
-        """Narrow bin storage only under memory pressure: int32 bins
-        beyond ~25% of device HBM (Expo-scale) switch to the int8
-        value-128 layout; narrow/regular data keeps the faster int32
-        G=8 kernel layout.  LGBT_BINS_INT8=0/1 overrides for on-chip
-        experiments."""
+        """Narrow bin storage only under memory pressure: a device's
+        share of the int32 store beyond 25% of its memory switches to
+        the int8 value-128 layout; a smaller store keeps the faster
+        int32 G=8 kernel layout.  LGBT_BINS_INT8=0/1 overrides for
+        on-chip experiments."""
         import os
         ov = os.environ.get("LGBT_BINS_INT8", "")
         if ov in ("0", "1"):

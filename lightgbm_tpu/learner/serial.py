@@ -145,7 +145,7 @@ class SerialTreeLearner:
         bt = sentinel_bins_t(dataset)              # store layout [N+1, C]
         self.bins = jnp.asarray(bt.T.copy())   # [C, N+1]
         self.bins_t = jnp.asarray(bt)          # [N+1, C]
-        self.num_bins_dev = jnp.asarray(dataset.num_bins)
+        self.num_bins_dev = jnp.asarray(dataset.split_num_bins)
         self.is_cat_dev = jnp.asarray(dataset.is_categorical)
         ft = dataset.bundle_feat_table()
         self.ftbl = (identity_feat_table(dataset.num_bins) if ft is None

@@ -147,6 +147,13 @@ UNBUNDLE_GATHER_ELEMS = "tree/unbundle_gather_elems"
 #    sharded over a mesh and the score does not.  Read off the two
 #    shardings, no sync; the 0 registers the key.
 SCORE_GATHER_ROWS = "tree/score_gather_rows"
+# Counted on the host by count() where a build's tree arrays become a
+# host tree (learner/fused.tree_arrays_to_host), from the is_cat they
+# carry: no device operation, no sync:
+#  - CATEGORICAL_SPLITS: splits on a categorical feature (one category
+#    against the rest) in the trees collected.  A tree with none counts
+#    0, which registers the key.
+CATEGORICAL_SPLITS = "tree/categorical_splits"
 # Nothing increments these three since the row feed they counted went;
 # they stay, at 0 from the start, only for benchmark/ (jobs/train.py and
 # the feed_rows_per_iter metric read them) until ROADMAP B0.5 drops it.
@@ -275,6 +282,7 @@ CANONICAL_COUNTERS = (
     HIST_LIVE_SLOTS, HIST_MXU_OPS, FEED_ROWS, FEED_LIVE_ROWS,
     PARTITION_ROWS, STORE_COPY_ROWS, EXCHANGE_COLLECTIVES,
     SPLIT_CELLS, UNBUNDLE_GATHER_ELEMS, SCORE_GATHER_ROWS,
+    CATEGORICAL_SPLITS,
     SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,

@@ -60,6 +60,21 @@ def main():
         "implementations (`lightgbm_tpu/metrics.py`, "
         "`lightgbm_tpu/ops/eval.py`).",
         "",
+        "## Categorical columns",
+        "",
+        "`categorical_column` (aliases `categorical_feature`, "
+        "`cat_feature`, `cat_column`) names the columns whose values are "
+        "category codes: indices (`0,3,5`), or `name:` and feature names "
+        "(`name:Origin,Dest`). It is read wherever a dataset is binned: a "
+        "data file, the C API, and `lgb.Dataset(X, y).construct(params)` "
+        "or `lgb.train(params, lgb.Dataset(X, y))`, where it adds to the "
+        "constructor's `categorical_feature` and to pandas `category` "
+        "columns. A split on such a column sends the rows of one category "
+        "left. Categories past the binning's cut (at least `max_bin` "
+        "kept, and as many more as 98 % of the sample takes) share one "
+        "bin that is never a threshold, so their rows go right in "
+        "training as in every predictor.",
+        "",
         "## TPU-specific parameters",
         "",
         "- `histogram_dtype` (default `float32`): MXU input precision for "

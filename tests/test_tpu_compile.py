@@ -270,6 +270,56 @@ def test_allstate_build_program(shape):
           "args", mem.argument_size_in_bytes)
 
 
+def test_airline_build_program(shape):
+    """The whole build step of the benchmark cell `airline.full`, as
+    RoundsTreeLearner jits it on the chip: the configuration's rows by 13
+    columns, six of them categorical, in the int8-stored layout the
+    learner picks for a store whose int32 layout would pass a quarter of
+    the chip — `[32, Np]` int8, one 32-column block, rows to the 2,048-row
+    chunk — 255 leaves, the per-leaf cache, and more than 2^31 store
+    elements on one device.  Above 16M rows a launch takes bfloat16
+    operands, so the build keeps no int8-quantised `[8, Np]` int32 copy of
+    its gradient block (a dead 32 B a row: 10.4 GB of temporaries at
+    100M rows with it, 3.7 GB without, PERF.md section 4)."""
+    import functools
+    import json
+    import os
+    from lightgbm_tpu.config import config_from_params
+    from lightgbm_tpu.learner.common import make_split_kw
+    from lightgbm_tpu.learner.rounds import build_tree_rounds
+    from lightgbm_tpu.ops.histogram import INT8_EXACT_ROWS, store_alignment
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "airline.json")) as f:
+        config = json.load(f)
+    cfg = config_from_params(dict(config["params"], verbose=-1))
+    col, row = store_alignment(1, B, "int8", 256)
+    rows = int(config["rows"])
+    F, n = 13 + (-13) % col, rows + (-rows) % row
+    assert (F, row) == (32, 2048) and F * n > 2 ** 31 and n > INT8_EXACT_ROWS
+    build = functools.partial(
+        build_tree_rounds, num_leaves=255, num_bins_padded=B,
+        max_num_bin=256, split_kw=make_split_kw(cfg), max_depth=-1,
+        min_data_in_leaf=cfg.min_data_in_leaf,
+        min_sum_hessian_in_leaf=cfg.min_sum_hessian_in_leaf,
+        backend="pallas", input_dtype=cfg.histogram_dtype,
+        cache_parent_hist=True)
+    compiled = build_program("airline", lambda: compile_for_chip(
+        build, shape((F, n), jnp.int8), shape((n,), jnp.float32),
+        shape((n,), jnp.float32), shape((n,), jnp.float32),
+        shape((F,), jnp.int32), shape((F,), jnp.bool_),
+        shape((F,), jnp.bool_)))
+    text = compiled.as_text()
+    assert f"s32[8,{n}]" not in text
+    mem = compiled.memory_analysis()
+    # read: 4,201,258,496 B at 115M rows, 36.5 B a row (4.17 GB reserved
+    # on the chip, PERF.md section 4)
+    assert mem.temp_size_in_bytes < 38 * n, mem.temp_size_in_bytes
+    held = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert held < 88 * n, held
+
+
 def criteo_tb_build(topo):
     """build_tree_rounds under shard_map over a described v5e:2x2 at
     `criteo_tb.data4`'s shapes: a shard `[72, 13,500,416]` of the int32
