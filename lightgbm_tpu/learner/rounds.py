@@ -61,7 +61,8 @@ from .. import profiling
 from ..jaxutil import RowLayout, bag_mask_dev
 from ..ops.histogram import (hist_multileaf_masked, hist_sparse_multileaf,
                              kernel_launch_slots, masked_hist_mxu_ops,
-                             masked_store_copy_rows, int8_operands,
+                             masked_pad_columns, masked_store_copy_rows,
+                             int8_operands,
                              sparse_window_streams, store_alignment)
 from ..ops.partition import (partition_rows, partition_rows_sparse,
                              partition_store_copy_rows)
@@ -166,7 +167,8 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                       num_devices: int = 1,
                       num_feature_shards: int = 1,
                       leaves_per_batch: int = 0,
-                      sparse: bool = False):
+                      sparse: bool = False,
+                      real_columns: int = 0):
     """Grow one tree in batched rounds.  Shapes as learner/fused.build_tree.
     Returns (TreeArrays, leaf_id, stats) — stats is a [12] f32 vector in
     the order of STATS_COUNTERS: rows processed by histogram kernels
@@ -254,7 +256,13 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     so hist_exchange (psum / psum_scatter slice ownership) and the
     round logic compose unchanged.  The stats vector's S_NNZ element
     counts the stored entries touched by histogram kernels (global
-    across shards — the tree/sparse_nnz_touched counter)."""
+    across shards — the tree/sparse_nnz_touched counter).
+
+    real_columns (static; 0 = all): the leading store columns that hold
+    data.  The dense launches histogram only those
+    (ops/histogram.hist_multileaf_masked) and hand back exact zeros for
+    the columns a learner pads the store with, which split search never
+    reads; tree/hist_mxu_ops counts the contraction as executed."""
     if sparse:
         sp_cols, sp_bins, sp_zb = bins[0], bins[1], bins[2]
         # stream leaves arrive stacked with a leading shard axis (one
@@ -366,6 +374,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         lay_kw = dict(bins_itemsize=binsf.dtype.itemsize, num_bins_padded=B,
                       backend=backend, input_dtype=input_dtype,
                       max_num_bin=max_num_bin)
+        cols_kw = dict(lay_kw, real_columns=real_columns)
     # rows of the store one round's partition copies before its kernel
     partition_copy = 0 if sparse else partition_store_copy_rows(
         F, Nloc, bins_itemsize=binsf.dtype.itemsize, num_slots=L + 1,
@@ -380,7 +389,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         if sparse:
             v[S_NNZ] = nnz_pass
         else:
-            v[S_OPS] = masked_hist_mxu_ops(F, Nloc, k, **lay_kw)
+            v[S_OPS] = masked_hist_mxu_ops(F, Nloc, k, **cols_kw)
             v[S_COPY] = masked_store_copy_rows(F, Nloc, k, **lay_kw)
         return jnp.stack([jnp.float32(x) for x in v])
 
@@ -395,7 +404,8 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 input_dtype=input_dtype)
         return hist_multileaf_masked(
             binsf, lid_, gh8, sl_, num_bins_padded=B, backend=backend,
-            input_dtype=input_dtype, max_num_bin=max_num_bin, ghq=ghq)
+            input_dtype=input_dtype, max_num_bin=max_num_bin, ghq=ghq,
+            real_columns=real_columns)
 
     # a bundled store searched in its own cells: the tree's feature
     # mask reaches cell space here, once, not once a searched slot; a
@@ -764,22 +774,28 @@ def _jit_build(fn):
     return jax.jit(step)
 
 
-def search_counters(totals, trees, *, cells, cached, unbundled):
-    """tree/split_cells and tree/unbundle_gather_elems of `trees` builds
-    whose stats vectors sum to `totals`, on the host (count_deferred's
-    `fold`): both are static per searched slot, and the vector already
-    counts the slots launched.  A tree searches its root's one slot and,
+def search_counters(totals, trees, *, cells, cached, unbundled,
+                    pad_columns=0.0):
+    """tree/split_cells, tree/unbundle_gather_elems and
+    tree/hist_pad_columns of `trees` builds whose stats vectors sum to
+    `totals`, on the host (count_deferred's `fold`): each is static per
+    searched slot or per launch, and the vector already counts the
+    slots and the launches.  A tree searches its root's one slot and,
     per executed chunk of a round, the slots of the tier the chunk ran
     at twice (smaller and larger children): with the parent cache
     (`cached`) one launch of that tier, both searched, else two, each
     searched once.  `cells` is one slot's [features, B] as the search
     scans it; an unbundle in front of it (`unbundled`) gathers that
-    three times over (grad, hess, count)."""
+    three times over (grad, hess, count).  `pad_columns` are the padded
+    store columns that every launch leaves out, summed over shards
+    (ops/histogram.masked_pad_columns)."""
     launched = float(totals[S_SLOTS])
     slots = trees + 2.0 * (launched - trees) if cached else launched
     return ((profiling.SPLIT_CELLS, cells * slots),
             (profiling.UNBUNDLE_GATHER_ELEMS,
-             3.0 * cells * slots if unbundled else 0.0))
+             3.0 * cells * slots if unbundled else 0.0),
+            (profiling.HIST_PAD_COLUMNS,
+             pad_columns * float(totals[S_PASSES])))
 
 
 class RoundsTreeLearner:
@@ -886,6 +902,13 @@ class RoundsTreeLearner:
         if self.hist_exchange == "psum_scatter" and nsh > 1:
             self.Fpad = pad_cols_to_ndev(
                 self.Fpad, self._nd_sc, align=col_mult)
+        # the dense launches histogram the Cstore real columns only: the
+        # padded ones each launch leaves out, on all shards together
+        self._pad_columns = 0 if self.sparse else nsh * masked_pad_columns(
+            self.Fpad, real_columns=self.Cstore,
+            bins_itemsize=np.dtype(bins_dtype).itemsize,
+            num_bins_padded=self.B, backend=backend,
+            input_dtype=input_dtype, max_num_bin=int(dataset.max_num_bin))
         if self.sparse:
             sps = dataset.sparse
             cols_np = sps.cols.astype(np.int32)
@@ -973,7 +996,8 @@ class RoundsTreeLearner:
                   num_devices=self.dd,
                   num_feature_shards=self.df,
                   ftbl=ftbl, unb=unb, sparse=self.sparse,
-                  input_dtype=input_dtype)
+                  input_dtype=input_dtype,
+                  real_columns=0 if self.sparse else self.Cstore)
         self._fold_stats = self._search_counters(
             unb is not None and not isinstance(unb, StoreCells))
         if mesh is None:
@@ -1040,7 +1064,8 @@ class RoundsTreeLearner:
             feats = self.Fpad
         return functools.partial(
             search_counters, cells=float(feats * self.B),
-            cached=self.cache_parent_hist, unbundled=unbundled)
+            cached=self.cache_parent_hist, unbundled=unbundled,
+            pad_columns=float(self._pad_columns))
 
     def _build_sparse_streams(self, cols_np: np.ndarray,
                               ell_np: np.ndarray, nsh: int, backend: str):

@@ -302,7 +302,7 @@ def _simple_onehot(gb, B, input_dtype):
 
 
 def _packed_onehot(gb_ref, g_, B, pack, bins_sub, out_dtype,
-                   bin_offset=0, bwin=0):
+                   bin_offset=0, bwin=0, count=0):
     """One-hot block [B, Ck] — bins on the sublanes, rows on the lanes —
     for `pack` features sharing the 128 bins of one output lane block:
     feature s of the pack occupies bins [s·bins_sub, (s+1)·bins_sub), so
@@ -334,10 +334,13 @@ def _packed_onehot(gb_ref, g_, B, pack, bins_sub, out_dtype,
     bf16 tiles hold 4x / 2x the lanes) does not exist on the v5e VPU:
     Mosaic refuses `arith.cmpi` on i8 vectors and `arith.cmpf` on bf16
     ("Target does not support this comparison"), and an i1 mask has no
-    relayout to the (32, 128) int8 tile, hence the i32 hop below."""
+    relayout to the (32, 128) int8 tile, hence the i32 hop below.
+
+    count: the features of the pack that are compared (0 = all `pack`);
+    the sub-blocks of the others stay zero."""
     iota = jax.lax.broadcasted_iota(jnp.int32, (B, 1), 0) + bwin
     acc = None
-    for s in range(pack):
+    for s in range(count or pack):
         gb = gb_ref[0, g_ * pack + s, :].astype(jnp.int32) + bin_offset
         cmp = (gb[None, :] + (s * bins_sub)) == iota
         acc = cmp if acc is None else acc | cmp
@@ -354,7 +357,8 @@ _CONTRACT_ROWS = (((1,), (1,)), ((), ()))
 def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
                         B: int, K: int, input_dtype, pack: int = 1,
                         bins_sub: int = 0, bin_offset: int = 0,
-                        windowed: bool = False):
+                        windowed: bool = False, ncols: int = 0,
+                        last: int = 0):
     """Multi-leaf histogram with the leaf masks built in VMEM.
 
     sl_ref : [Kp, 128] int32 — small-leaf id per slot, replicated across
@@ -380,6 +384,12 @@ def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
     Grid is (feature-blocks, row-chunks), or (feature-blocks,
     bin-windows, row-chunks) when `windowed` — the out block then
     covers one 128-lane bin window.
+
+    ncols: the (packed) columns of the block that are histogrammed, 0 =
+    all G/pack; `last` of the last one's `pack` features (0 = all).  The
+    output rows of the others keep the zeros of the init: a block whose
+    trailing columns are padding builds no one-hot and runs no
+    contraction for them.
     """
     from jax.experimental import pallas as pl
 
@@ -409,10 +419,10 @@ def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
             axis=0)
     prec = (jax.lax.Precision.HIGHEST if input_dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
-    G = gb_ref.shape[1]
-    for g_ in range(G // pack):
+    n = ncols or gb_ref.shape[1] // pack
+    for g_ in range(n):
         oh = _packed_onehot(gb_ref, g_, Bs, pack, bins_sub, input_dtype,
-                            bin_offset, bwin)
+                            bin_offset, bwin, last if g_ == n - 1 else 0)
         out_ref[0, g_, :, :] += jax.lax.dot_general(
             vals, oh, _CONTRACT_ROWS, preferred_element_type=jnp.float32,
             precision=prec)
@@ -421,7 +431,8 @@ def _hist_kernel_masked(sl_ref, gb_ref, lid_ref, gh_ref, out_ref, *,
 def _hist_kernel_masked_q(sl_ref, gb_ref, lid_ref, ghq_ref, out_ref, *,
                           B: int, K: int, pack: int = 1,
                           bins_sub: int = 0, bin_offset: int = 0,
-                          windowed: bool = False):
+                          windowed: bool = False, ncols: int = 0,
+                          last: int = 0):
     """int8-quantized variant of _hist_kernel_masked: vals and one-hot
     are int8 and the contraction accumulates exactly in int32 (v5e runs
     int8 MXU matmuls at 2x bf16 throughput).  ghq rows are pre-quantized
@@ -429,8 +440,8 @@ def _hist_kernel_masked_q(sl_ref, gb_ref, lid_ref, ghq_ref, out_ref, *,
     as int32; dequantization happens in the caller.  Every product is
     exact: masks are 0/1 and |q| <= 127.  Accumulation is exact while
     127 * rows_per_device < 2^31 — the caller enforces a 16M-row bound
-    and falls back to bfloat16 beyond it.  Grid as in
-    _hist_kernel_masked (bin-window axis only when `windowed`)."""
+    and falls back to bfloat16 beyond it.  Grid, `ncols` and `last` as
+    in _hist_kernel_masked (bin-window axis only when `windowed`)."""
     from jax.experimental import pallas as pl
 
     if windowed:
@@ -460,10 +471,10 @@ def _hist_kernel_masked_q(sl_ref, gb_ref, lid_ref, ghq_ref, out_ref, *,
             [vals32, jnp.zeros((Mp - 3 * K, vals32.shape[1]),
                                jnp.int32)], axis=0)
     vals = vals32.astype(jnp.int8)
-    G = gb_ref.shape[1]
-    for g_ in range(G // pack):
+    n = ncols or gb_ref.shape[1] // pack
+    for g_ in range(n):
         oh = _packed_onehot(gb_ref, g_, Bs, pack, bins_sub, jnp.int8,
-                            bin_offset, bwin)
+                            bin_offset, bwin, last if g_ == n - 1 else 0)
         out_ref[0, g_, :, :] += jax.lax.dot_general(
             vals, oh, _CONTRACT_ROWS, preferred_element_type=jnp.int32)
 
@@ -571,6 +582,24 @@ def _masked_layout(F: int, C: int, K: int, bins_itemsize: int, B: int,
     return _MaskedLayout(G, G // pack, pack, bins_sub, Mp, Kp, Bs, Ck, Cp, Fg)
 
 
+def _real_columns(F: int, real_columns: int) -> int:
+    """The leading columns of an [F, C] store that hold data: all F
+    unless the caller names fewer (0 = all)."""
+    return min(int(real_columns), F) if real_columns else F
+
+
+def _block_split(lay: _MaskedLayout, R: int) -> Tuple[int, int, int]:
+    """How a masked launch covers R real columns: (feature blocks
+    histogrammed whole, packed columns histogrammed in the block after
+    them — 0 if R fills its blocks —, features of that block's last
+    packed column, 0 if all `pack`).  The blocks past those are padding
+    and launch nothing."""
+    full, tail = divmod(R, lay.G)
+    ncols = -(-tail // lay.pack)
+    last = tail - (ncols - 1) * lay.pack if tail else 0
+    return full, ncols, 0 if last == lay.pack else last
+
+
 def store_alignment(bins_itemsize: int, num_bins_padded: int,
                     input_dtype: str, max_num_bin: int = 0
                     ) -> Tuple[int, int]:
@@ -605,35 +634,68 @@ def masked_store_copy_rows(F: int, C: int, K: int, *, bins_itemsize: int,
 
 def masked_hist_mxu_ops(F: int, C: int, K: int, *, bins_itemsize: int,
                         num_bins_padded: int, backend: str,
-                        input_dtype: str, max_num_bin: int = 0) -> float:
+                        input_dtype: str, max_num_bin: int = 0,
+                        real_columns: int = 0) -> float:
     """Operations (2 per multiply-add) that the contraction of one
-    `hist_multileaf_masked` launch over [F, C] bins and K slots
-    performs, padding included — what the MXU is asked to do, not what
-    the histogram needs.  Pallas: every row of the padded chunk grid
-    against Mp value rows, for each (packed) column of the padded
-    feature groups, over the padded bins.  XLA fallback: the plain
-    [3K, C] x [F, C, B] einsum."""
+    `hist_multileaf_masked` launch over [F, C] bins and K slots, of
+    which the first `real_columns` hold data (0 = all), performs as
+    executed — what the MXU is asked to do, not what the histogram
+    needs.  Pallas: every row of the padded chunk grid against Mp value
+    rows, over the padded bins, for each (packed) column launched: G/pack
+    a feature block the real columns fill, ceil(tail/pack) in the block
+    that holds the last of them, none in a block of padding.  XLA
+    fallback: the plain [3K, C] x [R, C, B] einsum over the real ones."""
     B = num_bins_padded
+    R = _real_columns(F, real_columns)
     if backend != "pallas":
-        return 2.0 * C * 3 * K * F * B
+        return 2.0 * C * 3 * K * R * B
     lay = _masked_layout(F, C, K, bins_itemsize, B, input_dtype, max_num_bin)
-    return 2.0 * lay.Cp * lay.Mp * (lay.Fg // lay.pack) * B
+    full, ncols, _ = _block_split(lay, R)
+    return 2.0 * lay.Cp * lay.Mp * (full * lay.Gp + ncols) * B
+
+
+def masked_pad_columns(F: int, *, bins_itemsize: int, num_bins_padded: int,
+                       backend: str, input_dtype: str, max_num_bin: int = 0,
+                       real_columns: int = 0) -> int:
+    """Columns of the padded feature blocks that one `hist_multileaf_masked`
+    launch over [F, C] bins, of which the first `real_columns` hold data,
+    does not histogram: every column past the real ones, the store's own
+    padding and the wrapper's (none on the XLA fallback, which pads
+    nothing)."""
+    R = _real_columns(F, real_columns)
+    if backend != "pallas":
+        return F - R
+    return _masked_layout(F, 1, 1, bins_itemsize, num_bins_padded,
+                          input_dtype, max_num_bin).Fg - R
 
 
 @functools.partial(jax.jit, static_argnames=("num_bins_padded", "backend",
                                              "input_dtype", "interpret",
-                                             "max_num_bin"))
+                                             "max_num_bin", "real_columns"))
 def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
                           sl: jax.Array, *, num_bins_padded: int,
                           backend: str = "xla",
                           input_dtype: str = "float32",
                           interpret: bool = False,
-                          max_num_bin: int = 0, ghq=None) -> jax.Array:
+                          max_num_bin: int = 0, ghq=None,
+                          real_columns: int = 0) -> jax.Array:
     """Histogram K leaves in one pass, masks built on the fly.
 
     gb_t: [F, C] int bins; lid: [C] int32 leaf ids; gh8: [8, C] f32
     (grad·rm, hess·rm, rm, pads); sl: [K] int32 leaf ids to histogram
     (-1 = empty slot).  Returns [K, F, 3, B] f32.
+
+    real_columns (static; 0 = all F): the leading columns that hold
+    data, for a store padded to the feature group.  No one-hot is built
+    and no contraction runs for the others, whose histograms come back
+    exact zeros on every backend; each real column's is the same
+    operations over the same row chunks in the same order as with none
+    left out.  On the pallas path the feature blocks the real columns
+    fill are one launch, the block that holds the last of them a second
+    launch of the same kernel that histograms only those, into the first
+    one's output buffer (a single block is only that launch), and a
+    block of padding none.  A store with no
+    padded column is the one launch over every block.
 
     ghq: quantize_gh(gh8), for a caller that launches many passes over
     one gh8 (every launch of a tree) and quantises it once; None
@@ -683,8 +745,10 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
     # (`lgbt.hist`, `lgbt.root`): a scope opened here around the
     # pallas_call would give the custom call its name in place of this
     # function's, which is how a trace finds the kernel
+    R = _real_columns(F, real_columns)
     if backend != "pallas":
         with jax.named_scope("lgbt.feed"):
+            gb_t = gb_t[:R] if R < F else gb_t
             if bin_offset:
                 gb_t = gb_t.astype(jnp.int32) + bin_offset
             if quant:
@@ -698,12 +762,16 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
             vals = jnp.concatenate(
                 [m * gh8[0:1], m * gh8[1:2], m * gh8[2:3]], axis=0)  # [3K, C]
         h = hist_multileaf_xla(gb_t, vals, num_bins_padded=B,
-                               input_dtype=input_dtype)          # [F, 3K, B]
+                               input_dtype=input_dtype)          # [R, 3K, B]
+        if R < F:
+            h = jnp.pad(h, ((0, F - R), (0, 0), (0, 0)))
         return jnp.stack([h[:, :K], h[:, K:2 * K], h[:, 2 * K:3 * K]],
                          axis=2).transpose(1, 0, 2, 3)
 
-    G, Gp, pack, bins_sub, Mp, Kp, Bs, Ck, Cp, Fg = _masked_layout(
-        F, C, K, gb_t.dtype.itemsize, B, input_dtype, max_num_bin)
+    lay = _masked_layout(F, C, K, gb_t.dtype.itemsize, B, input_dtype,
+                         max_num_bin)
+    G, Gp, pack, bins_sub, Mp, Kp, Bs, Ck, Cp, Fg = lay
+    full, ncols, last = _block_split(lay, R)
     nB = B // Bs
     with jax.named_scope("lgbt.feed"):
         if quant:
@@ -727,28 +795,70 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
             gb_g = gb_g.astype(jnp.int32)
         sl2 = jnp.broadcast_to(
             jnp.pad(sl, (0, Kp - K), constant_values=-1)[:, None], (Kp, 128))
-    if nB > 1:
-        grid = (Fg // G, nB, C // Ck)
-        in_specs = [
-            pl.BlockSpec((Kp, 128), lambda f, b, k: (0, 0)),
-            pl.BlockSpec((1, G, Ck), lambda f, b, k: (f, 0, k)),
-            pl.BlockSpec((1, Ck), lambda f, b, k: (0, k)),
-            pl.BlockSpec((8, Ck), lambda f, b, k: (0, k)),
-        ]
-        out_spec = pl.BlockSpec((1, Gp, Mp, Bs),
-                                lambda f, b, k: (f, 0, 0, b))
-    else:
-        # keep the plain 2-axis grid when no windowing is needed: the
-        # singleton middle axis measurably deoptimized Mosaic's
-        # pipelining (learner-level 2.5x at Epsilon 63-bin)
-        grid = (Fg // G, C // Ck)
-        in_specs = [
-            pl.BlockSpec((Kp, 128), lambda f, k: (0, 0)),
-            pl.BlockSpec((1, G, Ck), lambda f, k: (f, 0, k)),
-            pl.BlockSpec((1, Ck), lambda f, k: (0, k)),
-            pl.BlockSpec((8, Ck), lambda f, k: (0, k)),
-        ]
-        out_spec = pl.BlockSpec((1, Gp, Mp, Bs), lambda f, k: (f, 0, 0, 0))
+    def launch(kernel, out_dtype, operands):
+        """The kernel over the feature blocks the real columns fill and
+        then, into the same [Fg/G, G/pack, Mp, B] buffer (aliased, so no
+        second output and no concat), over the block that holds the last
+        of them with only those columns; zeros past the real columns."""
+        out = None
+        for first, blocks, tail in ((0, full, {}),
+                                    (full, int(ncols > 0),
+                                     dict(ncols=ncols, last=last))):
+            if not blocks:
+                continue
+
+            def blk(f, first=first):
+                return f + first if first else f
+            if nB > 1:
+                grid = (blocks, nB, C // Ck)
+                in_specs = [
+                    pl.BlockSpec((Kp, 128), lambda f, b, k: (0, 0)),
+                    pl.BlockSpec((1, G, Ck), lambda f, b, k: (blk(f), 0, k)),
+                    pl.BlockSpec((1, Ck), lambda f, b, k: (0, k)),
+                    pl.BlockSpec((8, Ck), lambda f, b, k: (0, k)),
+                ]
+                out_spec = pl.BlockSpec((1, Gp, Mp, Bs),
+                                        lambda f, b, k: (blk(f), 0, 0, b))
+            else:
+                # keep the plain 2-axis grid when no windowing is needed:
+                # the singleton middle axis measurably deoptimized
+                # Mosaic's pipelining (learner-level 2.5x at Epsilon
+                # 63-bin)
+                grid = (blocks, C // Ck)
+                in_specs = [
+                    pl.BlockSpec((Kp, 128), lambda f, k: (0, 0)),
+                    pl.BlockSpec((1, G, Ck), lambda f, k: (blk(f), 0, k)),
+                    pl.BlockSpec((1, Ck), lambda f, k: (0, k)),
+                    pl.BlockSpec((8, Ck), lambda f, k: (0, k)),
+                ]
+                out_spec = pl.BlockSpec((1, Gp, Mp, Bs),
+                                        lambda f, k: (blk(f), 0, 0, 0))
+            body = functools.partial(kernel, **tail)
+            args, alias = operands, {}
+            if out is not None:
+                # the buffer the first launch wrote goes in untouched
+                # (no DMA) and comes back with this launch's block.  The
+                # custom call keeps the jitted wrapper's name, which is
+                # how a trace and the phase table find a kernel launch
+                def body(*refs, _body=body):
+                    _body(*refs[:-2], refs[-1])
+                in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+                args, alias = operands + (out,), {len(operands): 0}
+            out = pl.pallas_call(
+                body,
+                out_shape=jax.ShapeDtypeStruct((Fg // G, Gp, Mp, B),
+                                               out_dtype),
+                grid=grid,
+                in_specs=in_specs,
+                out_specs=out_spec,
+                input_output_aliases=alias,
+                interpret=interpret,
+            )(*args)
+        rest = full + int(ncols > 0)
+        if rest < Fg // G:
+            # feature blocks of padding alone: never written
+            out = out.at[rest:].set(0)
+        return out
 
     def unpack(out):
         """[Fg/G, G/pack, Mp, B] kernel output -> [F, Mp, B] with each
@@ -762,32 +872,22 @@ def hist_multileaf_masked(gb_t: jax.Array, lid: jax.Array, gh8: jax.Array,
         return jnp.pad(h, ((0, 0), (0, 0), (0, B - bins_sub)))[:F]
 
     if quant:
-        out = pl.pallas_call(
+        out = launch(
             functools.partial(_hist_kernel_masked_q, B=B, K=K, pack=pack,
                               bins_sub=bins_sub, bin_offset=bin_offset,
                               windowed=nB > 1),
-            out_shape=jax.ShapeDtypeStruct((Fg // G, Gp, Mp, B), jnp.int32),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_spec,
-            interpret=interpret,
-        )(sl2, gb_g, lid[None, :], ghq)
+            jnp.int32, (sl2, gb_g, lid[None, :], ghq))
         h = unpack(out).astype(jnp.float32)
         return jnp.stack([h[:, :K] * sg, h[:, K:2 * K] * sh,
                           h[:, 2 * K:3 * K]],
                          axis=2).transpose(1, 0, 2, 3)
 
     dt = jnp.dtype(input_dtype)
-    out = pl.pallas_call(
+    out = launch(
         functools.partial(_hist_kernel_masked, B=B, K=K, input_dtype=dt,
                           pack=pack, bins_sub=bins_sub,
                           bin_offset=bin_offset, windowed=nB > 1),
-        out_shape=jax.ShapeDtypeStruct((Fg // G, Gp, Mp, B), jnp.float32),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        interpret=interpret,
-    )(sl2, gb_g, lid[None, :], gh8)
+        jnp.float32, (sl2, gb_g, lid[None, :], gh8))
     h = unpack(out)                                      # [F, Mp, B]
     return jnp.stack([h[:, :K], h[:, K:2 * K], h[:, 2 * K:3 * K]],
                      axis=2).transpose(1, 0, 2, 3)

@@ -201,7 +201,7 @@ def predict_trees(stack: TreeStack, X: jax.Array, *, depth: int) -> jax.Array:
         return _walk_one_tree(sf, th, dc, lc, rc, lv, nl, Xf, depth)
 
     vals = jax.vmap(one_tree)(*stack)          # [T, N]
-    return jnp.sum(vals, axis=0)
+    return _sum_trees(vals)
 
 
 def ensemble_raw(stacks, X: jax.Array, *, depths) -> jax.Array:
@@ -222,8 +222,45 @@ def ensemble_raw(stacks, X: jax.Array, *, depths) -> jax.Array:
         def one_tree(sf, th, dc, lc, rc, lv, nl, _d=depth):
             return _walk_one_tree(sf, th, dc, lc, rc, lv, nl, Xf, _d)
 
-        outs.append(jnp.sum(jax.vmap(one_tree)(*stack), axis=0))
+        outs.append(_sum_trees(jax.vmap(one_tree)(*stack)))
     return jnp.stack(outs)
+
+
+# Leaf values are summed in two float32 parts: the multiple of 2^-12
+# nearest each, whose sums are exact in any order while they stay below
+# 2^12 in magnitude, and the remainder, at most 2^-13, whose sums are
+# small and so round far finer than the margin.  Adding the two sums
+# rounds once, by at most half an ulp of the margin.  A plain float32
+# sum rounds once a tree: over ten trees at margins of a few units it
+# strays past 1e-6 of the float64 sum.
+_HI_STEP = 2.0 ** -12
+
+
+def _split(vals: jax.Array) -> tuple:
+    """(multiples of _HI_STEP nearest `vals`, the exact remainders)."""
+    hi = jnp.round(vals * (1.0 / _HI_STEP)) * _HI_STEP
+    return hi, vals - hi
+
+
+def _sum_trees(vals: jax.Array) -> jax.Array:
+    """[N] sums over the tree axis of [T, N] float32 leaf values."""
+    hi, lo = _split(vals)
+    return jnp.sum(hi, axis=0) + jnp.sum(lo, axis=0)
+
+
+def _class_sums(vals: jax.Array, class_id: jax.Array,
+                num_class: int) -> jax.Array:
+    """[K, N] per-class sums over the tree axis of [T, N] float32 leaf
+    values.  class_id is sorted (class-major flatten), so the segment-sum
+    reduces each class's trees in stack order."""
+    if num_class == 1:
+        return _sum_trees(vals)[None]
+    hi, lo = _split(vals)
+
+    def seg(v):
+        return jax.ops.segment_sum(v, class_id, num_segments=num_class,
+                                   indices_are_sorted=True)
+    return seg(hi) + seg(lo)
 
 
 # ----------------------------------------------------------------------
@@ -524,16 +561,11 @@ def stack_ensemble_group(members, *, binned: bool = False
 def _leaf_sums(stack: EnsembleStack, node: jax.Array, num_class: int
                ) -> jax.Array:
     """[K, N] per-class sums of the leaf values the [T, N] walk parked
-    on.  class_id is sorted (class-major flatten), so the segment-sum
-    reduces each class's trees in stack order — exact for fp32 dyadic
-    leaf values in any order, and the same trees the walk kernel sums."""
+    on (`_class_sums`) — exact for fp32 dyadic leaf values in any order,
+    and the same trees the walk kernel sums."""
     leaf = jnp.where(node < 0, ~node, 0)
     vals = jnp.take_along_axis(stack.leaf_value, leaf, axis=1)   # [T, N]
-    if num_class == 1:
-        return jnp.sum(vals, axis=0)[None]
-    return jax.ops.segment_sum(vals, stack.class_id,
-                               num_segments=num_class,
-                               indices_are_sorted=True)
+    return _class_sums(vals, stack.class_id, num_class)
 
 
 def _raw_decide(rec: jax.Array, v: jax.Array, any_cat: bool) -> jax.Array:
@@ -648,11 +680,7 @@ def predict_ensemble_perfect(stack: PerfectEnsemble, X: jax.Array, *,
         local = node - ((1 << (depth - 1)) - 1)
     r, gl = level(stack.last, local)
     vals = jnp.where(gl, r[..., 2], r[..., 3])              # [T, N]
-    if meta.num_class == 1:
-        return jnp.sum(vals, axis=0)[None]
-    return jax.ops.segment_sum(vals, stack.class_id,
-                               num_segments=meta.num_class,
-                               indices_are_sorted=True)
+    return _class_sums(vals, stack.class_id, meta.num_class)
 
 
 def predict_ensemble_any(stack, X: jax.Array, *,
@@ -861,28 +889,21 @@ def _grouped_sums(stack: EnsembleStack, node: jax.Array,
     features their splits name, park somewhere, and are discarded
     here).  Each tenant's reduction is a STATIC slice of the [T, N]
     leaf values (`meta.segments` — trace-time bounds) fed to the SAME
-    op and shape `_leaf_sums` uses on the tenant's solo stack: plain
-    ``sum(axis=0)`` for K==1, sorted segment-sum over class_id for
-    K>1.  Same addends in the same reduction ⇒ bitwise-identical to
-    per-tenant dispatch — which is why this is G static slices and NOT
-    one masked segment-sum over the concatenated stack (a different
-    accumulation order/shape XLA may reassociate differently).
+    reduction and shape `_leaf_sums` uses on the tenant's solo stack
+    (`_class_sums`).  Same addends in the same reduction ⇒
+    bitwise-identical to per-tenant dispatch — which is why this is G
+    static slices and NOT one masked segment-sum over the concatenated
+    stack (a different accumulation order/shape XLA may reassociate
+    differently).
     The final per-row select is a gather over the [G, K, N] stack of
     per-tenant answers; an out-of-range tid clamps (JAX gather
     semantics) rather than reading garbage.
     """
     leaf = jnp.where(node < 0, ~node, 0)
     vals = jnp.take_along_axis(stack.leaf_value, leaf, axis=1)   # [T, N]
-    per = []
-    for a, b in meta.segments:
-        seg = vals[a:b]
-        if meta.num_class == 1:
-            per.append(jnp.sum(seg, axis=0)[None])
-        else:
-            per.append(jax.ops.segment_sum(seg, stack.class_id[a:b],
-                                           num_segments=meta.num_class,
-                                           indices_are_sorted=True))
-    sums = jnp.stack(per)                                  # [G, K, N]
+    sums = jnp.stack([_class_sums(vals[a:b], stack.class_id[a:b],
+                                  meta.num_class)
+                      for a, b in meta.segments])          # [G, K, N]
     idx = jnp.broadcast_to(tids.astype(jnp.int32)[None, None, :],
                            (1,) + sums.shape[1:])
     return jnp.take_along_axis(sums, idx, axis=0)[0]       # [K, N]
@@ -1027,17 +1048,19 @@ def _segment_sums(stack: EnsembleStack, node: jax.Array, tree: jax.Array,
     vals = jnp.where(valid, stack.leaf_value[tree, leaf],
                      jnp.float32(0.0))                     # [L, N]
     if meta.num_class == 1:
-        return jnp.sum(vals, axis=0)[None]
+        return _sum_trees(vals)[None]
+    parts = jnp.stack(_split(vals), axis=1)                # [L, 2, N]
     cls = stack.class_id[tree]                             # [L, N]
-    ks = jnp.arange(meta.num_class, dtype=cls.dtype)[:, None]
+    ks = jnp.arange(meta.num_class, dtype=cls.dtype)[None, :, None]
 
     def step(j, acc):
-        return acc + jnp.where(cls[j][None, :] == ks, vals[j][None, :],
-                               jnp.float32(0.0))
+        return acc + jnp.where(cls[j][None, None, :] == ks,
+                               parts[j][:, None, :], jnp.float32(0.0))
 
-    return jax.lax.fori_loop(0, vals.shape[0], step,
-                             jnp.zeros((meta.num_class, node.shape[1]),
-                                       jnp.float32))
+    acc = jax.lax.fori_loop(0, vals.shape[0], step,
+                            jnp.zeros((2, meta.num_class, node.shape[1]),
+                                      jnp.float32))
+    return acc[0] + acc[1]
 
 
 @functools.partial(jax.jit, static_argnames=("meta",))

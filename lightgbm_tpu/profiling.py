@@ -92,8 +92,10 @@ SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
 #  - HIST_LIVE_SLOTS: the slots among them that held a leaf;
 #    live / slots is how full the launches ran.
 #  - HIST_MXU_OPS: multiply-adds x 2 that the dense launches'
-#    contractions perform, padding included (ops/histogram.
-#    masked_hist_mxu_ops), summed across shards.  The sparse kernels
+#    contractions perform as executed (ops/histogram.
+#    masked_hist_mxu_ops): the padded rows, value rows and bins
+#    included, the store's padded columns, which no launch
+#    histograms, not; summed across shards.  The sparse kernels
 #    add 0: their contraction runs over entry blocks, not rows.
 #  - PARTITION_ROWS: rows whose place in the leaf-id vector a round
 #    rewrites, as executed: every row of the shard in every round
@@ -129,6 +131,13 @@ SPLIT_RECORDS_BYTES = "tree/split_records_bytes"
 #    (ops/split.unbundle_hist): 3 x F x B per searched slot where a
 #    bundle plan packs a categorical feature, the one store the rounds
 #    learner still unbundles; 0 on every other.  Folded the same way.
+#  - HIST_PAD_COLUMNS: columns of the padded store (to the kernels'
+#    feature group, or to the scatter's slices) that the dense
+#    launches do not histogram — no one-hot, no contraction, exact
+#    zeros handed back (ops/histogram.masked_pad_columns) — summed over
+#    launches and shards.  Static per launch: folded the same way from
+#    HIST_PASSES; 0 on a store with no padded column and on the sparse
+#    path.
 TREE_ROUNDS = "tree/rounds"
 HIST_PASSES = "tree/hist_passes"
 HIST_SLOTS = "tree/hist_slots"
@@ -139,6 +148,7 @@ STORE_COPY_ROWS = "tree/store_copy_rows"
 EXCHANGE_COLLECTIVES = "tree/exchange_collectives"
 SPLIT_CELLS = "tree/split_cells"
 UNBUNDLE_GATHER_ELEMS = "tree/unbundle_gather_elems"
+HIST_PAD_COLUMNS = "tree/hist_pad_columns"
 # Outside the build, counted on the host by count() at each leaf-id
 # update of a score (boosting/score_updater._add_leaf_to_row):
 #  - SCORE_GATHER_ROWS: rows whose leaf ids that update has to fetch
@@ -281,7 +291,7 @@ CANONICAL_COUNTERS = (
     HIST_ROWS_DOWNGRADES, TREE_ROUNDS, HIST_PASSES, HIST_SLOTS,
     HIST_LIVE_SLOTS, HIST_MXU_OPS, FEED_ROWS, FEED_LIVE_ROWS,
     PARTITION_ROWS, STORE_COPY_ROWS, EXCHANGE_COLLECTIVES,
-    SPLIT_CELLS, UNBUNDLE_GATHER_ELEMS, SCORE_GATHER_ROWS,
+    SPLIT_CELLS, UNBUNDLE_GATHER_ELEMS, HIST_PAD_COLUMNS, SCORE_GATHER_ROWS,
     CATEGORICAL_SPLITS,
     SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,

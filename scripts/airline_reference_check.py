@@ -13,7 +13,8 @@ elements on one device):
 1. the device store: every stored bin is the dataset's bin less 128, every
    padded cell -128;
 2. the root launch of `hist_multileaf_masked` over the whole store, with
-   the operands `build_tree_rounds` gives it.  Above `INT8_EXACT_ROWS` rows
+   the operands and the real column count `build_tree_rounds` gives it
+   (the store's 19 padded columns have to come back exact zeros).  Above `INT8_EXACT_ROWS` rows
    a device the kernel takes bfloat16 operands in place of int8 ones (an
    int32 sum could overflow: `ops/histogram.hist_multileaf_masked`), and
    its sums are float32 sums of bfloat16 values; they are held to the
@@ -28,7 +29,9 @@ elements on one device):
    threshold bin; a categorical feature's threshold is one category)
    against `split_reference.best_split` of the reference histogram of
    step 2, over numerical `<=` and categorical `==` candidates: the same
-   feature and bin, or a float64 gain within four float32 steps.
+   feature and bin, or a float64 gain within four float32 steps.  The
+   line also gives that build's launches and the padded store columns
+   they left out (`tree/hist_passes`, `tree/hist_pad_columns`).
 
 `--check layout --rows N`: `--trees` trees with the int8-stored layout and
 with the int32 one (`LGBT_BINS_INT8=0`) on the same first N rows.  Where
@@ -83,12 +86,14 @@ def bf16(v: np.ndarray) -> np.ndarray:
 
 
 def root_launch(learner, grad, hess, dtype, rows=None):
-    """[F, 3, B] float32: the root launch over the learner's store (its
-    first `rows` rows, when given) with the operands build_tree_rounds
-    hands it; store columns past the dataset's cut off.  One program, as
-    in the build: the gradient block is one [8, rows] buffer (3.7 GB at
-    the cell's rows), and the program is dropped after the launch so that
-    its temporaries stay reserved no longer."""
+    """([F, 3, B] float32, padding zero): the root launch over the
+    learner's store (its first `rows` rows, when given) with the operands
+    and the real column count build_tree_rounds hands it — the dataset's
+    store columns, and whether the launch handed back exact zeros for
+    every padded column past them.  One program, as in the build: the
+    gradient block is one [8, rows] buffer (3.7 GB at the cell's rows),
+    and the program is dropped after the launch so that its temporaries
+    stay reserved no longer."""
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.ops.histogram import hist_multileaf_masked, int8_operands
@@ -103,12 +108,13 @@ def root_launch(learner, grad, hess, dtype, rows=None):
         return hist_multileaf_masked(
             bins, jnp.zeros(n, jnp.int32), gh8, jnp.zeros(1, jnp.int32),
             num_bins_padded=learner.B, backend=backend, input_dtype=dtype,
-            max_num_bin=int(learner.dataset.max_num_bin), ghq=ghq)[0]
+            max_num_bin=int(learner.dataset.max_num_bin), ghq=ghq,
+            real_columns=learner.Cstore)[0]
     mask, _ = learner._masks(None)
     out = np.asarray(jax.jit(launch)(learner.bins_dev, learner._rows_in(grad),
                                      learner._rows_in(hess), mask))
     jax.clear_caches()
-    return out[:learner.Cstore]
+    return out[:learner.Cstore], bool(not out[learner.Cstore:].any())
 
 
 def float_histogram(store, g, h, B):
@@ -155,6 +161,7 @@ def setup(config, params):
 def check_root(config, cell, params) -> list:
     import jax
     from benchmark.harness import split_reference as ref
+    from lightgbm_tpu import profiling
     from lightgbm_tpu.ops.histogram import _masked_layout, store_alignment
     bst, learner, ds = setup(config, params)
     failed = []
@@ -183,7 +190,7 @@ def check_root(config, cell, params) -> list:
     # holds the chip's memory beside the build's
     tree = tree_of(learner, grad, hess)
     jax.clear_caches()
-    path = root_launch(learner, grad, hess, dtype)
+    path, pad_zero = root_launch(learner, grad, hess, dtype)
     full_int8 = dtype == "int8" and learner.Np <= INT8_EXACT_ROWS
     t0 = time.perf_counter()
     if full_int8:
@@ -213,8 +220,10 @@ def check_root(config, cell, params) -> list:
                      max_err_over_abs_sum=float(rel.max()),
                      bound_over_abs_sum=float(bound.max() / max(
                          mag.max(), 1e-300)))
+    hist_ok = hist_ok and pad_zero
     say(check="root_histogram", ok=hist_ok, rows=int(seen[0, 2].sum()),
         cells=int(seen.size), reference_seconds=time.perf_counter() - t0,
+        padded_columns=learner.Fpad - C, padded_columns_zero=pad_zero,
         **facts)
     if not hist_ok:
         failed.append("root_histogram")
@@ -224,15 +233,16 @@ def check_root(config, cell, params) -> list:
         _, row = store_alignment(1 if int8 else 4, B, dtype,
                                  int(ds.max_num_bin))
         P = INT8_EXACT_ROWS // row * row
-        part = root_launch(learner, grad, hess, dtype, rows=P)
+        part, part_pad_zero = root_launch(learner, grad, hess, dtype, rows=P)
         gq, sg = ref.quantize(g_np[:P])
         hq, sh = ref.quantize(h_np[:P])
         exact = ref.histogram(want[:, :P], gq, hq, B)
         image = exact.astype(np.float32) * np.array(
             [sg, sh, 1.0], np.float32)[None, :, None]
-        prefix_ok = bool(np.array_equal(part, image))
+        prefix_ok = bool(np.array_equal(part, image)) and part_pad_zero
         say(check="root_histogram_int8_rows", ok=prefix_ok, rows=P,
             cells_off=int((part != image).sum()),
+            padded_columns_zero=part_pad_zero,
             largest_sum=int(np.abs(exact).max()))
         if not prefix_ok:
             failed.append("root_histogram_int8_rows")
@@ -253,7 +263,9 @@ def check_root(config, cell, params) -> list:
         categorical=bool(is_cat[got[0]]),
         raw_column=int(ds.used_features[got[0]]),
         reference_gain=want_split[2], gain_of_path_in_float64=gain_path,
-        float32_step_of_the_compared_sum=step, leaves=int(tree.num_leaves))
+        float32_step_of_the_compared_sum=step, leaves=int(tree.num_leaves),
+        hist_passes=profiling.counter_value(profiling.HIST_PASSES),
+        hist_pad_columns=profiling.counter_value(profiling.HIST_PAD_COLUMNS))
     if not split_ok:
         failed.append("root_split")
     return failed

@@ -12,7 +12,8 @@ once the set is binned):
 
 1. tree 1's root histogram in ORIGINAL feature space — the root launch of
    `hist_multileaf_masked` over the learner's store, gradients quantised as
-   `build_tree_rounds` quantises them, then `ops/split.unbundle_hist`
+   `build_tree_rounds` quantises them and its real store columns alone
+   launched (the padded ones have to come back exact zeros), then `ops/split.unbundle_hist`
    through the learner's tables — against int64 sums of the same int8
    levels per (feature, bin) over the CSR matrix's stored entries
    (`np.bincount`; a feature's zero bin is the leaf's totals less its
@@ -86,9 +87,10 @@ TIE_STEPS = 4.0
 
 
 def root_pass(learner, grad, hess, dtype):
-    """-> (the root launch as `build_tree_rounds` makes it, unbundled to
-    [F, 3, B] float32 by the program's own `unbundle_hist`, (grad scale,
-    hess scale))."""
+    """-> (the root launch as `build_tree_rounds` makes it, over the
+    store's real columns, unbundled to [F, 3, B] float32 by the program's
+    own `unbundle_hist`, (grad scale, hess scale), whether the launch
+    handed back exact zeros for every padded store column)."""
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.ops.histogram import hist_multileaf_masked, quantize_gh
@@ -104,13 +106,14 @@ def root_pass(learner, grad, hess, dtype):
         learner.bins_dev, jnp.zeros(n, jnp.int32), gh8,
         jnp.zeros(1, jnp.int32), num_bins_padded=learner.B, backend=backend,
         input_dtype=dtype, max_num_bin=int(learner.dataset.max_num_bin),
-        ghq=ghq)[0]
+        ghq=ghq, real_columns=learner.Cstore)[0]
+    pad_zero = bool(not np.asarray(store[learner.Cstore:]).any())
     totals = jnp.sum(store[0], axis=1)           # any store column's bins
     src, dmask = learner.dataset.unbundle_tables(learner.B, learner.Fpad)
     feat = unbundle_hist(store, jnp.asarray(src), jnp.asarray(dmask), totals)
     scales = ((float(ghq[1]), float(ghq[2])) if ghq is not None
               else (1.0, 1.0))
-    return np.asarray(feat), scales
+    return np.asarray(feat), scales, pad_zero
 
 
 def csr_histogram(X, uppers, used, gq, hq, B):
@@ -157,7 +160,7 @@ def check_root(config, cell, params) -> list:
     failed = []
     grad, hess = (a.reshape(-1) for a in bst._gbdt.boosting_gradients())
     dtype = params["histogram_dtype"]
-    feat, (sg, sh) = root_pass(learner, grad, hess, dtype)
+    feat, (sg, sh), pad_zero = root_pass(learner, grad, hess, dtype)
     N, B = learner.N, learner.B
     gq, sg_np = quantize(np.asarray(grad)[:N])
     hq, sh_np = quantize(np.asarray(hess)[:N])
@@ -197,8 +200,10 @@ def check_root(config, cell, params) -> list:
                         .max(initial=0.0))
     hist_ok = (singles_off == 0 and surplus == 0 and clean_off == 0
                and default_err <= 1e-6
-               and lost == int(ds.bundle_conflict_rows))
+               and lost == int(ds.bundle_conflict_rows) and pad_zero)
     say(check="root_histogram", ok=hist_ok, features=len(used),
+        padded_store_columns=learner.Fpad - learner.Cstore,
+        padded_store_columns_zero=pad_zero,
         cells=int(ref.size), singleton_features=int(singles.sum()),
         singleton_cells_off=singles_off, members=int(plan.num_packed),
         members_without_conflict=int(clean.sum()),

@@ -15,7 +15,8 @@ minutes once the set is binned):
    *dataset's own* host store: the same int8 levels summed per (column,
    bin) in int64 over the shard's real rows.  Each shard's kernel result
    has to be the float32 image of those sums, bit for bit (the kernel sums
-   exact products in int32), padded rows and columns adding nothing; the
+   exact products in int32), padded rows adding nothing and the padded
+   columns, which the launch leaves out, exact zeros; the
    shards' row counts add up to the configuration's rows;
 2. the root split of tree 1 as the learner's build grew it across the
    mesh (feature, threshold bin) against the best split of the summed
@@ -53,7 +54,8 @@ CELL = "criteo_tb.data4"
 
 def shard_root_pass(learner, grad, hess, params):
     """-> ([shards, Fpad, 3, B] float32, [shards, 2] float32 scales): the
-    root launch of every shard, as `build_tree_rounds` makes it."""
+    root launch of every shard, as `build_tree_rounds` makes it: over the
+    store's real columns, exact zeros for the padded ones."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -69,7 +71,8 @@ def shard_root_pass(learner, grad, hess, params):
         out = hist_multileaf_masked(
             bins, jnp.zeros(n, jnp.int32), gh8, jnp.zeros(1, jnp.int32),
             num_bins_padded=learner.B, backend=backend, input_dtype=dtype,
-            max_num_bin=int(learner.dataset.max_num_bin), ghq=ghq)
+            max_num_bin=int(learner.dataset.max_num_bin), ghq=ghq,
+            real_columns=learner.Cstore)
         scales = (jnp.stack([ghq[1], ghq[2]]) if ghq is not None
                   else jnp.ones(2, jnp.float32))
         return out, scales[None]
@@ -118,7 +121,7 @@ def check_root(config, cell, params) -> list:
         assert (sg, sh) == tuple(scales[s]), (s, sg, sh, scales[s])
         mine = path[s, :F]
         off += int((mine != ref.astype(np.float32) * scale).sum())
-        off += int(np.count_nonzero(path[s, F:, :, 1:]))   # padded columns
+        off += int(np.count_nonzero(path[s, F:]))   # padded columns: zeros
         worst = max(worst, float(np.abs(
             np.rint(mine.astype(np.float64) / scale) - ref).max()))
         rows += int(ref[0, 2].sum())

@@ -7,7 +7,9 @@ timed size and through the objects the timed path uses (the cell's binned
 training set, `lgb.Booster`, its learner's own build program):
 
 1. the root pass of tree 1 — `hist_multileaf_masked` over the learner's
-   device store with the arguments `build_tree_rounds` gives it — against
+   device store with the arguments `build_tree_rounds` gives it, the real
+   column count included (the store's padded columns have to come back
+   exact zeros) — against
    NumPy: the same int8-quantised gradient and hessian values
    (`ops/histogram.quantize_gh`, redone in NumPy float32), summed per
    (column, bin) in int64 over the binned store in blocks of a million
@@ -176,15 +178,17 @@ def main(argv=None) -> int:
     # as RoundsTreeLearner resolves it
     backend = "pallas" if jax.default_backend() == "tpu" else "xla"
     # the learner's store is laid out to the kernel's tiles: its padded
-    # rows carry row_mask 0, its padded columns are cut off the result
+    # rows carry row_mask 0, its padded columns are launched for nothing
+    # and have to come back exact zeros
     rows = learner._rows_in
     gh8 = (jnp.zeros((8, learner.Np), jnp.float32).at[0].set(rows(grad))
            .at[1].set(rows(hess)).at[2].set(jnp.asarray(learner._row_mask)))
-    path = np.asarray(hist_multileaf_masked(
+    out = np.asarray(hist_multileaf_masked(
         learner.bins_dev, jnp.zeros(learner.Np, jnp.int32), gh8,
         jnp.zeros(1, jnp.int32), num_bins_padded=B, backend=backend,
         input_dtype=params["histogram_dtype"],
-        max_num_bin=int(learner.dataset.max_num_bin)))[0, :F]   # [F, 3, B]
+        max_num_bin=int(learner.dataset.max_num_bin), real_columns=F))[0]
+    path, pad_zero = out[:F], bool(not out[F:].any())      # [F, 3, B]
     store = np.asarray(learner.bins_dev)[:F, :N]
     g_np, h_np = np.asarray(grad)[:N], np.asarray(hess)[:N]
     gq, sg = quantize(g_np)
@@ -197,8 +201,9 @@ def main(argv=None) -> int:
     # (bitwise equality of that is the check; back in units it reads 0
     # wherever a sum is under 2^24, which float32 holds exactly)
     units = np.abs(np.rint(path.astype(np.float64) / scale) - ref)
-    hist_ok = bool(np.array_equal(path, ref_f32))
+    hist_ok = bool(np.array_equal(path, ref_f32)) and pad_zero
     say(check="root_histogram", ok=hist_ok, cells=int(ref.size),
+        padded_columns=int(out.shape[0] - F), padded_columns_zero=pad_zero,
         rows=int(ref[0, 2].sum()), largest_sum=int(np.abs(ref).max()),
         cells_off=int((path != ref_f32).sum()), max_off_units=float(units.max()),
         grad_levels=np.unique(gq).tolist(), hess_levels=np.unique(hq).tolist(),

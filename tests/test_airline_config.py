@@ -254,18 +254,22 @@ def test_generator_keeps_each_code_column_within_the_int8_layout():
 def test_int8_store_launch_counts_32_columns_a_block(operands):
     """The int8-stored layout's contraction, as launched: 13 columns in
     one 32-column block, every row of a 2,048-row chunk grid, 3K value
-    rows padded to 8, 256 bins; above 16M rows a launch takes bfloat16
-    operands through the same layout."""
+    rows padded to 8, 256 bins, for the 13 real columns alone (the
+    block's 19 padded ones build no one-hot and run no contraction);
+    above 16M rows a launch takes bfloat16 operands through the same
+    layout."""
     from lightgbm_tpu.ops.histogram import (INT8_EXACT_ROWS,
                                             masked_hist_mxu_ops,
+                                            masked_pad_columns,
                                             store_alignment)
     assert store_alignment(1, 256, operands, 256) == (32, 2048)
     C = 115_000_000 + (-115_000_000) % 2048
+    kw = dict(bins_itemsize=1, num_bins_padded=256, backend="pallas",
+              input_dtype=operands, max_num_bin=256)
     for K, Mp in ((1, 8), (8, 24), (32, 96), (84, 256)):
-        assert masked_hist_mxu_ops(
-            32, C, K, bins_itemsize=1, num_bins_padded=256,
-            backend="pallas", input_dtype=operands,
-            max_num_bin=256) == 2.0 * C * Mp * 32 * 256
+        assert masked_hist_mxu_ops(32, C, K, real_columns=13,
+                                   **kw) == 2.0 * C * Mp * 13 * 256
+    assert masked_pad_columns(32, real_columns=13, **kw) == 19
     assert C > INT8_EXACT_ROWS
 
 

@@ -368,6 +368,75 @@ def test_hist_masked_int8_padded_rows_and_top_leaf():
     assert np.asarray(h_n)[1].max() == 0.0
 
 
+def _pallas_calls(fn, *args):
+    """pallas_call equations in the jaxpr of fn(*args), nested ones too."""
+    import jax
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call[")
+
+
+@pytest.mark.parametrize("K", [1, 8])
+@pytest.mark.parametrize("operands", ["int8", "bfloat16"])
+@pytest.mark.parametrize("itemsize,Fp,R,nbin,calls", [(1, 32, 13, 255, 1),
+                                                      (4, 32, 28, 255, 2),
+                                                      (4, 16, 16, 255, 1),
+                                                      (4, 24, 5, 255, 1),
+                                                      (4, 32, 27, 63, 2)],
+                         ids=["int8_13_of_32", "int32_28_of_32",
+                              "int32_16_of_16", "int32_5_of_24",
+                              "int32_27_of_32_packed"])
+def test_hist_masked_skips_the_stores_padded_columns(itemsize, Fp, R, nbin,
+                                                     calls, operands, K):
+    """A store padded to the feature group, as the rounds learner lays it
+    out: `real_columns` leading columns hold data, the rest bin 0 (-128
+    stored as int8).  The launch that histograms only the real columns
+    gives them the histograms of the launch over all columns, bit for
+    bit, and exact zeros for the padding, on the Pallas interpreter and
+    on XLA; over two row chunks.  One 32-column block (13 real) is one
+    launch; four 8-column blocks whose last holds 4 real columns are two,
+    the full blocks' and the tail's; a store with no padding is the one
+    launch it was; blocks of padding alone (a store padded to the
+    scatter's slices, say) launch nothing.  With 63 bins two features
+    share a packed column of 128 bins, and the tail's last real column
+    holds one of them."""
+    from lightgbm_tpu.ops.histogram import store_alignment
+    B = 128 if nbin <= 64 else 256
+    col, chunk = store_alignment(itemsize, B, operands, nbin)
+    assert Fp % col == 0
+    C = 2 * chunk
+    rng = np.random.RandomState(40 + R + K)
+    gb = np.zeros((Fp, C), np.int32)
+    gb[:R] = rng.randint(0, nbin, size=(R, C))
+    if itemsize == 1:
+        gb = (gb - 128).astype(np.int8)
+    lid = rng.randint(0, 2 * K + 1, size=C).astype(np.int32)
+    gh8 = np.zeros((8, C), np.float32)
+    gh8[2] = rng.rand(C) < 0.9
+    gh8[0] = rng.randn(C) * gh8[2]
+    gh8[1] = rng.rand(C) * gh8[2]
+    sl = rng.permutation(2 * K + 1)[:K].astype(np.int32)
+    args = tuple(jnp.asarray(a) for a in (gb, lid, gh8, sl))
+    kw = dict(num_bins_padded=B, input_dtype=operands, max_num_bin=nbin)
+
+    def hist(backend, **more):
+        return np.asarray(hist_multileaf_masked(
+            *args, backend=backend, interpret=backend == "pallas",
+            **kw, **more))
+    for backend in ("pallas", "xla"):
+        every = hist(backend)
+        real = hist(backend, real_columns=R)
+        assert real.shape == every.shape == (K, Fp, 3, B)
+        np.testing.assert_array_equal(real[:, :R], every[:, :R])
+        assert not real[:, R:].any()
+        if R < Fp:           # the padding's bin 0 held every masked row
+            assert every[:, R:, 2].any()
+        assert real[:, :R, 2].sum() > 0
+
+    def launch(*a):
+        return hist_multileaf_masked(*a, backend="pallas", interpret=True,
+                                     real_columns=R, **kw)
+    assert _pallas_calls(launch, *args) == calls
+
+
 def test_hist_pallas_bf16_onehot():
     """Gather-fed kernels with bf16 operands (_simple_onehot): must
     match the XLA bf16 formulation."""

@@ -140,6 +140,43 @@ def test_parity_multiclass_stump_and_empty_class():
     np.testing.assert_allclose(got, ref, rtol=0, atol=1e-6)
 
 
+@pytest.mark.parametrize("num_class", [1, 3])
+@pytest.mark.parametrize("layout", ["auto", "soa"])
+def test_compensated_pair_is_the_float64_sum_of_the_trees(layout,
+                                                          num_class):
+    """Every device kernel sums the trees' float32 leaf values as a pair
+    of parts (ops/predict._class_sums) that rounds once: each margin is
+    the float64 sum of those leaf values over the same routes to within
+    half a float32 ulp, where a float32 sum of the 200 trees, one
+    rounding a tree, strays past 1e-6.  The per-class walk agrees."""
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.predict import build_ensemble, predict_ensemble_any
+    rng = np.random.RandomState(7 + num_class)
+    F = 6
+    X = rng.rand(300, F).astype(np.float32)
+    tbc = []
+    for _ in range(num_class):
+        trees = [_rand_tree(rng, F, leaves=15, maxdepth=5)
+                 for _ in range(200)]
+        for t in trees:       # margins of ~10, leaf values float32-exact
+            t.leaf_value[:] = np.float32(t.leaf_value + 0.05)
+        tbc.append(trees)
+    per_tree = [np.stack([t.predict_raw(X.astype(np.float64))
+                          for t in trees]) for trees in tbc]
+    ref = np.stack([v.sum(axis=0) for v in per_tree])
+    stack, meta = build_ensemble(tbc, binned=False, layout=layout)
+    stack = jax.device_put(stack)
+    got = np.asarray(predict_ensemble_any(stack, jnp.asarray(X), meta=meta))
+    assert got.shape == (num_class, 300) and got.dtype == np.float32
+    half_ulp = np.spacing(np.abs(ref).astype(np.float32)) / 2
+    assert np.all(np.abs(got - ref) <= half_ulp + 1e-9)
+    assert np.array_equal(got, _walk_raw(tbc, X))
+    plain = np.stack([v.astype(np.float32).sum(axis=0, dtype=np.float32)
+                      for v in per_tree])
+    assert np.abs(plain - ref).max() > 1e-6
+
+
 def test_categorical_routes_through_soa_bitwise():
     from lightgbm_tpu.ops.predict import EnsembleStack
     rng = np.random.RandomState(4)

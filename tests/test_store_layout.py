@@ -264,6 +264,30 @@ def test_a_store_under_one_row_chunk_stays_as_it_is(pallas_interpreted):
     assert moved["tree/store_copy_rows"] == 0
 
 
+@pytest.mark.parametrize("f", [28, 32], ids=["padded", "unpadded"])
+def test_padded_columns_are_left_out_and_counted(pallas_interpreted,
+                                                 monkeypatch, f):
+    """The learner hands its launches the store's real column count: the
+    tree is the one of launches over every column, bit for bit, and
+    tree/hist_pad_columns is the columns left out once a launch, folded
+    on the host from tree/hist_passes (registered at 0 where the store
+    has no padded column)."""
+    ds, cfg, g, h, _ = _problem(3_001, f, 63, "int8", seed=f)
+    lrn = RoundsTreeLearner(ds, cfg, None)
+    assert (lrn.Cstore, lrn.Fpad) == (f, 32)
+    arrs, lid, moved = _build(lrn, g, h)
+    assert moved["tree/hist_passes"] >= 3
+    assert moved[profiling.HIST_PAD_COLUMNS] == (
+        moved["tree/hist_passes"] * (32 - f))
+
+    real = rounds.hist_multileaf_masked
+    monkeypatch.setattr(rounds, "hist_multileaf_masked",
+                        lambda *a, real_columns=0, **kw: real(*a, **kw))
+    arrs_all, lid_all, _ = _build(RoundsTreeLearner(ds, cfg, None), g, h)
+    _same_arrays(arrs, arrs_all)
+    np.testing.assert_array_equal(lid, lid_all)
+
+
 def test_off_the_chip_the_store_is_not_padded():
     """The XLA kernels tile nothing, so the CPU learner pads nothing."""
     ds, cfg, g, h, _ = _problem(9_001, 28, 63, "int8", seed=2)

@@ -183,7 +183,9 @@ def test_mxu_ops_of_a_pallas_launch_from_its_shapes():
     """The chip's launches at the Epsilon cell's shapes, by hand: int32
     bins in groups of 8 columns, 3K value rows padded to 8, rows padded
     to the 8192-row chunk, 256 bins; 63 bins pack two columns into 128
-    lanes; int8-stored bins take groups of 32 columns and 2048 rows."""
+    lanes; int8-stored bins take groups of 32 columns and 2048 rows, of
+    which a launch histograms the real ones alone (28: the wrapper's 4
+    padded columns run nothing), as the XLA fallback does."""
     kw = dict(backend="pallas", input_dtype="int8")
     full = masked_hist_mxu_ops(2000, 200064, 84, bins_itemsize=4,
                                num_bins_padded=256, max_num_bin=255, **kw)
@@ -196,7 +198,7 @@ def test_mxu_ops_of_a_pallas_launch_from_its_shapes():
     assert b63 == 2.0 * 204800 * 256 * 1000 * 128
     narrow = masked_hist_mxu_ops(28, 4096, 8, bins_itemsize=1,
                                  num_bins_padded=256, max_num_bin=255, **kw)
-    assert narrow == 2.0 * 4096 * 24 * 32 * 256
+    assert narrow == 2.0 * 4096 * 24 * 28 * 256
     xla = masked_hist_mxu_ops(28, 1000, 8, bins_itemsize=4,
                               num_bins_padded=256, backend="xla",
                               input_dtype="float32")
