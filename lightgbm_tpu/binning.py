@@ -26,6 +26,10 @@ import numpy as np
 
 NUMERICAL = 0
 CATEGORICAL = 1
+# missing types, as the reference's model text codes them in bits 2-3 of
+# a split's decision_type (MissingType: None 0, Zero 1, NaN 2)
+MISSING_NONE = 0
+MISSING_NAN = 2
 
 
 @dataclass
@@ -44,6 +48,15 @@ class BinMapper:
     max_val: float = 0.0
     default_bin: int = 0
     sparse_rate: float = 0.0
+    # MISSING_NAN: a numerical feature whose column holds NaN; NaN has a
+    # bin of its own, the last (`nan_bin`), after the value bins that
+    # `bin_upper_bound` bounds.  MISSING_NONE: NaN bins as 0.0 does
+    missing_type: int = MISSING_NONE
+
+    @property
+    def nan_bin(self) -> int:
+        """The NaN bin, or -1 where NaN bins as 0.0."""
+        return self.num_bin - 1 if self.missing_type == MISSING_NAN else -1
 
     @property
     def other_bin(self) -> bool:
@@ -63,14 +76,19 @@ class BinMapper:
 
     def value_to_bin(self, values: np.ndarray) -> np.ndarray:
         """Vectorized value->bin (reference bin.h:418-440).  NaN maps to
-        value 0 (v2.0-era missing handling; searchsorted would otherwise
-        return an out-of-range bin).  A category outside the kept list
-        maps to the `other_bin`, or to bin 0 where there is none."""
+        the `nan_bin` where the feature has one, else to the bin of value
+        0 (missing type None, as the reference).  A category outside the
+        kept list maps to the `other_bin`, or to bin 0 where there is
+        none."""
         values = np.asarray(values, dtype=np.float64)
-        values = np.where(np.isnan(values), 0.0, values)
+        nan = np.isnan(values)
+        values = np.where(nan, 0.0, values)
         if self.bin_type == NUMERICAL:
-            return np.searchsorted(self.bin_upper_bound, values, side="left").astype(
-                np.int32)
+            out = np.searchsorted(self.bin_upper_bound, values,
+                                  side="left").astype(np.int32)
+            if self.missing_type == MISSING_NAN:
+                out[nan] = self.nan_bin
+            return out
         # categorical: one searchsorted over the sorted category table
         # instead of a Python loop per category (a store of a hundred
         # million rows holds hundreds of categories in a column)
@@ -98,7 +116,8 @@ class BinMapper:
     def bin_to_value(self, b: int) -> float:
         """Real-valued threshold stored in the model text for bin `b`."""
         if self.bin_type == NUMERICAL:
-            return float(self.bin_upper_bound[min(b, self.num_bin - 1)])
+            return float(self.bin_upper_bound[
+                min(b, len(self.bin_upper_bound) - 1)])
         return float(self.bin_2_categorical[min(b, len(self.bin_2_categorical) - 1)])
 
     def feature_info(self) -> str:
@@ -110,16 +129,18 @@ class BinMapper:
         return ":".join(str(c) for c in self.bin_2_categorical)
 
 
-def _distinct_with_zero(sample_values: np.ndarray, total_sample_cnt: int
-                        ) -> Tuple[np.ndarray, np.ndarray]:
+def _distinct_with_zero(sample_values: np.ndarray, total_sample_cnt: int,
+                        na_cnt: int = 0) -> Tuple[np.ndarray, np.ndarray]:
     """Distinct values + counts with zero injected at the right rank.
 
     `sample_values` are the NON-ZERO sampled values; zeros are implied
     (reference bin.cpp:70-103 treats zero_cnt = total - num_sampled).
+    NaN among them counts as a zero, unless `na_cnt` takes the sampled
+    NaN out of the implied zeros (a feature with a NaN bin).
     """
     sample_values = np.asarray(sample_values, dtype=np.float64)
     sample_values = sample_values[~np.isnan(sample_values)]
-    zero_cnt = int(total_sample_cnt - sample_values.size)
+    zero_cnt = int(total_sample_cnt - sample_values.size - na_cnt)
     if sample_values.size == 0:
         return np.array([0.0]), np.array([max(zero_cnt, 1)], dtype=np.int64)
     vals, counts = np.unique(sample_values, return_counts=True)
@@ -251,32 +272,43 @@ def _need_filter(cnt_in_bin: Sequence[int], total_cnt: int, filter_cnt: int,
 
 def find_bin(sample_values: np.ndarray, total_sample_cnt: int, max_bin: int,
              min_data_in_bin: int = 3, min_split_data: int = 20,
-             bin_type: int = NUMERICAL) -> BinMapper:
-    """Construct a BinMapper from sampled (non-zero) values of one feature.
+             bin_type: int = NUMERICAL, na_cnt: int = -1) -> BinMapper:
+    """Construct a BinMapper from sampled (non-zero) values of one feature
+    (`na_cnt` as find_bin_from_distinct's).
 
     Mirrors reference BinMapper::FindBin (bin.cpp:67-240).
     """
-    vals, counts = _distinct_with_zero(sample_values, total_sample_cnt)
+    vals, counts = _distinct_with_zero(sample_values, total_sample_cnt,
+                                       max(na_cnt, 0))
     return find_bin_from_distinct(vals, counts, total_sample_cnt, max_bin,
-                                  min_data_in_bin, min_split_data, bin_type)
+                                  min_data_in_bin, min_split_data, bin_type,
+                                  na_cnt=na_cnt)
 
 
 def find_bin_from_distinct(vals: np.ndarray, counts: np.ndarray,
                            total_sample_cnt: int, max_bin: int,
                            min_data_in_bin: int = 3, min_split_data: int = 20,
-                           bin_type: int = NUMERICAL) -> BinMapper:
+                           bin_type: int = NUMERICAL,
+                           na_cnt: int = -1) -> BinMapper:
     """BinMapper from an already-built distinct-value summary (sorted
     `vals` with per-value `counts`, zero already injected).  The body of
     `find_bin`, exposed so the mergeable quantile sketches
     (sharded/sketch.py) can reuse the exact same greedy boundary logic
     on their weighted summaries — a sketch that still holds every
-    distinct value yields the bitwise-identical mapper."""
+    distinct value yields the bitwise-identical mapper.
+
+    na_cnt >= 0 (numerical only): the column holds NaN, `na_cnt` of them
+    among the sampled rows, which `counts` leave out; the value bins are
+    found over the other rows and NaN gets the last bin (MISSING_NAN).
+    -1: NaN is counted as 0.0, as `_distinct_with_zero` counts it."""
     m = BinMapper(bin_type=bin_type)
     counts = np.asarray(counts, np.int64)
     m.min_val, m.max_val = float(vals[0]), float(vals[-1])
+    missing = na_cnt >= 0 and bin_type == NUMERICAL
+    value_cnt = total_sample_cnt - na_cnt if missing else total_sample_cnt
 
     if bin_type == NUMERICAL:
-        ub, cnt_in_bin = _numerical_bins(vals, counts, total_sample_cnt, max_bin,
+        ub, cnt_in_bin = _numerical_bins(vals, counts, value_cnt, max_bin,
                                          min_data_in_bin)
         m.bin_upper_bound = ub
         m.num_bin = int(ub.size)
@@ -305,6 +337,12 @@ def find_bin_from_distinct(vals: np.ndarray, counts: np.ndarray,
             cnt_in_bin[-1] += int(total_sample_cnt - used_cnt)
         m.num_bin = len(cnt_in_bin)
 
+    if missing:
+        # NaN's own bin after the value bins, judged for triviality with
+        # them (reference FindBin, missing type NaN)
+        m.missing_type = MISSING_NAN
+        m.num_bin += 1
+        cnt_in_bin.append(int(na_cnt))
     m.is_trivial = m.num_bin <= 1
     if not m.is_trivial and _need_filter(cnt_in_bin, total_sample_cnt,
                                          min_split_data, bin_type):
@@ -351,6 +389,10 @@ class StoreCells(NamedTuple):
     lo: np.ndarray      # [C, B] int32 first cell of the prefix (own index
     #                     where the cell is no prefix candidate)
     hi: np.ndarray      # [C, B] int32 last cell of the suffix (likewise)
+    # [C] int32 the NaN bin of a column that holds one numerical feature
+    # with a NaN bin (never packed: its own column, the bin its last
+    # cell), -1 for any other column; None where no feature has one
+    nan: Optional[np.ndarray] = None
 
 
 @dataclass
@@ -435,7 +477,8 @@ class BundlePlan:
         return src, dmask
 
     def search_tables(self, num_bins: np.ndarray, is_cat: np.ndarray,
-                      B: int, num_columns_padded: int = 0
+                      B: int, num_columns_padded: int = 0,
+                      missing_bins: Optional[np.ndarray] = None
                       ) -> Optional[StoreCells]:
         """Per-cell tables over the padded store histogram [C, B] for a
         split search in the store's own cells, with no [F, 3, B] array
@@ -450,6 +493,11 @@ class BundlePlan:
         numerical, and every bin by itself if categorical (one against
         the rest).  Slot 0 of a packed column, bins past a column's last
         slot and padded columns are no candidate.
+
+        missing_bins ([F] int32, a feature's NaN bin or -1): a feature
+        with one keeps a column of its own (plan_bundles' never_pack),
+        and `nan` names that bin, whose sums the search adds to each
+        threshold's left side for the candidates with NaN on the left.
 
         None where the plan packs a categorical feature: its candidate
         on the default bin has no slot, and callers keep the gather
@@ -482,12 +530,21 @@ class BundlePlan:
             lo[col, below] = off
             suffix[col, above] = True
             hi[col, above] = off + nb - 2
-        return StoreCells(feat, thr, suffix, lo, hi)
+        nan = None
+        if missing_bins is not None:
+            mb = np.asarray(missing_bins, np.int32)
+            if np.any(self.feat_packed & (mb >= 0)):
+                raise ValueError("a feature with a NaN bin was packed")
+            nan = np.full(C, -1, np.int32)
+            own_nan = np.flatnonzero(mb >= 0)
+            nan[self.feat_col[own_nan]] = mb[own_nan]
+        return StoreCells(feat, thr, suffix, lo, hi, nan)
 
 
 def plan_bundles(sample_bins: np.ndarray, num_bins: np.ndarray,
                  default_bins: np.ndarray, max_conflict_rate: float,
-                 max_bundle_bins: int = 256, max_probe: int = 128
+                 max_bundle_bins: int = 256, max_probe: int = 128,
+                 never_pack: Optional[np.ndarray] = None
                  ) -> Optional[BundlePlan]:
     """Greedy conflict-graph bundling over SAMPLED binned columns.
 
@@ -503,6 +560,10 @@ def plan_bundles(sample_bins: np.ndarray, num_bins: np.ndarray,
     — they become singleton columns immediately, which keeps planning
     O(sparse^2) instead of O(F^2) on dense data.
 
+    `never_pack` ([F] bool) marks features that keep a column of their
+    own: those with a NaN bin, whose NaN rows a bundle's default slot
+    would merge with the others' defaults.
+
     Returns None when no bundle would hold >= 2 features (store unchanged).
     """
     F, S = sample_bins.shape
@@ -512,7 +573,8 @@ def plan_bundles(sample_bins: np.ndarray, num_bins: np.ndarray,
     nd_cnt = nd.sum(axis=1)
     budget = int(max_conflict_rate * S)
     cand = [k for k in range(F)
-            if nd_cnt[k] <= 0.5 * S and 2 <= num_bins[k] <= max_bundle_bins]
+            if nd_cnt[k] <= 0.5 * S and 2 <= num_bins[k] <= max_bundle_bins
+            and (never_pack is None or not never_pack[k])]
     cand.sort(key=lambda k: -int(nd_cnt[k]))
 
     bundles: List[List[int]] = []       # member inner indices
@@ -643,7 +705,9 @@ def allocate_bin_budgets(distinct: np.ndarray, mass: np.ndarray,
 def find_bin_mappers(X: np.ndarray, max_bin: int, min_data_in_bin: int,
                      min_split_data: int, categorical: Sequence[int] = (),
                      sample_cnt: int = 200000, seed: int = 1,
-                     bin_budget: int = 0) -> List[BinMapper]:
+                     bin_budget: int = 0, use_missing: bool = False,
+                     nan_columns: Optional[np.ndarray] = None
+                     ) -> List[BinMapper]:
     """Find bin mappers for all columns of a dense matrix.
 
     Equivalent of DatasetLoader::ConstructBinMappersFromTextData
@@ -654,6 +718,11 @@ def find_bin_mappers(X: np.ndarray, max_bin: int, min_data_in_bin: int,
     (the global budget split by distinct-value/mass share, read off
     each column's distinct-value summary — computed ONCE per column
     and shared with the boundary search via find_bin_from_distinct).
+
+    ``use_missing``: a numerical column that holds NaN — anywhere in
+    `nan_columns` ([F] bool, decided by the caller over whole columns),
+    or in the sample where that is not given — gets a NaN bin of its
+    own (BinMapper.missing_type); off, NaN bins as 0.0 everywhere.
     """
     n, f = X.shape
     rng = np.random.RandomState(seed)
@@ -665,11 +734,20 @@ def find_bin_mappers(X: np.ndarray, max_bin: int, min_data_in_bin: int,
         sample = X
         total = n
     cats = set(int(c) for c in categorical)
+    if use_missing:
+        na = np.isnan(sample).sum(axis=0)
+        has_nan = (np.asarray(nan_columns, bool) if nan_columns is not None
+                   else na > 0)
+        na_cnt = [int(na[j]) if has_nan[j] and j not in cats else -1
+                  for j in range(f)]
+    else:
+        na_cnt = [-1] * f
     summaries = []
     for j in range(f):
         col = np.asarray(sample[:, j], dtype=np.float64)
         nonzero = col[col != 0.0]      # NaNs dropped by _distinct_*
-        summaries.append(_distinct_with_zero(nonzero, total))
+        summaries.append(_distinct_with_zero(nonzero, total,
+                                             max(na_cnt[j], 0)))
     if bin_budget > 0 and f:
         # distinct incl. the implied zero = vals.size; mass (non-zero
         # sample count) = total minus the zero value's count
@@ -686,5 +764,14 @@ def find_bin_mappers(X: np.ndarray, max_bin: int, min_data_in_bin: int,
         mb = int(budgets[j]) if budgets is not None else max_bin
         mappers.append(find_bin_from_distinct(
             vals, counts, total, mb, min_data_in_bin, min_split_data,
-            bt))
+            bt, na_cnt=na_cnt[j]))
     return mappers
+
+
+def nan_columns_of(X: np.ndarray, block: int = 1 << 16) -> np.ndarray:
+    """[F] bool: the columns of `X` that hold a NaN anywhere, a block of
+    rows at a time (no [N, F] temporary)."""
+    out = np.zeros(X.shape[1], bool)
+    for lo in range(0, X.shape[0], block):
+        out |= np.isnan(X[lo:lo + block]).any(axis=0)
+    return out

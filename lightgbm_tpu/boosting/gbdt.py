@@ -173,7 +173,8 @@ class GBDT:
         self.train_score = ScoreUpdater(
             lambda: self.learner.bins_t, self.num_data, self.K,
             train_set.metadata.init_score,
-            feat_tbl=train_set.bundle_feat_table(), layout=layout)
+            feat_tbl=train_set.bundle_feat_table(), layout=layout,
+            missing_bins=train_set.missing_bins)
         # continued training (input_model): replay the loaded model onto
         # the fresh training scores (the reference re-scores via a
         # Predictor closure during loading, application.cpp:106-113) —
@@ -233,7 +234,8 @@ class GBDT:
                 np.concatenate([bins_np, pad], axis=1).T.copy())
         su = ScoreUpdater(bins_t, valid_set.num_data, self.K,
                           valid_set.metadata.init_score,
-                          feat_tbl=valid_set.bundle_feat_table())
+                          feat_tbl=valid_set.bundle_feat_table(),
+                          missing_bins=valid_set.missing_bins)
         names = cfg.metric or (default_metric_for_objective(cfg.objective),)
         ms = []
         for nm in names:

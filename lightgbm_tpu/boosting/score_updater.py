@@ -85,7 +85,10 @@ def _walk_step(node, bins_nt, split_feature, threshold, decision,
         in_r = (s >= 0) & (s < ns)
         orig = jnp.where(in_r, s + (s >= dflt).astype(jnp.int32), dflt)
         bv = jnp.where(pk, orig, bv_store)
-    go_left = jnp.where(d == 1, bv == t, bv <= t)
+    # a decision lane of -1 - b sends the NaN bin b left (tree.binned_lanes)
+    di = d.astype(jnp.int32)
+    go_left = jnp.where(d == 1, bv == t,
+                        (bv <= t) | ((di < 0) & (bv == -1 - di)))
     nxt = jnp.where(go_left, r[3], r[4]).astype(jnp.int32)
     return jnp.where(node < 0, node, nxt)
 
@@ -118,13 +121,22 @@ def predict_binned_leaf(bins_t: jax.Array, split_feature_inner: jax.Array,
 @jax.jit
 def traverse_tree_device(bins_t, split_feature, threshold_bin, is_cat,
                          left_child, right_child, num_leaves,
-                         feat_tbl=None) -> jax.Array:
+                         feat_tbl=None, default_left=None,
+                         missing_bins=None) -> jax.Array:
     """Leaf index per row from DEVICE tree arrays (learner TreeArrays) —
     no host tree needed, so the pipelined training path can score valid
     sets without waiting for the tree fetch.  A `while_loop` walks until
     every row parked at a leaf (negative node), so cost tracks the actual
-    tree depth instead of a static worst-case bound."""
+    tree depth instead of a static worst-case bound.  With the tree's
+    `default_left` and the store's `missing_bins` (Dataset.missing_bins),
+    a split on a feature with a NaN bin sends that bin to its default
+    side."""
     bins_nt, N = _bins_rows(bins_t)
+    decision = is_cat
+    if default_left is not None:
+        mb = missing_bins[split_feature]
+        decision = jnp.where(is_cat, 1, jnp.where(
+            default_left & (mb >= 0), -1 - mb, 0))
     # stump: everything is leaf 0 (node -1 == ~0) from the start
     n0 = jnp.where(num_leaves < 2, jnp.int32(-1), jnp.int32(0))
     node = jnp.full(N, n0, jnp.int32)
@@ -139,7 +151,7 @@ def traverse_tree_device(bins_t, split_feature, threshold_bin, is_cat,
     def body(st):
         i, node = st
         node = _walk_step(node, bins_nt, split_feature, threshold_bin,
-                          is_cat, left_child, right_child, nn, feat_tbl)
+                          decision, left_child, right_child, nn, feat_tbl)
         return i + 1, node
 
     _, node = jax.lax.while_loop(cond, body, (jnp.int32(0), node))
@@ -271,7 +283,7 @@ class ScoreUpdater:
 
     def __init__(self, bins_t, num_data: int, K: int,
                  init_score: Optional[np.ndarray] = None, feat_tbl=None,
-                 layout: Optional[RowLayout] = None):
+                 layout: Optional[RowLayout] = None, missing_bins=None):
         # bins_t: [N+1, C] array, the sparse ELL triple (cols, binsv,
         # zero_bin), None, or a ZERO-ARG CALLABLE resolved on first
         # traversal.  Sparse stores hand the triple so every traversal
@@ -282,6 +294,9 @@ class ScoreUpdater:
         # [5, F] bundle walk table when bins_t is an EFB store (see
         # _walk_step), None for the plain per-feature layout
         self.feat_tbl = None if feat_tbl is None else jnp.asarray(feat_tbl)
+        # [F] NaN bin per feature of a store that has one (None without)
+        self.missing_bins = (None if missing_bins is None
+                             else jnp.asarray(missing_bins))
         self.num_data = num_data
         self.K = K
         self.has_init_score = init_score is not None
@@ -381,10 +396,11 @@ class ScoreUpdater:
         """Whole-data score update from DEVICE TreeArrays (pipelined path
         for datasets that don't have the training leaf_id — valid sets).
         `leaf_values` carries shrinkage/clamp pre-applied."""
+        dl = arrs.default_left
         leaf_idx = traverse_tree_device(
             self.bins_t, arrs.split_feature, arrs.threshold_bin,
             arrs.is_cat, arrs.left_child, arrs.right_child, arrs.num_leaves,
-            self.feat_tbl)
+            self.feat_tbl, dl, None if dl is None else self.missing_bins)
         self._add_by_leaf_id(leaf_idx, leaf_values, tree_id)
 
     def add_tree_by_leaf_id_dev(self, leaf_id: jax.Array,

@@ -110,11 +110,15 @@ class CApiDataset:
         mappers = []
         for j, (addr, cnt) in enumerate(zip(col_addrs, num_per_col)):
             vals = _view(addr, cnt, 1).astype(np.float64, copy=True)
-            vals = vals[(vals != 0.0) & ~np.isnan(vals)]
+            nan = np.isnan(vals)
             bt = CATEGORICAL if j in cats else NUMERICAL
+            # a numerical column with sampled NaN gets a NaN bin
+            na = (int(nan.sum()) if cfg.use_missing and bt == NUMERICAL
+                  and nan.any() else -1)
+            vals = vals[(vals != 0.0) & ~nan]
             mappers.append(find_bin(vals, int(num_sample_row), cfg.max_bin,
                                     cfg.min_data_in_bin,
-                                    cfg.min_data_in_leaf, bt))
+                                    cfg.min_data_in_leaf, bt, na_cnt=na))
         used = [i for i, m in enumerate(mappers) if not m.is_trivial]
         inner = _InnerDataset._empty_from_mappers(
             cfg, mappers, used, int(num_total_row), len(mappers), None)

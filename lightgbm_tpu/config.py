@@ -493,6 +493,12 @@ class Config:
     bin_construct_sample_cnt: int = 200000
     sparse_threshold: float = 0.8
     min_data_in_bin: int = 3
+    # missing values as the reference handles them (Advanced-Topics,
+    # "Missing Value Handle"): a numerical column that holds NaN gets a
+    # NaN bin of its own, split search tries NaN on both sides and the
+    # split records the side (decision_type's default_left bit).  Off,
+    # NaN bins and predicts as 0.0.
+    use_missing: bool = True
     # distributed / out-of-core bin finding (docs/Distributed-Data.md):
     # "allgather" derives mappers from the process-allgathered global
     # sample (the validated PR-era path); "sketch" merges per-host (and

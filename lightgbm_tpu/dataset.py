@@ -26,6 +26,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from .binning import (BinMapper, BundlePlan, find_bin_mappers,
+                      nan_columns_of,
                       pack_bundle_column, plan_bundles, CATEGORICAL)
 from .config import Config
 
@@ -202,7 +203,9 @@ def _plan_bundles_from_sample(sample: np.ndarray, mappers: List[BinMapper],
         np.asarray(sample[:, i], np.float64)) for i in used])
     nb = np.asarray([mappers[i].num_bin for i in used], np.int32)
     db = np.asarray([mappers[i].default_bin for i in used], np.int32)
-    return plan_bundles(sb, nb, db, cfg.max_conflict_rate)
+    nan = np.asarray([mappers[i].nan_bin >= 0 for i in used], bool)
+    return plan_bundles(sb, nb, db, cfg.max_conflict_rate,
+                        never_pack=nan if nan.any() else None)
 
 
 def _log_bundle_state(plan: Optional[BundlePlan], num_used: int,
@@ -601,7 +604,8 @@ def load_file_two_round(path: str, cfg: Config,
         mappers = find_bin_mappers(
             sample, cfg.max_bin, cfg.min_data_in_bin, cfg.min_data_in_leaf,
             categorical=cats, sample_cnt=len(sample),
-            seed=cfg.data_random_seed, bin_budget=cfg.bin_budget)
+            seed=cfg.data_random_seed, bin_budget=cfg.bin_budget,
+            use_missing=cfg.use_missing)
         used = [i for i, m in enumerate(mappers) if not m.is_trivial]
         plan = _plan_bundles_from_sample(sample, mappers, used, cfg)
         _log_bundle_state(plan, len(used), cfg)
@@ -674,7 +678,10 @@ class Dataset:
                     categorical=categorical_feature,
                     sample_cnt=cfg.bin_construct_sample_cnt,
                     seed=cfg.data_random_seed,
-                    bin_budget=cfg.bin_budget)
+                    bin_budget=cfg.bin_budget,
+                    use_missing=cfg.use_missing,
+                    nan_columns=(nan_columns_of(X) if cfg.use_missing
+                                 else None))
             self.used_features = [i for i, m in enumerate(self.mappers)
                                   if not m.is_trivial]
             plan = _plan_bundles_from_sample(X, self.mappers,
@@ -1058,11 +1065,15 @@ class Dataset:
             used = reference.used_features
             plan = reference.bundle_plan
         else:
+            nan_cols = (np.asarray([
+                bool(np.isnan(data[indptr[j]:indptr[j + 1]]).any())
+                for j in range(num_raw)]) if cfg.use_missing else None)
             mappers = find_bin_mappers(
                 sample, cfg.max_bin, cfg.min_data_in_bin,
                 cfg.min_data_in_leaf, categorical=categorical_feature,
                 sample_cnt=len(sample), seed=cfg.data_random_seed,
-                bin_budget=cfg.bin_budget)
+                bin_budget=cfg.bin_budget, use_missing=cfg.use_missing,
+                nan_columns=nan_cols)
             used = [i for i, m in enumerate(mappers) if not m.is_trivial]
             plan = _plan_bundles_from_sample(sample, mappers, used, cfg)
             _log_bundle_state(plan, len(used), cfg)
@@ -1150,6 +1161,16 @@ class Dataset:
             return None
         return self.bundle_plan.feat_table()
 
+    @property
+    def missing_bins(self) -> Optional[np.ndarray]:
+        """[F] int32 per used feature: its NaN bin (binning.BinMapper
+        .nan_bin; always its last), -1 for a feature without one — or
+        None where no feature has one, so that a store without NaN
+        builds today's programs, with no operand for it."""
+        nb = np.asarray([self.mappers[i].nan_bin for i in
+                         self.used_features], np.int32)
+        return nb if (nb >= 0).any() else None
+
     def unbundle_tables(self, num_bins_padded: int,
                         num_columns_padded: int = 0):
         """(src, dmask) gather tables for ops/split.unbundle_hist, or
@@ -1165,16 +1186,16 @@ class Dataset:
     def search_tables(self, num_bins_padded: int,
                       num_columns_padded: int = 0):
         """StoreCells for a split search in the bundled store's own
-        cells (BundlePlan.search_tables), or None: no plan, or a plan
-        that packs a categorical feature, which is searched through
-        unbundle_tables."""
+        cells (BundlePlan.search_tables, the NaN bins included), or
+        None: no plan, or a plan that packs a categorical feature,
+        which is searched through unbundle_tables."""
         if self.bundle_plan is None:
             return None
         # a categorical member packs nothing here (None above), so its
         # bins are its candidates: the split count, not the stored one
         return self.bundle_plan.search_tables(
             self.split_num_bins, self.is_categorical, num_bins_padded,
-            num_columns_padded)
+            num_columns_padded, self.missing_bins)
 
     def unbundled_bins(self) -> np.ndarray:
         """Materialize the ORIGINAL [num_features, N] per-feature store
@@ -1365,7 +1386,7 @@ class Dataset:
         for i, m in enumerate(self.mappers):
             arrays[f"m{i}_meta"] = np.asarray(
                 [m.bin_type, m.num_bin, 1 if m.is_trivial else 0,
-                 m.default_bin], np.int64)
+                 m.default_bin, m.missing_type], np.int64)
             arrays[f"m{i}_fl"] = np.asarray(
                 [m.min_val, m.max_val, m.sparse_rate], np.float64)
             arrays[f"m{i}_upper"] = np.asarray(m.bin_upper_bound, np.float64)
@@ -1441,7 +1462,15 @@ class Dataset:
                 min_val=float(fl[0]), max_val=float(fl[1]),
                 sparse_rate=float(fl[2]),
                 bin_upper_bound=d[f"m{i}_upper"],
-                bin_2_categorical=cats))
+                bin_2_categorical=cats,
+                missing_type=int(meta[4]) if meta.size > 4 else 0))
+        if not cfg.use_missing and any(m.nan_bin >= 0 for m in ds.mappers):
+            # NaN bins as 0.0 under use_missing=false: a file binned
+            # with NaN bins would train another model under that config
+            raise ValueError(
+                f"binary dataset {path} was built with use_missing=true "
+                "and holds NaN bins, config wants use_missing=false; "
+                "delete the cache to rebuild")
         plan = None
         if "bundle_feat" in d:
             bf = d["bundle_feat"]

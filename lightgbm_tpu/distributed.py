@@ -306,7 +306,8 @@ def find_bin_mappers_distributed(local_sample, cfg, categorical=(),
         m = find_bin_mappers(
             local_sample, cfg.max_bin, cfg.min_data_in_bin,
             cfg.min_data_in_leaf, categorical=categorical,
-            sample_cnt=len(local_sample), seed=cfg.data_random_seed)
+            sample_cnt=len(local_sample), seed=cfg.data_random_seed,
+            use_missing=cfg.use_missing)
         return (m, local_sample) if return_sample else m
 
     # pad local samples to one shape (process sample sizes can differ by
@@ -321,5 +322,5 @@ def find_bin_mappers_distributed(local_sample, cfg, categorical=(),
     m = find_bin_mappers(
         flat, cfg.max_bin, cfg.min_data_in_bin, cfg.min_data_in_leaf,
         categorical=categorical, sample_cnt=len(flat),
-        seed=cfg.data_random_seed)
+        seed=cfg.data_random_seed, use_missing=cfg.use_missing)
     return (m, flat) if return_sample else m

@@ -47,9 +47,10 @@ from ..ops.predict import sparse_bin_lookup
 from ..ops.split import (best_split, best_split_in_store,
                          bundle_predicate_params, combine_sharded_records,
                          identity_feat_table, leaf_output, maybe_unbundle,
-                         sharded_slice_search, store_go_left,
+                         record_width, sharded_slice_search, store_go_left,
                          store_search_operands)
-from ..tree import Tree, NUMERICAL_DECISION, CATEGORICAL_DECISION
+from ..tree import (Tree, NUMERICAL_DECISION, CATEGORICAL_DECISION,
+                    DEFAULT_LEFT_MASK, MISSING_NAN_DECISION)
 from ..binning import CATEGORICAL, StoreCells
 
 NEG_INF = -jnp.inf
@@ -70,6 +71,15 @@ class TreeArrays(NamedTuple):
     leaf_count: jax.Array       # [L] f32
     leaf_depth: jax.Array       # [L] int32
     num_leaves: jax.Array       # scalar int32
+    # [L-1] bool: the NaN bin's side (a store with NaN bins), else None
+    default_left: Optional[jax.Array] = None
+
+
+def tree_arrays_specs(spec, missing: bool) -> TreeArrays:
+    """`spec` for every field of a build's TreeArrays: the shard_map
+    out_specs; `default_left` only where the build carries it."""
+    n = len(TreeArrays._fields) - 1
+    return TreeArrays(*[spec] * n, default_left=spec if missing else None)
 
 
 def _psum(x, axis):
@@ -88,7 +98,8 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
                voting_k: int = 0,
                num_machines: int = 1,
                hist_exchange: str = "psum",
-               cache_parent_hist: bool = True):
+               cache_parent_hist: bool = True,
+               missing_bins=None):
     """Grow one tree; runs per-shard inside `shard_map` (or standalone when
     both axes are None).
 
@@ -108,6 +119,9 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
                binning.StoreCells — searched in the store's own cells —
                or the (src, dmask) gather tables of a plan that packs a
                categorical feature, unbundled before split search
+    missing_bins : [F] int32 NaN bin of every ORIGINAL feature (all
+               shards'), -1 none; None where no feature has one
+               (learner/rounds.build_tree_rounds)
     Returns (TreeArrays, leaf_id [Nloc] int32).
     """
     sparse = isinstance(bins, (tuple, list))
@@ -123,6 +137,14 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
     l1, l2 = skw["lambda_l1"], skw["lambda_l2"]
     f_off = (jax.lax.axis_index(feature_axis) * feature_shard_size
              if feature_axis is not None else jnp.int32(0))
+    rw = record_width(missing_bins)
+    if missing_bins is not None:
+        missing_bins = jnp.asarray(missing_bins)
+        # this feature shard's rows, beside its num_bins / is_cat
+        mb_loc = jax.lax.dynamic_slice_in_dim(missing_bins, f_off,
+                                              is_cat.shape[0])
+    else:
+        mb_loc = None
 
     voting = voting_k > 0 and data_axis is not None
     # psum_scatter exchange (hist_exchange knob; the reference's
@@ -192,15 +214,18 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
                 nb_s = jax.lax.dynamic_slice_in_dim(num_bins, off, Fs)
                 ic_s = jax.lax.dynamic_slice_in_dim(is_cat, off, Fs)
                 fm_s = jax.lax.dynamic_slice_in_dim(fmask, off, Fs)
+                mb_s = (None if mb_loc is None else
+                        jax.lax.dynamic_slice_in_dim(mb_loc, off, Fs))
                 # fold the FEATURE-shard base into the slice offset so
                 # the shared search emits global feature ids directly
                 off = off + f_off
             else:
-                nb_s = ic_s = fm_s = None
+                nb_s = ic_s = fm_s = mb_s = None
             p = sharded_slice_search(
                 hist, sums, off=off, nb_s=nb_s, ic_s=ic_s, fm_s=fm_s,
                 num_bins=num_bins, is_cat=is_cat, fmask=fmask,
-                unb=search if in_store else unb, skw=skw)
+                unb=search if in_store else unb, skw=skw,
+                mb_s=mb_s, missing_bins=mb_loc)
             p = combine_sharded_records(p, data_axis)
         else:
             if in_store:
@@ -209,11 +234,11 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
             else:
                 rec = best_split(maybe_unbundle(hist, unb, sums),
                                  num_bins, is_cat, fmask,
-                                 sums[0], sums[1], sums[2], **skw)
+                                 sums[0], sums[1], sums[2], mb_loc, **skw)
             p = rec.packed()
             p = p.at[1].add(f_off.astype(jnp.float32))
         if feature_axis is not None:
-            allp = jax.lax.all_gather(p, feature_axis)     # [k, 11]
+            allp = jax.lax.all_gather(p, feature_axis)     # [k, rw]
             # argmax picks the first max → smallest shard → smallest
             # feature id among ties (split_info.hpp:100-105 determinism)
             p = allp[jnp.argmax(allp[:, 0])]
@@ -233,9 +258,10 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
             1, skw["min_data_in_leaf"] // max(num_machines, 1))
         relaxed["min_sum_hessian_in_leaf"] = (
             skw["min_sum_hessian_in_leaf"] / max(num_machines, 1))
-        gains, _, _, _ = split_gain_matrix(
+        gains = split_gain_matrix(
             hist_local, num_bins, is_cat, fmask,
-            local_sums[0], local_sums[1], local_sums[2], **relaxed)
+            local_sums[0], local_sums[1], local_sums[2], mb_loc,
+            **relaxed)[0]
         per_feat = jnp.max(gains, axis=1)                  # [F]
         k = min(voting_k, per_feat.shape[0])
         _, topk = jax.lax.top_k(per_feat, k)               # [k] local vote
@@ -260,6 +286,7 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
                 selp, jax.lax.axis_index(data_axis) * ks, ks)
             rec = best_split(hs, num_bins[sel_s], is_cat[sel_s],
                              fmask[sel_s], sums[0], sums[1], sums[2],
+                             None if mb_loc is None else mb_loc[sel_s],
                              **skw)
             p = rec.packed()
             # combine on the GLOBAL slot id so gain ties break by vote
@@ -275,16 +302,19 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
             return can_gate(p, sums)
         hist_sel = _psum(hist_local[sel], data_axis)       # [2k, 3, B]
         rec = best_split(hist_sel, num_bins[sel], is_cat[sel], fmask[sel],
-                         sums[0], sums[1], sums[2], **skw)
+                         sums[0], sums[1], sums[2],
+                         None if mb_loc is None else mb_loc[sel], **skw)
         p = rec.packed()
         p = p.at[1].set(sel[rec.feature].astype(jnp.float32))
         return can_gate(p, sums)
 
-    def go_left_row(feat, thr, catf):
+    def go_left_row(feat, thr, catf, dlf):
         """[Nloc] bool: does each local row go left under the ORIGINAL-
-        space split (feat, thr)?  The owning store-column shard evaluates
-        the store-space predicate; others contribute zeros."""
-        col, T, lo, hi1, dl = bundle_predicate_params(ftbl, feat, thr, catf)
+        space split (feat, thr, NaN bin to the left where dlf)?  The
+        owning store-column shard evaluates the store-space predicate;
+        others contribute zeros."""
+        col, T, lo, hi1, dl = bundle_predicate_params(
+            ftbl, feat, thr, catf, missing_bins, dlf)
         lf = col - f_off
         owned = (lf >= 0) & (lf < Floc)
         lc = jnp.clip(lf, 0, Floc - 1)
@@ -333,7 +363,7 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
         sum_g, sum_h, cnt = root_sums[0], root_sums[1], root_sums[2]
 
     leaf_id = jnp.zeros(Nloc, jnp.int32)
-    leaf_best = jnp.full((L, 11), NEG_INF, jnp.float32).at[0].set(
+    leaf_best = jnp.full((L, rw), NEG_INF, jnp.float32).at[0].set(
         find_best(hist0, root_sums))
     leaf_depth = jnp.zeros(L, jnp.int32)
     leaf_parent = jnp.full(L, -1, jnp.int32)
@@ -361,6 +391,8 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
         leaf_count=jnp.zeros(L, jnp.float32).at[0].set(cnt),
         leaf_depth=jnp.zeros(L, jnp.int32),
         num_leaves=jnp.int32(1),
+        default_left=(None if missing_bins is None
+                      else jnp.zeros(L - 1, bool)),
     )
 
     def body(i, st):
@@ -374,6 +406,7 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
         do = gated[best_leaf] > 0
         feat = rec[1].astype(jnp.int32)
         thr = rec[2].astype(jnp.int32)
+        dlf = None if missing_bins is None else rec[11] > 0.5
         new_leaf = jnp.int32(i + 1)
         node = jnp.int32(i)
 
@@ -387,7 +420,7 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
             catf = jax.lax.psum(catf.astype(jnp.int32), feature_axis) > 0
 
         # ---- partition (DataPartition::Split analog, mask-based) ----------
-        gl = go_left_row(feat, thr, catf)
+        gl = go_left_row(feat, thr, catf, dlf)
         split_mask = do & (leaf_id == best_leaf) & ~gl
         leaf_id2 = jnp.where(split_mask, new_leaf, leaf_id)
 
@@ -449,6 +482,9 @@ def build_tree(bins, grad, hess, row_mask, num_bins, is_cat, fmask, ftbl,
                 child_depth).at[new_leaf].set(child_depth),
             num_leaves=arrs.num_leaves + 1,
         )
+        if dlf is not None:
+            arrs2 = arrs2._replace(
+                default_left=arrs.default_left.at[node].set(dlf))
         new_st = (
             leaf_id2,
             leaf_best.at[best_leaf].set(rec_left).at[new_leaf].set(rec_right),
@@ -476,7 +512,7 @@ def pack_tree_arrays(arrs: TreeArrays) -> jax.Array:
     transfer (per-array fetches cost a device round-trip each — ruinous on
     remote-attached TPUs).  All int fields fit f32 exactly (< 2^24)."""
     return jnp.concatenate(
-        [jnp.ravel(x).astype(jnp.float32) for x in arrs]
+        [jnp.ravel(x).astype(jnp.float32) for x in arrs if x is not None]
         + [jnp.zeros(1, jnp.float32)])
 
 
@@ -485,12 +521,15 @@ def unpack_tree_arrays(vec: np.ndarray, L: int) -> TreeArrays:
     dts = ([np.int32, np.int32, bool, np.int32, np.int32, np.float32,
             np.float32, np.float32, np.float32, np.float32, np.int32,
             np.int32])
+    if vec.size > sum(sizes) + 1:           # default_left follows
+        sizes.append(L - 1)
+        dts.append(bool)
     out, off = [], 0
     for sz, dt in zip(sizes, dts):
         part = vec[off:off + sz]
         out.append(part.astype(dt) if dt != bool else part > 0.5)
         off += sz
-    out[-1] = out[-1][0]
+    out[11] = out[11][0]
     return TreeArrays(*out)
 
 
@@ -513,6 +552,15 @@ def tree_arrays_to_host(arrs, dataset: Dataset, max_leaves: int) -> Tree:
     from .. import profiling
     profiling.count(profiling.CATEGORICAL_SPLITS,
                     float(np.count_nonzero(a.is_cat[:k])))
+    mbins = dataset.missing_bins
+    if mbins is not None:
+        # a split on a feature with a NaN bin is of missing type NaN
+        node_mb = np.where(a.is_cat[:k], -1, mbins[a.split_feature[:k]])
+        on_nan = node_mb >= 0
+        profiling.count(profiling.MISSING_SPLITS,
+                        float(np.count_nonzero(on_nan)))
+        profiling.count(profiling.MISSING_DEFAULT_LEFT, float(
+            np.count_nonzero(on_nan & a.default_left[:k])))
     if n < 2:
         t.leaf_value[0] = float(a.leaf_value[0])
         return t
@@ -520,6 +568,11 @@ def tree_arrays_to_host(arrs, dataset: Dataset, max_leaves: int) -> Tree:
     t.threshold_in_bin[:k] = a.threshold_bin[:k]
     t.decision_type[:k] = np.where(a.is_cat[:k], CATEGORICAL_DECISION,
                                    NUMERICAL_DECISION)
+    if mbins is not None:
+        t.missing_bin[:k] = node_mb
+        t.decision_type[:k] |= np.where(
+            on_nan, MISSING_NAN_DECISION | np.where(
+                a.default_left[:k], DEFAULT_LEFT_MASK, 0), 0).astype(np.int8)
     t.has_categorical = bool(a.is_cat[:k].any())
     t.left_child[:k] = a.left_child[:k]
     t.right_child[:k] = a.right_child[:k]
@@ -661,7 +714,11 @@ class FusedTreeLearner:
             cache_cols = max(1, cache_cols // self.dd)
         self.cache_parent_hist = use_parent_hist_cache(
             cfg, cache_cols, self.B)
+        mbv = dataset.missing_bins
+        if mbv is not None:
+            mbv = np.pad(mbv, (0, self.Fp - self.F), constant_values=-1)
         kw = dict(num_leaves=cfg.num_leaves, num_bins_padded=self.B,
+                  missing_bins=mbv,
                   split_kw=self.split_kw, max_depth=int(cfg.max_depth),
                   min_data_in_leaf=int(cfg.min_data_in_leaf),
                   min_sum_hessian_in_leaf=float(cfg.min_sum_hessian_in_leaf),
@@ -691,8 +748,7 @@ class FusedTreeLearner:
             bins_spec = ((P(fa, da, None), P(fa, da, None), P(fa, None))
                          if sp_feed is not None else P(fa, da))
             in_specs = (bins_spec, P(da), P(da), P(da), P(fa), P(fa), P(fa))
-            out_specs = (jax.tree_util.tree_map(lambda _: P(), TreeArrays(
-                *[0] * len(TreeArrays._fields))), P(da))
+            out_specs = (tree_arrays_specs(P(), mbv is not None), P(da))
             self._build = jax.jit(jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False))
@@ -819,7 +875,9 @@ class FusedTreeLearner:
             hx_bytes = per * passes
         profiling.count(profiling.HIST_EXCHANGE_BYTES, hx_bytes)
         profiling.count(profiling.SPLIT_RECORDS_BYTES,
-                        4.0 * self.dd * 11 * calls if hxs else 0.0)
+                        4.0 * self.dd * record_width(
+                            self.dataset.missing_bins) * calls
+                        if hxs else 0.0)
 
     def train(self, grad: jax.Array, hess: jax.Array,
               bag_idx: Optional[jax.Array] = None,

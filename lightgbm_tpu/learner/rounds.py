@@ -56,7 +56,7 @@ from ..sharded.mesh import (check_scatter_divisible, check_tree_divergence,
 from .common import (CPU_TIER_BYTES_LIMIT, device_bytes_limit,
                      make_split_kw, padded_bin_count, sentinel_bins_t,
                      use_parent_hist_cache)
-from .fused import TreeArrays, tree_arrays_to_host
+from .fused import TreeArrays, tree_arrays_specs, tree_arrays_to_host
 from .. import profiling
 from ..jaxutil import RowLayout, bag_mask_dev
 from ..ops.histogram import (hist_multileaf_masked, hist_sparse_multileaf,
@@ -69,6 +69,7 @@ from ..ops.partition import (partition_rows, partition_rows_sparse,
 from ..binning import StoreCells
 from ..ops.split import (best_split, best_split_in_store,
                          bundle_predicate_params, combine_sharded_records,
+                         record_width,
                          identity_feat_table, leaf_output, maybe_unbundle,
                          sharded_slice_search, store_search_operands)
 from ..tree import Tree
@@ -168,7 +169,8 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                       num_feature_shards: int = 1,
                       leaves_per_batch: int = 0,
                       sparse: bool = False,
-                      real_columns: int = 0):
+                      real_columns: int = 0,
+                      missing_bins=None):
     """Grow one tree in batched rounds.  Shapes as learner/fused.build_tree.
     Returns (TreeArrays, leaf_id, stats) — stats is a [12] f32 vector in
     the order of STATS_COUNTERS: rows processed by histogram kernels
@@ -262,7 +264,13 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
     data.  The dense launches histogram only those
     (ops/histogram.hist_multileaf_masked) and hand back exact zeros for
     the columns a learner pads the store with, which split search never
-    reads; tree/hist_mxu_ops counts the contraction as executed."""
+    reads; tree/hist_mxu_ops counts the contraction as executed.
+
+    missing_bins ([F] int32, a feature's NaN bin or -1; None where no
+    feature has one — nothing of it is then traced): split search tries
+    the NaN bin on both sides (ops/split.best_split), the records gain a
+    twelfth field, `default_left`, which the partition's window and the
+    TreeArrays carry."""
     if sparse:
         sp_cols, sp_bins, sp_zb = bins[0], bins[1], bins[2]
         # stream leaves arrive stacked with a leading shard axis (one
@@ -331,10 +339,12 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             return 4.0 * k2 * Fs * 3 * B * hx_legs
         return 4.0 * k2 * F * 3 * B
 
+    rw = record_width(missing_bins)
+
     def _records_bytes(k2: int) -> float:
         """Per-device payload of the best-split-record allgather (only
         the psum_scatter path exchanges records)."""
-        return 4.0 * nd * k2 * 11 if hx else 0.0
+        return 4.0 * nd * k2 * rw if hx else 0.0
 
     def count_collectives(stv, exchanges: int, searches: int, more: int = 0):
         """`stv` with the collectives added that one device launches
@@ -436,22 +446,26 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                 nb_s = jax.lax.dynamic_slice_in_dim(num_bins, off, Fs)
                 ic_s = jax.lax.dynamic_slice_in_dim(is_cat, off, Fs)
                 fm_s = jax.lax.dynamic_slice_in_dim(fmask, off, Fs)
+                mb_s = (None if missing_bins is None else
+                        jax.lax.dynamic_slice_in_dim(
+                            jnp.asarray(missing_bins), off, Fs))
             else:
-                nb_s = ic_s = fm_s = None
+                nb_s = ic_s = fm_s = mb_s = None
 
         def one(h, s):
             if hx:
                 p = sharded_slice_search(
                     h, s, off=off, nb_s=nb_s, ic_s=ic_s, fm_s=fm_s,
                     num_bins=num_bins, is_cat=is_cat, fmask=fmask,
-                    unb=search if in_store else unb, skw=skw)
+                    unb=search if in_store else unb, skw=skw,
+                    mb_s=mb_s, missing_bins=missing_bins)
             elif in_store:
                 p = best_split_in_store(h, search, s[0], s[1], s[2],
                                         **skw).packed()
             else:
                 rec = best_split(maybe_unbundle(h, unb, s),
                                  num_bins, is_cat, fmask,
-                                 s[0], s[1], s[2], **skw)
+                                 s[0], s[1], s[2], missing_bins, **skw)
                 p = rec.packed()
             can = ((s[2] >= 2 * min_data_in_leaf)
                    & (s[1] >= 2 * min_sum_hessian_in_leaf))
@@ -498,7 +512,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
         stats = count_collectives(
             launch_stats(1, 1).at[S_EXCHANGE].set(_exchange_bytes(1))
             .at[S_RECORDS].set(_records_bytes(1)), 1, 1, more=int(hx))
-        leaf_best = jnp.full((L, 11), NEG_INF, jnp.float32).at[0].set(
+        leaf_best = jnp.full((L, rw), NEG_INF, jnp.float32).at[0].set(
             find_best_batch(hist0[None], root_sums[None])[0])
         leaf_depth = jnp.zeros(L, jnp.int32)
         leaf_parent = jnp.full(L, -1, jnp.int32)
@@ -524,6 +538,8 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             leaf_count=jnp.zeros(L, jnp.float32).at[0].set(cnt),
             leaf_depth=jnp.zeros(L, jnp.int32),
             num_leaves=jnp.int32(1),
+            default_left=(None if missing_bins is None
+                          else jnp.zeros(L - 1, bool)),
         )
 
     def round_body(st):
@@ -544,10 +560,11 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             m = jnp.sum(do.astype(jnp.int32))
 
             pl_ = order                                          # parent leaf/slot
-            rec = leaf_best[pl_]                                 # [L, 11]
+            rec = leaf_best[pl_]                                 # [L, rw]
             feat = rec[:, 1].astype(jnp.int32)
             thr = rec[:, 2].astype(jnp.int32)
             catf = is_cat[feat]
+            dlf = None if missing_bins is None else rec[:, 11] > 0.5
             new_leaf = n_leaves + prefix                         # [L]
             node = (n_leaves - 1) + prefix                       # [L]
             l_sums = rec[:, 3:6]
@@ -567,7 +584,7 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
             # bundled columns partition without ever materializing original
             # bins
             colv, Tv, lov, hi1v, dlv = bundle_predicate_params(
-                ftbl, feat, thr, catf)
+                ftbl, feat, thr, catf, missing_bins, dlf)
             tbl_idx = jnp.where(do, pl_, L)                      # drop-slot L
             zeros = jnp.zeros(L + 1, jnp.float32)
 
@@ -620,6 +637,9 @@ def build_tree_rounds(bins, grad, hess, row_mask, num_bins, is_cat, fmask,
                                                             mode="drop"),
                 num_leaves=n_leaves + m,
             )
+            if dlf is not None:
+                arrs2 = arrs2._replace(default_left=arrs.default_left.at[
+                    nodei].set(dlf, mode="drop"))
             leaf_depth2 = leaf_depth.at[lvali].set(
                 child_depth, mode="drop").at[nvali].set(child_depth, mode="drop")
             leaf_parent2 = leaf_parent.at[lvali].set(
@@ -836,6 +856,7 @@ class RoundsTreeLearner:
         input_dtype = getattr(config, "histogram_dtype", "float32")
         nbv = dataset.split_num_bins.astype(np.int32)  # ORIGINAL [F]
         icv = np.asarray(dataset.is_categorical)     # ORIGINAL [F]
+        mbv = dataset.missing_bins                   # ORIGINAL [F] or None
         plan = dataset.bundle_plan
         # nonzero-iterating sparse path (docs/Sparse.md): single-process
         # only for now — per-host stream assembly is the multi-host
@@ -945,6 +966,8 @@ class RoundsTreeLearner:
             nbv = np.pad(nbv, (0, fp), constant_values=1)
             icv = np.pad(icv, (0, fp))
             self._base_fmask = np.pad(np.ones(self.F, bool), (0, fp))
+            if mbv is not None:
+                mbv = np.pad(mbv, (0, fp), constant_values=-1)
             ftbl = None
             unb = None
         else:
@@ -997,7 +1020,8 @@ class RoundsTreeLearner:
                   num_feature_shards=self.df,
                   ftbl=ftbl, unb=unb, sparse=self.sparse,
                   input_dtype=input_dtype,
-                  real_columns=0 if self.sparse else self.Cstore)
+                  real_columns=0 if self.sparse else self.Cstore,
+                  missing_bins=mbv)
         self._fold_stats = self._search_counters(
             unb is not None and not isinstance(unb, StoreCells))
         if mesh is None:
@@ -1023,8 +1047,7 @@ class RoundsTreeLearner:
             bins_spec = ((P(da), P(da), P(), P(da), P(da), P(da), P(da))
                          if self.sparse else P(None, da))
             in_specs = (bins_spec, P(da), P(da), P(da), P(), P(), P())
-            out_specs = (jax.tree_util.tree_map(lambda _: P(), TreeArrays(
-                *[0] * len(TreeArrays._fields))), P(da), P())
+            out_specs = (tree_arrays_specs(P(), mbv is not None), P(da), P())
             self._build = _jit_build(jax.shard_map(
                 fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
                 check_vma=False))

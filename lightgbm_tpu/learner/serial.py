@@ -48,7 +48,8 @@ def _next_pow2(n: int) -> int:
 @functools.partial(jax.jit, static_argnames=("cap", "num_bins_padded",
                                              "backend", "split_kw"))
 def _root_step(bins_t, grad_pad, hess_pad, idx, num_bins, is_cat, fmask,
-               unb, *, cap, num_bins_padded, backend, split_kw):
+               unb, missing_bins, *, cap, num_bins_padded, backend,
+               split_kw):
     hist = histogram_from_indices(bins_t, grad_pad, hess_pad, idx,
                                   num_bins_padded=num_bins_padded,
                                   backend=backend)
@@ -58,18 +59,20 @@ def _root_step(bins_t, grad_pad, hess_pad, idx, num_bins, is_cat, fmask,
     sums = jnp.stack([sum_g, sum_h, cnt])
     h = maybe_unbundle(hist, unb, sums)
     rec = best_split(h, num_bins, is_cat, fmask, sum_g, sum_h, cnt,
-                     **dict(split_kw))
+                     missing_bins, **dict(split_kw))
     return hist, rec.packed(), sums
 
 
 def _store_partition(bins, leaf_id, parent_leaf, new_leaf, feat, thr,
-                     is_cat_split, ftbl):
+                     is_cat_split, ftbl, missing_bins, default_left):
     """Move the parent's right-going rows to new_leaf, evaluating the
     ORIGINAL-space split (feat, thr) on the bundled store via the
-    store-space predicate (ops/split.bundle_predicate_params)."""
+    store-space predicate (ops/split.bundle_predicate_params); a NaN
+    bin goes left where `default_left`."""
     N = leaf_id.shape[0]
-    col, T, lo, hi1, dl = bundle_predicate_params(ftbl, feat, thr,
-                                                  is_cat_split)
+    col, T, lo, hi1, dl = bundle_predicate_params(
+        ftbl, feat, thr, is_cat_split, missing_bins,
+        None if missing_bins is None else default_left)
     featrow = jax.lax.dynamic_index_in_dim(bins, col, axis=0,
                                            keepdims=False)[:N]
     featrow = featrow.astype(jnp.int32)
@@ -84,6 +87,7 @@ def _store_partition(bins, leaf_id, parent_leaf, new_leaf, feat, thr,
 def _split_step(bins, bins_t, grad_pad, hess_pad, leaf_id, parent_leaf,
                 new_leaf, feat, thr, is_cat_split, smaller_leaf, parent_hist,
                 num_bins, is_cat, fmask, small_sums, large_sums, ftbl, unb,
+                missing_bins, default_left,
                 *, cap, num_bins_padded, backend, split_kw, with_subtract):
     """Partition parent rows, histogram the smaller child (gathered, cap
     static), obtain the larger by subtraction, best-split both.  The
@@ -91,7 +95,8 @@ def _split_step(bins, bins_t, grad_pad, hess_pad, leaf_id, parent_leaf,
     the unbundled per-feature view."""
     N = leaf_id.shape[0]
     leaf_id = _store_partition(bins, leaf_id, parent_leaf, new_leaf, feat,
-                               thr, is_cat_split, ftbl)
+                               thr, is_cat_split, ftbl, missing_bins,
+                               default_left)
 
     small_mask = leaf_id == smaller_leaf
     idx = jnp.nonzero(small_mask, size=cap, fill_value=N)[0].astype(jnp.int32)
@@ -105,19 +110,22 @@ def _split_step(bins, bins_t, grad_pad, hess_pad, leaf_id, parent_leaf,
     kw = dict(split_kw)
     rec_small = best_split(maybe_unbundle(hist_small, unb, small_sums),
                            num_bins, is_cat, fmask,
-                           small_sums[0], small_sums[1], small_sums[2], **kw)
+                           small_sums[0], small_sums[1], small_sums[2],
+                           missing_bins, **kw)
     rec_large = best_split(maybe_unbundle(hist_large, unb, large_sums),
                            num_bins, is_cat, fmask,
-                           large_sums[0], large_sums[1], large_sums[2], **kw)
+                           large_sums[0], large_sums[1], large_sums[2],
+                           missing_bins, **kw)
     return (leaf_id, hist_small, hist_large,
             jnp.stack([rec_small.packed(), rec_large.packed()]))
 
 
 @jax.jit
 def _partition_only(bins, leaf_id, parent_leaf, new_leaf, feat, thr,
-                    is_cat_split, ftbl):
+                    is_cat_split, ftbl, missing_bins, default_left):
     return _store_partition(bins, leaf_id, parent_leaf, new_leaf, feat,
-                            thr, is_cat_split, ftbl)
+                            thr, is_cat_split, ftbl, missing_bins,
+                            default_left)
 
 
 class _LeafInfo:
@@ -150,6 +158,8 @@ class SerialTreeLearner:
         ft = dataset.bundle_feat_table()
         self.ftbl = (identity_feat_table(dataset.num_bins) if ft is None
                      else jnp.asarray(ft))
+        mb = dataset.missing_bins
+        self.missing_bins_dev = None if mb is None else jnp.asarray(mb)
         unb = dataset.unbundle_tables(self.B)
         self.unb = (None if unb is None
                     else (jnp.asarray(unb[0]), jnp.asarray(unb[1])))
@@ -200,7 +210,8 @@ class SerialTreeLearner:
         hist, packed, sums = _root_step(
             self.bins_t, self._grad_pad, self._hess_pad, idx,
             self.num_bins_dev, self.is_cat_dev, self._fmask, self.unb,
-            cap=cap, num_bins_padded=self.B, backend=self.backend,
+            self.missing_bins_dev, cap=cap, num_bins_padded=self.B,
+            backend=self.backend,
             split_kw=self.split_kw)
         return hist, np.asarray(packed)
 
@@ -236,7 +247,8 @@ class SerialTreeLearner:
         hist, packed, sums = _root_step(
             self.bins_t, self._grad_pad, self._hess_pad, idx,
             self.num_bins_dev, self.is_cat_dev, self._fmask, self.unb,
-            cap=int(idx.shape[0]), num_bins_padded=self.B,
+            self.missing_bins_dev, cap=int(idx.shape[0]),
+            num_bins_padded=self.B,
             backend=self.backend, split_kw=self.split_kw)
         sums = np.asarray(sums, dtype=np.float64)
 
@@ -262,6 +274,7 @@ class SerialTreeLearner:
             l_sum = (float(rec[3]), float(rec[4]), int(round(float(rec[5]))))
             r_sum = (float(rec[6]), float(rec[7]), int(round(float(rec[8]))))
             l_out, r_out = float(rec[9]), float(rec[10])
+            dleft = rec.size > 11 and float(rec[11]) > 0.5
             real_feat = self.dataset.inner_to_real(feat)
             mapper = self.dataset.mappers[real_feat]
             bin_type = (CATEGORICAL_DECISION
@@ -269,7 +282,8 @@ class SerialTreeLearner:
             new_leaf = tree.split(
                 best_leaf, feat, bin_type, thr, real_feat,
                 mapper.bin_to_value(thr), l_out, r_out, l_sum[2], r_sum[2],
-                best_gain)
+                best_gain, default_left=dleft, missing_bin=mapper.nan_bin)
+            dl_dev = jnp.asarray(dleft)
 
             child_depth = info.depth + 1
             left = _LeafInfo(l_sum[0], l_sum[1], l_sum[2], child_depth,
@@ -302,7 +316,7 @@ class SerialTreeLearner:
                                  float(small.count)], jnp.float32),
                     jnp.asarray([large.sum_grad, large.sum_hess,
                                  float(large.count)], jnp.float32),
-                    self.ftbl, self.unb,
+                    self.ftbl, self.unb, self.missing_bins_dev, dl_dev,
                     cap=cap, num_bins_padded=self.B, backend=self.backend,
                     split_kw=self.split_kw, with_subtract=with_subtract)
                 recs = np.asarray(recs)
@@ -323,7 +337,7 @@ class SerialTreeLearner:
             else:
                 self.leaf_id = _partition_only(
                     self.bins, self.leaf_id, best_leaf, new_leaf, feat, thr,
-                    is_cat_split, self.ftbl)
+                    is_cat_split, self.ftbl, self.missing_bins_dev, dl_dev)
 
             leaves[best_leaf] = left
             leaves[new_leaf] = right

@@ -40,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config import COSTACK_KERNELS, PREDICT_KERNELS
+from ..tree import (binned_lanes, is_missing_nan, nan_goes_left,
+                    raw_lanes)
 
 
 def resolve_predict_kernel(kernel: str = "auto") -> str:
@@ -112,7 +114,8 @@ class TreeStack(NamedTuple):
     split_feature: jax.Array   # [T, M-1] int32 (inner feature index)
     threshold: jax.Array       # [T, M-1] f32 — bin id for binned input,
                                #               raw value for raw input
-    decision_type: jax.Array   # [T, M-1] int32 (0 numerical, 1 categorical)
+    decision_type: jax.Array   # [T, M-1] int32 lanes (tree.raw_lanes /
+                               #   tree.binned_lanes)
     left_child: jax.Array      # [T, M-1] int32
     right_child: jax.Array     # [T, M-1] int32
     leaf_value: jax.Array      # [T, M] f32
@@ -130,6 +133,16 @@ def threshold_f32(th) -> np.ndarray:
     t32 = th.astype(np.float32)
     above = t32.astype(np.float64) > th
     return np.where(above, np.nextafter(t32, np.float32(-np.inf)), t32)
+
+
+def _lanes(t, k: int, binned: bool) -> np.ndarray:
+    """The decision lane of a tree's first k splits (tree.raw_lanes for
+    raw stacks, tree.binned_lanes for binned ones)."""
+    if binned:
+        return binned_lanes(
+            getattr(t, "binned_decision_type", t.decision_type)[:k],
+            t.threshold[:k], t.missing_bin[:k])
+    return raw_lanes(t.decision_type[:k], t.threshold[:k])
 
 
 def stack_trees(trees, binned: bool) -> TreeStack:
@@ -154,7 +167,7 @@ def stack_trees(trees, binned: bool) -> TreeStack:
                      else t.split_feature[:k])
         th[i, :k] = (t.threshold_in_bin[:k].astype(np.float32) if binned
                      else threshold_f32(t.threshold[:k]))
-        dc[i, :k] = t.decision_type[:k]
+        dc[i, :k] = _lanes(t, k, binned)
         lc[i, :k] = t.left_child[:k]
         rc[i, :k] = t.right_child[:k]
     return TreeStack(*map(jnp.asarray, (sf, th, dc, lc, rc, lv, nl)))
@@ -171,13 +184,17 @@ def _walk_one_tree(sf, th, dc, lc, rc, lv, nl, Xf, depth: int) -> jax.Array:
         f = sf[safe]
         v = jnp.take_along_axis(Xf, f[:, None], axis=1)[:, 0]
         t = th[safe]
-        cat = dc[safe] == 1
+        d = dc[safe]
         # categorical: int truncation compare with the host walk's
         # explicit finite mask (tree.py predict_leaf_index) — a bare
-        # int cast of NaN is backend-defined and could match category 0
+        # int cast of NaN is backend-defined and could match category 0.
+        # Numerical: NaN to the side its lane names (raw stacks), a
+        # NaN bin sent left by its lane (binned stacks)
         finite = jnp.isfinite(v)
         vi = jnp.where(finite, v, -1.0).astype(jnp.int32)
-        gl = jnp.where(cat, finite & (vi == t.astype(jnp.int32)), v <= t)
+        num = jnp.where(jnp.isnan(v), d == 2,
+                        (v <= t) | ((d < 0) & (v == (-1 - d))))
+        gl = jnp.where(d == 1, finite & (vi == t.astype(jnp.int32)), num)
         nxt = jnp.where(gl, lc[safe], rc[safe])
         return jnp.where(node >= 0, nxt, node)
 
@@ -275,12 +292,13 @@ def _class_sums(vals: jax.Array, class_id: jax.Array,
 # child ids / feature ids are exact in f32 (|v| < 2^24, num_leaves caps
 # far below that), so the raw record can stay one dtype.  Binned stacks
 # narrow the whole record to int16 whenever every lane fits — half the
-# per-level record-gather bytes on the serving request path.  NaN/missing
-# routing needs no lane: raw kernels send NaN right (v <= t is False,
-# categorical finite mask matches nothing) and the binned request path
-# encodes missing as the quantizer's sentinel bin, which routes right
-# through the same integer compares (lightgbm_tpu/quantize.py) — the
-# never-populated default_left lane PR 7 reserved is gone.
+# per-level record-gather bytes on the serving request path.  NaN routing
+# rides in the decision lane, no lane of its own: a raw lane of 2 sends
+# NaN left (tree.raw_lanes: a split of missing type NaN with its default
+# left, or of type None whose threshold is at or above 0.0), any other
+# right; a binned lane of -1 - b sends the NaN bin b left
+# (tree.binned_lanes), any other compares it as a bin, above every
+# threshold.
 _LANES = 5
 
 
@@ -318,6 +336,11 @@ class PerfectEnsemble(NamedTuple):
     inner: jax.Array       # [T, 2^(D-1)-1, 2] f32: (feature, threshold)
     last: jax.Array        # [T, 2^(D-1), 4] f32: (feat, thr, lval, rval)
     class_id: jax.Array    # [T] int32, sorted ascending
+    # Raw stacks of a model with splits of missing type NaN
+    # (EnsembleMeta.missing) carry where each split sends a raw NaN in
+    # the feature lane's sign: f for NaN right, -1 - f for NaN left
+    # (tree.nan_goes_left), so the walk reads no lane more.  Any other
+    # stack holds f alone and compares NaN as 0.0.
 
 
 class EnsembleMeta(NamedTuple):
@@ -325,6 +348,8 @@ class EnsembleMeta(NamedTuple):
     depth: int             # levels to walk (max grown depth, >= 1)
     num_class: int         # K — rows of the [K, N] output
     any_cat: bool          # ensemble has categorical splits
+    missing: bool = False  # a split of missing type NaN (raw), a NaN bin
+    #                        sent left (binned)
 
 
 class GroupMeta(NamedTuple):
@@ -340,6 +365,7 @@ class GroupMeta(NamedTuple):
     num_class: int         # K — shared by every tenant in the group
     any_cat: bool          # any tenant has categorical splits
     segments: tuple        # ((start, stop), ...) tree bounds per tenant
+    missing: bool = False  # as EnsembleMeta.missing, over every tenant
 
 
 # perfect relayout budget: total value-slab slots (T * 2^depth) above
@@ -351,24 +377,26 @@ PERFECT_SLOT_BUDGET = 1 << 22
 
 
 def _ensemble_shape(flat, binned: bool):
-    """(max-capacity leaves, walk depth, any_cat) over a class-major
-    [(class, tree)] flatten — the ONE scan shared by `build_ensemble`'s
-    layout choice and `stack_ensemble`'s meta, so the two can't
-    desynchronize.  Binned stacks compare on `binned_decision_type`
-    (trivial-feature categorical splits rebin to numerical
-    sentinels)."""
+    """(max-capacity leaves, walk depth, any_cat, missing) over a
+    class-major [(class, tree)] flatten — the ONE scan shared by
+    `build_ensemble`'s layout choice and `stack_ensemble`'s meta, so the
+    two can't desynchronize.  Binned stacks compare on
+    `binned_decision_type` (trivial-feature categorical splits rebin to
+    numerical sentinels)."""
     m = max(max(t.max_leaves for _, t in flat), 2)
     depth = 1
-    any_cat = False
+    any_cat = missing = False
     for _, t in flat:
         if t.num_leaves < 2:
             continue
         depth = max(depth, t.max_depth_grown)
         k = t.num_leaves - 1
-        dec = (getattr(t, "binned_decision_type", t.decision_type)
-               if binned else t.decision_type)
-        any_cat = any_cat or bool(np.any(dec[:k] == 1))
-    return m, max(int(depth), 1), any_cat
+        lanes = _lanes(t, k, binned)
+        any_cat = any_cat or bool(np.any(lanes == 1))
+        missing = missing or bool(
+            np.any(lanes < 0) if binned
+            else np.any(is_missing_nan(t.decision_type[:k])))
+    return m, max(int(depth), 1), any_cat, missing
 
 
 def build_ensemble(trees_by_class, *, binned: bool = False,
@@ -386,17 +414,23 @@ def build_ensemble(trees_by_class, *, binned: bool = False,
     if not flat:
         raise ValueError("build_ensemble needs at least one tree")
     shape = _ensemble_shape(flat, binned)
-    m, depth, any_cat = shape
-    meta = EnsembleMeta(depth=depth, num_class=num_class, any_cat=any_cat)
+    m, depth, any_cat, missing = shape
+    meta = EnsembleMeta(depth=depth, num_class=num_class, any_cat=any_cat,
+                        missing=missing)
     if layout not in ("auto", "perfect", "soa"):
         raise ValueError(f"unknown ensemble layout: {layout!r}")
+    # its records hold no decision lane: a raw stack carries the NaN
+    # side in the feature lane's sign, a binned one has no room for a
+    # split's NaN bin
+    perfect_ok = not (any_cat or (missing and binned))
     if layout == "auto":
         fits = len(flat) << depth <= PERFECT_SLOT_BUDGET
-        layout = "perfect" if fits and not any_cat else "soa"
+        layout = "perfect" if fits and perfect_ok else "soa"
     if layout == "perfect":
-        if any_cat:
+        if not perfect_ok:
             raise ValueError("perfect layout supports numerical "
-                             "ensembles only")
+                             "ensembles, binned ones of missing type "
+                             "None only")
         return _build_perfect(flat, meta, binned=binned)
     return stack_ensemble(trees_by_class, binned=binned, _shape=shape)
 
@@ -415,6 +449,11 @@ def _build_perfect(flat, meta: EnsembleMeta, binned: bool = False
         # < 2^24, exact in the f32 lanes
         sf = t.split_feature_inner if binned else t.split_feature
         th = t.threshold_in_bin if binned else threshold_f32(t.threshold)
+        if meta.missing and not binned and t.num_leaves >= 2:
+            k = t.num_leaves - 1
+            sf = np.asarray(sf[:k], np.int64)
+            sf = np.where(nan_goes_left(t.decision_type[:k],
+                                        t.threshold[:k]), -1 - sf, sf)
         if t.num_leaves < 2:                 # stump: one giant filler
             last[i, :, 2] = last[i, :, 3] = np.float32(t.leaf_value[0])
             continue
@@ -463,8 +502,9 @@ def stack_ensemble(trees_by_class, *, binned: bool, _shape=None
     flat = [(k, t) for k, trees in enumerate(trees_by_class) for t in trees]
     if not flat:
         raise ValueError("stack_ensemble needs at least one tree")
-    m, depth, any_cat = _shape or _ensemble_shape(flat, binned)
-    meta = EnsembleMeta(depth=depth, num_class=num_class, any_cat=any_cat)
+    m, depth, any_cat, missing = _shape or _ensemble_shape(flat, binned)
+    meta = EnsembleMeta(depth=depth, num_class=num_class, any_cat=any_cat,
+                        missing=missing)
     nodes, lv, root, cls = _fill_stack(flat, m, binned)
     stack = EnsembleStack(nodes=_maybe_narrow(nodes, binned),
                           leaf_value=lv, root=root, class_id=cls)
@@ -491,14 +531,12 @@ def _fill_stack(flat, m: int, binned: bool):
             continue
         knodes = n - 1
         if binned:
-            dec = getattr(t, "binned_decision_type", t.decision_type)
             nodes[i, :knodes, 0] = t.split_feature_inner[:knodes]
             nodes[i, :knodes, 1] = t.threshold_in_bin[:knodes]
-            nodes[i, :knodes, 2] = dec[:knodes]
         else:
             nodes[i, :knodes, 0] = t.split_feature[:knodes]
             nodes[i, :knodes, 1] = threshold_f32(t.threshold[:knodes])
-            nodes[i, :knodes, 2] = t.decision_type[:knodes]
+        nodes[i, :knodes, 2] = _lanes(t, knodes, binned)
         nodes[i, :knodes, 3] = t.left_child[:knodes]
         nodes[i, :knodes, 4] = t.right_child[:knodes]
     return nodes, lv, root, cls
@@ -549,9 +587,9 @@ def stack_ensemble_group(members, *, binned: bool = False
             raise ValueError("every co-stacked member needs at least "
                              "one tree")
         segments.append((start, len(flat)))
-    m, depth, any_cat = _ensemble_shape(flat, binned)
+    m, depth, any_cat, missing = _ensemble_shape(flat, binned)
     meta = GroupMeta(depth=depth, num_class=num_class, any_cat=any_cat,
-                     segments=tuple(segments))
+                     segments=tuple(segments), missing=missing)
     nodes, lv, root, cls = _fill_stack(flat, m, binned)
     stack = EnsembleStack(nodes=_maybe_narrow(nodes, binned),
                           leaf_value=lv, root=root, class_id=cls)
@@ -573,27 +611,32 @@ def _raw_decide(rec: jax.Array, v: jax.Array, any_cat: bool) -> jax.Array:
     values — THE numerical/categorical routing decision, shared by the
     full-stack walk (`_walk_raw_nodes`) and the segment-gathered walk
     (`_walk_raw_segment`) so the two can never disagree: numerical
-    ``v <= t`` (NaN falls right), categorical int-truncation compare
-    behind a finite mask."""
+    ``v <= t``, NaN to the side its lane names (tree.raw_lanes),
+    categorical int-truncation compare behind a finite mask."""
     t = rec[..., 1]
-    gl = v <= t
+    gl = jnp.where(jnp.isnan(v), rec[..., 2] == 2, v <= t)
     if any_cat:
         finite = jnp.isfinite(v)
         vi = jnp.where(finite, v, -1.0).astype(jnp.int32)
-        gl = jnp.where(rec[..., 2] > 0,
+        gl = jnp.where(rec[..., 2] == 1,
                        finite & (vi == t.astype(jnp.int32)), gl)
     return gl
 
 
 def _binned_decide(rec: jax.Array, bv: jax.Array,
-                   any_cat: bool) -> jax.Array:
+                   any_cat: bool, missing: bool = False) -> jax.Array:
     """Go-left mask from packed BINNED node records and gathered bin
     ids — integer compares end to end, shared by `_walk_binned_nodes`
-    and `_walk_binned_segment` (same contract as `_raw_decide`)."""
+    and `_walk_binned_segment` (same contract as `_raw_decide`; with
+    `missing`, a lane of -1 - b sends bin b left, tree.binned_lanes)."""
     t = rec[..., 1].astype(jnp.int32)
+    gl = bv <= t
     if any_cat:
-        return jnp.where(rec[..., 2] == 1, bv == t, bv <= t)
-    return bv <= t
+        gl = jnp.where(rec[..., 2] == 1, bv == t, gl)
+    if missing:
+        d = rec[..., 2].astype(jnp.int32)
+        gl = gl | ((d < 0) & (bv == -1 - d))
+    return gl
 
 
 def _walk_raw_nodes(stack: EnsembleStack, Xf: jax.Array, meta
@@ -650,26 +693,45 @@ def predict_ensemble_perfect(stack: PerfectEnsemble, X: jax.Array, *,
     (every row reads record 0), and the last level's records carry both
     child leaf values, so the separate leaf-value gather disappears.
     Bitwise-identical routing to `_walk_one_tree` (same ``v <= t`` f32
-    compare on the same thresholds).
+    compare on the same thresholds).  A raw NaN compares as 0.0, or,
+    in a stack of a model with splits of missing type NaN
+    (`meta.missing`), goes to the side the feature lane's sign names.
     """
     Xf = X.astype(jnp.float32)
+    if not meta.missing:
+        Xf = jnp.where(jnp.isnan(Xf), jnp.float32(0.0), Xf)
     T = stack.last.shape[0]
     N = Xf.shape[0]
     rows = jnp.arange(N)[None, :]
     depth = meta.depth
 
+    def feature(lane):
+        """(feature ids, NaN-left mask or None) of a feature lane."""
+        if not meta.missing:
+            return lane.astype(jnp.int32), None
+        nan_left = lane < 0
+        return (jnp.where(nan_left, -1.0 - lane, lane).astype(jnp.int32),
+                nan_left)
+
+    def decide(v, thr, nan_left):
+        if nan_left is None:
+            return v <= thr
+        return jnp.where(jnp.isnan(v), nan_left, v <= thr)
+
     def level(rec_slab, node):
         r = jnp.take_along_axis(rec_slab, node[:, :, None], axis=1)
-        f = r[..., 0].astype(jnp.int32)
-        gl = Xf[rows, f] <= r[..., 1]
+        f, nan_left = feature(r[..., 0])
+        gl = decide(Xf[rows, f], r[..., 1], nan_left)
         return r, gl
 
     if depth == 1:
         local = jnp.zeros((T, N), jnp.int32)
     else:
         # level 0: every row is at the root — broadcast, no gather
-        f0 = stack.inner[:, 0, 0].astype(jnp.int32)
-        gl0 = jnp.take(Xf, f0, axis=1).T <= stack.inner[:, 0, 1][:, None]
+        f0, nl0 = feature(stack.inner[:, 0, 0])
+        gl0 = decide(jnp.take(Xf, f0, axis=1).T,
+                     stack.inner[:, 0, 1][:, None],
+                     None if nl0 is None else nl0[:, None])
         node = 2 - gl0.astype(jnp.int32)
 
         def step(_, node):
@@ -765,7 +827,7 @@ def _walk_binned_nodes(stack: EnsembleStack, bins_nt,
             in_r = (s >= 0) & (s < ns)
             orig = jnp.where(in_r, s + (s >= dflt).astype(jnp.int32), dflt)
             bv = jnp.where(pk, orig, bv_store)
-        gl = _binned_decide(rec, bv, meta.any_cat)
+        gl = _binned_decide(rec, bv, meta.any_cat, meta.missing)
         nxt = jnp.where(gl, rec[..., 3], rec[..., 4]).astype(jnp.int32)
         return jnp.where(node >= 0, nxt, node)
 
@@ -1017,7 +1079,7 @@ def _walk_binned_segment(stack: EnsembleStack, bins_nt: jax.Array,
         rec = stack.nodes[tree, safe]                      # [L, N, lanes]
         f = rec[..., 0].astype(jnp.int32)
         bv = bins_nt[rows, f]                              # [L, N]
-        gl = _binned_decide(rec, bv, meta.any_cat)
+        gl = _binned_decide(rec, bv, meta.any_cat, meta.missing)
         nxt = jnp.where(gl, rec[..., 3], rec[..., 4]).astype(jnp.int32)
         return jnp.where(node >= 0, nxt, node)
 

@@ -21,6 +21,15 @@ smallest threshold — matching the reference's deterministic tie-break
 (split_info.hpp:100-105; its right-to-left scan with strict `>` also keeps
 the smallest threshold).
 
+Missing values (`missing_bins`, [F] int32: a feature's NaN bin, its last,
+or -1): a numerical feature with a NaN bin is scanned twice over its value
+bins, once with the NaN bin's sums on the right (thresholds 0 .. nb - 2,
+the last of them "every value left, NaN right") and once with them on the
+left (thresholds 0 .. nb - 3), as the reference's two scans for missing
+type NaN.  A threshold keeps the better of its two candidates, NaN right
+on a tie, and the record carries `default_left`.  Without `missing_bins`
+nothing of this is traced: the record keeps its 11 fields.
+
 A store that Exclusive Feature Bundling packed (binning.BundlePlan) is
 searched in its own cells, `best_split_in_store`: a bundle's slots are its
 members' bins, so the same candidates are scored with the same formulas
@@ -31,7 +40,7 @@ stays for learner/serial.py and for a plan that packs a categorical feature.
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -56,14 +65,26 @@ class SplitResult(NamedTuple):
     right_count: jax.Array
     left_output: jax.Array
     right_output: jax.Array
+    # the NaN bin's side, bool (a search given missing_bins), else None
+    default_left: Optional[jax.Array] = None
 
     def packed(self) -> jax.Array:
-        return jnp.stack([self.gain, self.feature.astype(jnp.float32),
-                          self.threshold_bin.astype(jnp.float32),
-                          self.left_sum_grad, self.left_sum_hess,
-                          self.left_count, self.right_sum_grad,
-                          self.right_sum_hess, self.right_count,
-                          self.left_output, self.right_output])
+        """[11], or [12] with `default_left` (1.0 left) last."""
+        v = [self.gain, self.feature.astype(jnp.float32),
+             self.threshold_bin.astype(jnp.float32),
+             self.left_sum_grad, self.left_sum_hess,
+             self.left_count, self.right_sum_grad,
+             self.right_sum_hess, self.right_count,
+             self.left_output, self.right_output]
+        if self.default_left is not None:
+            v.append(self.default_left.astype(jnp.float32))
+        return jnp.stack(v)
+
+
+def record_width(missing_bins) -> int:
+    """Fields of a packed record: 12 where the search is given
+    missing_bins (SplitResult.default_left), else 11."""
+    return 11 if missing_bins is None else 12
 
 
 # ----------------------------------------------------------------------------
@@ -80,7 +101,8 @@ def identity_feat_table(num_bins) -> "jnp.ndarray":
                       jnp.asarray(num_bins).astype(jnp.float32), z])
 
 
-def bundle_predicate_params(feat_tbl, feat, thr, is_cat):
+def bundle_predicate_params(feat_tbl, feat, thr, is_cat, missing_bins=None,
+                            default_left=None):
     """Translate an ORIGINAL-space split (feature, threshold bin, is-cat)
     into STORE-space go-left parameters (col, T, lo, hi1, dl):
 
@@ -98,7 +120,11 @@ def bundle_predicate_params(feat_tbl, feat, thr, is_cat):
     slot range sit at the default bin, which goes left iff default <= thr
     (numerical) / default == thr (categorical).  For a categorical split
     ON the default bin, T = offset - 1 matches no in-range slot (offsets
-    start at 1) and dl sends the default rows left."""
+    start at 1) and dl sends the default rows left.
+
+    missing_bins ([F], as best_split's) with the split's `default_left`:
+    a feature with a NaN bin is never packed, and its window ends below
+    that bin, so its NaN rows go by dl = default_left."""
     feat = jnp.asarray(feat, jnp.int32)
     thr = jnp.asarray(thr, jnp.int32)
     feat_tbl = jnp.asarray(feat_tbl)   # may arrive as a host constant
@@ -114,6 +140,11 @@ def bundle_predicate_params(feat_tbl, feat, thr, is_cat):
     lo = jnp.where(pk, off, 0)
     hi1 = jnp.where(pk, off + ns - 1, jnp.int32(1 << 30))
     dl = pk & jnp.where(is_cat, thr == d, d <= thr)
+    if missing_bins is not None:
+        mb = jnp.asarray(missing_bins)[feat]
+        nan = mb >= 0
+        hi1 = jnp.where(nan, mb - 1, hi1)
+        dl = jnp.where(nan, default_left, dl)
     return col, T, lo, hi1, dl
 
 
@@ -188,7 +219,8 @@ def unbundle_hist_local(hist: jax.Array, src: jax.Array, dmask: jax.Array,
 
 
 def sharded_slice_search(h, sums, *, off, nb_s, ic_s, fm_s,
-                         num_bins, is_cat, fmask, unb, skw) -> jax.Array:
+                         num_bins, is_cat, fmask, unb, skw,
+                         mb_s=None, missing_bins=None) -> jax.Array:
     """Per-shard best split of ONE leaf from its reduce-scattered
     store-column slice (the psum_scatter exchange of learner/rounds.py
     and learner/fused.py — shared so the two learners cannot diverge).
@@ -203,10 +235,11 @@ def sharded_slice_search(h, sums, *, off, nb_s, ic_s, fm_s,
     slice is unbundled to the full original-feature layout with
     non-owned features masked out of the search.  Returns the packed
     [11] record in ORIGINAL feature space; combine across shards with
-    `combine_sharded_records`."""
+    `combine_sharded_records`.  mb_s / missing_bins: the slice's and
+    the whole store's NaN bins (best_split), or None."""
     if unb is None:
         rec = best_split(h, nb_s, ic_s, fm_s,
-                         sums[0], sums[1], sums[2], **skw)
+                         sums[0], sums[1], sums[2], mb_s, **skw)
         p = rec.packed()
         return p.at[1].add(jnp.asarray(off).astype(jnp.float32))
     if isinstance(unb, StoreSearch):
@@ -215,7 +248,7 @@ def sharded_slice_search(h, sums, *, off, nb_s, ic_s, fm_s,
     with jax.named_scope("lgbt.unbundle"):
         hF, owned = unbundle_hist_local(h, unb[0], unb[1], sums, off)
     rec = best_split(hF, num_bins, is_cat, fmask & owned,
-                     sums[0], sums[1], sums[2], **skw)
+                     sums[0], sums[1], sums[2], missing_bins, **skw)
     return rec.packed()
 
 
@@ -258,15 +291,18 @@ def leaf_output(G, H, l1, l2):
 
 def split_gain_matrix(hist: jax.Array, num_bins: jax.Array, is_cat: jax.Array,
                       feature_mask: jax.Array, sum_grad: jax.Array,
-                      sum_hess: jax.Array, num_data: jax.Array, *,
+                      sum_hess: jax.Array, num_data: jax.Array,
+                      missing_bins=None, *,
                       lambda_l1: float = 0.0, lambda_l2: float = 0.0,
                       min_data_in_leaf: int = 20,
                       min_sum_hessian_in_leaf: float = 1e-3,
                       min_gain_to_split: float = 0.0):
     """[F, B] total gain per candidate threshold (K_MIN_SCORE where
-    invalid), plus (GL, HL, CL) cumulatives for record assembly.  Exposed
-    separately from `best_split` so the voting-parallel learner can rank
-    features locally (voting_parallel_tree_learner.cpp local top-k)."""
+    invalid), plus (GL, HL, CL) cumulatives for record assembly and the
+    [F, B] bool NaN side of each candidate (None without
+    missing_bins).  Exposed separately from `best_split` so the
+    voting-parallel learner can rank features locally
+    (voting_parallel_tree_learner.cpp local top-k)."""
     F, _, B = hist.shape
     l1, l2 = lambda_l1, lambda_l2
     g, h, c = hist[:, 0, :], hist[:, 1, :], hist[:, 2, :]
@@ -292,7 +328,23 @@ def split_gain_matrix(hist: jax.Array, num_bins: jax.Array, is_cat: jax.Array,
         t_valid & feature_mask[:, None], GL, HL, CL, GR, HR, CR,
         sum_grad, sum_hess, l1, l2, min_data_in_leaf,
         min_sum_hessian_in_leaf, min_gain_to_split)
-    return total_gain, GL, HL, CL
+    if missing_bins is None:
+        return total_gain, GL, HL, CL, None
+
+    # ---- the NaN bin on the left: value thresholds t in [0, nb-3] -------
+    mb = jnp.asarray(missing_bins)
+    nan_at = jnp.maximum(mb, 0)[:, None]
+    GLn = GL + jnp.take_along_axis(g, nan_at, axis=1)
+    HLn = HL + jnp.take_along_axis(h, nan_at, axis=1)
+    CLn = CL + jnp.take_along_axis(c, nan_at, axis=1)
+    left_valid = ((mb >= 0) & ~is_cat)[:, None] & (bin_idx < nb - 2)
+    gain_n = _candidate_gains(
+        left_valid & feature_mask[:, None], GLn, HLn, CLn, sum_grad - GLn,
+        sum_hess - HLn, num_data - CLn, sum_grad, sum_hess, l1, l2,
+        min_data_in_leaf, min_sum_hessian_in_leaf, min_gain_to_split)
+    dl = gain_n > total_gain
+    return (jnp.where(dl, gain_n, total_gain), jnp.where(dl, GLn, GL),
+            jnp.where(dl, HLn, HL), jnp.where(dl, CLn, CL), dl)
 
 
 def _candidate_gains(cand, GL, HL, CL, GR, HR, CR, sum_grad, sum_hess,
@@ -337,7 +389,8 @@ def _split_record(bg, bf, bt, glb, hlb, clb, sum_grad, sum_hess, num_data,
                      "min_sum_hessian_in_leaf", "min_gain_to_split"))
 def best_split(hist: jax.Array, num_bins: jax.Array, is_cat: jax.Array,
                feature_mask: jax.Array, sum_grad: jax.Array,
-               sum_hess: jax.Array, num_data: jax.Array, *,
+               sum_hess: jax.Array, num_data: jax.Array,
+               missing_bins=None, *,
                lambda_l1: float = 0.0, lambda_l2: float = 0.0,
                min_data_in_leaf: int = 20,
                min_sum_hessian_in_leaf: float = 1e-3,
@@ -349,12 +402,13 @@ def best_split(hist: jax.Array, num_bins: jax.Array, is_cat: jax.Array,
     is_cat : [F] bool
     feature_mask : [F] bool (feature_fraction subset for this tree)
     sum_grad/sum_hess/num_data : leaf totals (host-accurate scalars)
+    missing_bins : [F] int32 NaN bin per feature, -1 none; or None
     """
     F, _, B = hist.shape
     l1, l2 = lambda_l1, lambda_l2
-    total_gain, GL, HL, CL = split_gain_matrix(
+    total_gain, GL, HL, CL, DL = split_gain_matrix(
         hist, num_bins, is_cat, feature_mask, sum_grad, sum_hess, num_data,
-        lambda_l1=lambda_l1, lambda_l2=lambda_l2,
+        missing_bins, lambda_l1=lambda_l1, lambda_l2=lambda_l2,
         min_data_in_leaf=min_data_in_leaf,
         min_sum_hessian_in_leaf=min_sum_hessian_in_leaf,
         min_gain_to_split=min_gain_to_split)
@@ -366,8 +420,11 @@ def best_split(hist: jax.Array, num_bins: jax.Array, is_cat: jax.Array,
     bt = (best % B).astype(jnp.int32)
     bg = flat[best]
     glb, hlb, clb = GL.reshape(-1)[best], HL.reshape(-1)[best], CL.reshape(-1)[best]
-    return _split_record(bg, bf, bt, glb, hlb, clb, sum_grad, sum_hess,
-                         num_data, gain_shift, l1, l2)
+    rec = _split_record(bg, bf, bt, glb, hlb, clb, sum_grad, sum_hess,
+                        num_data, gain_shift, l1, l2)
+    if DL is not None:
+        rec = rec._replace(default_left=DL.reshape(-1)[best])
+    return rec
 
 
 class StoreSearch(NamedTuple):
@@ -380,6 +437,7 @@ class StoreSearch(NamedTuple):
     suffix: jax.Array    # [C, B] bool
     mask: jax.Array      # [C, B] bool: a candidate, its feature in the tree
     member: jax.Array    # [C, B, B] f32 0/1: cell s' is in cell s's sum
+    nan: Optional[jax.Array] = None   # [C] int32 StoreCells.nan, or None
 
 
 def store_search_operands(cells: StoreCells, feature_mask: jax.Array,
@@ -389,7 +447,8 @@ def store_search_operands(cells: StoreCells, feature_mask: jax.Array,
     member lies whole in one column) under the tree's feature_mask [F]
     over ORIGINAL features.  Once a tree, not once a searched slot."""
     size = size or cells.feat.shape[0]
-    feat, thr, suffix, lo, hi = (
+    feat, thr, suffix, lo, hi, nan = (
+        None if t is None else
         jax.lax.dynamic_slice_in_dim(jnp.asarray(t), start, size)
         for t in cells)
     mask = (feat >= 0) & jnp.asarray(feature_mask)[jnp.maximum(feat, 0)]
@@ -397,7 +456,7 @@ def store_search_operands(cells: StoreCells, feature_mask: jax.Array,
     sp = jax.lax.broadcasted_iota(jnp.int32, (1, B, 1), 1)
     member = (sp >= lo[:, None, :]) & (sp <= hi[:, None, :])
     return StoreSearch(feat, thr, suffix, mask,
-                       member.astype(jnp.float32))
+                       member.astype(jnp.float32), nan)
 
 
 def _member_sums(hist: jax.Array, member: jax.Array) -> jax.Array:
@@ -439,7 +498,13 @@ def best_split_in_store(hist: jax.Array, search: StoreSearch,
     other's differ by the order of a float32 sum.  The winner is the
     maximum gain, then the smallest original feature, then the smallest
     threshold — the flat argmax over [F, B]; cells of a column are not
-    in feature order, so it takes two reductions here."""
+    in feature order, so it takes two reductions here.
+
+    A column with a NaN bin (`search.nan`: one numerical feature, its
+    prefix cells its value thresholds) scores each threshold again with
+    that bin's sums on the left, as split_gain_matrix, and the record
+    carries `default_left`; without `search.nan` nothing of it is
+    traced."""
     B = hist.shape[-1]
     l1, l2 = lambda_l1, lambda_l2
     feat, thr, suffix = search.feat, search.thr, search.suffix
@@ -453,6 +518,25 @@ def best_split_in_store(hist: jax.Array, search: StoreSearch,
     total_gain = _candidate_gains(
         search.mask, GL, HL, CL, GR, HR, CR, sum_grad, sum_hess, l1, l2,
         min_data_in_leaf, min_sum_hessian_in_leaf, min_gain_to_split)
+    DL = None
+    if search.nan is not None:
+        # the NaN bin on the left: value thresholds t in [0, nb-3]
+        nan = search.nan[:, None]
+        at = jnp.maximum(nan, 0)[:, None, :]                # [C, 1, 1]
+        nan_sums = jnp.take_along_axis(hist, jnp.broadcast_to(
+            at, (hist.shape[0], 3, 1)), axis=2)             # [C, 3, 1]
+        GLn = GL + nan_sums[:, 0, :]
+        HLn = HL + nan_sums[:, 1, :]
+        CLn = CL + nan_sums[:, 2, :]
+        gain_n = _candidate_gains(
+            search.mask & (nan >= 0) & (thr < nan - 1), GLn, HLn, CLn,
+            sum_grad - GLn, sum_hess - HLn, num_data - CLn, sum_grad,
+            sum_hess, l1, l2, min_data_in_leaf, min_sum_hessian_in_leaf,
+            min_gain_to_split)
+        DL = gain_n > total_gain
+        total_gain = jnp.where(DL, gain_n, total_gain)
+        GL, HL, CL = (jnp.where(DL, GLn, GL), jnp.where(DL, HLn, HL),
+                      jnp.where(DL, CLn, CL))
 
     flat = total_gain.reshape(-1)
     bg = jnp.max(flat)
@@ -463,6 +547,9 @@ def best_split_in_store(hist: jax.Array, search: StoreSearch,
     bf = jnp.maximum(feat.reshape(-1)[best], 0)
     bt = thr.reshape(-1)[best]
     glb, hlb, clb = GL.reshape(-1)[best], HL.reshape(-1)[best], CL.reshape(-1)[best]
-    return _split_record(bg, bf, bt, glb, hlb, clb, sum_grad, sum_hess,
-                         num_data, leaf_split_gain(sum_grad, sum_hess, l1, l2),
-                         l1, l2)
+    rec = _split_record(bg, bf, bt, glb, hlb, clb, sum_grad, sum_hess,
+                        num_data, leaf_split_gain(sum_grad, sum_hess, l1, l2),
+                        l1, l2)
+    if DL is not None:
+        rec = rec._replace(default_left=DL.reshape(-1)[best])
+    return rec

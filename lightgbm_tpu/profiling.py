@@ -164,6 +164,14 @@ SCORE_GATHER_ROWS = "tree/score_gather_rows"
 #    against the rest) in the trees collected.  A tree with none counts
 #    0, which registers the key.
 CATEGORICAL_SPLITS = "tree/categorical_splits"
+#  - MISSING_SPLITS: splits on a feature with a NaN bin (missing type
+#    NaN; binning.BinMapper.nan_bin).  Counted only for a training set
+#    that has such a feature: a program or a store without one has no
+#    key.
+#  - MISSING_DEFAULT_LEFT: those of them that send the NaN bin left
+#    (decision_type's default_left bit).
+MISSING_SPLITS = "tree/missing_splits"
+MISSING_DEFAULT_LEFT = "tree/missing_default_left"
 # Nothing increments these three since the row feed they counted went;
 # they stay, at 0 from the start, only for benchmark/ (jobs/train.py and
 # the feed_rows_per_iter metric read them) until ROADMAP B0.5 drops it.
@@ -292,7 +300,7 @@ CANONICAL_COUNTERS = (
     HIST_LIVE_SLOTS, HIST_MXU_OPS, FEED_ROWS, FEED_LIVE_ROWS,
     PARTITION_ROWS, STORE_COPY_ROWS, EXCHANGE_COLLECTIVES,
     SPLIT_CELLS, UNBUNDLE_GATHER_ELEMS, HIST_PAD_COLUMNS, SCORE_GATHER_ROWS,
-    CATEGORICAL_SPLITS,
+    CATEGORICAL_SPLITS, MISSING_SPLITS, MISSING_DEFAULT_LEFT,
     SPARSE_NNZ_TOUCHED, SPARSE_FALLBACKS,
     REGISTRY_SWAP_FAILURES, SERVE_CHUNK_RETRIES, SERVE_REPLICA_FAILURES,
     SERVE_REPLICA_BROKEN, SERVE_REPLICA_READMITTED, SERVE_REPLICA_PROBES,

@@ -10,8 +10,7 @@ training and serving (ROADMAP item: binned int8 inference):
   (`Dataset.streaming_from` → `append_rows`) both route their row
   chunks through `bin_rows_into` — the TRAIN policy: float64
   searchsorted against the mapper's float64 bounds, NaN mapped to the
-  bin of value 0.0 (the v2.0-era missing convention the histogram
-  kernels train on).
+  mapper's NaN bin where it has one, else to the bin of value 0.0.
 - **Serving ingress** (`serving.PredictorRuntime` with
   ``serve_quantize=binned``) quantizes each request chunk with a
   `FeatureQuantizer` — the SERVE policy, engineered to be
@@ -31,10 +30,13 @@ values that straddle a float64 boundary but collapse onto it in f32
 (a float64 searchsorted would misroute those against the f32 kernel).
 Non-finite handling mirrors the raw kernels' decisions exactly:
 
-- NaN quantizes to the MISSING sentinel — one code past every real
-  bin, so it compares greater than any numerical threshold bin and
-  equal to no categorical bin: NaN routes RIGHT everywhere, the raw
-  kernel's ``v <= t -> False`` / finite-mask behavior.
+- NaN in a numerical column quantizes to the column's NaN bin where
+  its mapper has one (the binned walk sends that bin to the split's
+  default side, as the raw kernels send NaN), else to the bin of 0.0
+  (the raw kernels compare NaN as 0.0 there).  NaN in a categorical
+  column quantizes to the MISSING sentinel — one code past every real
+  bin, equal to no categorical bin: it routes RIGHT, as the raw
+  kernel's finite mask sends it.
 - +/-inf land on the last/first real bin (the raw compare's outcome).
 - A finite category absent from the mapper's table quantizes to the
   sentinel too: the raw categorical compare (int truncation behind a
@@ -98,6 +100,11 @@ def bin_rows_into(X: np.ndarray, mappers: Sequence[BinMapper],
             for j, k in enumerate(num_ks):
                 c = k if plan is None else int(plan.feat_col[k])
                 store[c, sl] = out[j]
+                nb = mappers[used_features[k]].nan_bin
+                if nb >= 0:
+                    # the native binner reads NaN as 0.0; a feature with
+                    # a NaN bin takes it here
+                    store[c, sl][np.isnan(X[:, used_features[k]])] = nb
             done = set(num_ks)
     for k, i in enumerate(used_features):
         if k in done:
@@ -220,7 +227,7 @@ class FeatureQuantizer:
     __slots__ = ("used_features", "num_total_features", "num_columns",
                  "dtype", "missing_bin", "_numeric", "_tables",
                  "_num_ks", "_num_raw", "_num_uppers64", "_grids",
-                 "_use_native")
+                 "_use_native", "_nan_codes")
 
     def __init__(self, mappers: Sequence[BinMapper],
                  used_features: Sequence[int]):
@@ -267,6 +274,15 @@ class FeatureQuantizer:
         self._num_raw = [self.used_features[k] for k in self._num_ks]
         self._num_uppers64 = [self._tables[k].astype(np.float64)
                               for k in self._num_ks]
+        # NaN's code per numeric feature: its NaN bin where the mapper
+        # has one (the split's default side decides, as in the raw
+        # kernels), else the bin of 0.0 (NaN compares as 0.0)
+        self._nan_codes = np.asarray(
+            [mappers[self.used_features[k]].nan_bin
+             if mappers[self.used_features[k]].nan_bin >= 0
+             else np.searchsorted(self._tables[k], np.float32(0.0),
+                                  side="left")
+             for k in self._num_ks], self.dtype)
         # probe native availability ONCE: quantize() must not pay the
         # f64 staging copy of every chunk just to learn the library was
         # never built (the common pure-Python install)
@@ -294,9 +310,8 @@ class FeatureQuantizer:
                 from .native import bin_numerical_native
                 sub64 = sub32.astype(np.float64)
                 if nanmask.any():
-                    # +inf lands in the last real bin — the native
-                    # binner's NaN→0.0 convention must not fire; the
-                    # sentinel overwrites these positions below
+                    # +inf lands in the last real bin; NaN's own code
+                    # overwrites these positions below
                     sub64[nanmask] = np.inf
                 native_bins = bin_numerical_native(
                     np.ascontiguousarray(sub64),
@@ -317,7 +332,7 @@ class FeatureQuantizer:
                             self._tables[k], sub32[:, j], side="left")
             if nanmask.any():
                 out[:, self._num_ks] = np.where(
-                    nanmask, self.dtype(miss), out[:, self._num_ks])
+                    nanmask, self._nan_codes[None, :], out[:, self._num_ks])
         # ---- categorical columns --------------------------------------
         for k, i in enumerate(self.used_features):
             if self._numeric[k]:

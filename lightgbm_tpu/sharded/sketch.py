@@ -75,9 +75,9 @@ def sketch_capacity(eps: float) -> int:
 class QuantileSketch:
     """Mergeable weighted quantile summary of ONE feature's non-zero,
     non-NaN sample values (zeros are implied by the row count, exactly
-    like binning._distinct_with_zero)."""
+    like binning._distinct_with_zero), and the count of its NaN."""
 
-    __slots__ = ("eps", "capacity", "vals", "counts", "res", "fuzz")
+    __slots__ = ("eps", "capacity", "vals", "counts", "res", "fuzz", "nan")
 
     def __init__(self, eps: float, capacity: int = 0):
         self.eps = float(eps)
@@ -86,6 +86,7 @@ class QuantileSketch:
         self.counts = np.zeros(0, np.float64)
         self.res = 0.0    # value-resolution rank error (widest bucket)
         self.fuzz = 0.0   # cross-sketch attribution error
+        self.nan = 0.0    # NaN values absorbed (exact)
 
     @property
     def total(self) -> float:
@@ -107,7 +108,9 @@ class QuantileSketch:
         """Absorb a batch of raw values (NaN filtered here; zero/total
         bookkeeping is the caller's, matching find_bin's contract)."""
         values = np.asarray(values, np.float64)
-        values = values[~np.isnan(values)]
+        nan = np.isnan(values)
+        self.nan += float(nan.sum())
+        values = values[~nan]
         if values.size == 0:
             return
         nv, nc = np.unique(values, return_counts=True)
@@ -115,6 +118,7 @@ class QuantileSketch:
 
     def merge(self, other: "QuantileSketch") -> None:
         """Merge another sketch (disjoint data) into this one."""
+        self.nan += other.nan
         if other.vals.size == 0:
             return
         if self.vals.size == 0:
@@ -161,7 +165,7 @@ class QuantileSketch:
 
     # -- fixed-width serialization (allgather transport) ----------------
 
-    WIDTH_SCALARS = 4                       # n_entries, total, res, fuzz
+    WIDTH_SCALARS = 5               # n_entries, total, res, fuzz, nan
 
     def pack_width(self) -> int:
         return 2 * self.capacity + self.WIDTH_SCALARS
@@ -176,6 +180,7 @@ class QuantileSketch:
         out[1] = self.total
         out[2] = self.res
         out[3] = self.fuzz
+        out[4] = self.nan
         s = self.WIDTH_SCALARS
         out[s:s + n] = self.vals
         out[s + self.capacity:s + self.capacity + n] = self.counts
@@ -188,6 +193,7 @@ class QuantileSketch:
         n = int(arr[0])
         sk.res = float(arr[2])
         sk.fuzz = float(arr[3])
+        sk.nan = float(arr[4])
         s = cls.WIDTH_SCALARS
         sk.vals = np.asarray(arr[s:s + n], np.float64).copy()
         sk.counts = np.asarray(
@@ -333,15 +339,17 @@ class SketchSet:
     # -- mappers ---------------------------------------------------------
 
     def mappers(self, max_bin: int, min_data_in_bin: int,
-                min_split_data: int, bin_budget: int = 0
-                ) -> List[BinMapper]:
+                min_split_data: int, bin_budget: int = 0,
+                use_missing: bool = False) -> List[BinMapper]:
         """Derive the BinMappers — the exact find_bin greedy run on the
         (merged) summaries, zero injected from the row count exactly
         like binning._distinct_with_zero.  ``bin_budget > 0`` applies
         the adaptive per-feature allocation (binning.
         allocate_bin_budgets) with distinct/mass counts read off the
         summaries themselves — the sketch-side analog of the exact
-        sample path's column stats."""
+        sample path's column stats.  ``use_missing``: a numerical
+        feature that absorbed NaN gets a NaN bin (binning.
+        find_bin_mappers), its NaN no longer counted as zeros."""
         budgets = None
         if bin_budget > 0 and self.sketches:
             from ..binning import allocate_bin_budgets
@@ -367,8 +375,10 @@ class SketchSet:
             # counts are exact integers carried in f64 (< 2^53)
             counts = np.rint(sk.counts).astype(np.int64)
             nonzero = int(counts.sum())
+            na = (int(round(sk.nan)) if use_missing and bt == NUMERICAL
+                  and sk.nan > 0 else -1)
             zero_cnt = max(total - nonzero - int(round(
-                getattr(sk, "dropped", 0.0))), 0)
+                getattr(sk, "dropped", 0.0))) - max(na, 0), 0)
             if vals.size == 0:
                 vals = np.array([0.0])
                 counts = np.array([max(zero_cnt, 1)], np.int64)
@@ -384,13 +394,14 @@ class SketchSet:
             mb = int(budgets[j]) if budgets is not None else max_bin
             out.append(find_bin_from_distinct(
                 vals, counts, total, mb, min_data_in_bin,
-                min_split_data, bt))
+                min_split_data, bt, na_cnt=na))
         return out
 
     def mappers_from_config(self, cfg) -> List[BinMapper]:
         return self.mappers(cfg.max_bin, cfg.min_data_in_bin,
                             cfg.min_data_in_leaf,
-                            bin_budget=int(getattr(cfg, "bin_budget", 0)))
+                            bin_budget=int(getattr(cfg, "bin_budget", 0)),
+                            use_missing=cfg.use_missing)
 
     # -- wire format -----------------------------------------------------
 

@@ -23,6 +23,51 @@ K_MAX_TREE_OUTPUT = 100.0  # reference tree.h kMaxTreeOutput
 
 NUMERICAL_DECISION = 0
 CATEGORICAL_DECISION = 1
+# decision_type bits, as the reference writes them (tree.h
+# kCategoricalMask, kDefaultLeftMask; the missing type in bits 2-3)
+DEFAULT_LEFT_MASK = 2
+MISSING_NAN_DECISION = 2 << 2          # binning.MISSING_NAN in bits 2-3
+
+
+def is_categorical(dt) -> np.ndarray:
+    return (np.asarray(dt, np.int64) & CATEGORICAL_DECISION) != 0
+
+
+def is_missing_nan(dt) -> np.ndarray:
+    """Splits of missing type NaN (bits 2-3 of decision_type)."""
+    return (np.asarray(dt, np.int64) & (3 << 2)) == MISSING_NAN_DECISION
+
+
+def nan_goes_left(dt, threshold) -> np.ndarray:
+    """Where each split sends a raw NaN, the reference's rule: a
+    numerical split of missing type NaN to its default side, one of
+    missing type None as 0.0 (left where 0.0 <= threshold), a
+    categorical split right."""
+    dt = np.asarray(dt, np.int64)
+    left = np.where(is_missing_nan(dt), (dt & DEFAULT_LEFT_MASK) != 0,
+                    np.asarray(threshold, np.float64) >= 0.0)
+    return left & ~is_categorical(dt)
+
+
+def raw_lanes(dt, threshold) -> np.ndarray:
+    """The decision lane of the raw device predictors (ops/predict.py):
+    1 categorical, 2 numerical sending NaN left, 0 numerical sending it
+    right."""
+    return np.where(is_categorical(dt), 1,
+                    np.where(nan_goes_left(dt, threshold), 2, 0)
+                    ).astype(np.int32)
+
+
+def binned_lanes(dt, threshold, missing_bin) -> np.ndarray:
+    """The decision lane of the binned walks, over bins of the dataset a
+    tree is binned against: 1 categorical; -1 - b for a numerical split
+    that sends NaN left on a feature whose NaN bin is b (its rows go
+    left, whatever the threshold); else 0 (`bin <= threshold` — NaN
+    binned as 0.0, or NaN's bin, above every threshold, going right)."""
+    mb = np.asarray(missing_bin, np.int64)
+    left = nan_goes_left(dt, threshold) & (mb >= 0)
+    return np.where(is_categorical(dt), 1,
+                    np.where(left, -1 - mb, 0)).astype(np.int32)
 
 
 def _arr_to_str(a, fmt="{:g}") -> str:
@@ -41,6 +86,9 @@ class Tree:
         self.threshold_in_bin = np.zeros(m - 1, np.int64)
         self.threshold = np.zeros(m - 1, np.float64)
         self.decision_type = np.zeros(m - 1, np.int8)
+        # the NaN bin of each split's feature in the dataset the tree is
+        # binned against, -1 where it has none (binned_lanes)
+        self.missing_bin = np.full(m - 1, -1, np.int32)
         self.split_gain = np.zeros(m - 1, np.float64)
         self.leaf_parent = np.full(m, -1, np.int32)
         self.leaf_value = np.zeros(m, np.float64)
@@ -57,7 +105,11 @@ class Tree:
     def split(self, leaf: int, inner_feature: int, bin_type: int,
               threshold_bin: int, real_feature: int, threshold_double: float,
               left_value: float, right_value: float, left_cnt: int,
-              right_cnt: int, gain: float) -> int:
+              right_cnt: int, gain: float, default_left: bool = False,
+              missing_bin: int = -1) -> int:
+        """`missing_bin` >= 0: a numerical split on a feature with that
+        NaN bin (missing type NaN), whose NaN rows go left where
+        `default_left`."""
         new_node = self.num_leaves - 1
         parent = self.leaf_parent[leaf]
         if parent >= 0:
@@ -68,7 +120,11 @@ class Tree:
         self.split_feature_inner[new_node] = inner_feature
         self.split_feature[new_node] = real_feature
         if bin_type == NUMERICAL_DECISION:
-            self.decision_type[new_node] = 0
+            self.decision_type[new_node] = (
+                (MISSING_NAN_DECISION
+                 | (DEFAULT_LEFT_MASK if default_left else 0))
+                if missing_bin >= 0 else 0)
+            self.missing_bin[new_node] = missing_bin
         else:
             self.decision_type[new_node] = 1
             self.has_categorical = True
@@ -133,11 +189,16 @@ class Tree:
             # bin — the reference has the same train/predict asymmetry
             # (its raw predict casts NaN with static_cast<int>, tree.h:
             # 217-241, never matching a category); we emulate it rather
-            # than diverge from reference predictions on NaN rows
+            # than diverge from reference predictions on NaN rows.  A
+            # numerical split sends NaN by nan_goes_left
             finite = np.isfinite(v)
             vi = np.where(finite, v, -1.0).astype(np.int64)
-            go_left = np.where(dec == 0, v <= thr,
-                               finite & (vi == thr.astype(np.int64)))
+            # a numerical threshold may be +inf (every value left, NaN
+            # right): no category, and no int64 cast of it
+            ti = np.where(np.isfinite(thr), thr, -2.0).astype(np.int64)
+            num = np.where(np.isnan(v), nan_goes_left(dec, thr), v <= thr)
+            go_left = np.where(is_categorical(dec), finite & (vi == ti),
+                               num)
             nxt = np.where(go_left, self.left_child[node[active]],
                            self.right_child[node[active]])
             node[active] = nxt
@@ -159,8 +220,9 @@ class Tree:
             # the boosting loop.  Padding slots are unreachable from the
             # root walk, so their (zero) contents never matter.
             n = max(self.max_leaves - 1, 1)
-            binned_dec = getattr(self, "binned_decision_type",
-                                 self.decision_type)
+            binned_dec = binned_lanes(
+                getattr(self, "binned_decision_type", self.decision_type),
+                self.threshold, self.missing_bin)
             # ONE explicit pytree upload (jax.device_put): per-array
             # jnp.asarray was six implicit transfers per new tree inside
             # the boosting loop (sanitizer transfer-guard violations)
@@ -237,7 +299,8 @@ class Tree:
         t.leaf_value[:n] = floats("leaf_value")
         if "decision_type" in kv:
             t.decision_type[: n - 1] = ints("decision_type").astype(np.int8)
-            t.has_categorical = bool((t.decision_type[: n - 1] == 1).any())
+            t.has_categorical = bool(
+                is_categorical(t.decision_type[: n - 1]).any())
         if "leaf_parent" in kv:
             t.leaf_parent[:n] = ints("leaf_parent")
         if "leaf_count" in kv:
@@ -287,18 +350,20 @@ class Tree:
             real = int(self.split_feature[node])
             inner = dataset.real_to_inner(real)
             mapper = dataset.mappers[real]
+            self.missing_bin[node] = -1
             if inner >= 0:
                 self.split_feature_inner[node] = inner
                 self.threshold_in_bin[node] = int(mapper.value_to_bin(
                     np.array([self.threshold[node]]))[0])
                 self.binned_decision_type[node] = self.decision_type[node]
+                self.missing_bin[node] = mapper.nan_bin
             else:
                 # feature filtered as trivial in this dataset: every row
                 # has the same value, so the comparison has one outcome —
                 # encode as an always-left (huge bin) or always-right (-1)
                 # NUMERICAL test on feature 0 (bins are never negative)
                 c = mapper.bin_to_value(0)
-                if self.decision_type[node] == CATEGORICAL_DECISION:
+                if is_categorical(self.decision_type[node]):
                     left = c == self.threshold[node]
                 else:
                     left = c <= self.threshold[node]
@@ -318,8 +383,12 @@ class Tree:
                     "threshold": float(self.threshold[index]),
                     # reference names (tree.h GetDecisionTypeName):
                     # numerical "no_greater", categorical "is"
-                    "decision_type": ("is" if self.decision_type[index] == 1
-                                      else "no_greater"),
+                    "decision_type": ("is" if is_categorical(
+                        self.decision_type[index]) else "no_greater"),
+                    "default_left": bool(self.decision_type[index]
+                                         & DEFAULT_LEFT_MASK),
+                    "missing_type": ("NaN" if is_missing_nan(
+                        self.decision_type[index]) else "None"),
                     "internal_value": float(self.internal_value[index]),
                     "internal_count": int(self.internal_count[index]),
                     "left_child": node_json(int(self.left_child[index])),

@@ -133,9 +133,11 @@ def test_dump_model_field_parity(model):
                                  "leaf_count"}, set(node)
             return
         assert set(node) == {"split_index", "split_feature", "split_gain",
-                             "threshold", "decision_type", "internal_value",
+                             "threshold", "decision_type", "default_left",
+                             "missing_type", "internal_value",
                              "internal_count", "left_child",
                              "right_child"}, set(node)
+        assert node["missing_type"] in ("None", "NaN")
         # reference decision-type names (tree.h GetDecisionTypeName)
         assert node["decision_type"] in ("no_greater", "is")
         walk(node["left_child"], depth + 1)

@@ -546,15 +546,17 @@ def _store_hists(plan, Cpad, B, rows, leaves, rng, dyadic):
     return out.astype(np.float32), sums.astype(np.float32)
 
 
-def _both_searches(plan, nb, cat, hists, sums, B, Cpad, fmask=None, **kw):
-    """Packed [leaves, 11] records: (store cells, gathered oracle)."""
+def _both_searches(plan, nb, cat, hists, sums, B, Cpad, fmask=None,
+                   missing_bins=None, **kw):
+    """Packed [leaves, 11] records ([leaves, 12] with missing_bins):
+    (store cells, gathered oracle)."""
     import jax
     import jax.numpy as jnp
     from lightgbm_tpu.ops.split import (best_split, best_split_in_store,
                                         store_search_operands,
                                         unbundle_hist)
     fmask = np.ones(len(nb), bool) if fmask is None else fmask
-    cells = plan.search_tables(nb, cat, B, Cpad)
+    cells = plan.search_tables(nb, cat, B, Cpad, missing_bins)
     src, dmask = plan.unbundle_tables(nb, B, Cpad)
     search = store_search_operands(cells, fmask)
 
@@ -565,7 +567,7 @@ def _both_searches(plan, nb, cat, hists, sums, B, Cpad, fmask=None, **kw):
     def oracle(h, s):
         return best_split(unbundle_hist(h, src, dmask, s), jnp.asarray(nb),
                           jnp.asarray(cat), jnp.asarray(fmask),
-                          s[0], s[1], s[2], **kw).packed()
+                          s[0], s[1], s[2], missing_bins, **kw).packed()
     hists, sums = jnp.asarray(hists), jnp.asarray(sums)
     return (np.asarray(jax.vmap(store)(hists, sums)),
             np.asarray(jax.vmap(oracle)(hists, sums)))
@@ -628,7 +630,7 @@ SEARCH_KW = dict(lambda_l1=0.0, lambda_l2=0.0, min_data_in_leaf=1,
 
 SEARCH_CASES = ["defaults_anywhere", "padded_columns", "feature_mask",
                 "min_data_binds", "min_hessian_binds", "l1_l2_min_gain",
-                "float_sums"]
+                "float_sums", "nan_bins"]
 
 
 @pytest.mark.parametrize("case", SEARCH_CASES)
@@ -643,8 +645,16 @@ def test_store_search_is_the_gathered_search(case):
     for _ in range(3):
         plan, nb, cat = _random_plan(rng, B)
         Cpad = plan.num_columns + (3 if case == "padded_columns" else 0)
-        kw, fmask = dict(SEARCH_KW), None
-        if case == "feature_mask":
+        kw, fmask, mb = dict(SEARCH_KW), None, None
+        if case == "nan_bins":
+            # the numerical column of its own holds a NaN bin, its last;
+            # only its feature searched, so every record is its best side
+            k = next(k for k in range(len(nb))
+                     if not plan.feat_packed[k] and not cat[k])
+            mb = np.full(len(nb), -1, np.int32)
+            mb[k] = nb[k] - 1
+            fmask = np.arange(len(nb)) == k
+        elif case == "feature_mask":
             fmask = rng.random(len(nb)) < 0.5
             fmask[rng.integers(len(nb))] = True
         elif case == "min_data_binds":
@@ -656,11 +666,13 @@ def test_store_search_is_the_gathered_search(case):
         hists, sums = _store_hists(plan, Cpad, B, 2000, 8, rng,
                                    dyadic=case != "float_sums")
         got, want = _both_searches(plan, nb, cat, hists, sums, B, Cpad,
-                                   fmask, **kw)
+                                   fmask, mb, **kw)
         _assert_same_records(got, want, steps=8 if case == "float_sums"
                              else 0)
         if fmask is not None:
             assert fmask[want[:, 1].astype(int)].all()
+        if mb is not None:              # both sides of the NaN bin won
+            assert set(want[:, 11]) == {0.0, 1.0}
 
 
 @pytest.mark.parametrize("default_bin", [0, 1])

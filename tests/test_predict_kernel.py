@@ -79,7 +79,7 @@ def test_dyadic_bitwise_parity_both_layouts():
     rng = np.random.RandomState(0)
     F = 12
     X = rng.rand(513, F).astype(np.float32)
-    X[5, 3] = np.nan                   # NaN falls right in both kernels
+    X[5, 3] = np.nan                   # NaN compares as 0.0 in both kernels
     tbc = [[_rand_tree(rng, F, dyadic=True) for _ in range(40)]]
     ref = _walk_raw(tbc, X)
     got_p, st_p = _tens_raw(tbc, X)
@@ -190,12 +190,12 @@ def test_categorical_routes_through_soa_bitwise():
 
 
 def test_nan_routes_right_and_no_dead_lane():
-    """NaN rows route RIGHT in both kernels (``v <= t`` is False; the
-    categorical compare's finite mask matches nothing), and the node
-    record carries exactly the five live lanes — the never-populated
-    ``default_left`` lane PR 7 reserved is deleted (binned serving
-    derives missing routing from the quantizer's sentinel bin
-    instead; tests/test_serve_binned.py)."""
+    """NaN rows route as the reference routes them in both kernels: a
+    numerical split of missing type None compares NaN as 0.0 (so here,
+    thresholds in [0, 1), every split sends it LEFT, as the host walk
+    does), and the node record carries exactly the five live lanes —
+    no ``default_left`` lane: the NaN side rides in the decision lane
+    (tree.raw_lanes)."""
     from lightgbm_tpu.ops.predict import _LANES, EnsembleMeta
     assert _LANES == 5
     assert "any_default_left" not in EnsembleMeta._fields
@@ -208,15 +208,18 @@ def test_nan_routes_right_and_no_dead_lane():
     assert st.nodes.shape[-1] == 5
     ref = _walk_raw([[t]], X)
     assert np.array_equal(ref, got)
-    # all-NaN rows land on the rightmost leaf (every compare fails)
+    # all-NaN rows land on the leftmost leaf (0.0 <= every threshold),
+    # where the host walk puts them and where rows of zeros go
     node = 0
     while True:
-        nxt = int(t.right_child[node])
+        nxt = int(t.left_child[node])
         if nxt < 0:
-            rightmost = ~nxt
+            leftmost = ~nxt
             break
         node = nxt
-    assert np.allclose(got[0][10:], t.leaf_value[rightmost])
+    assert np.allclose(got[0][10:], t.leaf_value[leftmost])
+    assert np.allclose(got[0][10:], t.predict_raw(
+        np.where(np.isnan(X[10:]), 0.0, X[10:]).astype(np.float64)))
 
 
 def test_deep_ensemble_over_budget_uses_soa(monkeypatch):

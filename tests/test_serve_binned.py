@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 import lightgbm_tpu as lgb
-from lightgbm_tpu.binning import BinMapper, CATEGORICAL, NUMERICAL
+from lightgbm_tpu.binning import BinMapper, CATEGORICAL, MISSING_NAN, NUMERICAL
 from lightgbm_tpu.log import LightGBMError
 from lightgbm_tpu.quantize import (FeatureQuantizer, file_sha1,
                                    load_refbin, rebin_models_for_serving)
@@ -77,13 +77,21 @@ def test_quantizer_matches_f32_compare_at_f64_boundaries():
 def test_quantizer_nan_inf_sentinel():
     m = _num_mapper([0.25, 0.5, 0.75, np.inf])
     q = FeatureQuantizer([m], [0])
-    b = q.quantize(np.array([[np.nan], [np.inf], [-np.inf], [0.6]]))
+    b = q.quantize(np.array([[np.nan], [np.inf], [-np.inf], [0.6],
+                             [0.0]]))
     assert b.dtype == np.uint8
-    assert b[0, 0] == q.missing_bin                  # NaN -> sentinel
+    # NaN -> the bin of 0.0: a numerical split of missing type None
+    # compares NaN as 0.0 in the raw kernels
+    assert b[0, 0] == b[4, 0] == 0
     assert b[1, 0] == m.num_bin - 1                  # +inf -> last bin
     assert b[2, 0] == 0                              # -inf -> first bin
-    # sentinel exceeds every possible threshold bin: routes right
+    # the sentinel (unseen categories) exceeds every threshold bin
     assert q.missing_bin > m.num_bin - 1
+    # a mapper with a NaN bin: NaN -> that bin, values as before
+    m.missing_type, m.num_bin = MISSING_NAN, m.num_bin + 1
+    q = FeatureQuantizer([m], [0])
+    b = q.quantize(np.array([[np.nan], [np.inf], [0.6]]))
+    assert b[:, 0].tolist() == [m.nan_bin, m.nan_bin - 1, 2]
 
 
 def test_quantizer_unseen_category_sentinel():
@@ -105,7 +113,7 @@ def test_quantizer_dtype_widens_past_255_bins():
     q = FeatureQuantizer([m], [0])
     assert q.dtype == np.uint16 and q.missing_bin == 0xFFFF
     b = q.quantize(np.array([[250.5], [np.nan]]))
-    assert b[0, 0] == 251 and b[1, 0] == 0xFFFF
+    assert b[0, 0] == 251 and b[1, 0] == 0       # NaN -> the bin of 0.0
 
 
 def test_grid_quantizer_matches_searchsorted_adversarially():

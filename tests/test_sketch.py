@@ -20,7 +20,8 @@ def _higgs(n, f=8, seed=42):
 def test_exact_mode_mappers_bitwise():
     """While capacity holds every distinct value, the sketch IS the
     exact distinct summary — mappers must be bitwise the direct ones,
-    including zero injection, NaN-as-zero, categorical and trivial
+    including zero injection, NaN as a bin of its own (use_missing) and
+    NaN-as-zero (use_missing=false), categorical and trivial
     features."""
     rng = np.random.RandomState(0)
     n = 4000
@@ -29,15 +30,22 @@ def test_exact_mode_mappers_bitwise():
     X[:, 2] = np.where(rng.rand(n) < 0.8, 0.0, X[:, 2])   # sparse
     X[::7, 3] = np.nan
     X[:, 4] = rng.randint(0, 12, n)                        # categorical
-    cfg = Config()
-    ss = sketch_columns(X, cfg, categorical=[4], min_capacity_rows=n)
-    assert ss.exact and ss.err_bound() == 0.0
-    got = ss.mappers_from_config(cfg)
-    want = find_bin_mappers(X, cfg.max_bin, cfg.min_data_in_bin,
-                            cfg.min_data_in_leaf, categorical=[4],
-                            sample_cnt=n)
+    for use_missing in (True, False):
+        cfg = Config(use_missing=use_missing)
+        ss = sketch_columns(X, cfg, categorical=[4], min_capacity_rows=n)
+        assert ss.exact and ss.err_bound() == 0.0
+        got = ss.mappers_from_config(cfg)
+        want = find_bin_mappers(X, cfg.max_bin, cfg.min_data_in_bin,
+                                cfg.min_data_in_leaf, categorical=[4],
+                                sample_cnt=n, use_missing=use_missing)
+        assert (got[3].nan_bin >= 0) == use_missing
+        _assert_mappers_equal(got, want)
+
+
+def _assert_mappers_equal(got, want):
     for g, w in zip(got, want):
         assert g.bin_type == w.bin_type
+        assert g.missing_type == w.missing_type
         assert g.num_bin == w.num_bin
         assert g.is_trivial == w.is_trivial
         assert g.default_bin == w.default_bin
